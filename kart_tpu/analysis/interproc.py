@@ -468,7 +468,7 @@ _TRACE_WRAPPERS = frozenset({"jit", "pmap", "lazy_jit", "vmap"})
 def _is_trace_wrapper(func_expr):
     """Does calling this expression trace its function argument?  Covers
     ``jax.jit`` / ``jax.pmap`` / ``lazy_jit`` and any ``shard_map``-shaped
-    callable, including the repo's ``_shard_map()(fn, ...)`` indirection."""
+    callable."""
     d = dotted_name(func_expr)
     if d is not None:
         leaf = d.rsplit(".", 1)[-1]
@@ -479,7 +479,7 @@ def _is_trace_wrapper(func_expr):
 def traced_functions(summary):
     """FunctionInfos in this file that jax traces: ``@jax.jit``-style
     decorators, ``lazy_jit(fn)`` / ``jax.pmap(fn)`` wrapping, and
-    ``shard_map(...)(fn)`` / ``_shard_map()(fn, ...)`` bodies. A name
+    ``shard_map(...)(fn)`` / ``jax.shard_map(fn, ...)`` bodies. A name
     passed to a wrapper resolves to the def sharing the wrapper call's
     enclosing function (several factories nest their own ``_step``)."""
     by_name = {}

@@ -92,7 +92,6 @@ ENV_VARS = {
     "KART_JAX_REPROBE": "source",
     "KART_NO_XLA_CACHE": "source",
     "KART_PROBE_CACHE": "source",
-    "KART_INSULATE_CPU": "source",
     "KART_TESTS_ON_TPU": "tests",
     # native library
     "KART_TPU_NATIVE_LIB": "source",

@@ -212,14 +212,6 @@ def pack_geom_pairs(col_a, ia, col_b, ib):
     }
 
 
-def _shard_map():
-    try:  # jax >= 0.6 exposes shard_map at top level
-        from jax import shard_map  # type: ignore[attr-defined]
-    except ImportError:  # pragma: no cover - version-dependent
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 @functools.lru_cache(maxsize=16)
 def make_batched_classify(mesh, kernel, counts_only=False):
     """Jitted shard_map classify for fixed-shape record-batch rounds.
@@ -255,7 +247,7 @@ def make_batched_classify(mesh, kernel, counts_only=False):
 
     jax.config.update("jax_enable_x64", True)  # int64 keys / PAD_KEY
     spec = P(FEATURES_AXIS)
-    fn = _shard_map()(
+    fn = jax.shard_map(
         _step,
         mesh=mesh,
         in_specs=(spec,) * 6,

@@ -34,10 +34,11 @@ def _lib_path():
 _autobuild_attempted = False
 
 
-def _run_make():
+def _run_make(force=False):
     """Compile the native libraries, serialized across processes with a
     lock file (the Makefile links via temp+rename, so readers never see a
-    half-written .so). Returns True when make reported success."""
+    half-written .so). ``force`` rebuilds even what looks up to date.
+    Returns True when make reported success."""
     makefile_dir = os.path.abspath(_NATIVE_DIR)
     if not os.path.exists(os.path.join(makefile_dir, "Makefile")):
         return False
@@ -48,7 +49,7 @@ def _run_make():
         with open(lock_path, "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
             result = subprocess.run(
-                ["make", "-C", makefile_dir, "-k"],
+                ["make", "-C", makefile_dir, "-k"] + (["-B"] if force else []),
                 capture_output=True,
                 timeout=120,
             )
@@ -160,6 +161,20 @@ def load():
         # AttributeError: a stale/foreign .so without the expected symbols
         L.warning("could not load native lib %s: %s", path, e)
     return _lib
+
+
+def rebuild():
+    """Rebuild both libraries from native/*.cpp whatever .so files are lying
+    in the tree; -> True when make succeeded. For a measurement, which is
+    compared with this engine and must know it was built from the checkout.
+    Call it before the first load: a library already mapped stays the one
+    in use."""
+    global _load_attempted, _io_load_attempted, _autobuild_attempted
+    _autobuild_attempted = True
+    built = _run_make(force=True)
+    _load_attempted = False
+    _io_load_attempted = False
+    return built
 
 
 def ensure_built():

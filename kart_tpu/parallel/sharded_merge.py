@@ -23,7 +23,7 @@ import numpy as np
 
 from kart_tpu.ops.blocks import PAD_KEY, bucket_size
 from kart_tpu.parallel.mesh import FEATURES_AXIS
-from kart_tpu.parallel.sharded_diff import STATS, _repad, _shard_map, partition_block
+from kart_tpu.parallel.sharded_diff import STATS, _repad, partition_block
 
 
 def _sharded_merge_step(
@@ -56,7 +56,7 @@ def make_sharded_merge(mesh):
     from jax.sharding import PartitionSpec as P
 
     spec = P(FEATURES_AXIS)
-    fn = _shard_map()(
+    fn = jax.shard_map(
         _sharded_merge_step,
         mesh=mesh,
         in_specs=(spec,) * 11,

@@ -217,7 +217,7 @@ def synth_repo(path, n, *, edit_frac=0.01, seed=0, blobs="promised",
                ds_path="synth", spatial=False):
     """Create a repo at ``path`` with one int-pk dataset of ``n`` features
     and two commits: the base import and an ``edit_frac`` oid-rewrite.
-    -> (repo, dict with commit oids + edit count).
+    -> (repo, dict with commit oids, edit count and the edited pks).
 
     Blob modes: "real" writes every feature blob; "promised" writes none
     (partial-clone state); "changed" writes real blobs for the edited rows
@@ -335,6 +335,8 @@ def synth_repo(path, n, *, edit_frac=0.01, seed=0, blobs="promised",
         "edit_commit": commits[1],
         "n": n,
         "n_edits": n_edits,
+        # the edited pks, sorted — what a diff of the two commits must name
+        "edit_pks": np.sort(pks[edit_rows]),
     }
 
 

@@ -1,0 +1,241 @@
+"""Ahead-of-time compiles for a described TPU v5e — what only the chip's
+compiler can say, asked of it without a chip.
+
+The suite runs on the CPU backend, where every kernel of the device path
+compiles and agrees with its host twin; what that cannot show is whether
+the *TPU* compiler accepts the same programs (a Pallas block not aligned to
+the tiling, an int64 op with no lowering, a program that does not fit the
+chip's memory). The TPU compiler is installed with jax and compiles for a
+topology that is described, not attached, so these tests lower the device
+programs at their production shapes with ``ShapeDtypeStruct`` arguments and
+compile them. Nothing runs: a pass here is not a chip run (that is
+``chip_smoke.py``).
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may hold the TPU library, the xdist workers each import
+this file, and a call at import time (or in a ``skipif`` / ``parametrize``
+argument) would make the workers collect different tests. Keep every
+TPU-compile test in this one file for the same reason.
+
+The sort-join is the one program whose compile is slow (70 s at a 64 Ki-row
+record batch, ~150 s at the 10M-row bucket, against 2 s at 1024 rows): the
+tier-1 cases compile it at a small bucket and the real widths are marked
+``slow``.
+"""
+
+import numpy as np
+import pytest
+
+from kart_tpu.diff.device_batch import DEVICE_BATCH_ROWS
+from kart_tpu.ops.blocks import bucket_size
+from kart_tpu.parallel.mesh import FEATURES_AXIS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one: the next run
+    # would warn and recompile, so the cache stays off around these tests
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.config.update("jax_enable_x64", True)  # int64 keys, as lazy_jit sets
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", params=[1, 4], ids=["mesh1", "mesh4"])
+def mesh(request, topo):
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(topo.devices[: request.param]), (FEATURES_AXIS,))
+
+
+def _shape(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _sharded(mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return NamedSharding(mesh, P(FEATURES_AXIS)), NamedSharding(mesh, P())
+
+
+def _block_shapes(n, sharding, lead=()):
+    """(keys, oids, count) of one padded block side."""
+    return (
+        _shape(lead + (n,), np.int64, sharding),
+        _shape(lead + (n, 5), np.uint32, sharding),
+        _shape(lead, np.int64, sharding),
+    )
+
+
+@pytest.mark.parametrize("n_envelopes", [65_536, 10_027_008])
+def test_bbox_pallas_compiles(one_chip, n_envelopes):
+    """The Pallas envelope scan at the small grid and at what 10M envelopes
+    pad to (`pad_envelopes`): a real Mosaic kernel, not a jnp route."""
+    import jax
+
+    from kart_tpu.ops.bbox import _bbox_pallas_inner_core
+
+    col = _shape((n_envelopes,), np.float32, one_chip)
+    query = _shape((4,), np.float32, one_chip)
+    with jax.enable_x64(False):  # as bbox_intersects_pallas runs it
+        compiled = (
+            jax.jit(_bbox_pallas_inner_core)
+            .lower(col, col, col, col, query)
+            .compile()
+        )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "bucket",
+    [
+        1024,
+        # what a 10M-row diff pads to, and the streamed path's 8M-row chunk
+        pytest.param(bucket_size(10_000_000), marks=pytest.mark.slow),
+        pytest.param(bucket_size(8_000_000), marks=pytest.mark.slow),
+    ],
+)
+def test_classify_mergesort_compiles(one_chip, bucket):
+    import jax
+
+    from kart_tpu.ops.diff_kernel import _classify_mergesort_core
+
+    ok, oo, oc = _block_shapes(bucket, one_chip)
+    compiled = (
+        jax.jit(_classify_mergesort_core).lower(ok, oo, ok, oo, oc, oc).compile()
+    )
+    mem = compiled.memory_analysis()
+    # arguments + temporaries + outputs must fit one v5e chip's 16 GB
+    assert (
+        mem.argument_size_in_bytes
+        + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes
+    ) < 16e9
+
+
+@pytest.mark.parametrize("bucket", [1024, bucket_size(4_000_000)])
+def test_merge_classify_compiles(one_chip, bucket):
+    """The 3-way classify at a small bucket and at the 4M-row merge the
+    chip smoke runs (searchsorted joins: seconds to compile at any size)."""
+    import jax
+
+    from kart_tpu.ops.merge_kernel import _merge_classify_padded_core
+
+    side = _block_shapes(bucket, one_chip)
+    union = (_shape((bucket,), np.int64, one_chip), side[2])
+    jax.jit(_merge_classify_padded_core).lower(*side * 3, *union).compile()
+
+
+@pytest.mark.parametrize("counts_only", [False, True], ids=["classes", "counts"])
+@pytest.mark.parametrize(
+    "batch_rows",
+    [1024, pytest.param(DEVICE_BATCH_ROWS, marks=pytest.mark.slow)],
+)
+def test_record_batch_classify_compiles(mesh, batch_rows, counts_only):
+    """The sharded backend's shard_map classify (sort kernel, as routing
+    picks on an accelerator) over a one- and a four-device mesh."""
+    from kart_tpu.diff.device_batch import make_batched_classify
+
+    n = int(mesh.devices.size)
+    sharded, _ = _sharded(mesh)
+    side = _block_shapes(batch_rows, sharded, lead=(n,))
+    fn = make_batched_classify(mesh, "sort", counts_only)
+    # arg order: old keys, old oids, new keys, new oids, old count, new count
+    fn.lower(side[0], side[1], side[0], side[1], side[2], side[2]).compile()
+
+
+def test_sharded_merge_compiles(mesh):
+    """`sharded_merge_classify`'s program at the 4M-row merge: block-cyclic
+    shards of 4M/n rows each."""
+    from kart_tpu.parallel.sharded_merge import make_sharded_merge
+
+    n = int(mesh.devices.size)
+    sharded, _ = _sharded(mesh)
+    bucket = bucket_size(-(-4_000_000 // n), 256)
+    keys, oids, _ = _block_shapes(bucket, sharded, lead=(n,))
+    count = _shape((n,), np.int32, sharded)
+    make_sharded_merge(mesh).lower(
+        *(keys, oids, count) * 3, keys, count
+    ).compile()
+
+
+def test_sharded_bbox_compiles(mesh):
+    """`sharded_envelope_hits` over a 10M-envelope sidecar block."""
+    from kart_tpu.diff.backend import _make_sharded_bbox
+
+    n = int(mesh.devices.size)
+    sharded, replicated = _sharded(mesh)
+    col = _shape((n, bucket_size(-(-10_000_000 // n))), np.float32, sharded)
+    query = _shape((4,), np.float32, replicated)
+    _make_sharded_bbox(mesh).lower(col, col, col, col, query).compile()
+
+
+def test_sharded_mercator_compiles(mesh):
+    """`sharded_merc_envelopes` at DEVICE_MIN_ENVELOPES rows: f64
+    sin/log on a chip that emulates f64."""
+    from kart_tpu.diff.backend import _make_sharded_merc
+    from kart_tpu.ops.bbox import DEVICE_MIN_ENVELOPES
+
+    n = int(mesh.devices.size)
+    sharded, _ = _sharded(mesh)
+    col = _shape(
+        (n, bucket_size(-(-DEVICE_MIN_ENVELOPES // n))), np.float64, sharded
+    )
+    _make_sharded_merc(mesh).lower(col, col, col, col).compile()
+
+
+def test_sharded_join_compiles(mesh):
+    """`sharded_join_counts` at one query batch: 64 Ki probe rows against a
+    4096-row build tile."""
+    from kart_tpu.diff.backend import _make_sharded_join
+    from kart_tpu.query.join import TILE_ROWS
+    from kart_tpu.query.scan import DEFAULT_BATCH_ROWS
+
+    n = int(mesh.devices.size)
+    sharded, replicated = _sharded(mesh)
+    probe = _shape(
+        (n, bucket_size(-(-DEFAULT_BATCH_ROWS // n), minimum=256)),
+        np.float32,
+        sharded,
+    )
+    build = _shape((TILE_ROWS,), np.float32, replicated)
+    _make_sharded_join(mesh).lower(*(probe,) * 4, *(build,) * 4).compile()
+
+
+def test_sharded_refine_compiles(mesh):
+    """`sharded_refine_pairs` at one exact-refine round of box polygons
+    (4 segments, padded to the 8-segment bucket): int64 products."""
+    from kart_tpu.diff.backend import _make_sharded_refine
+    from kart_tpu.geom import DEFAULT_GEOM_BATCH_ROWS
+
+    n = int(mesh.devices.size)
+    sharded, _ = _sharded(mesh)
+    per = bucket_size(-(-DEFAULT_GEOM_BATCH_ROWS // n), minimum=64)
+    seg = _shape((n, per, 8), np.int32, sharded)
+    n_seg = _shape((n, per), np.int32, sharded)
+    is_poly = _shape((n, per), np.bool_, sharded)
+    side = (seg,) * 4 + (n_seg,)
+    _make_sharded_refine(mesh).lower(*side, *side, is_poly, is_poly).compile()
