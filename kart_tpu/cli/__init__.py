@@ -164,13 +164,16 @@ def cli(ctx, repo_dir, verbose, trace_flag, reprobe_flag):
     # inherits this root context's trace id, and the wire carries it to
     # the servers (docs/OBSERVABILITY.md §8)
     telemetry.set_root_request(verb=ctx.invoked_subcommand)
-    if ctx.invoked_subcommand:
-        telemetry.incr("cli.commands", cmd=ctx.invoked_subcommand)
+    # the command's root span: every main-thread span descends from it, so
+    # what no span covers is its self time (docs/OBSERVABILITY.md §2)
+    root_span = telemetry.span("cli.command", cmd=ctx.invoked_subcommand)
+    root_span.__enter__()
 
     @ctx.call_on_close
     def _flush_telemetry():
         from kart_tpu.telemetry import sinks
 
+        root_span.__exit__(None, None, None)
         if telemetry.tracing_enabled():
             dropped = telemetry.events_dropped_count()
             path = sinks.write_chrome_trace()

@@ -334,15 +334,16 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
     ):
         for r in range(n_rounds):
             chunk0 = r * n_shards
-            with tm.span("diff.device.transfer", round=r):
-                if transfer_hook is not None:
-                    transfer_hook()
+            with tm.span("diff.device.pack", round=r):
                 ok, oo, oc = pack_round(
                     old_keys, old_oids, old_splits, chunk0, n_shards, batch_rows
                 )
                 nk, no, nc = pack_round(
                     new_keys, new_oids, new_splits, chunk0, n_shards, batch_rows
                 )
+            with tm.span("diff.device.transfer", round=r):
+                if transfer_hook is not None:
+                    transfer_hook()
                 args = [jax.device_put(a, sharding) for a in (ok, oo, nk, no, oc, nc)]
             in_flight.append((fn(*args), chunk0))
             if len(in_flight) >= 2:

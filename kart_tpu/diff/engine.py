@@ -191,6 +191,7 @@ def get_feature_diff_columnar(base_ds, target_ds, ds_filter=None, *, blocks=None
         "diff.classify",
         rows=max(old_block.count, new_block.count),
         backend=backend.name,
+        counts_only=False,
     ):
         old_class, new_class, _ = backend.classify(old_block, new_block)
         old_idx, new_idx = changed_indices(old_class, new_class)
@@ -497,6 +498,7 @@ def get_dataset_feature_count_fast(
         "diff.classify",
         rows=max(old_block.count, new_block.count),
         backend=backend.name,
+        counts_only=True,
     ):
         counts = backend.counts(old_block, new_block)
     return counts["inserts"] + counts["updates"] + counts["deletes"]
@@ -556,6 +558,7 @@ def get_feature_diff_rows(base_rs, target_rs, ds_path):
         "diff.classify",
         rows=max(old_block.count, new_block.count),
         backend=backend.name,
+        counts_only=False,
     ):
         old_class, new_class, _ = backend.classify(old_block, new_block)
         old_idx, new_idx = changed_indices(old_class, new_class)

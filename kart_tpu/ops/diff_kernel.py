@@ -280,29 +280,49 @@ def classify_blocks(old_block, new_block):
     try:
         if n_rows >= STREAM_MIN_ROWS and default_backend() != "cpu":
             return classify_blocks_streamed(old_block, new_block)
-        kernel = (
-            _classify_padded_binsearch
+        import jax
+
+        program, kernel = (
+            ("binsearch", _classify_padded_binsearch)
             if default_backend() == "cpu"
-            else _classify_padded
+            else ("mergesort", _classify_padded)
         )
-        ok, oo = _padded_arrays(old_block)
-        nk, no = _padded_arrays(new_block)
-        old_class, new_class, _, counts = kernel(
-            ok,
-            oo,
-            nk,
-            no,
-            old_block.count,
-            new_block.count,
-        )
+        # the four stages are statements of the program, each under its own
+        # span (docs/DEVICE.md §5). The two block_until_ready calls add no
+        # wait: the kernel cannot start before its arguments have landed,
+        # and np.asarray below would wait for the kernel anyway
+        with tm.span(
+            "diff.device.pack", rows=old_block.count + new_block.count
+        ) as sp:
+            ok, oo = _padded_arrays(old_block)
+            nk, no = _padded_arrays(new_block)
+            host = (ok, oo, nk, no)
+            sources = (
+                old_block.keys, old_block.oids, new_block.keys, new_block.oids
+            )
+            bucket = max(len(ok), len(nk))
+            sp.set(
+                bucket=bucket,
+                bytes=sum(a.nbytes for a, src in zip(host, sources) if a is not src),
+            )
+        with tm.span("diff.device.transfer", bytes=sum(a.nbytes for a in host)):
+            dev = jax.block_until_ready([jax.device_put(a) for a in host])
+        with tm.span("diff.device.kernel", program=program, bucket=bucket):
+            old_class, new_class, _, counts = jax.block_until_ready(
+                kernel(*dev, old_block.count, new_block.count)
+            )
     except Exception as e:
         # device OOM / runtime failure mid-call: the CLI must still complete
         # (north-star scale can exceed a single chip's HBM)
         note_device_fallback("device_classify", e, "host path")
         return classify_blocks_host(old_block, new_block)
-    old_class = np.asarray(old_class)[: old_block.count]
-    new_class = np.asarray(new_class)[: new_block.count]
-    counts = np.asarray(counts)
+    with tm.span("diff.device.fetch") as sp:
+        old_class = np.asarray(old_class)
+        new_class = np.asarray(new_class)
+        counts = np.asarray(counts)
+        sp.set(bytes=old_class.nbytes + new_class.nbytes + counts.nbytes)
+        old_class = old_class[: old_block.count]
+        new_class = new_class[: new_block.count]
     return (
         old_class,
         new_class,
@@ -394,20 +414,35 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     in_flight = deque()
 
     def _drain():
-        out, (olo, ohi), (nlo, nhi) = in_flight.popleft()
+        out, c, (olo, ohi), (nlo, nhi) = in_flight.popleft()
         oc, nc, _, counts = out
-        old_class[olo:ohi] = np.asarray(oc)[: ohi - olo]
-        new_class[nlo:nhi] = np.asarray(nc)[: nhi - nlo]
-        totals[:] += np.asarray(counts)
+        with tm.span("diff.device.fetch", chunk=c) as sp:
+            oc, nc, counts = np.asarray(oc), np.asarray(nc), np.asarray(counts)
+            sp.set(bytes=oc.nbytes + nc.nbytes + counts.nbytes)
+            old_class[olo:ohi] = oc[: ohi - olo]
+            new_class[nlo:nhi] = nc[: nhi - nlo]
+            totals[:] += counts
 
+    # the monolithic path's four span names, per chunk. Nothing here waits
+    # for the device but the fetch: transfer and kernel time what the host
+    # spends enqueueing, and the overlap is read from the device trace
     for c in range(n_chunks):
         olo, ohi = int(old_splits[c]), int(old_splits[c + 1])
         nlo, nhi = int(new_splits[c]), int(new_splits[c + 1])
-        ok, oo = _padded(old_keys, old_block.oids, olo, ohi)
-        nk, no = _padded(new_keys, new_block.oids, nlo, nhi)
-        dev = [jax.device_put(a) for a in (ok, oo, nk, no)]
-        out = _classify_padded(dev[0], dev[1], dev[2], dev[3], ohi - olo, nhi - nlo)
-        in_flight.append((out, (olo, ohi), (nlo, nhi)))
+        with tm.span(
+            "diff.device.pack", chunk=c, rows=ohi - olo + nhi - nlo, bucket=bucket
+        ) as sp:
+            ok, oo = _padded(old_keys, old_block.oids, olo, ohi)
+            nk, no = _padded(new_keys, new_block.oids, nlo, nhi)
+            nbytes = ok.nbytes + oo.nbytes + nk.nbytes + no.nbytes
+            sp.set(bytes=nbytes)
+        with tm.span("diff.device.transfer", chunk=c, bytes=nbytes):
+            dev = [jax.device_put(a) for a in (ok, oo, nk, no)]
+        with tm.span(
+            "diff.device.kernel", chunk=c, program="mergesort", bucket=bucket
+        ):
+            out = _classify_padded(*dev, ohi - olo, nhi - nlo)
+        in_flight.append((out, c, (olo, ohi), (nlo, nhi)))
         if len(in_flight) >= 2:
             _drain()
     while in_flight:
@@ -504,11 +539,15 @@ def classify_blocks_reference(old_block, new_block):
 
 def changed_indices(old_class, new_class):
     """-> (old_changed_idx, new_changed_idx): row indices whose values need
-    materialising (everything except UNCHANGED)."""
-    return (
-        np.nonzero(old_class != UNCHANGED)[0],
-        np.nonzero(new_class != UNCHANGED)[0],
-    )
+    materialising (everything except UNCHANGED). Host work the device
+    never sees, so it has a span of its own inside ``diff.classify``."""
+    with tm.span(
+        "diff.changed_indices", rows=len(old_class) + len(new_class)
+    ) as sp:
+        old_idx = np.nonzero(old_class != UNCHANGED)[0]
+        new_idx = np.nonzero(new_class != UNCHANGED)[0]
+        sp.set(changed=len(old_idx) + len(new_idx))
+    return old_idx, new_idx
 
 
 def _columnar_equal_core(old_cols, new_cols, null_mask_old, null_mask_new):

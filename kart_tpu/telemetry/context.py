@@ -42,6 +42,11 @@ REQUEST_EVENT_CAP = 512
 
 _var = contextvars.ContextVar("kart_request_context", default=None)
 
+#: the command's root context (:func:`set_root_request`), for threads the
+#: contextvar does not reach: a worker thread starts with an empty context,
+#: and its span events still belong to the command's trace
+_root = None
+
 
 def _new_trace_id():
     return os.urandom(16).hex()
@@ -207,18 +212,27 @@ def set_root_request(verb=None, **baggage):
     """Install a process-lifetime root context (the CLI calls this once per
     command): verb calls made anywhere below inherit its trace id. -> the
     root context. No reset — the root lives as long as the command."""
+    global _root
     ctx = RequestContext(
         _new_trace_id(), _new_request_id(), verb=verb, **baggage
     )
     _var.set(ctx)
+    _root = ctx
     return ctx
+
+
+def root():
+    """The root context :func:`set_root_request` installed, or None."""
+    return _root
 
 
 def clear_context():
     """Drop any lingering context on this thread (tests; fork children) —
     a root context installed by :func:`set_root_request` has no scope to
     exit, so reset must clear it explicitly."""
+    global _root
     _var.set(None)
+    _root = None
 
 
 def annotate(**kv):

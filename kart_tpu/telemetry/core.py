@@ -18,11 +18,17 @@ observability they aren't using. The enablement ladder:
 * ``-v`` on the CLI enables span aggregation only, feeding the
   end-of-command phase summary.
 
-Disabled, ``incr()``/``span()`` are one module-global bool test (measured
-by bench.py's ``telemetry_overhead_pct`` and bounded < 2% by a tier-1
-test). Instrumented code calls through the package attributes
-(``telemetry.span`` / ``telemetry.incr``), so tests and the overhead bench
-can swap in counting stubs without touching call sites.
+Disabled, ``incr()``/``span()`` are one module-global bool test; a tier-1
+test bounds the calls a 1M-row diff issues times that cost under 2% of the
+diff (a CPU figure). What the instrumentation costs a command on the chip
+is measured by the benchmark's tracing-off comparison of the PR that put
+the stage spans inside ``diff.classify`` (PERF.md §6, PR 26: ``diff_wall_s``
+1.6486 s at the parent, 1.5944 s at the change in ``points10m.diff_count``;
+2.9456 s and 2.9566 s in ``polygons10m.diff_jsonl`` — medians of six
+runs, inside both cells' spread; the driver's own comparison is in
+``PERF_LEDGER.jsonl`` under PR 26). Instrumented code calls through the
+package attributes (``telemetry.span`` / ``telemetry.incr``), so tests and
+the overhead bench can swap in counting stubs without touching call sites.
 
 Naming grammar (guarded by a tier-1 test, documented in
 docs/OBSERVABILITY.md): dotted lowercase ``<subsystem>.<metric>[.<part>]``
@@ -289,8 +295,9 @@ class _Span:
         dur = time.perf_counter() - t0
         stack = _tls.stack
         stack.pop()
-        if stack:
-            stack[-1]._child += dur
+        parent = stack[-1] if stack else None
+        if parent is not None:
+            parent._child += dur
         self_s = dur - self._child
         self._child = 0.0
         # request-context stamping: one contextvar read per span exit —
@@ -310,9 +317,16 @@ class _Span:
                 if len(_events) < _EVENT_CAP:
                     t = threading.current_thread()
                     args = dict(self.attrs) if self.attrs else {}
-                    if ctx is not None:
-                        args["request_id"] = ctx.request_id
-                        args["trace_id"] = ctx.trace_id
+                    if parent is not None:
+                        # the span that caused this one: the one open below
+                        # it on this thread (absent at a thread's root)
+                        args["parent"] = parent.name
+                    # a worker thread has no context of its own: its
+                    # events carry the command's ids all the same
+                    ids = ctx if ctx is not None else _rctx.root()
+                    if ids is not None:
+                        args["request_id"] = ids.request_id
+                        args["trace_id"] = ids.trace_id
                     _events.append(
                         {
                             "name": self.name,

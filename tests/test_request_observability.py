@@ -660,10 +660,13 @@ def test_client_and_server_traces_merge_on_request_ids(
     server_doc = json.load(open(server_trace))
 
     def ids(doc, key):
+        # a process's root span (cli.command) belongs to its own command:
+        # the server's is `serve-stdio`'s, not a part of the client's trace
         return {
             e["args"][key]
             for e in doc["traceEvents"]
             if e.get("ph") == "X" and key in e.get("args", {})
+            and e["name"] != "cli.command"
         }
 
     client_pids = {e["pid"] for e in client_doc["traceEvents"]}

@@ -416,6 +416,157 @@ def test_trace_diff_covers_four_subsystems(tmp_path, cli_runner, monkeypatch):
         assert sum(1 for _ in f) > 1
 
 
+# -- the one-chip classify path opened up (ISSUE 26) ------------------------
+
+
+DEVICE_STAGES = [
+    "diff.device.pack", "diff.device.transfer", "diff.device.kernel",
+    "diff.device.fetch",
+]
+
+
+def _block(n, seed, padded):
+    """A sorted FeatureBlock of ``n`` rows, padded to its bucket or not (an
+    mmap-backed sidecar block comes unpadded)."""
+    from kart_tpu.parallel.sharded_diff import synthetic_block
+
+    block = synthetic_block(n, seed=seed)
+    if not padded:
+        block.keys, block.oids = block.keys[:n].copy(), block.oids[:n].copy()
+    return block
+
+
+class _StubDataset:
+    path_encoder = None
+    repo = None
+
+    @staticmethod
+    def get_feature_promise_from_oid(pks, oid):
+        return None
+
+
+def _device_classify(rows, padded):
+    """One columnar diff of two ``rows``-row blocks, 1 in 100 edited."""
+    from kart_tpu.diff.engine import get_feature_diff_columnar
+
+    old, new = _block(rows, 3, padded), _block(rows, 3, padded)
+    new.oids = new.oids.copy()
+    new.oids[7:rows:100, 0] ^= 1
+    ds = _StubDataset()
+    return get_feature_diff_columnar(ds, ds, blocks=(old, new)), old, new
+
+
+@pytest.mark.parametrize("rows,padded", [(900, False), (5000, False), (5000, True)])
+def test_device_classify_names_its_four_stages(rows, padded, monkeypatch):
+    """The monolithic device classify, forced on XLA-CPU, emits pack ->
+    transfer -> kernel -> fetch under ``diff.classify`` and nothing else
+    from the device family: each names ``diff.classify`` as its parent,
+    counts the bytes of the arrays it handled, and together they take no
+    longer than the span around them."""
+    from kart_tpu.ops.blocks import bucket_size
+
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    telemetry.enable(trace=True)
+    diff, old, new = _device_classify(rows, padded)
+    assert len(diff) == len(range(7, rows, 100))
+    events = telemetry.drain_events()
+    (classify,) = [e for e in events if e["name"] == "diff.classify"]
+    assert classify["args"]["backend"] == "device_jax"
+    assert classify["args"]["counts_only"] is False
+    children = [e for e in events if e["args"].get("parent") == "diff.classify"]
+    assert [e["name"] for e in children] == DEVICE_STAGES + ["diff.changed_indices"]
+    assert [e["name"] for e in events if e["name"].startswith("diff.device.")] == (
+        DEVICE_STAGES
+    )
+    pack, transfer, kernel, fetch, select = children
+    bucket = bucket_size(rows)
+    side = bucket * 8 + bucket * 5 * 4  # int64 keys + (n, 5) uint32 oids
+    assert pack["args"]["rows"] == 2 * rows and pack["args"]["bucket"] == bucket
+    # a block that comes padded is handed on as it is: nothing was copied
+    assert pack["args"]["bytes"] == (0 if padded else 2 * side)
+    assert transfer["args"]["bytes"] == 2 * side
+    assert kernel["args"] == {
+        "program": "binsearch", "bucket": bucket, "parent": "diff.classify"
+    }
+    assert fetch["args"]["bytes"] == 2 * bucket + 3 * 8  # int8 classes + counts
+    assert select["args"]["rows"] == 2 * rows
+    assert select["args"]["changed"] == 2 * len(diff)
+    # in order, one after the other, inside the parent
+    for before, after in zip(children, children[1:]):
+        assert before["ts"] + before["dur"] <= after["ts"]
+    assert classify["ts"] <= pack["ts"]
+    assert select["ts"] + select["dur"] <= classify["ts"] + classify["dur"]
+    assert sum(e["dur"] for e in children) <= classify["dur"]
+
+
+def test_device_classify_disabled_records_nothing(monkeypatch):
+    """With telemetry off the same path leaves no event, no aggregate and
+    no attribute behind: every new span is an early-out at ``__enter__``."""
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    made = []
+    real_exit = core._Span.__exit__
+
+    def counting_exit(self, *exc):
+        made.append((self.name, self._t0))
+        return real_exit(self, *exc)
+
+    monkeypatch.setattr(core._Span, "__exit__", counting_exit)
+    diff, _, _ = _device_classify(900, False)
+    assert len(diff) == 9
+    assert {name for name, _ in made} >= set(DEVICE_STAGES)
+    assert all(t0 is None for _, t0 in made)  # none was ever started
+    assert telemetry.drain_events() == []
+    assert telemetry.snapshot() == {"counters": [], "gauges": [], "histograms": []}
+
+
+def test_cli_command_is_the_root_of_a_traced_diff(tmp_path, cli_runner, monkeypatch):
+    """``kart --trace diff``: ``cli.command`` is the root, every other
+    main-thread event descends from it through ``args.parent`` and lies
+    inside it, every event carries the command's one ``trace_id``, and only
+    the root and a worker thread's first span name no parent."""
+    from kart_tpu.cli import cli
+    from kart_tpu.synth import synth_repo
+
+    synth_repo(str(tmp_path / "repo"), 3000, edit_frac=0.01, blobs="real")
+    trace_path = str(tmp_path / "trace.json")
+    monkeypatch.setenv("KART_TRACE", trace_path)
+    r = cli_runner.invoke(
+        cli,
+        ["-C", str(tmp_path / "repo"), "diff", "HEAD^...HEAD", "-o", "json-lines",
+         "--output", str(tmp_path / "out.jsonl")],
+    )
+    assert r.exit_code == 0, r.output
+    doc = json.load(open(trace_path))
+    assert [e for e in doc["traceEvents"] if e["name"] == "kart_trace_epoch"]
+    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    (root,) = [e for e in spans if e["name"] == "cli.command"]
+    assert root["args"]["cmd"] == "diff" and "parent" not in root["args"]
+    assert {e["args"]["trace_id"] for e in spans} == {root["args"]["trace_id"]}
+    main = [e for e in spans if e["tid"] == root["tid"] and e is not root]
+    assert {"diff.classify", "serialise.features", "sidecar.load"} <= {
+        e["name"] for e in main
+    }
+    by_name = {e["name"]: e for e in main}
+    for e in main:
+        assert root["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= root["ts"] + root["dur"]
+        name, hops = e["args"]["parent"], 0
+        while name != "cli.command":  # walk up to the root
+            name, hops = by_name[name]["args"]["parent"], hops + 1
+            assert hops < 16
+    others = [e for e in spans if e["tid"] != root["tid"]]
+    assert others, "the prefetch thread has a lane of its own"
+    for tid in {e["tid"] for e in others}:
+        lane = sorted((e for e in others if e["tid"] == tid), key=lambda e: e["ts"])
+        orphans = [e for e in lane if "parent" not in e["args"]]
+        # only a thread's outermost spans name no parent
+        for e in orphans:
+            assert not any(
+                o is not e and o["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= o["ts"] + o["dur"] for o in lane
+            )
+
+
 # -- acceptance: kart stats vs a fault-injected fetch -----------------------
 
 
