@@ -1486,17 +1486,18 @@ def multichip_worker():
     old_block, new_block = _multichip_slice(lo, hi)
     if mode == "mono":
         from kart_tpu.ops.diff_kernel import (
-            _classify_padded_binsearch,
-            _padded_arrays,
+            _classify_split_binsearch,
+            _split_columns,
         )
 
         # compile + first-touch at full shape (jit specialises per padded
         # bucket size, so a tiny warm pair would not pre-pay this compile)
         def run():
-            ok, oo = _padded_arrays(old_block)
-            nk, no = _padded_arrays(new_block)
-            oc, ncl, _, cnt = _classify_padded_binsearch(
-                ok, oo, nk, no, old_block.count, new_block.count
+            oc, ncl, _, cnt = _classify_split_binsearch(
+                *_split_columns(old_block),
+                *_split_columns(new_block),
+                old_block.count,
+                new_block.count,
             )
             cnt = np.asarray(cnt)
             # worker-protocol counts (same shape as the classify counts

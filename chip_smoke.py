@@ -405,11 +405,15 @@ def _merge_index(repo_path):
 def _padded_block(keys, oids):
     """Sorted (keys, oids) -> FeatureBlock padded to its bucket, as
     FeatureBlock.from_dataset hands the merge its blocks."""
-    from kart_tpu.ops.blocks import FeatureBlock
-    from kart_tpu.ops.diff_kernel import _padded_arrays
+    from kart_tpu.ops.blocks import PAD_KEY, FeatureBlock, bucket_size
 
     n = len(keys)
-    return FeatureBlock(*_padded_arrays(FeatureBlock(keys, oids, None, n)), None, n)
+    size = bucket_size(max(n, 1))
+    padded_keys = np.full(size, PAD_KEY, dtype=np.int64)
+    padded_keys[:n] = keys
+    padded_oids = np.zeros((size, 5), dtype=np.uint32)
+    padded_oids[:n] = oids
+    return FeatureBlock(padded_keys, padded_oids, None, n)
 
 
 def _merge_blocks(n, conflicts, seed):

@@ -47,9 +47,27 @@ def bucket_size(n, minimum=1024):
     classify kernel's sort cost scales with the padded size."""
     if n <= minimum:
         return minimum
-    k = max((n - 1).bit_length() - 4, 0)
-    step = 1 << k
+    step = _grid_step(n)
     return ((n + step - 1) // step) * step
+
+
+def _grid_step(n):
+    """Spacing of the bucket grid in n's octave: a sixteenth of the power of
+    two at or above n, so every size in (2^(m-1), 2^m] shares one step."""
+    return 1 << max((n - 1).bit_length() - 4, 0)
+
+
+def bucket_body(size, minimum=1024):
+    """Rows that every block landing in the ``bucket_size`` bucket ``size``
+    is sure to have: the bucket less one grid step (a block of n rows takes
+    the first multiple of the step >= n, so n > size - step). A function of
+    the bucket alone — what lets the device classify take a block as a
+    zero-copy body view of this length plus one padded step-row tail without
+    a compiled shape per row count. 0 at the minimum bucket, which blocks of
+    any smaller size share."""
+    if size <= minimum:
+        return 0
+    return size - _grid_step(size)
 
 
 def pack_oid_hex(oids_hex):

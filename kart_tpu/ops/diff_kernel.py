@@ -1,23 +1,31 @@
 """Vectorized diff classification — reference hot loop #1 as one jitted
 merge-join (SURVEY.md §3.1, rich_base_dataset.py:205-300).
 
-Given two FeatureBlocks (sorted key+oid arrays, padded), classification runs
+Given two FeatureBlocks (sorted key+oid arrays), classification runs
 entirely on device with no Python per-feature work, no data-dependent control
 flow, and static shapes. Two device kernels with identical semantics:
 
-- ``_classify_padded`` (the flagship, default on accelerators): one 3-operand
-  ``lax.sort`` of the concatenated keys (with concat position for stability
-  and a 64-bit oid fold as the payload) brings every old/new pair of the same
-  key adjacent, then neighbour compares classify all keys at once and a
-  scatter returns classes to block order. TPU's bitonic sort network is ~20x
-  faster than the log(n) serial gather rounds a binary search lowers to, and
-  streaming the folded oid through the sort beats a post-sort random gather
-  of (n,5) oid rows ~2x: 2 linear passes over HBM (sort, scatter).
-- ``_classify_padded_binsearch``: a pair of ``searchsorted`` joins — faster
+- ``_classify_mergesort_core`` (the flagship, default on accelerators): one
+  3-operand ``lax.sort`` of the concatenated keys (with concat position for
+  stability and a 64-bit oid fold as the payload) brings every old/new pair
+  of the same key adjacent, then neighbour compares classify all keys at
+  once and scatters return classes and partner rows to block order. TPU's
+  bitonic sort network is ~20x faster than the log(n) serial gather rounds
+  a binary search lowers to. It is far from a pass or two over HBM, though:
+  per 10M-row command on a v5e (0.777 s, 0.091% of the memory roof) the
+  (n, 5) oid row gather of the exactness re-check is the largest op at
+  0.232 s, the three scatters take 0.312 s and the four sort phases
+  0.221 s (PERF.md §5).
+- ``_classify_binsearch_core``: a pair of ``searchsorted`` joins — faster
   on CPU where binary search doesn't serialise. Bit-identical to the sort
   path: both compare full 160-bit oids (the sort path re-verifies its
   64-bit fold matches via a monotonic partner gather), as does the numpy
   reference below.
+
+The monolithic route (:func:`classify_blocks`) never copies a block to pad
+it: each column reaches the device as a body — a view of the caller's own
+pages — and one padded tail (:func:`_split_columns`), joined on the device
+by the ``*_split`` entries.
 
 Classes: 0 = unchanged, 1 = insert, 2 = update, 3 = delete.
 """
@@ -118,9 +126,10 @@ def _classify_mergesort_core(
 
     # Exactness restore: a pair the fold called equal is re-checked against
     # the full 160-bit oids. Both blocks are key-sorted so idx_in_new is
-    # monotonic — this gather streams, unlike the random post-sort gather
-    # the fold exists to avoid. A fold collision therefore surfaces as an
-    # UPDATE instead of a silent diff miss.
+    # monotonic, unlike the random post-sort gather the fold exists to
+    # avoid — yet as a row gather it is still the program's largest op on a
+    # v5e (0.232 of 0.777 s at 10M rows, PERF.md §5). A fold collision
+    # therefore surfaces as an UPDATE instead of a silent diff miss.
     full_eq = jnp.all(old_oids == new_oids[idx_in_new], axis=1)
     collide = (
         (old_class == UNCHANGED) & (jnp.arange(n_old) < old_count) & ~full_eq
@@ -199,6 +208,40 @@ def _classify_binsearch_core(
 
 
 _classify_padded_binsearch = lazy_jit(_classify_binsearch_core)
+
+
+def _split_entry(core):
+    """The jitted entry of the monolithic route: ``core`` with each of its
+    four columns arriving as (body, tail) — the sidecar's own pages and one
+    padded step of the bucket grid (:func:`_split_columns`) — joined on the
+    device. Shapes depend on the bucket alone, so this compiles once per
+    bucket as the six-argument core does. The program is named after the
+    core (``jit__classify_mergesort_core_split``): the benchmark's kernel
+    metrics find it by that prefix in the device trace."""
+
+    def entry(
+        old_keys, old_keys_tail, old_oids, old_oids_tail,
+        new_keys, new_keys_tail, new_oids, new_oids_tail,
+        old_count, new_count,
+    ):
+        import jax.numpy as jnp
+
+        return core(
+            jnp.concatenate([old_keys, old_keys_tail]),
+            jnp.concatenate([old_oids, old_oids_tail]),
+            jnp.concatenate([new_keys, new_keys_tail]),
+            jnp.concatenate([new_oids, new_oids_tail]),
+            old_count,
+            new_count,
+        )
+
+    entry.__name__ = entry.__qualname__ = core.__name__ + "_split"
+    return entry
+
+
+_classify_split = lazy_jit(_split_entry(_classify_mergesort_core))
+_classify_split_binsearch = lazy_jit(_split_entry(_classify_binsearch_core))
+
 
 def _env_int(name, default):
     """Tolerant env knob: a malformed value must never kill the CLI."""
@@ -282,10 +325,12 @@ def classify_blocks(old_block, new_block):
             return classify_blocks_streamed(old_block, new_block)
         import jax
 
+        from kart_tpu.ops.blocks import bucket_size
+
         program, kernel = (
-            ("binsearch", _classify_padded_binsearch)
+            ("binsearch", _classify_split_binsearch)
             if default_backend() == "cpu"
-            else ("mergesort", _classify_padded)
+            else ("mergesort", _classify_split)
         )
         # the four stages are statements of the program, each under its own
         # span (docs/DEVICE.md §5). The two block_until_ready calls add no
@@ -294,16 +339,12 @@ def classify_blocks(old_block, new_block):
         with tm.span(
             "diff.device.pack", rows=old_block.count + new_block.count
         ) as sp:
-            ok, oo = _padded_arrays(old_block)
-            nk, no = _padded_arrays(new_block)
-            host = (ok, oo, nk, no)
-            sources = (
-                old_block.keys, old_block.oids, new_block.keys, new_block.oids
-            )
-            bucket = max(len(ok), len(nk))
+            host = _split_columns(old_block) + _split_columns(new_block)
+            bucket = bucket_size(max(n_rows, 1))  # the larger side's
+            # bytes the host copied: the tails it made; a view owns nothing
             sp.set(
                 bucket=bucket,
-                bytes=sum(a.nbytes for a, src in zip(host, sources) if a is not src),
+                bytes=sum(a.nbytes for a in host if a.flags.owndata),
             )
         with tm.span("diff.device.transfer", bytes=sum(a.nbytes for a in host)):
             dev = jax.block_until_ready([jax.device_put(a) for a in host])
@@ -458,22 +499,28 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     )
 
 
-def _padded_arrays(block):
-    """(keys, oids) padded to the bucket size the monolithic device kernels
-    compile for; a no-op view when the block is already padded (only the
-    device route pays the copy — the host engine and the streamed/sharded
-    paths take count-sliced views)."""
-    from kart_tpu.ops.blocks import PAD_KEY, bucket_size
+def _split_columns(block):
+    """(keys body, keys tail, oids body, oids tail): the block's two columns
+    as the monolithic device kernels take them, copying at most one step of
+    the bucket grid. The body is the first ``bucket_body(bucket)`` rows,
+    which every block of that bucket has — a view of the caller's arrays
+    (the sidecar's mmap'd pages, read-only and unaligned as they come). The
+    tail is the rest of the bucket: a view too when the block arrives
+    padded, else freshly made — the block's last rows, then ``PAD_KEY`` /
+    zero oids."""
+    from kart_tpu.ops.blocks import PAD_KEY, bucket_body, bucket_size
 
     n = block.count
     size = bucket_size(max(n, 1))
-    if len(block.keys) >= size:
-        return block.keys, block.oids
-    keys = np.full(size, PAD_KEY, dtype=np.int64)
-    keys[:n] = block.keys[:n]
-    oids = np.zeros((size, 5), dtype=np.uint32)
-    oids[:n] = block.oids[:n]
-    return keys, oids
+    body = bucket_body(size)
+    keys, oids = block.keys, block.oids
+    if len(keys) >= size:
+        return keys[:body], keys[body:size], oids[:body], oids[body:size]
+    keys_tail = np.full(size - body, PAD_KEY, dtype=np.int64)
+    keys_tail[: n - body] = keys[body:n]
+    oids_tail = np.zeros((size - body, 5), dtype=np.uint32)
+    oids_tail[: n - body] = oids[body:n]
+    return keys[:body], keys_tail, oids[:body], oids_tail
 
 
 def classify_blocks_host(old_block, new_block):

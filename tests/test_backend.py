@@ -459,8 +459,8 @@ def test_classify_rung_counts_its_fallback(monkeypatch, fallback_counter):
     old, new = _pair(seed=5)
     want = classify_blocks_host(old, new)
     monkeypatch.setenv("KART_DIFF_DEVICE", "1")
-    monkeypatch.setattr(diff_kernel, "_classify_padded_binsearch", _boom)
-    monkeypatch.setattr(diff_kernel, "_classify_padded", _boom)
+    monkeypatch.setattr(diff_kernel, "_classify_split_binsearch", _boom)
+    monkeypatch.setattr(diff_kernel, "_classify_split", _boom)
     got = diff_kernel.classify_blocks(old, new)
     assert got[2] == want[2]
     np.testing.assert_array_equal(got[0], want[0])

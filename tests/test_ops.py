@@ -1,7 +1,14 @@
 import numpy as np
 import pytest
 
-from kart_tpu.ops.blocks import FeatureBlock, bucket_size, pack_oid_hex, unpack_oid_hex
+from kart_tpu.ops.blocks import (
+    PAD_KEY,
+    FeatureBlock,
+    bucket_body,
+    bucket_size,
+    pack_oid_hex,
+    unpack_oid_hex,
+)
 from kart_tpu.ops.bbox import bbox_intersects, bbox_intersects_np
 from kart_tpu.ops.diff_kernel import (
     DELETE,
@@ -35,6 +42,25 @@ def test_bucket_size():
         b = bucket_size(n)
         assert b >= n
         assert (b - n) / n <= 0.125  # waste cap above the minimum floor
+
+
+@pytest.mark.parametrize(
+    "n", [0, 1, 1024, 1025, 1152, 1153, 2048, 2049, 10_000_000, 2**24, 2**24 + 1]
+)
+def test_bucket_body_is_inside_every_block_of_the_bucket(n):
+    """The split the device classify relies on: the body of n's bucket is a
+    whole number of grid steps short of it and strictly inside the n rows,
+    whatever n of that bucket — so it can be a view of an unpadded block."""
+    bucket = bucket_size(n)
+    body = bucket_body(bucket)
+    if n <= 1024:
+        assert (bucket, body) == (1024, 0)
+    else:
+        assert body < n <= bucket
+        step = bucket - body
+        assert step & (step - 1) == 0 and 9 <= bucket // step <= 16
+        # the first and the last row count of this bucket agree on it
+        assert bucket_size(body + 1) == bucket == bucket_size(bucket)
 
 
 def test_pack_unpack_oids():
@@ -577,3 +603,167 @@ def test_classify_streamed_disjoint_key_ranges():
     old_class, new_class, counts = classify_blocks_streamed(old, new, chunk_rows=500)
     assert counts == {"inserts": n, "updates": 0, "deletes": n}
     assert (old_class == DELETE).all() and (new_class == INSERT).all()
+
+
+# -- the monolithic device route takes the block's own pages (ISSUE 27) -----
+
+
+def _sorted_block(n, seed, stride=3):
+    """An unpadded FeatureBlock of ``n`` rows with sorted keys, as
+    ``sidecar.load_block(pad=False)`` hands the diff its blocks."""
+    rng = np.random.default_rng(seed)
+    keys = np.sort(
+        rng.choice(np.arange(max(n, 1) * stride, dtype=np.int64), size=n, replace=False)
+    )
+    oids = rng.integers(0, 2**32, size=(n, 5), dtype=np.uint32)
+    return FeatureBlock(keys, oids, None, n)
+
+
+def _edited(block, n_new, seed):
+    """A second revision of ``block`` with ``n_new`` rows: every 7th row
+    dropped, every 9th rewritten, fresh keys above the old range appended
+    (or rows cut) to reach ``n_new``."""
+    rng = np.random.default_rng(seed)
+    keep = np.arange(block.count) % 7 != 3
+    keys, oids = block.keys[keep], block.oids[keep].copy()
+    oids[::9, 0] ^= 1
+    if n_new <= len(keys):
+        keys, oids = keys[:n_new], oids[:n_new]
+    else:
+        extra = n_new - len(keys)
+        top = int(block.keys[-1]) + 1 if block.count else 0
+        keys = np.concatenate([keys, np.arange(top, top + extra, dtype=np.int64)])
+        oids = np.concatenate(
+            [oids, rng.integers(0, 2**32, size=(extra, 5), dtype=np.uint32)]
+        )
+    return FeatureBlock(keys, oids, None, n_new)
+
+
+def _padded(block):
+    size = bucket_size(max(block.count, 1))
+    keys = np.full(size, PAD_KEY, dtype=np.int64)
+    keys[: block.count] = block.keys[: block.count]
+    oids = np.zeros((size, 5), dtype=np.uint32)
+    oids[: block.count] = block.oids[: block.count]
+    return FeatureBlock(keys, oids, None, block.count)
+
+
+def _as_sidecar_view(block):
+    """The block's columns as the sidecar gives them: read-only
+    ``np.frombuffer`` views at an odd offset into one buffer."""
+    n = block.count
+    buf = b"KCOL1\n{}\n" + block.keys.tobytes() + block.oids.tobytes()
+    keys = np.frombuffer(buf, dtype="<i8", count=n, offset=9)
+    oids = (
+        np.frombuffer(buf, dtype=np.uint8, count=20 * n, offset=9 + 8 * n)
+        .reshape(n, 5, 4).view(np.uint32).reshape(n, 5)
+    )
+    assert not keys.flags.aligned and not keys.flags.writeable
+    return FeatureBlock(keys, oids, None, n)
+
+
+_BODY_5120 = bucket_body(5120)  # 4608: buckets 5120 and its step 512
+
+SPLIT_CASES = {
+    "empty_old": (0, 40, None),
+    "empty_both": (0, 0, None),
+    "one_row": (1, 1, None),
+    "under_minimum": (700, 650, None),
+    "exactly_body": (_BODY_5120 + 200, _BODY_5120, None),
+    "body_plus_one": (_BODY_5120 + 1, _BODY_5120 + 1, None),
+    "exactly_bucket": (5120, 5120, None),
+    "different_buckets": (5000, 9000, None),
+    "already_padded": (5000, 4800, _padded),
+    "sidecar_view": (5000, 4900, _as_sidecar_view),
+}
+
+
+@pytest.mark.parametrize("case", SPLIT_CASES)
+def test_device_classify_split_matches_reference(case, monkeypatch):
+    """classify_blocks on the monolithic device route (XLA-CPU's binsearch
+    twin, forced) equals the numpy reference at every edge of the body/tail
+    split: classes, counts, and never by way of the host fallback."""
+    from kart_tpu import telemetry as tm
+    from kart_tpu.ops.diff_kernel import DELETE, INSERT, UPDATE
+
+    n_old, n_new, shape = SPLIT_CASES[case]
+    old = _sorted_block(n_old, seed=11)
+    new = _edited(old, n_new, seed=12)
+    if shape is not None:
+        old, new = shape(old), shape(new)
+    ref_old, ref_new = classify_blocks_reference(old, new)
+
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    tm.reset()
+    tm.enable(metrics=True)
+    try:
+        old_class, new_class, counts = classify_blocks(old, new)
+        fallbacks = [
+            k for k in tm.counters_snapshot() if k[0] == "diff.device.fallbacks"
+        ]
+    finally:
+        tm.reset()
+    assert fallbacks == []
+    np.testing.assert_array_equal(old_class, ref_old)
+    np.testing.assert_array_equal(new_class, ref_new)
+    assert counts == {
+        "inserts": int(np.sum(ref_new == INSERT)),
+        "updates": int(np.sum(ref_old == UPDATE)),
+        "deletes": int(np.sum(ref_old == DELETE)),
+    }
+
+
+@pytest.mark.parametrize("n", [700, _BODY_5120 + 1, 5000, 5120, 9000])
+@pytest.mark.parametrize("shape", [None, _padded, _as_sidecar_view])
+def test_split_columns_copies_one_tail_and_views_the_rest(n, shape):
+    """``_split_columns``: body + tail spell the padded block row for row,
+    the body is the caller's own memory, shapes depend on the bucket alone,
+    and what is copied is at most one grid step of 28-byte rows."""
+    from kart_tpu.ops.diff_kernel import _split_columns
+
+    block = _sorted_block(n, seed=5)
+    if shape is not None:
+        block = shape(block)
+    bucket = bucket_size(n)
+    body = bucket_body(bucket)
+    keys_body, keys_tail, oids_body, oids_tail = _split_columns(block)
+    assert keys_body.shape == (body,) and keys_tail.shape == (bucket - body,)
+    assert oids_body.shape == (body, 5) and oids_tail.shape == (bucket - body, 5)
+    want = _padded(block)
+    np.testing.assert_array_equal(np.concatenate([keys_body, keys_tail]), want.keys)
+    np.testing.assert_array_equal(np.concatenate([oids_body, oids_tail]), want.oids)
+    if body:
+        assert np.shares_memory(keys_body, block.keys)
+        assert np.shares_memory(oids_body, block.oids)
+    copied = sum(
+        a.nbytes for a in (keys_body, keys_tail, oids_body, oids_tail)
+        if a.flags.owndata
+    )
+    # a block that fills its bucket, padded or by its own rows, has its
+    # tail in place too
+    whole = len(block.keys) >= bucket
+    assert copied == (0 if whole else (bucket - body) * 28)
+    assert np.shares_memory(keys_tail, block.keys) == whole
+
+
+def test_device_classify_pack_span_counts_only_the_tails(monkeypatch):
+    """The ``diff.device.pack`` span's ``bytes`` is what the host copied —
+    at most 2 x step x 28 — while ``diff.device.transfer`` still carries
+    both whole buckets."""
+    from kart_tpu import telemetry as tm
+
+    old = _as_sidecar_view(_sorted_block(5000, seed=1))
+    new = _as_sidecar_view(_edited(old, 4900, seed=2))
+    step = 5120 - _BODY_5120
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    tm.reset()
+    tm.enable(trace=True)
+    try:
+        classify_blocks(old, new)
+        events = {e["name"]: e["args"] for e in tm.drain_events()}
+    finally:
+        tm.reset()
+    assert events["diff.device.pack"]["bucket"] == 5120
+    assert events["diff.device.pack"]["bytes"] == 2 * step * 28
+    assert events["diff.device.transfer"]["bytes"] == 2 * 5120 * 28
+    assert events["diff.device.kernel"]["program"] == "binsearch"

@@ -463,7 +463,7 @@ def test_device_classify_names_its_four_stages(rows, padded, monkeypatch):
     from the device family: each names ``diff.classify`` as its parent,
     counts the bytes of the arrays it handled, and together they take no
     longer than the span around them."""
-    from kart_tpu.ops.blocks import bucket_size
+    from kart_tpu.ops.blocks import bucket_body, bucket_size
 
     monkeypatch.setenv("KART_DIFF_DEVICE", "1")
     telemetry.enable(trace=True)
@@ -482,8 +482,10 @@ def test_device_classify_names_its_four_stages(rows, padded, monkeypatch):
     bucket = bucket_size(rows)
     side = bucket * 8 + bucket * 5 * 4  # int64 keys + (n, 5) uint32 oids
     assert pack["args"]["rows"] == 2 * rows and pack["args"]["bucket"] == bucket
-    # a block that comes padded is handed on as it is: nothing was copied
-    assert pack["args"]["bytes"] == (0 if padded else 2 * side)
+    # the host copies one tail per column — a step of the bucket grid, or
+    # the whole minimum bucket — and nothing of a block that comes padded
+    tail = bucket - bucket_body(bucket)
+    assert pack["args"]["bytes"] == (0 if padded else 2 * tail * (8 + 5 * 4))
     assert transfer["args"]["bytes"] == 2 * side
     assert kernel["args"] == {
         "program": "binsearch", "bucket": bucket, "parent": "diff.classify"
