@@ -745,12 +745,12 @@ def _make_pmapped_counts(n_dev, kernel):
         _classify_binsearch_core if kernel == "binsearch" else _classify_mergesort_core
     )
 
-    def _step(ok, oo, nk, no, oc, nc):
+    def _pmapped_counts(ok, oo, nk, no, oc, nc):
         _, _, _, counts = core(ok, oo, nk, no, oc, nc)
         return jax.lax.psum(counts, "devices")
 
     jax.config.update("jax_enable_x64", True)  # int64 keys / PAD_KEY
-    return jax.pmap(_step, axis_name="devices")
+    return jax.pmap(_pmapped_counts, axis_name="devices")
 
 
 def sampled_counts_pmapped(old_block, new_block):

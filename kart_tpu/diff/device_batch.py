@@ -33,6 +33,7 @@ attempt and the backend falls back to host-native with no partial state —
 results are only ever published after the final round drains.
 """
 
+import bisect
 import functools
 
 import numpy as np
@@ -76,7 +77,15 @@ def batch_splits(key_arrays, batch_rows):
         ]
         if cands:
             bound = min(cands)
-            his = [int(np.searchsorted(k, bound)) for k in sides]
+            # a bisection over each side's next batch_rows + 1 rows: the
+            # boundary cannot lie further on. Not np.searchsorted over the
+            # side: that copies an unaligned array first, and a sidecar's
+            # mmap'd key section starts where its header ends — 80 MB a
+            # call at 10M rows (PERF.md §6, PR 28)
+            his = [
+                bisect.bisect_left(k, bound, lo, min(lo + batch_rows + 1, len(k)))
+                for lo, k in zip(los, sides)
+            ]
         else:
             his = [len(k) for k in sides]
         for i, (lo, hi) in enumerate(zip(los, his)):
@@ -236,7 +245,9 @@ def make_batched_classify(mesh, kernel, counts_only=False):
 
     core = _classify_binsearch_core if kernel == "binsearch" else _classify_mergesort_core
 
-    def _step(ok, oo, nk, no, oc, nc):
+    # the function's name is the program's on the device trace
+    # (``jit__mesh_classify``): no other program of the repo shares it
+    def _mesh_classify(ok, oo, nk, no, oc, nc):
         old_class, new_class, _, counts = core(
             ok[0], oo[0], nk[0], no[0], oc[0], nc[0]
         )
@@ -248,7 +259,7 @@ def make_batched_classify(mesh, kernel, counts_only=False):
     jax.config.update("jax_enable_x64", True)  # int64 keys / PAD_KEY
     spec = P(FEATURES_AXIS)
     fn = jax.shard_map(
-        _step,
+        _mesh_classify,
         mesh=mesh,
         in_specs=(spec,) * 6,
         out_specs=P() if counts_only else (spec, spec, P()),
@@ -299,10 +310,6 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
     new_keys = np.asarray(new_block.keys[:n_new])
     old_oids = old_block.oids
     new_oids = new_block.oids
-    (old_splits, new_splits), n_chunks = batch_splits(
-        (old_keys, new_keys), batch_rows
-    )
-    n_rounds = max(-(-n_chunks // n_shards), 1)
 
     fn = make_batched_classify(mesh, kernel, counts_only)
     sharding = NamedSharding(mesh, P(FEATURES_AXIS))
@@ -311,47 +318,75 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
     old_class = None if counts_only else np.zeros(n_old, dtype=np.int8)
     new_class = None if counts_only else np.zeros(n_new, dtype=np.int8)
     totals = np.zeros(3, dtype=np.int64)
-    in_flight = []  # [(device outputs, chunk0)] — at most 2 (double buffer)
+    in_flight = []  # [(device outputs, chunk0, round)] — at most 2 (double buffer)
 
     tm.gauge_set("diff.device.shards", n_shards)
     tm.gauge_set("diff.device.batch_rows", batch_rows)
 
     def _drain():
-        out, chunk0 = in_flight.pop(0)
-        if counts_only:
-            totals[:] += np.asarray(out)
-            return
-        oc, nc, counts = out
-        unpack_round(old_class, oc, old_splits, chunk0, n_shards)
-        unpack_round(new_class, nc, new_splits, chunk0, n_shards)
-        totals[:] += np.asarray(counts)
+        # the only wait of the path: np.asarray blocks until the round's
+        # program has run, so this span holds whatever of the device's work
+        # the host's packing of later rounds did not hide
+        out, chunk0, r = in_flight.pop(0)
+        with tm.span("diff.device.fetch", round=r) as fetch:
+            if counts_only:
+                counts = np.asarray(out)
+                fetch.set(bytes=counts.nbytes)
+            else:
+                oc, nc, counts = (np.asarray(a) for a in out)
+                fetch.set(bytes=oc.nbytes + nc.nbytes + counts.nbytes)
+                unpack_round(old_class, oc, old_splits, chunk0, n_shards)
+                unpack_round(new_class, nc, new_splits, chunk0, n_shards)
+            totals[:] += counts
 
+    h2d_bytes = 0
     with tm.span(
         "diff.device.classify",
         rows=int(max(n_old, n_new)),
         shards=n_shards,
-        rounds=n_rounds,
-    ):
+        batch_rows=batch_rows,
+        counts_only=bool(counts_only),
+        kernel=kernel,
+    ) as root:
+        with tm.span("diff.device.splits") as splits:
+            (old_splits, new_splits), n_chunks = batch_splits(
+                (old_keys, new_keys), batch_rows
+            )
+            splits.set(chunks=n_chunks)
+        n_rounds = max(-(-n_chunks // n_shards), 1)
+        root.set(rounds=n_rounds, chunks=n_chunks)
         for r in range(n_rounds):
             chunk0 = r * n_shards
-            with tm.span("diff.device.pack", round=r):
+            with tm.span("diff.device.pack", round=r) as pack:
                 ok, oo, oc = pack_round(
                     old_keys, old_oids, old_splits, chunk0, n_shards, batch_rows
                 )
                 nk, no, nc = pack_round(
                     new_keys, new_oids, new_splits, chunk0, n_shards, batch_rows
                 )
-            with tm.span("diff.device.transfer", round=r):
+                packed = (ok, oo, nk, no, oc, nc)
+                # all six arrays are made here, padding and all
+                round_bytes = sum(a.nbytes for a in packed)
+                pack.set(bytes=round_bytes)
+            # device_put is asynchronous and nothing waits for it here (round
+            # r+1's copy overlaps round r's program): the span is the enqueue
+            with tm.span("diff.device.transfer", round=r, bytes=round_bytes):
                 if transfer_hook is not None:
                     transfer_hook()
-                args = [jax.device_put(a, sharding) for a in (ok, oo, nk, no, oc, nc)]
-            in_flight.append((fn(*args), chunk0))
+                args = [jax.device_put(a, sharding) for a in packed]
+            h2d_bytes += round_bytes
+            with tm.span("diff.device.kernel", round=r, program="mesh_classify"):
+                out = fn(*args)  # the enqueue only; the wait is in the fetch
+            in_flight.append((out, chunk0, r))
             if len(in_flight) >= 2:
                 _drain()
         while in_flight:
             _drain()
+        root.set(bytes=h2d_bytes)
 
     tm.incr("diff.device.batches", n_rounds * n_shards)
+    tm.incr("diff.device.rounds", n_rounds)
+    tm.incr("diff.device.h2d_bytes", h2d_bytes)
     return (
         old_class,
         new_class,

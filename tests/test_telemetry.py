@@ -521,6 +521,155 @@ def test_device_classify_disabled_records_nothing(monkeypatch):
     assert telemetry.snapshot() == {"counters": [], "gauges": [], "histograms": []}
 
 
+# -- the mesh classify path opened up (ISSUE 28) ----------------------------
+
+
+MESH_ROUND_STAGES = [
+    "diff.device.pack", "diff.device.transfer", "diff.device.kernel",
+    "diff.device.fetch",
+]
+
+
+def _count_command(cli_runner, repo, trace_path=None, **env):
+    """`kart diff HEAD^...HEAD -o feature-count` -> (stdout, its span events)."""
+    from kart_tpu.cli import cli
+
+    if trace_path is not None:
+        env["KART_TRACE"] = trace_path
+    r = cli_runner.invoke(
+        cli, ["-C", repo, "diff", "HEAD^...HEAD", "-o", "feature-count"], env=env
+    )
+    assert r.exit_code == 0, r.output
+    if trace_path is None:
+        return r.output, []
+    with open(trace_path) as f:
+        events = [e for e in json.load(f)["traceEvents"] if e.get("ph") == "X"]
+    os.remove(trace_path)
+    return r.output, events
+
+
+def test_mesh_count_command_names_every_stage(tmp_path, cli_runner, monkeypatch):
+    """`kart diff -o feature-count` forced onto the mesh (all eight virtual
+    devices, 256 rows a shard, so several rounds): ``diff.classify`` says
+    ``sharded_jax``; under it one ``diff.device.classify`` whose children
+    are the splits and, once per round, pack -> transfer -> kernel with the
+    fetch of the round before — each with its attributes, the packs' bytes
+    adding up to the root's — and the answer is the host engine's."""
+    import jax
+
+    from kart_tpu.diff import device_batch
+    from kart_tpu.synth import synth_repo
+
+    rows, batch_rows = 12000, 256
+    repo = str(tmp_path / "repo")
+    _, info = synth_repo(repo, rows, edit_frac=0.01)
+    monkeypatch.setattr(device_batch, "DEVICE_BATCH_ROWS", batch_rows)
+    trace = str(tmp_path / "trace.json")
+    host_out, host_events = _count_command(
+        cli_runner, repo, trace, KART_DIFF_BACKEND="host_native"
+    )
+    (classify,) = [e for e in host_events if e["name"] == "diff.classify"]
+    assert classify["args"]["backend"] == "host_native"
+    assert not [e for e in host_events if e["name"].startswith("diff.device.")]
+    telemetry.reset()
+
+    out, events = _count_command(cli_runner, repo, trace, KART_DIFF_SHARDED="1")
+    assert out == host_out and f"{info['n_edits']} features changed" in out
+    (classify,) = [e for e in events if e["name"] == "diff.classify"]
+    assert classify["args"]["backend"] == "sharded_jax"
+    assert classify["args"]["counts_only"] is True
+    (root,) = [e for e in events if e["name"] == "diff.device.classify"]
+    shards, chunks = jax.device_count(), -(-rows // batch_rows)
+    rounds = -(-chunks // shards)
+    assert rounds > 2
+    round_bytes = 2 * (shards * batch_rows * (8 + 5 * 4) + shards * 8)
+    assert root["args"] == {
+        "rows": rows, "shards": shards, "rounds": rounds, "chunks": chunks,
+        "batch_rows": batch_rows, "counts_only": True, "kernel": "binsearch",
+        "bytes": rounds * round_bytes, "parent": "diff.classify",
+        "request_id": root["args"]["request_id"],
+        "trace_id": classify["args"]["trace_id"],
+    }
+    device = [e for e in events if e["name"].startswith("diff.device.")]
+    children = [e for e in device if e is not root]
+    assert all(e["args"]["parent"] == "diff.device.classify" for e in children)
+    (splits,) = [e for e in children if e["name"] == "diff.device.splits"]
+    assert splits["args"]["chunks"] == chunks
+    by_name = {
+        name: [e for e in children if e["name"] == name] for name in MESH_ROUND_STAGES
+    }
+    assert len(children) == 1 + 4 * rounds
+    for name, spans in by_name.items():
+        assert [e["args"]["round"] for e in spans] == list(range(rounds)), name
+    assert [e["args"]["bytes"] for e in by_name["diff.device.pack"]] == (
+        [round_bytes] * rounds
+    )
+    assert sum(e["args"]["bytes"] for e in by_name["diff.device.pack"]) == (
+        root["args"]["bytes"]
+    )
+    assert [e["args"]["bytes"] for e in by_name["diff.device.transfer"]] == (
+        [round_bytes] * rounds
+    )
+    assert {e["args"]["program"] for e in by_name["diff.device.kernel"]} == {
+        "mesh_classify"
+    }
+    # counts only: three int64 come home a round, the classes never do
+    assert [e["args"]["bytes"] for e in by_name["diff.device.fetch"]] == [24] * rounds
+    # a round's fetch waits until the next round has been sent off
+    ends = {
+        name: [e["ts"] + e["dur"] for e in spans] for name, spans in by_name.items()
+    }
+    for r in range(rounds - 1):
+        assert ends["diff.device.kernel"][r + 1] <= by_name["diff.device.fetch"][r]["ts"]
+    assert sum(e["dur"] for e in children) <= root["dur"] <= classify["dur"]
+
+
+def test_mesh_classify_counts_rounds_and_bytes():
+    """The counters beside ``diff.device.batches``, and the gauges, as the
+    benchmark's reference reads them."""
+    from kart_tpu.diff.device_batch import classify_blocks_batched
+    from kart_tpu.parallel.mesh import make_mesh
+
+    telemetry.enable(metrics=True)
+    old, new = _block(3000, 3, False), _block(3000, 3, False)
+    classify_blocks_batched(old, new, mesh=make_mesh(4), batch_rows=256,
+                            counts_only=True)
+    snap = telemetry.snapshot()
+    counters = {name: v for name, _, v in snap["counters"]}
+    gauges = {name: v for name, _, v in snap["gauges"]}
+    rounds = -(--(-3000 // 256) // 4)
+    assert gauges["diff.device.shards"] == 4
+    assert gauges["diff.device.batch_rows"] == 256
+    assert counters["diff.device.rounds"] == rounds == 3
+    assert counters["diff.device.batches"] == rounds * 4
+    assert counters["diff.device.h2d_bytes"] == rounds * 2 * (4 * 256 * 28 + 4 * 8)
+
+
+def test_mesh_classify_disabled_records_nothing(monkeypatch):
+    """With telemetry off the mesh path's spans are never started and leave
+    nothing behind."""
+    from kart_tpu.diff.device_batch import classify_blocks_batched
+    from kart_tpu.parallel.mesh import make_mesh
+
+    made = []
+    real_exit = core._Span.__exit__
+
+    def counting_exit(self, *exc):
+        made.append((self.name, self._t0))
+        return real_exit(self, *exc)
+
+    monkeypatch.setattr(core._Span, "__exit__", counting_exit)
+    old, new = _block(3000, 3, False), _block(3000, 3, False)
+    classify_blocks_batched(old, new, mesh=make_mesh(4), batch_rows=256)
+    assert {name for name, _ in made} == set(MESH_ROUND_STAGES) | {
+        "diff.device.classify", "diff.device.splits"
+    }
+    assert all(t0 is None for _, t0 in made)
+    assert telemetry.drain_events() == []
+    assert telemetry.snapshot() == {"counters": [], "gauges": [], "histograms": []}
+
+
+
 def test_cli_command_is_the_root_of_a_traced_diff(tmp_path, cli_runner, monkeypatch):
     """``kart --trace diff``: ``cli.command`` is the root, every other
     main-thread event descends from it through ``args.parent`` and lies
