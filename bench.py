@@ -203,15 +203,9 @@ def worker():
         classify_blocks_host(h_old, h_new)
     host_rate = n / ((time.perf_counter() - t0) / reps)
 
-    # --- device path: the kernel variant production routing would pick for
-    # this backend (sort-join on accelerators, binary-search join on
-    # XLA-CPU — measuring the sort network on CPU benchmarks a variant the
-    # engine never uses there)
-    from kart_tpu.ops.diff_kernel import _classify_padded_binsearch
-
-    kernel = (
-        _classify_padded if info["backend"] != "cpu" else _classify_padded_binsearch
-    )
+    # --- device path: the sort-join, the one kernel every backend can run
+    # (on XLA-CPU routing never picks it: the headline there is host_rate)
+    kernel = _classify_padded
     args, n_changed = _device_args(n)
     jax.block_until_ready(args)
 
@@ -239,7 +233,7 @@ def worker():
 
     # The headline value is the rate of the engine `classify_blocks` would
     # actually route to on this backend (VERDICT r4 weak #5): the native
-    # host merge-join on XLA-CPU fallback (device_profitable routes CPU
+    # host merge-join on XLA-CPU fallback (kart_tpu.routing sends CPU
     # backends to it at every size), the device kernel on an accelerator.
     # The unrouted kernel rate stays as a secondary key.
     routed_rate = host_rate if info["backend"] == "cpu" else dev_rate
@@ -650,7 +644,8 @@ def _merge_bench():
         import numpy as np
 
         from kart_tpu.merge import materialise_conflicts
-        from kart_tpu.ops.merge_kernel import CONFLICT, merge_classify
+        from kart_tpu.diff.backend import merge_classify
+        from kart_tpu.ops.merge_kernel import CONFLICT
         from kart_tpu.parallel.sharded_diff import synthetic_block
 
         from kart_tpu.models.paths import PathEncoder
@@ -1215,23 +1210,15 @@ def _cli_diff_100m():
         assert r.exit_code == 0, r.output
         routed_s = time.perf_counter() - t0
 
-        # host engine: force the numpy classify (no device round trip); the
-        # env knob is read at module import so patch the module value too
-        os.environ["KART_DEVICE_MIN_ROWS"] = str(1 << 62)
-        os.environ["KART_DIFF_SHARDED"] = "0"
-        from kart_tpu.ops import diff_kernel
-
-        orig_min_rows = diff_kernel.DEVICE_MIN_ROWS
+        # host engine: every device route closed (no device round trip)
+        os.environ["KART_DIFF_BACKEND"] = "host_native"
         try:
-            diff_kernel.DEVICE_MIN_ROWS = 1 << 62
             t0 = time.perf_counter()
             r = runner.invoke(cli, args)
             assert r.exit_code == 0, r.output
             host_s = time.perf_counter() - t0
         finally:
-            os.environ.pop("KART_DEVICE_MIN_ROWS", None)
-            os.environ.pop("KART_DIFF_SHARDED", None)
-            diff_kernel.DEVICE_MIN_ROWS = orig_min_rows
+            os.environ.pop("KART_DIFF_BACKEND", None)
 
         # BASELINE config #4: the spatially-filtered diff through the same
         # CLI — envelope-column batch lookup, bbox prefilter kernel,
@@ -1485,15 +1472,12 @@ def multichip_worker():
 
     old_block, new_block = _multichip_slice(lo, hi)
     if mode == "mono":
-        from kart_tpu.ops.diff_kernel import (
-            _classify_split_binsearch,
-            _split_columns,
-        )
+        from kart_tpu.ops.diff_kernel import _classify_split, _split_columns
 
         # compile + first-touch at full shape (jit specialises per padded
         # bucket size, so a tiny warm pair would not pre-pay this compile)
         def run():
-            oc, ncl, _, cnt = _classify_split_binsearch(
+            oc, ncl, _, cnt = _classify_split(
                 *_split_columns(old_block),
                 *_split_columns(new_block),
                 old_block.count,
@@ -1515,12 +1499,10 @@ def multichip_worker():
         # compile with the production batch shape before the clock starts: a
         # tiny warm pair hits the same (S, B) fixed shapes as the real slice
         warm_old, warm_new = _multichip_slice(0, 4096)
-        classify_blocks_batched(warm_old, warm_new, mesh=mesh, kernel="binsearch")
+        classify_blocks_batched(warm_old, warm_new, mesh=mesh)
 
         def run():
-            return classify_blocks_batched(
-                old_block, new_block, mesh=mesh, kernel="binsearch"
-            )[2]
+            return classify_blocks_batched(old_block, new_block, mesh=mesh)[2]
 
     print(
         json.dumps({"ready": True, "probe_cached": bool(info.get("cached"))}),
@@ -1725,7 +1707,7 @@ def multichip_main():
         "ok": prewarm.returncode == 0,
         "skipped": False,
         "multichip_rows": n,
-        "multichip_kernel": "binsearch",
+        "multichip_kernel": "sort",
         "multichip_host_cores": len(cpus),
         "backend_probe_cached": 0,
         "multichip_counts_exact": 1,

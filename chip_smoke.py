@@ -61,11 +61,6 @@ ROUTING_OVERRIDES = (
     "KART_DIFF_SHARDED",
     "KART_DIFF_ENGINE",
     "KART_NO_JAX",
-    "KART_DEVICE_MIN_ROWS",
-    "KART_SHARDED_MIN_ROWS",
-    "KART_DEVICE_MIN_ENVELOPES",
-    "KART_RESIDENT_MIN_ENVELOPES",
-    "KART_STREAM_MIN_ROWS",
     "KART_DEVICE_BATCH_ROWS",
 )
 
@@ -452,7 +447,7 @@ def phase_merge(smoke):
     (most of a minute per 2.1M-row merge), so the million-conflict merge is
     run at the kernel's own entry point."""
     from kart_tpu import telemetry as tm
-    from kart_tpu.ops.merge_kernel import merge_classify
+    from kart_tpu.diff.backend import merge_classify
     from kart_tpu.synth import commit_feature_edits, synth_repo
 
     args = smoke.args
@@ -624,7 +619,7 @@ def phase_fork(smoke):
 
 def phase_one_device_mesh(smoke):
     """The device programs auto routing cannot reach on one chip —
-    should_shard wants two devices — each run once on a one-device mesh
+    routing.mesh_open wants two devices — each run once on a one-device mesh
     against its host twin, at one production batch."""
     from kart_tpu import telemetry as tm
     from kart_tpu.core.repo import KartRepo
@@ -636,7 +631,7 @@ def phase_one_device_mesh(smoke):
         boxes_vertex_column,
         refine_pairs_host,
     )
-    from kart_tpu.ops.bbox import DEVICE_MIN_ENVELOPES
+    from kart_tpu.routing import DEVICE_MIN_ENVELOPES
     from kart_tpu.ops.blocks import FeatureBlock
     from kart_tpu.ops.diff_kernel import classify_blocks_host
     from kart_tpu.parallel.mesh import make_mesh

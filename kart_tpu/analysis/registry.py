@@ -69,12 +69,6 @@ ENV_VARS = {
     "KART_DIFF_DEVICE": "source",
     "KART_DIFF_SHARDED": "source",
     "KART_DEVICE_BATCH_ROWS": "source",
-    "KART_DEVICE_MIN_ROWS": "source",
-    "KART_SHARDED_MIN_ROWS": "source",
-    "KART_STREAM_MIN_ROWS": "source",
-    "KART_STREAM_CHUNK_ROWS": "source",
-    "KART_DEVICE_MIN_ENVELOPES": "source",
-    "KART_RESIDENT_MIN_ENVELOPES": "source",
     "KART_BLOCK_PRUNE": "source",
     "KART_FUSED_JSONL": "source",
     "KART_FUSED_PROCS": "source",
@@ -341,8 +335,8 @@ DEVICE_MODULES = frozenset(
         "kart_tpu/ops/merge_kernel.py",
         "kart_tpu/parallel/__init__.py",
         "kart_tpu/parallel/mesh.py",
-        "kart_tpu/parallel/sharded_diff.py",
         "kart_tpu/parallel/sharded_merge.py",
+        "kart_tpu/routing.py",
         "kart_tpu/runtime.py",
         "bench.py",
     }
@@ -370,6 +364,9 @@ DEVICE_SEAMS = {
             # numpy predicates by default, shard_map when the row count
             # clears the sharding floor, host fallback mid-call
             "refine_intersects",
+            # merge_classify: mesh -> streamed -> monolithic -> host
+            # fallback ladder inside the function
+            "merge_classify",
             # the host overlap predicate the join counts with — the refine
             # stage recomputes it to recover the exact pair set the counts
             # hold (pure numpy, no device dependency)
@@ -387,8 +384,9 @@ DEVICE_SEAMS = {
     ),
     "kart_tpu/ops/bbox.py": frozenset(
         {
-            # bbox_intersects guards with jax_ready() and falls back to the
-            # native/numpy host scan; *_np names are the host twins
+            # bbox_intersects asks kart_tpu.routing (row floor, jax_ready())
+            # and falls back to the native/numpy host scan; *_np names are
+            # the host twins
             "bbox_intersects",
             "bbox_intersects_np",
             "bbox_blocks_np",
@@ -410,9 +408,7 @@ DEVICE_SEAMS = {
     ),
     "kart_tpu/ops/merge_kernel.py": frozenset(
         {
-            # merge_classify: sharded -> streamed -> monolithic -> host
-            # fallback ladder inside the function
-            "merge_classify",
+            # decision codes (the router is diff/backend.py merge_classify)
             "CONFLICT",
             "KEEP_OURS",
             "TAKE_THEIRS",

@@ -40,7 +40,7 @@ def _env_read_name(node):
                 "getenv",
             ) or leaf.startswith(("_env_", "env_")):
                 # the last group covers the local typed helpers
-                # (_env_int/_env_float in retry.py, diff_kernel.py, ...)
+                # (_env_int/_env_float in retry.py, device_batch.py, ...)
                 return str_const(node.args[0])
     elif isinstance(node, ast.Subscript):
         if dotted_name(node.value) in ("os.environ", "environ"):

@@ -14,7 +14,8 @@ and counts, pinned by tests):
   axis; the spatial prefilter and the estimation's sampled count ride the
   same mesh (pmapped psum — only 3 scalars leave each device).
 
-Selection (:func:`select_backend`) is ``KART_DIFF_BACKEND`` when set
+Selection (:func:`select_backend`) asks :mod:`kart_tpu.routing`, where the
+ladder and its forcing knobs live: ``KART_DIFF_BACKEND`` when set
 (``host_native`` / ``device_jax`` / ``sharded_jax``), else the cost-model
 auto route: sharding when the mesh exists and the block pays for it,
 single-device when profitable, host otherwise. The probe verdict these
@@ -30,14 +31,12 @@ asserts it stayed 0.
 """
 
 import functools
-import logging
 import os
 
 import numpy as np
 
+from kart_tpu import routing
 from kart_tpu import telemetry as tm
-
-L = logging.getLogger("kart_tpu.diff.backend")
 
 BACKENDS = {}
 
@@ -205,7 +204,7 @@ class ShardedJaxBackend(DiffBackend):
         if (
             block.envelopes is None
             or q[2] < q[0]  # wrapping query rect: host path owns the cyclic math
-            or not _device_envelopes_worthwhile(block.count)
+            or not routing.runtime_ready(block.count, routing.DEVICE_MIN_ENVELOPES)
         ):
             return super().envelope_hits(block, query)
         try:
@@ -215,7 +214,7 @@ class ShardedJaxBackend(DiffBackend):
 
     def merc_envelopes(self, env):
         e = np.asarray(env, dtype=np.float64)
-        if not _device_envelopes_worthwhile(len(e)):
+        if not routing.runtime_ready(len(e), routing.DEVICE_MIN_ENVELOPES):
             return super().merc_envelopes(e)
         try:
             return sharded_merc_envelopes(e)
@@ -242,63 +241,147 @@ class ShardedJaxBackend(DiffBackend):
             )
 
 
-def _device_envelopes_worthwhile(n):
-    from kart_tpu.ops.bbox import DEVICE_MIN_ENVELOPES
-    from kart_tpu.runtime import jax_ready
-
-    return n >= DEVICE_MIN_ENVELOPES and jax_ready()
-
-
 def select_backend(n_rows):
-    """The backend the production diff path runs ``n_rows`` through.
-
-    ``KART_DIFF_BACKEND`` picks by name (unknown names warn and fall back
-    to auto, malformed config must never kill the CLI). Auto is the cost
-    model, cheapest test first — the row-count gates run before any jax
-    import, so a small diff stays instant with a wedged accelerator."""
-    mode = os.environ.get("KART_DIFF_BACKEND", "auto")
-    if mode != "auto":
-        backend = BACKENDS.get(mode)
-        if backend is not None:
-            return backend
-        L.warning(
-            "unknown KART_DIFF_BACKEND=%r (have: %s); using auto routing",
-            mode,
-            ", ".join(sorted(BACKENDS)),
-        )
-    from kart_tpu.ops.diff_kernel import device_profitable
-    from kart_tpu.parallel.sharded_diff import should_shard
-
-    if should_shard(n_rows):
-        return BACKENDS["sharded_jax"]
-    if device_profitable(n_rows):
-        return BACKENDS["device_jax"]
-    return BACKENDS["host_native"]
+    """The backend the production diff path runs ``n_rows`` through:
+    :func:`kart_tpu.routing.select_engine`'s answer as a backend object."""
+    return BACKENDS[routing.select_engine(n_rows)]
 
 
 def warm_probe(n_rows):
     """Kick the async backend probe as soon as a diff *might* route to a
     device — init overlaps the remaining sidecar loads / prefilter instead
     of serialising after them. Row-gated so small diffs never pay the
-    background jax import, and env-gated exactly like the routing it warms
-    for: a configuration that disabled every device path (e.g. a known
-    stuck accelerator runtime) must never touch jax at all."""
-    mode = os.environ.get("KART_DIFF_BACKEND", "auto")
-    if mode == "host_native":
-        return
-    if (
-        mode == "auto"
-        and os.environ.get("KART_DIFF_DEVICE") == "0"
-        and os.environ.get("KART_DIFF_SHARDED") == "0"
-    ):
-        return  # auto routing can only ever pick host_native
-    from kart_tpu.ops.diff_kernel import DEVICE_MIN_ROWS
-    from kart_tpu.parallel.sharded_diff import _sharded_min_rows
-
-    if n_rows >= min(DEVICE_MIN_ROWS, _sharded_min_rows()):
+    background jax import, and knob-gated exactly like the routing it warms
+    for (:func:`kart_tpu.routing.any_device_route`)."""
+    if routing.any_device_route(n_rows):
         from kart_tpu.runtime import probe_backend_async
 
         probe_backend_async()
+
+
+def _mesh_or_host(n_rows, allow_device):
+    """The backend of a batch workload with a mesh kernel and a host twin
+    (projection, join, refine): the routing ladder's answer for ``n_rows``
+    — knobs, row floor, ``jax_ready()`` (a stuck runtime can't hang the
+    first device_put), no virtual CPU mesh as a production engine, two
+    devices or more: on one chip these never route to the device
+    (docs/DEVICE.md "what runs where")."""
+    return BACKENDS[
+        routing.mesh_or_host(n_rows) if allow_device else "host_native"
+    ]
+
+
+# --- 3-way merge classify: mesh -> one device -> host -----------------------
+
+def merge_classify(ancestor_block, ours_block, theirs_block):
+    """FeatureBlock x3 -> (union_keys (U,) int64 np, decision (U,) int8 np,
+    presence (U,) int8 np with bits a=1/o=2/t=4, stats dict).
+
+    Union keys are computed host-side (cheap, sorted inputs) and padded to a
+    bucket so jit shapes are reused. The ``diff.merge_classify`` span names
+    the engine that answered (``backend=`` — the merge twin of the
+    ``diff.classify`` span's attribute).
+    """
+    n_max = max(ancestor_block.count, ours_block.count, theirs_block.count)
+    with tm.span("diff.merge_classify", rows=n_max) as span:
+        result, backend = _merge_classify_routed(
+            ancestor_block, ours_block, theirs_block, n_max
+        )
+        span.set(backend=backend)
+    return result
+
+
+def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
+    """-> (merge_classify's result, the name of the backend that produced
+    it): mesh when it exists and pays, one device when profitable, the host
+    engine otherwise and beneath every device rung."""
+    from kart_tpu.ops.blocks import PAD_KEY, bucket_size
+    from kart_tpu.ops.diff_kernel import STREAM_MIN_ROWS, note_device_fallback
+    from kart_tpu.ops.merge_kernel import (
+        CONFLICT,
+        TAKE_THEIRS,
+        _merge_classify_np,
+        _merge_classify_padded,
+        merge_classify_streamed,
+    )
+
+    if routing.mesh_open(n_max):
+        # >1 device: shard-local 3-way classify over the mesh (block-cyclic
+        # PK partition; only the count vector crosses ICI)
+        from kart_tpu.parallel.sharded_merge import sharded_merge_classify
+
+        try:
+            return (
+                sharded_merge_classify(ancestor_block, ours_block, theirs_block),
+                "sharded_jax",
+            )
+        except Exception as e:
+            note_device_fallback("merge_sharded", e, "single-chip path")
+
+    if n_max >= STREAM_MIN_ROWS and routing.device_open(n_max):
+        from kart_tpu.runtime import default_backend
+
+        if default_backend() != "cpu":
+            # accelerator at north-star scale: chunked double-buffered
+            # upload instead of one monolithic 3-block transfer
+            try:
+                return (
+                    merge_classify_streamed(
+                        ancestor_block, ours_block, theirs_block
+                    ),
+                    "device_jax",
+                )
+            except Exception as e:
+                note_device_fallback("merge_streamed", e, "monolithic path")
+
+    a_real = ancestor_block.keys[: ancestor_block.count]
+    o_real = ours_block.keys[: ours_block.count]
+    t_real = theirs_block.keys[: theirs_block.count]
+    union = np.union1d(np.union1d(a_real, o_real), t_real).astype(np.int64)
+    u = len(union)
+
+    def on_host():
+        decision, presence = _merge_classify_np(
+            ancestor_block, ours_block, theirs_block, union
+        )
+        return (
+            union,
+            decision,
+            presence,
+            {
+                "conflicts": int(np.sum(decision == CONFLICT)),
+                "take_theirs": int(np.sum(decision == TAKE_THEIRS)),
+            },
+        ), "host_native"
+
+    # same cost model as classify_blocks: small merges never pay backend
+    # init / compile, and XLA-CPU backends route to the host path (where the
+    # native/numpy engines win at every size)
+    if not routing.device_open(u):
+        return on_host()
+
+    size = bucket_size(max(u, 1))
+    union_padded = np.full(size, PAD_KEY, dtype=np.int64)
+    union_padded[:u] = union
+
+    try:
+        decision, presence, n_conf, n_theirs = _merge_classify_padded(
+            ancestor_block.keys, ancestor_block.oids, ancestor_block.count,
+            ours_block.keys, ours_block.oids, ours_block.count,
+            theirs_block.keys, theirs_block.oids, theirs_block.count,
+            union_padded, u,
+        )
+    except Exception as e:
+        # device OOM / runtime failure mid-call: the merge must still
+        # complete (same guarantee classify_blocks gives the diff path)
+        note_device_fallback("merge_device", e, "host path")
+        return on_host()
+    return (
+        union,
+        np.asarray(decision)[:u],
+        np.asarray(presence)[:u],
+        {"conflicts": int(n_conf), "take_theirs": int(n_theirs)},
+    ), "device_jax"
 
 
 # --- sharded bbox prefilter kernel ------------------------------------------
@@ -390,25 +473,8 @@ def project_envelopes(env, allow_device=True):
     whose quantized value lands within a safety margin of a rounding
     boundary (:func:`kart_tpu.tiles.clip.quantize_from_merc`) — the
     exported integers are provably the host integers either way."""
-    from kart_tpu.parallel.sharded_diff import should_shard
-
     e = np.asarray(env, dtype=np.float64)
-    backend = BACKENDS["host_native"]
-    if (
-        allow_device
-        and os.environ.get("KART_DIFF_DEVICE") != "0"
-        and os.environ.get("KART_DIFF_BACKEND", "auto")
-        in ("auto", "sharded_jax")
-        # should_shard is the classify path's full readiness ladder: env
-        # gates, row floor, jax_ready() (the watchdogged probe — a stuck
-        # runtime can't hang the first device_put), and the refusal to
-        # treat a virtual CPU mesh as a production engine. It also needs
-        # >= 2 devices: on one chip this never routes to the device
-        # (docs/DEVICE.md "what runs where")
-        and should_shard(len(e))
-    ):
-        backend = BACKENDS["sharded_jax"]
-    return backend.merc_envelopes(e)
+    return _mesh_or_host(len(e), allow_device).merc_envelopes(e)
 
 
 @functools.lru_cache(maxsize=8)
@@ -585,20 +651,10 @@ def join_bbox_counts(build_env, probe_env, allow_device=True, route_rows=None):
     same host fallback. ``route_rows`` lets the caller gate on the *whole*
     probe side rather than one batch (the join streams many fixed-size
     batches through one routing decision)."""
-    from kart_tpu.parallel.sharded_diff import should_shard
-
     b = np.asarray(build_env, dtype=np.float32)
     p = np.asarray(probe_env, dtype=np.float32)
-    backend = BACKENDS["host_native"]
-    if (
-        allow_device
-        and os.environ.get("KART_DIFF_DEVICE") != "0"
-        and os.environ.get("KART_DIFF_BACKEND", "auto")
-        in ("auto", "sharded_jax")
-        and should_shard(len(p) if route_rows is None else int(route_rows))
-    ):
-        backend = BACKENDS["sharded_jax"]
-    return backend.join_counts(b, p)
+    rows = len(p) if route_rows is None else int(route_rows)
+    return _mesh_or_host(rows, allow_device).join_counts(b, p)
 
 
 # --- exact-refine batch kernel (the query engine's refine stage, ISSUE 20) --
@@ -716,37 +772,20 @@ def refine_intersects(col_a, ia, col_b, ib, allow_device=True, route_rows=None):
     only hand over pairs whose both sides have usable geometry (kind != 0);
     everything else keeps its envelope verdict — the fail-open rule that
     makes exact matches a structural subset of bbox matches."""
-    from kart_tpu.parallel.sharded_diff import should_shard
-
-    backend = BACKENDS["host_native"]
-    if (
-        allow_device
-        and os.environ.get("KART_DIFF_DEVICE") != "0"
-        and os.environ.get("KART_DIFF_BACKEND", "auto")
-        in ("auto", "sharded_jax")
-        and should_shard(len(ia) if route_rows is None else int(route_rows))
-    ):
-        backend = BACKENDS["sharded_jax"]
-    return backend.refine_pairs(col_a, ia, col_b, ib)
+    rows = len(ia) if route_rows is None else int(route_rows)
+    return _mesh_or_host(rows, allow_device).refine_pairs(col_a, ia, col_b, ib)
 
 
 # --- pmapped sampled-count reduction ----------------------------------------
 
 @functools.lru_cache(maxsize=8)
-def _make_pmapped_counts(n_dev, kernel):
+def _make_pmapped_counts(n_dev):
     import jax
 
-    from kart_tpu.ops.diff_kernel import (
-        _classify_binsearch_core,
-        _classify_mergesort_core,
-    )
-
-    core = (
-        _classify_binsearch_core if kernel == "binsearch" else _classify_mergesort_core
-    )
+    from kart_tpu.ops.diff_kernel import _classify_mergesort_core
 
     def _pmapped_counts(ok, oo, nk, no, oc, nc):
-        _, _, _, counts = core(ok, oo, nk, no, oc, nc)
+        _, _, _, counts = _classify_mergesort_core(ok, oo, nk, no, oc, nc)
         return jax.lax.psum(counts, "devices")
 
     jax.config.update("jax_enable_x64", True)  # int64 keys / PAD_KEY
@@ -761,13 +800,8 @@ def sampled_counts_pmapped(old_block, new_block):
     key-aligned, so shard-local joins equal the global join)."""
     import jax
 
-    from kart_tpu.diff.device_batch import (
-        batch_splits,
-        default_kernel,
-        pack_round,
-    )
+    from kart_tpu.diff.device_batch import batch_splits, pack_round
     from kart_tpu.ops.blocks import bucket_size
-    from kart_tpu.runtime import default_backend
 
     n_dev = jax.local_device_count()
     n_old, n_new = old_block.count, new_block.count
@@ -786,7 +820,7 @@ def sampled_counts_pmapped(old_block, new_block):
     bucket = bucket_size(cap)
     ok, oo, oc = pack_round(old_keys, old_block.oids, old_splits, 0, n_dev, bucket)
     nk, no, nc = pack_round(new_keys, new_block.oids, new_splits, 0, n_dev, bucket)
-    fn = _make_pmapped_counts(n_dev, default_kernel(default_backend()))
+    fn = _make_pmapped_counts(n_dev)
     with tm.span("diff.device.classify", rows=int(max(n_old, n_new)), shards=n_dev):
         counts = np.asarray(fn(ok, oo, nk, no, oc, nc))[0]
     return {

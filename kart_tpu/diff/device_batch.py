@@ -9,7 +9,7 @@ fixed-shape record batches**:
 * every batch ships exactly ``KART_DEVICE_BATCH_ROWS`` slots per mesh shard
   (keys int64 padded with PAD_KEY, oids uint32 (B, 5) zero-padded) plus a
   validity count — shapes never depend on the data, so XLA compiles the
-  classify **once per (mesh, kernel) pair** and reuses it across batches,
+  classify **once per mesh** and reuses it across batches,
   commits and datasets (the monolithic kernel recompiles per bucket size);
 * batch boundaries are *key-aligned across both sides*
   (:func:`batch_splits`): a key present in either revision falls in the
@@ -22,11 +22,6 @@ fixed-shape record batches**:
 * transfers are double-buffered: ``jax.device_put`` is asynchronous, so
   round ``r+1``'s host→HBM copy overlaps round ``r``'s on-device classify.
 
-Cache behaviour on CPU meshes is a real win too: the monolithic kernel's
-random access over multi-GB arrays thrashes, while a 64 Ki-row batch's
-working set (~4 MB) is cache-resident (measured 3.1x single-device at 100M
-rows on the XLA-CPU backend).
-
 Faults: the ``diff.device_transfer`` point fires at every round's
 host→device transfer; an injected (or real) failure aborts the whole device
 attempt and the backend falls back to host-native with no partial state —
@@ -35,14 +30,27 @@ results are only ever published after the final round drains.
 
 import bisect
 import functools
+import logging
+import os
 
 import numpy as np
 
 from kart_tpu import faults
 from kart_tpu import telemetry as tm
 from kart_tpu.ops.blocks import PAD_KEY
-from kart_tpu.ops.diff_kernel import _env_int
 from kart_tpu.parallel.mesh import FEATURES_AXIS
+
+L = logging.getLogger("kart_tpu.diff.device_batch")
+
+
+def _env_int(name, default):
+    """Tolerant env knob: a malformed value must never kill the CLI."""
+    try:
+        return int(os.environ.get(name, default))
+    except ValueError:
+        L.warning("ignoring malformed %s=%r", name, os.environ[name])
+        return default
+
 
 #: record-batch capacity (rows per mesh-shard slot). Default favours
 #: cache residency: 64 Ki rows = ~4 MB working set per side pair.
@@ -222,33 +230,26 @@ def pack_geom_pairs(col_a, ia, col_b, ib):
 
 
 @functools.lru_cache(maxsize=16)
-def make_batched_classify(mesh, kernel, counts_only=False):
-    """Jitted shard_map classify for fixed-shape record-batch rounds.
-
-    ``kernel``: "binsearch" (the CPU-backend join — binary search does not
-    serialise there) or "sort" (the accelerator flagship sort-join). Both
-    are bit-identical to the host engine. Inputs are the stacked
+def make_batched_classify(mesh, counts_only=False):
+    """Jitted shard_map classify for fixed-shape record-batch rounds: the
+    sort-join (``ops.diff_kernel._classify_mergesort_core``) on every
+    shard, bit-identical to the host engine. Inputs are the stacked
     (S, B[, 5]) outputs of :func:`pack_round`; outputs are per-shard class
     arrays plus the psum-reduced count vector — or, with ``counts_only``,
     the psum'd 3-vector alone (``-o feature-count`` and estimation: the
-    per-row classes never leave the devices). Cached per (mesh, kernel,
+    per-row classes never leave the devices). Cached per (mesh,
     counts_only), and because batch shapes are fixed, each cache entry
     compiles exactly once."""
     import jax
 
     from jax.sharding import PartitionSpec as P
 
-    from kart_tpu.ops.diff_kernel import (
-        _classify_binsearch_core,
-        _classify_mergesort_core,
-    )
-
-    core = _classify_binsearch_core if kernel == "binsearch" else _classify_mergesort_core
+    from kart_tpu.ops.diff_kernel import _classify_mergesort_core
 
     # the function's name is the program's on the device trace
     # (``jit__mesh_classify``): no other program of the repo shares it
     def _mesh_classify(ok, oo, nk, no, oc, nc):
-        old_class, new_class, _, counts = core(
+        old_class, new_class, _, counts = _classify_mergesort_core(
             ok[0], oo[0], nk[0], no[0], oc[0], nc[0]
         )
         total = jax.lax.psum(counts, FEATURES_AXIS)
@@ -267,15 +268,8 @@ def make_batched_classify(mesh, kernel, counts_only=False):
     return jax.jit(fn)
 
 
-def default_kernel(backend_name):
-    """The per-shard join variant production routing picks for a backend:
-    binary search on CPU, the sort network on accelerators (same crossover
-    logic as the single-device dispatcher)."""
-    return "binsearch" if backend_name == "cpu" else "sort"
-
-
 def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
-                            kernel=None, counts_only=False):
+                            counts_only=False):
     """Drop-in for ``ops.diff_kernel.classify_blocks`` executed as
     shard_map rounds of device-resident record batches over ``mesh``:
     -> (old_class int8 (n_old,), new_class (n_new,), counts dict), in
@@ -295,15 +289,12 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from kart_tpu.parallel.mesh import make_mesh
-    from kart_tpu.runtime import default_backend
 
     if mesh is None:
         mesh = make_mesh()
     n_shards = int(mesh.devices.size)
     if batch_rows is None:
         batch_rows = DEVICE_BATCH_ROWS
-    if kernel is None:
-        kernel = default_kernel(default_backend())
 
     n_old, n_new = old_block.count, new_block.count
     old_keys = np.asarray(old_block.keys[:n_old])
@@ -311,7 +302,7 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
     old_oids = old_block.oids
     new_oids = new_block.oids
 
-    fn = make_batched_classify(mesh, kernel, counts_only)
+    fn = make_batched_classify(mesh, counts_only)
     sharding = NamedSharding(mesh, P(FEATURES_AXIS))
     transfer_hook = faults.hook("diff.device_transfer")
 
@@ -346,7 +337,7 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
         shards=n_shards,
         batch_rows=batch_rows,
         counts_only=bool(counts_only),
-        kernel=kernel,
+        kernel="sort",
     ) as root:
         with tm.span("diff.device.splits") as splits:
             (old_splits, new_splits), n_chunks = batch_splits(

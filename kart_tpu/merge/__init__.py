@@ -4,7 +4,8 @@ The reference delegates tree merging to libgit2 (`repo.merge_trees`,
 `kart/merge.py:99-100`) and inherits per-feature conflicts from the
 one-feature-one-blob layout. Here the same semantics are computed directly:
 feature sets go through the vectorized 3-way kernel
-(`kart_tpu/ops/merge_kernel.py`) — one jitted classification of the whole
+(`kart_tpu/ops/merge_kernel.py`, routed by `diff/backend.py merge_classify`)
+— one jitted classification of the whole
 PK-space union per dataset — and the small residue (meta items, attachments)
 through an identical host-side rule. Clean changes are written to a merged
 tree immediately; conflicts become a MergeIndex and move the repo to the
@@ -38,12 +39,7 @@ from kart_tpu.merge.index import (
     RowPaths,
 )
 from kart_tpu.ops.blocks import FeatureBlock, unpack_oid_hex
-from kart_tpu.ops.merge_kernel import (
-    CONFLICT,
-    KEEP_OURS,
-    TAKE_THEIRS,
-    merge_classify,
-)
+from kart_tpu.ops.merge_kernel import CONFLICT, KEEP_OURS, TAKE_THEIRS
 
 
 class MergeResult:
@@ -132,6 +128,8 @@ def _merge_dataset_features(ds_path, structures, tree_builder):
         # hash-keyed identity collided (~1e-4 probability at 1e8 features):
         # host path with identical semantics
         return _merge_dataset_features_host(ds_path, blocks, datasets, tree_builder)
+
+    from kart_tpu.diff.backend import merge_classify
 
     union, decision, presence, stats = merge_classify(a_block, o_block, t_block)
 

@@ -215,19 +215,6 @@ def pad_envelopes(envelopes, multiple=None):
     return cols[0], cols[1], cols[2], cols[3], n
 
 
-from kart_tpu.ops.diff_kernel import _env_int
-
-# below this count the numpy path wins outright and never touches jax
-# measured crossover on TPU v5e: numpy wins to ~1M envelopes, the device
-# kernel is ~7x faster at 10M
-DEVICE_MIN_ENVELOPES = _env_int("KART_DEVICE_MIN_ENVELOPES", 1_000_000)
-
-# the resident cache routes to the device at the same crossover as one-shot
-# dispatch (same float32 rounding trade, so adding a cache_key never changes
-# results) — its win is skipping the transfer on repeats; lower via the env
-# knob on hosts where the kernel-only crossover (~100k) is worth float32
-RESIDENT_MIN_ENVELOPES = _env_int("KART_RESIDENT_MIN_ENVELOPES", DEVICE_MIN_ENVELOPES)
-
 _RESIDENT_CACHE = {}  # cache_key -> (w, s, e, n device arrays, count)
 _RESIDENT_CACHE_MAX = 4
 _RESIDENT_LOCK = threading.Lock()  # the HTTP server filters concurrently
@@ -270,10 +257,12 @@ def bbox_intersects(envelopes, query, *, cache_key=None):
     n = len(envelopes)
     if n == 0:
         return np.zeros(0, dtype=bool)
-    from kart_tpu.runtime import default_backend, jax_ready
+    from kart_tpu import routing
+    from kart_tpu.runtime import default_backend
 
-    min_rows = RESIDENT_MIN_ENVELOPES if cache_key is not None else DEVICE_MIN_ENVELOPES
-    if n < min_rows or not jax_ready():
+    # the ladder up to jax_ready() and no further: unlike classify and
+    # merge, an XLA-CPU backend keeps this scan (bbox_intersects_jnp)
+    if not routing.runtime_ready(n, routing.DEVICE_MIN_ENVELOPES):
         return _bbox_host(envelopes, query)
     backend = default_backend()
     if cache_key is not None:

@@ -3,7 +3,7 @@ merge-join (SURVEY.md §3.1, rich_base_dataset.py:205-300).
 
 Given two FeatureBlocks (sorted key+oid arrays), classification runs
 entirely on device with no Python per-feature work, no data-dependent control
-flow on the host, and static shapes. Three device programs with identical
+flow on the host, and static shapes. Two device programs with identical
 semantics:
 
 - ``_classify_mergesort_core_window`` (the monolithic route on a TPU,
@@ -25,8 +25,9 @@ semantics:
   key span: a bulk insert or delete of a contiguous key range) raises the
   program's overflow flag and the call is answered by the sort-join.
 - ``_classify_mergesort_core`` (the sort-join: the windowed join's exact
-  fallback, and still the program of other accelerators, the streamed
-  chunks, the mesh's record batches and ``parallel/sharded_diff.py``): one 3-operand ``lax.sort`` of
+  fallback, and the program of every other backend — XLA-CPU when forced,
+  i.e. the test suite, included — of the streamed chunks and of the mesh's
+  record batches): one 3-operand ``lax.sort`` of
   the concatenated keys (with concat position for stability and a 64-bit oid
   fold as the payload) brings every old/new pair of the same key adjacent,
   then neighbour compares classify all keys at once and scatters return
@@ -34,11 +35,8 @@ semantics:
   (0.777 s, 0.091% of the memory roof) the (n, 5) oid row gather of the
   exactness re-check is the largest op at 0.232 s, the three scatters take
   0.312 s and the four sort phases 0.221 s (PERF.md §5, PR 27).
-- ``_classify_binsearch_core``: a pair of ``searchsorted`` joins — faster
-  on CPU where binary search doesn't serialise; the monolithic route's
-  program on XLA-CPU.
 
-All three are bit-identical to the numpy reference below: each compares full
+Both are bit-identical to the numpy reference below: each compares full
 160-bit oids (the sort path re-verifies its 64-bit fold matches via a
 monotonic partner gather).
 
@@ -51,7 +49,6 @@ Classes: 0 = unchanged, 1 = insert, 2 = update, 3 = delete.
 """
 
 import logging
-import os
 
 import numpy as np
 
@@ -172,64 +169,6 @@ def _classify_mergesort_core(
 _classify_padded = lazy_jit(_classify_mergesort_core)
 
 
-def _classify_binsearch_core(
-    old_keys, old_oids, new_keys, new_oids, old_count, new_count
-):
-    """Binary-search join: the CPU-backend variant."""
-    import jax.numpy as jnp
-
-    n_old = old_keys.shape[0]
-    n_new = new_keys.shape[0]
-    old_valid = jnp.arange(n_old) < old_count
-    new_valid = jnp.arange(n_new) < new_count
-
-    # old -> new join
-    idx_in_new = jnp.searchsorted(new_keys, old_keys)
-    idx_in_new_c = jnp.minimum(idx_in_new, n_new - 1)
-    old_found = (new_keys[idx_in_new_c] == old_keys) & (idx_in_new < n_new)
-    old_found &= idx_in_new_c < new_count
-    oid_same = jnp.all(
-        old_oids == new_oids[idx_in_new_c], axis=1
-    )
-    old_class = jnp.where(
-        old_valid,
-        jnp.where(
-            old_found,
-            jnp.where(oid_same, UNCHANGED, UPDATE),
-            DELETE,
-        ),
-        UNCHANGED,
-    ).astype(jnp.int8)
-
-    # new -> old join (only inserts remain to be found)
-    idx_in_old = jnp.searchsorted(old_keys, new_keys)
-    idx_in_old_c = jnp.minimum(idx_in_old, n_old - 1)
-    new_found = (old_keys[idx_in_old_c] == new_keys) & (idx_in_old < n_old)
-    new_found &= idx_in_old_c < old_count
-    new_class = jnp.where(
-        new_valid,
-        jnp.where(new_found, UNCHANGED, INSERT),
-        UNCHANGED,
-    ).astype(jnp.int8)
-    # mark updates on the new side too (same classification, new-row view)
-    new_oid_same = jnp.all(new_oids == old_oids[idx_in_old_c], axis=1)
-    new_class = jnp.where(
-        new_valid & new_found & ~new_oid_same, UPDATE, new_class
-    ).astype(jnp.int8)
-
-    counts = jnp.stack(
-        [
-            jnp.sum(new_class == INSERT),
-            jnp.sum(old_class == UPDATE),
-            jnp.sum(old_class == DELETE),
-        ]
-    )
-    return old_class, new_class, idx_in_new_c, counts
-
-
-_classify_padded_binsearch = lazy_jit(_classify_binsearch_core)
-
-
 def _split_entry(core):
     """The jitted entry of the monolithic route: ``core`` with each of its
     four columns arriving as (body, tail) — the sidecar's own pages and one
@@ -260,7 +199,6 @@ def _split_entry(core):
 
 
 _classify_split = lazy_jit(_split_entry(_classify_mergesort_core))
-_classify_split_binsearch = lazy_jit(_split_entry(_classify_binsearch_core))
 
 
 # -- the windowed join: the monolithic route's program on a TPU --------------
@@ -657,15 +595,6 @@ def _classify_mergesort_core_window(
 _classify_window_split = lazy_jit(_split_entry(_classify_mergesort_core_window))
 
 
-def _env_int(name, default):
-    """Tolerant env knob: a malformed value must never kill the CLI."""
-    try:
-        return int(os.environ.get(name, default))
-    except ValueError:
-        L.warning("ignoring malformed %s=%r", name, os.environ[name])
-        return default
-
-
 def note_device_fallback(what, e, to):
     """One device→host rung taken: the CLI still completes, but never
     silently — ``diff.device.fallbacks{what=…}`` counts it (a measurement
@@ -677,61 +606,29 @@ def note_device_fallback(what, e, to):
     )
 
 
-# below this row count the host engine beats the device round trip (and never
-# touches backend init / compile — a `kart diff` of a small repo must be
-# instant even when the accelerator is cold or its runtime is stuck). The
-# value dates from round 2 (numpy 0.35 s vs device 1.85 s at 1M rows,
-# transfer-dominated) and predates the native host engine; re-tuning it
-# needs chip numbers for both engines in one cell (ROADMAP queue 1 #3).
-DEVICE_MIN_ROWS = _env_int("KART_DEVICE_MIN_ROWS", 2_000_000)
-
 # above this row count the accelerator path streams the blocks chunk-wise so
 # host->HBM transfer of chunk i+1 overlaps the sort of chunk i (SURVEY §2.3
-# "pipelined lazy diff streaming") instead of paying one monolithic upload
-STREAM_MIN_ROWS = _env_int("KART_STREAM_MIN_ROWS", 16_000_000)
-STREAM_CHUNK_ROWS = _env_int("KART_STREAM_CHUNK_ROWS", 8_000_000)
-
-
-def device_profitable(n_rows):
-    """Cost-model routing for the classify kernels: True when the device
-    round trip is expected to beat the host engine.
-
-    - Below DEVICE_MIN_ROWS the host path wins on any backend (no backend
-      init, no compile, no transfer) — and the check runs before any jax
-      import, so small diffs stay instant even with a wedged accelerator.
-    - On an XLA-**CPU** backend the host engine wins at *every* size: the
-      native C++ merge-join is sequential-scan bound (~1.1 s at 100M rows)
-      where the XLA join lost 13.6x at 100M (measured r3: 65.3 s vs 4.8 s),
-      and even the numpy twin beats XLA-CPU. XLA-CPU exists for correctness
-      twins and virtual-mesh tests, not as a production diff engine.
-    - On a real accelerator, size is the only question.
-
-    KART_DIFF_DEVICE=1/0 forces the answer (tests, experiments)."""
-    mode = os.environ.get("KART_DIFF_DEVICE", "auto")
-    if mode == "0":
-        return False
-    if n_rows < DEVICE_MIN_ROWS and mode != "1":
-        return False
-    from kart_tpu.runtime import default_backend, jax_ready
-
-    if not jax_ready():
-        return False
-    return mode == "1" or default_backend() != "cpu"
+# "pipelined lazy diff streaming") instead of paying one monolithic upload.
+# Monolithic or streamed is this module's own choice from the size it
+# observes, not a routing decision (kart_tpu/routing.py)
+STREAM_MIN_ROWS = 16_000_000
+STREAM_CHUNK_ROWS = 8_000_000
 
 
 def classify_blocks(old_block, new_block):
     """FeatureBlock x2 -> (old_class np.int8 (n_old,), new_class (n_new,),
     counts dict). Host wrapper: unpads and returns numpy. Routing is a cost
-    model (:func:`device_profitable`): the host engine owns small blocks,
-    CPU backends and wedged accelerators; a TPU gets the windowed join (the
-    sort-join when a tile overflows its window, counted as
-    ``diff.device.join_overflows``; other accelerators the sort-join) — and
+    model (:func:`kart_tpu.routing.device_open`): the host engine owns small
+    blocks, CPU backends and wedged accelerators; a TPU gets the windowed
+    join (the sort-join when a tile overflows its window, counted as
+    ``diff.device.join_overflows``; any other backend the sort-join) — and
     the sort-join streamed in double-buffered chunks at north-star scale so
     transfer overlaps compute. Bit-identical results on every route."""
+    from kart_tpu import routing
     from kart_tpu.runtime import default_backend
 
     n_rows = max(old_block.count, new_block.count)
-    if not device_profitable(n_rows):
+    if not routing.device_open(n_rows):
         # the host merge-join reads count-sliced views directly — callers
         # may pass unpadded (mmap-backed) blocks with no copy at all
         return classify_blocks_host(old_block, new_block)
@@ -743,7 +640,6 @@ def classify_blocks(old_block, new_block):
         from kart_tpu.ops.blocks import bucket_size
 
         backend = default_backend()
-        program = "binsearch" if backend == "cpu" else "mergesort"
         # the four stages are statements of the program, each under its own
         # span (docs/DEVICE.md §5). The two block_until_ready calls add no
         # wait: the kernel cannot start before its arguments have landed,
@@ -760,7 +656,7 @@ def classify_blocks(old_block, new_block):
             )
         with tm.span("diff.device.transfer", bytes=sum(a.nbytes for a in host)):
             dev = jax.block_until_ready([jax.device_put(a) for a in host])
-        with tm.span("diff.device.kernel", program=program, bucket=bucket) as sp:
+        with tm.span("diff.device.kernel", program="mergesort", bucket=bucket) as sp:
             args = (*dev, old_block.count, new_block.count)
             join = None
             if backend == "tpu":  # the windowed join's kernel is Mosaic's
@@ -776,11 +672,8 @@ def classify_blocks(old_block, new_block):
                     tm.incr("diff.device.join_overflows")
                     join = "sort"
             if join != "window":
-                kernel = (
-                    _classify_split_binsearch if backend == "cpu" else _classify_split
-                )
                 old_class, new_class, _, counts = jax.block_until_ready(
-                    kernel(*args)
+                    _classify_split(*args)
                 )
             if join:
                 sp.set(join=join, tiles=2 * -(-bucket // JOIN_TILE))
@@ -862,7 +755,7 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     from kart_tpu.ops.blocks import PAD_KEY, bucket_size as _bucket
 
     if chunk_rows is None:
-        chunk_rows = max(STREAM_CHUNK_ROWS, 1)
+        chunk_rows = STREAM_CHUNK_ROWS
     n_old, n_new = old_block.count, new_block.count
     old_keys = old_block.keys[:n_old]
     new_keys = new_block.keys[:n_new]

@@ -16,11 +16,14 @@ Per-key decision for versions a/o/t (absent = not present):
     otherwise        -> CONFLICT
 
 Codes: 0 = KEEP_OURS, 1 = TAKE_THEIRS, 2 = CONFLICT.
+
+This module holds the one-device kernels and their numpy twin; the router
+that picks between them and the mesh (``parallel/sharded_merge.py``) is
+:func:`kart_tpu.diff.backend.merge_classify`.
 """
 
 import numpy as np
 
-from kart_tpu import telemetry as tm
 from kart_tpu.ops._lazy import lazy_jit
 from kart_tpu.ops.blocks import PAD_KEY, bucket_size
 
@@ -86,114 +89,6 @@ def _merge_classify_padded_core(
 _merge_classify_padded = lazy_jit(_merge_classify_padded_core)
 
 
-def merge_classify(ancestor_block, ours_block, theirs_block):
-    """FeatureBlock x3 -> (union_keys (U,) int64 np, decision (U,) int8 np,
-    presence (U,) int8 np with bits a=1/o=2/t=4, stats dict).
-
-    Union keys are computed host-side (cheap, sorted inputs) and padded to a
-    bucket so jit shapes are reused. The ``diff.merge_classify`` span names
-    the engine that answered (``backend=`` — the merge twin of the
-    ``diff.classify`` span's attribute).
-    """
-    n_max = max(ancestor_block.count, ours_block.count, theirs_block.count)
-    with tm.span("diff.merge_classify", rows=n_max) as span:
-        result, backend = _merge_classify_routed(
-            ancestor_block, ours_block, theirs_block, n_max
-        )
-        span.set(backend=backend)
-    return result
-
-
-def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
-    """-> (merge_classify's result, the name of the backend that produced
-    it): mesh when it exists and pays, one device when profitable, the host
-    engine otherwise and beneath every device rung."""
-    from kart_tpu.ops.diff_kernel import (
-        STREAM_MIN_ROWS,
-        device_profitable,
-        note_device_fallback,
-    )
-    from kart_tpu.parallel.sharded_diff import should_shard
-
-    if should_shard(n_max):
-        # >1 device: shard-local 3-way classify over the mesh (block-cyclic
-        # PK partition; only the count vector crosses ICI)
-        from kart_tpu.parallel.sharded_merge import sharded_merge_classify
-
-        try:
-            return (
-                sharded_merge_classify(ancestor_block, ours_block, theirs_block),
-                "sharded_jax",
-            )
-        except Exception as e:
-            note_device_fallback("merge_sharded", e, "single-chip path")
-
-    if n_max >= STREAM_MIN_ROWS and device_profitable(n_max):
-        from kart_tpu.runtime import default_backend
-
-        if default_backend() != "cpu":
-            # accelerator at north-star scale: chunked double-buffered
-            # upload instead of one monolithic 3-block transfer
-            try:
-                return (
-                    merge_classify_streamed(
-                        ancestor_block, ours_block, theirs_block
-                    ),
-                    "device_jax",
-                )
-            except Exception as e:
-                note_device_fallback("merge_streamed", e, "monolithic path")
-
-    a_real = ancestor_block.keys[: ancestor_block.count]
-    o_real = ours_block.keys[: ours_block.count]
-    t_real = theirs_block.keys[: theirs_block.count]
-    union = np.union1d(np.union1d(a_real, o_real), t_real).astype(np.int64)
-    u = len(union)
-
-    def on_host():
-        decision, presence = _merge_classify_np(
-            ancestor_block, ours_block, theirs_block, union
-        )
-        return (
-            union,
-            decision,
-            presence,
-            {
-                "conflicts": int(np.sum(decision == CONFLICT)),
-                "take_theirs": int(np.sum(decision == TAKE_THEIRS)),
-            },
-        ), "host_native"
-
-    # same cost model as classify_blocks: small merges never pay backend
-    # init / compile, and XLA-CPU backends route to the host path (where the
-    # native/numpy engines win at every size)
-    if not device_profitable(u):
-        return on_host()
-
-    size = bucket_size(max(u, 1))
-    union_padded = np.full(size, PAD_KEY, dtype=np.int64)
-    union_padded[:u] = union
-
-    try:
-        decision, presence, n_conf, n_theirs = _merge_classify_padded(
-            ancestor_block.keys, ancestor_block.oids, ancestor_block.count,
-            ours_block.keys, ours_block.oids, ours_block.count,
-            theirs_block.keys, theirs_block.oids, theirs_block.count,
-            union_padded, u,
-        )
-    except Exception as e:
-        # device OOM / runtime failure mid-call: the merge must still
-        # complete (same guarantee classify_blocks gives the diff path)
-        note_device_fallback("merge_device", e, "host path")
-        return on_host()
-    return (
-        union,
-        np.asarray(decision)[:u],
-        np.asarray(presence)[:u],
-        {"conflicts": int(n_conf), "take_theirs": int(n_theirs)},
-    ), "device_jax"
-
-
 def merge_classify_streamed(
     ancestor_block, ours_block, theirs_block, chunk_rows=None
 ):
@@ -212,7 +107,7 @@ def merge_classify_streamed(
     from kart_tpu.ops.diff_kernel import STREAM_CHUNK_ROWS, stream_chunk_splits
 
     if chunk_rows is None:
-        chunk_rows = max(STREAM_CHUNK_ROWS, 1)
+        chunk_rows = STREAM_CHUNK_ROWS
     blocks = (ancestor_block, ours_block, theirs_block)
     reals = tuple(
         (b.keys[: b.count], b.oids[: b.count]) for b in blocks

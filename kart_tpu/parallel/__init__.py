@@ -1,31 +1,17 @@
-"""Scale-out layer: device meshes, block-cyclic PK sharding, collective
-diff/merge reductions (SURVEY.md §2.3, §7 step 7).
+"""Scale-out layer: the device mesh and the mesh-sharded 3-way merge with
+its block-cyclic PK sharding (SURVEY.md §2.3, §7 step 7). The mesh diff is
+``kart_tpu/diff/device_batch.py`` (key-range record batches).
 
 The reference scales with process fan-out (N `git fast-import` workers,
 `kart/fast_import.py:286-399`) and its "network" is the git smart protocol.
-Here the same roles are played by a `jax.sharding.Mesh`: feature blocks are
+Here the same roles are played by a `jax.sharding.Mesh`: the merge's blocks are
 partitioned over devices by PK modulus (the same invariant kart's PathEncoder
 uses to spread features over subtrees — `kart/dataset3_paths.py:283-299`), so
 every device owns a deterministic slice of PK-space in *every* revision and
-all diff/merge joins are shard-local; only the scalar counts cross the ICI
+all its joins are shard-local; only the scalar counts cross the ICI
 via `psum`.
 """
 
 from kart_tpu.parallel.mesh import make_mesh, best_device_count
-from kart_tpu.parallel.sharded_diff import (
-    classify_blocks_sharded,
-    partition_block,
-    sharded_classify,
-    sharded_diff_step,
-    should_shard,
-)
 
-__all__ = [
-    "make_mesh",
-    "best_device_count",
-    "classify_blocks_sharded",
-    "partition_block",
-    "sharded_classify",
-    "sharded_diff_step",
-    "should_shard",
-]
+__all__ = ["make_mesh", "best_device_count"]

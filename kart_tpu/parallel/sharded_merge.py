@@ -1,7 +1,8 @@
 """Mesh-sharded 3-way merge classification (VERDICT r3 next-step #7).
 
-The same block-cyclic PK-space partition as the sharded diff
-(``key % n_shards`` — kart_tpu/parallel/sharded_diff.py): a key lands on
+Blocks are partitioned host-side by ``key % n_shards`` — block-cyclic over
+PK-space, the device analog of kart's PathEncoder modulus sharding
+(`kart/dataset3_paths.py:283-299`): a key lands on
 the same shard in all three revisions, so every per-key 3-way decision is
 fully shard-local and only the (conflicts, take_theirs) count vector
 crosses the interconnect via ``psum``. Per-shard union key arrays are
@@ -23,7 +24,55 @@ import numpy as np
 
 from kart_tpu.ops.blocks import PAD_KEY, bucket_size
 from kart_tpu.parallel.mesh import FEATURES_AXIS
-from kart_tpu.parallel.sharded_diff import STATS, _repad, partition_block
+from kart_tpu.parallel.sharded_diff import STATS
+
+
+def partition_block(block, n_shards, min_bucket=256):
+    """FeatureBlock -> (keys (S, B) int64, oids (S, B, 5) uint32,
+    counts (S,) int32, src (S, B) int64): PK-modulus partition, each shard
+    sorted + padded to a common power-of-two bucket B. ``src`` maps each
+    shard slot back to the original block row (-1 for padding), so per-shard
+    results scatter back to block order.
+
+    Shard order inside a bucket remains key-sorted, so per-shard joins have
+    identical semantics to the single-chip path.
+    """
+    real_keys = block.keys[: block.count]
+    real_oids = block.oids[: block.count]
+    shard_of = (real_keys % n_shards).astype(np.int64)
+    counts = np.bincount(shard_of, minlength=n_shards).astype(np.int32)
+    bucket = bucket_size(max(int(counts.max()) if len(counts) else 1, 1), min_bucket)
+
+    keys = np.full((n_shards, bucket), PAD_KEY, dtype=np.int64)
+    oids = np.zeros((n_shards, bucket, 5), dtype=np.uint32)
+    src = np.full((n_shards, bucket), -1, dtype=np.int64)
+    # real_keys is globally sorted; a stable partition keeps each shard sorted
+    order = np.argsort(shard_of, kind="stable")
+    offsets = np.zeros(n_shards + 1, dtype=np.int64)
+    np.cumsum(counts, out=offsets[1:])
+    sorted_keys = real_keys[order]
+    sorted_oids = real_oids[order]
+    for s in range(n_shards):
+        lo, hi = offsets[s], offsets[s + 1]
+        keys[s, : hi - lo] = sorted_keys[lo:hi]
+        oids[s, : hi - lo] = sorted_oids[lo:hi]
+        src[s, : hi - lo] = order[lo:hi]
+    return keys, oids, counts, src
+
+
+def _repad(part, bucket):
+    keys, oids, counts, src = part
+    cur = keys.shape[1]
+    if cur >= bucket:
+        return part
+    s = keys.shape[0]
+    keys2 = np.full((s, bucket), PAD_KEY, dtype=np.int64)
+    keys2[:, :cur] = keys
+    oids2 = np.zeros((s, bucket, 5), dtype=np.uint32)
+    oids2[:, :cur] = oids
+    src2 = np.full((s, bucket), -1, dtype=np.int64)
+    src2[:, :cur] = src
+    return keys2, oids2, counts, src2
 
 
 def _sharded_merge_step(

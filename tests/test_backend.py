@@ -459,7 +459,7 @@ def test_classify_rung_counts_its_fallback(monkeypatch, fallback_counter):
     old, new = _pair(seed=5)
     want = classify_blocks_host(old, new)
     monkeypatch.setenv("KART_DIFF_DEVICE", "1")
-    monkeypatch.setattr(diff_kernel, "_classify_split_binsearch", _boom)
+    monkeypatch.setattr(diff_kernel, "_classify_split", _boom)
     monkeypatch.setattr(diff_kernel, "_classify_window_split", _boom)
     got = diff_kernel.classify_blocks(old, new)
     assert got[2] == want[2]
@@ -496,7 +496,7 @@ def test_merge_rungs_count_their_fallback(
     → host): same decisions as the host path, the rung taken counted under
     its own label, and the span names the engine that finally answered."""
     from kart_tpu import telemetry as tm
-    from kart_tpu.ops.merge_kernel import merge_classify
+    from kart_tpu.diff.backend import merge_classify
 
     blocks = _merge_triple()
     monkeypatch.setenv("KART_DIFF_DEVICE", "0")
@@ -516,27 +516,12 @@ def test_merge_rungs_count_their_fallback(
     assert [s["args"]["backend"] for s in spans] == ["host_native"]
 
 
-def test_block_cyclic_sharded_classify_counts_its_fallback(
-    monkeypatch, fallback_counter
-):
-    from kart_tpu.parallel import sharded_diff
-
-    old, new = _pair(seed=9)
-    want = classify_blocks_host(old, new)
-    monkeypatch.setattr(sharded_diff, "sharded_classify", _boom)
-    got = sharded_diff.classify_blocks_sharded(old, new)
-    assert got[2] == want[2]
-    np.testing.assert_array_equal(got[0], want[0])
-    np.testing.assert_array_equal(got[1], want[1])
-    assert fallback_counter("block_cyclic_classify") == 1
-
-
 def test_merge_span_names_the_backend_that_answered(monkeypatch):
     """diff.merge_classify carries backend=, the merge twin of
     diff.classify's attribute: host on XLA-CPU auto routing, the device
     kernel when forced, the mesh when forced."""
     from kart_tpu import telemetry as tm
-    from kart_tpu.ops.merge_kernel import merge_classify
+    from kart_tpu.diff.backend import merge_classify
 
     blocks = _merge_triple(seed=4)
     tm.reset()

@@ -191,7 +191,7 @@ MIXES = [
 
 
 @pytest.mark.parametrize("mix", MIXES)
-@pytest.mark.parametrize("n_shards", [1, 2, 8])
+@pytest.mark.parametrize("n_shards", [1, 2, 4, 8])  # 4: the mesh cell's
 def test_batched_classify_bit_identical_to_host_native(mix, n_shards):
     if jax.device_count() < n_shards:
         pytest.skip(f"needs {n_shards} devices")
@@ -206,14 +206,13 @@ def test_batched_classify_bit_identical_to_host_native(mix, n_shards):
     np.testing.assert_array_equal(got_new, want_new)
 
 
-@pytest.mark.parametrize("kernel", ["binsearch", "sort"])
-def test_both_shard_kernels_agree(kernel):
+def test_shard_kernel_agrees_with_host():
     rng = np.random.default_rng(17)
     old, new = _edited_pair(rng, n=2000, n_ins=19, n_upd=23, n_del=29)
     want = classify_blocks_host(old, new)
     got = classify_blocks_batched(
         old, new, mesh=make_mesh(min(jax.device_count(), 4)),
-        batch_rows=256, kernel=kernel,
+        batch_rows=256,
     )
     assert got[2] == want[2]
     np.testing.assert_array_equal(got[0], want[0])

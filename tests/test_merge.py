@@ -17,11 +17,11 @@ from kart_tpu.merge import (
 )
 from kart_tpu.merge.index import ConflictEntry, MergeIndex
 from kart_tpu.ops.blocks import FeatureBlock
+from kart_tpu.diff.backend import merge_classify
 from kart_tpu.ops.merge_kernel import (
     CONFLICT,
     KEEP_OURS,
     TAKE_THEIRS,
-    merge_classify,
     merge_classify_reference,
 )
 
@@ -289,7 +289,8 @@ class TestConflictMaterialisation:
         paths with the int encoder would collapse labels (and so conflicts)."""
         from kart_tpu.merge import materialise_conflicts
         from kart_tpu.models.paths import PathEncoder
-        from kart_tpu.ops.merge_kernel import CONFLICT, merge_classify
+        from kart_tpu.diff.backend import merge_classify
+        from kart_tpu.ops.merge_kernel import CONFLICT
 
         int_enc = PathEncoder.INT_PK_ENCODER
         keys = np.arange(4, dtype=np.int64)
@@ -327,7 +328,8 @@ class TestConflictMaterialisation:
         from kart_tpu.merge import materialise_conflicts
         from kart_tpu.models.paths import PathEncoder
         from kart_tpu.ops.blocks import hash_keys_for_paths
-        from kart_tpu.ops.merge_kernel import CONFLICT, merge_classify
+        from kart_tpu.diff.backend import merge_classify
+        from kart_tpu.ops.merge_kernel import CONFLICT
 
         int_enc = PathEncoder.INT_PK_ENCODER
         hash_enc = PathEncoder.GENERAL_ENCODER
@@ -364,7 +366,8 @@ class TestConflictMaterialisation:
     def test_labels_fall_back_per_version_without_encoder(self):
         """datasets=None versions still label every conflict distinctly."""
         from kart_tpu.merge import materialise_conflicts
-        from kart_tpu.ops.merge_kernel import CONFLICT, merge_classify
+        from kart_tpu.diff.backend import merge_classify
+        from kart_tpu.ops.merge_kernel import CONFLICT
 
         keys = np.arange(3, dtype=np.int64)
         paths = [f"aa/k{k}" for k in keys]
@@ -547,10 +550,8 @@ class TestStreamedMergeClassify:
         a key's 3-way decision)."""
         import numpy as np
 
-        from kart_tpu.ops.merge_kernel import (
-            merge_classify,
-            merge_classify_streamed,
-        )
+        from kart_tpu.diff.backend import merge_classify
+        from kart_tpu.ops.merge_kernel import merge_classify_streamed
         from kart_tpu.parallel.sharded_diff import synthetic_block
 
         monkeypatch.setenv("KART_DIFF_SHARDED", "0")
@@ -582,10 +583,8 @@ class TestStreamedMergeClassify:
         import numpy as np
 
         from kart_tpu.ops.blocks import FeatureBlock
-        from kart_tpu.ops.merge_kernel import (
-            merge_classify,
-            merge_classify_streamed,
-        )
+        from kart_tpu.diff.backend import merge_classify
+        from kart_tpu.ops.merge_kernel import merge_classify_streamed
 
         monkeypatch.setenv("KART_DIFF_SHARDED", "0")
 

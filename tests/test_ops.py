@@ -234,7 +234,7 @@ def test_feature_block_from_dataset(tmp_path):
     assert not block.has_key_collisions()
 
 
-@pytest.mark.parametrize("kernel_name", ["sort", "binsearch", "window"])
+@pytest.mark.parametrize("kernel_name", ["sort", "window"])
 def test_jitted_kernels_match_reference_directly(kernel_name):
     """The size threshold routes small classify_blocks calls to numpy — so
     drive every jitted variant directly (they must stay bit-compatible with
@@ -247,7 +247,6 @@ def test_jitted_kernels_match_reference_directly(kernel_name):
     jax.config.update("jax_enable_x64", True)  # as lazy_jit sets it
     kernel = {
         "sort": diff_kernel._classify_padded,
-        "binsearch": diff_kernel._classify_padded_binsearch,
         "window": jax.jit(diff_kernel._classify_mergesort_core_window),
     }[kernel_name]
 
@@ -416,8 +415,10 @@ def test_bbox_resident_cache():
     query = (-20.0, -20.0, 40.0, 30.0)
     ref = bbox.bbox_intersects_np(env, query)
 
-    old_min = bbox.RESIDENT_MIN_ENVELOPES
-    bbox.RESIDENT_MIN_ENVELOPES = 1
+    from kart_tpu import routing
+
+    old_min = routing.DEVICE_MIN_ENVELOPES
+    routing.DEVICE_MIN_ENVELOPES = 1
     try:
         bbox._RESIDENT_CACHE.clear()
         key = ("test", 1)
@@ -440,7 +441,7 @@ def test_bbox_resident_cache():
         got4 = bbox.bbox_intersects(env2, query, cache_key=key)
         assert np.array_equal(got4, bbox.bbox_intersects_np(env2, query))
     finally:
-        bbox.RESIDENT_MIN_ENVELOPES = old_min
+        routing.DEVICE_MIN_ENVELOPES = old_min
         bbox._RESIDENT_CACHE.clear()
 
 
@@ -537,17 +538,17 @@ def test_classify_streamed_one_side_empty():
     assert (old_class == DELETE).all()
 
 
-def test_device_profitable_cost_model(monkeypatch):
+def test_device_open_cost_model(monkeypatch):
     """Routing: CPU backends go host at every size (r3 post-mortem: XLA-CPU
     lost 13.6x to the native engine at 100M rows); small blocks go host on
     any backend; KART_DIFF_DEVICE forces either way."""
     import kart_tpu.runtime as runtime
-    from kart_tpu.ops.diff_kernel import device_profitable
+    from kart_tpu.routing import device_open
 
     monkeypatch.delenv("KART_DIFF_DEVICE", raising=False)
     # small: host, decided before any backend probe
     monkeypatch.setattr(runtime, "_probe_result", None)
-    assert not device_profitable(10)
+    assert not device_open(10)
     assert runtime._probe_result is None  # no probe happened
 
     # big + cpu backend: host
@@ -557,7 +558,7 @@ def test_device_profitable_cost_model(monkeypatch):
         {"ok": True, "backend": "cpu", "device_kind": "cpu", "n_devices": 1,
          "init_seconds": 0.0, "error": None},
     )
-    assert not device_profitable(10**9)
+    assert not device_open(10**9)
     # big + accelerator: device
     monkeypatch.setattr(
         runtime,
@@ -565,7 +566,7 @@ def test_device_profitable_cost_model(monkeypatch):
         {"ok": True, "backend": "tpu", "device_kind": "TPU v5", "n_devices": 1,
          "init_seconds": 0.0, "error": None},
     )
-    assert device_profitable(10**9)
+    assert device_open(10**9)
     # wedged: host
     monkeypatch.setattr(
         runtime,
@@ -573,7 +574,7 @@ def test_device_profitable_cost_model(monkeypatch):
         {"ok": False, "backend": None, "device_kind": None, "n_devices": 0,
          "init_seconds": 0.0, "error": "simulated"},
     )
-    assert not device_profitable(10**9)
+    assert not device_open(10**9)
     # forced
     monkeypatch.setenv("KART_DIFF_DEVICE", "0")
     monkeypatch.setattr(
@@ -582,7 +583,7 @@ def test_device_profitable_cost_model(monkeypatch):
         {"ok": True, "backend": "tpu", "device_kind": "TPU v5", "n_devices": 1,
          "init_seconds": 0.0, "error": None},
     )
-    assert not device_profitable(10**9)
+    assert not device_open(10**9)
     monkeypatch.setenv("KART_DIFF_DEVICE", "1")
     monkeypatch.setattr(
         runtime,
@@ -590,7 +591,7 @@ def test_device_profitable_cost_model(monkeypatch):
         {"ok": True, "backend": "cpu", "device_kind": "cpu", "n_devices": 1,
          "init_seconds": 0.0, "error": None},
     )
-    assert device_profitable(10)
+    assert device_open(10)
 
 
 def test_classify_streamed_disjoint_key_ranges():
@@ -893,14 +894,15 @@ def test_join_lower_bounds_equal_searchsorted(n):
     )
 
 
-@pytest.mark.parametrize("route", ["binsearch", "window"])
+@pytest.mark.parametrize("route", ["sort", "window"])
 @pytest.mark.parametrize("case", list(SPLIT_CASES) + list(JOIN_CASES))
 def test_device_classify_split_matches_reference(case, route, monkeypatch):
     """classify_blocks on the monolithic device route equals the numpy
     reference — at every edge of the body/tail split and on every shape of
     commit the windowed join has to get right: classes, counts, and never
-    by way of the host fallback. ``binsearch`` is the route as XLA-CPU
-    takes it; ``window`` is the accelerator's (the backend's name forced,
+    by way of the host fallback. ``sort`` is the route as any backend but
+    a TPU takes it (XLA-CPU here: the sort-join through ``_classify_split``);
+    ``window`` is the TPU's (the backend's name forced,
     the Pallas kernel interpreted), down to the overflow branch: a tile
     whose partners do not fit its window sends the call to the sort-join,
     which is counted and is no fallback."""
@@ -944,7 +946,7 @@ def test_device_classify_split_matches_reference(case, route, monkeypatch):
         assert attrs["tiles"] == 2 * -(-attrs["bucket"] // JOIN_TILE)
         assert overflowed == (1 if overflows else 0)
     else:
-        assert attrs["program"] == "binsearch" and "join" not in attrs
+        assert attrs["program"] == "mergesort" and "join" not in attrs
         assert overflowed == 0
 
 
@@ -1060,4 +1062,4 @@ def test_device_classify_pack_span_counts_only_the_tails(monkeypatch):
     assert events["diff.device.pack"]["bucket"] == 5120
     assert events["diff.device.pack"]["bytes"] == 2 * step * 28
     assert events["diff.device.transfer"]["bytes"] == 2 * 5120 * 28
-    assert events["diff.device.kernel"]["program"] == "binsearch"
+    assert events["diff.device.kernel"]["program"] == "mergesort"

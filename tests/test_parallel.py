@@ -1,6 +1,8 @@
-"""Mesh-sharded diff: identical counts to the single-chip and numpy paths.
+"""The mesh paths reached from the CLI and the routers, and the mesh merge
+with its block-cyclic partition: identical to the single-chip and numpy
+paths. (The mesh diff's own tests are tests/test_device_batch.py.)
 
-Runs on whatever devices are live; the multi-device cases skip below 8
+Runs on whatever devices are live; the multi-device cases skip below 2
 devices (use the virtual CPU mesh per tests/conftest.py).
 """
 
@@ -10,9 +12,8 @@ import pytest
 import jax
 
 from kart_tpu.ops.blocks import FeatureBlock, pack_oid_hex
-from kart_tpu.ops.diff_kernel import classify_blocks
-from kart_tpu.parallel import make_mesh, partition_block, sharded_classify
 from kart_tpu.parallel.sharded_diff import synthetic_block
+from kart_tpu.parallel.sharded_merge import partition_block
 
 
 def _blocks_with_edits(n=1000, n_ins=7, n_upd=11, n_del=5, seed=42):
@@ -59,62 +60,18 @@ def test_partition_block_roundtrip():
     assert np.array_equal(np.sort(all_rows), np.arange(old.count))
 
 
-@pytest.mark.parametrize("n_shards", [1, 2, 4, 8])
-def test_sharded_counts_match_single_chip(n_shards):
-    if jax.device_count() < n_shards:
-        pytest.skip(f"needs {n_shards} devices")
-    old, new, expected = _blocks_with_edits()
-    _, _, single_counts = classify_blocks(old, new)
-    mesh = make_mesh(n_shards)
-    _, _, sharded_counts, _ = sharded_classify(mesh, old, new)
-    assert single_counts == expected
-    assert sharded_counts == expected
-
-
-def test_sharded_classify_classes_cover_all_changes():
-    n_shards = min(jax.device_count(), 8)
-    old, new, expected = _blocks_with_edits(n=4096, n_ins=13, n_upd=29, n_del=17)
-    mesh = make_mesh(n_shards)
-    old_class, new_class, counts, (old_part, new_part) = sharded_classify(
-        mesh, old, new
-    )
-    assert counts == expected
-    from kart_tpu.ops.diff_kernel import DELETE, INSERT, UPDATE
-
-    assert int((new_class == INSERT).sum()) == expected["inserts"]
-    assert int((old_class == UPDATE).sum()) == expected["updates"]
-    assert int((old_class == DELETE).sum()) == expected["deletes"]
-    # classes only ever set on real rows
-    for s in range(n_shards):
-        assert np.all(old_class[s, old_part[2][s] :] == 0)
-        assert np.all(new_class[s, new_part[2][s] :] == 0)
-
-
-def test_classify_blocks_sharded_matches_single_chip():
-    """The production mesh entry point returns block-row-order classes
-    bit-identical to the single-chip classify."""
-    from kart_tpu.parallel.sharded_diff import STATS, classify_blocks_sharded
-
-    old, new, expected = _blocks_with_edits(n=2048, n_ins=19, n_upd=23, n_del=31)
-    single_old, single_new, single_counts = classify_blocks(old, new)
-    before = STATS["sharded_classify_calls"]
-    sh_old, sh_new, sh_counts = classify_blocks_sharded(old, new)
-    assert STATS["sharded_classify_calls"] == before + 1
-    assert sh_counts == single_counts == expected
-    assert np.array_equal(sh_old, single_old)
-    assert np.array_equal(sh_new, single_new)
-
-
-def test_should_shard_env_override(monkeypatch):
-    from kart_tpu.parallel.sharded_diff import should_shard
+def test_mesh_open_env_override(monkeypatch):
+    """On the suite's real (virtual CPU) devices, not a simulated platform
+    as tests/test_routing.py."""
+    from kart_tpu.routing import mesh_open
 
     monkeypatch.setenv("KART_DIFF_SHARDED", "0")
-    assert not should_shard(10**9)
+    assert not mesh_open(10**9)
     monkeypatch.setenv("KART_DIFF_SHARDED", "1")
     if jax.device_count() >= 2:
-        assert should_shard(10)
+        assert mesh_open(10)
     monkeypatch.setenv("KART_DIFF_SHARDED", "auto")
-    assert not should_shard(10)  # far below the crossover
+    assert not mesh_open(10)  # far below the crossover
 
 
 def test_engine_routes_through_mesh(tmp_path, monkeypatch):
@@ -179,7 +136,7 @@ def _merge_blocks(n=3000, seed=9):
 def test_sharded_merge_matches_single_chip(monkeypatch):
     """sharded_merge_classify must reproduce merge_classify exactly: same
     global union order, decisions, presence bits, stats."""
-    from kart_tpu.ops.merge_kernel import merge_classify
+    from kart_tpu.diff.backend import merge_classify
     from kart_tpu.parallel.sharded_diff import STATS
     from kart_tpu.parallel.sharded_merge import sharded_merge_classify
 
@@ -200,7 +157,7 @@ def test_sharded_merge_matches_single_chip(monkeypatch):
 
 def test_merge_classify_routes_through_mesh(monkeypatch):
     """KART_DIFF_SHARDED=1 routes merge_classify itself onto the mesh."""
-    from kart_tpu.ops.merge_kernel import merge_classify
+    from kart_tpu.diff.backend import merge_classify
     from kart_tpu.parallel.sharded_diff import STATS
 
     if jax.device_count() < 2:

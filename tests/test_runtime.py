@@ -15,10 +15,8 @@ from kart_tpu.ops.diff_kernel import (
     UPDATE,
     DELETE,
 )
-from kart_tpu.ops.merge_kernel import (
-    merge_classify,
-    merge_classify_reference,
-)
+from kart_tpu.diff.backend import merge_classify
+from kart_tpu.ops.merge_kernel import merge_classify_reference
 
 
 def _block(pk_to_oid):
@@ -78,9 +76,9 @@ def test_merge_classify_fallback_matches_device_path(no_jax, monkeypatch):
     """The numpy fallback must agree with the jitted kernel bit-for-bit; run
     the same inputs through both (jit path via a fresh ready probe). The
     small-input threshold is lowered so the second call genuinely jits."""
-    import kart_tpu.ops.diff_kernel as diff_kernel
+    from kart_tpu import routing
 
-    monkeypatch.setattr(diff_kernel, "DEVICE_MIN_ROWS", 0)
+    monkeypatch.setattr(routing, "DEVICE_MIN_ROWS", 0)
     # the cost model routes CPU backends to the host engine; force the
     # device kernel so this test genuinely jits
     monkeypatch.setenv("KART_DIFF_DEVICE", "1")

@@ -488,7 +488,7 @@ def test_device_classify_names_its_four_stages(rows, padded, monkeypatch):
     assert pack["args"]["bytes"] == (0 if padded else 2 * tail * (8 + 5 * 4))
     assert transfer["args"]["bytes"] == 2 * side
     assert kernel["args"] == {
-        "program": "binsearch", "bucket": bucket, "parent": "diff.classify"
+        "program": "mergesort", "bucket": bucket, "parent": "diff.classify"
     }
     assert fetch["args"]["bytes"] == 2 * bucket + 3 * 8  # int8 classes + counts
     assert select["args"]["rows"] == 2 * rows
@@ -585,7 +585,7 @@ def test_mesh_count_command_names_every_stage(tmp_path, cli_runner, monkeypatch)
     round_bytes = 2 * (shards * batch_rows * (8 + 5 * 4) + shards * 8)
     assert root["args"] == {
         "rows": rows, "shards": shards, "rounds": rounds, "chunks": chunks,
-        "batch_rows": batch_rows, "counts_only": True, "kernel": "binsearch",
+        "batch_rows": batch_rows, "counts_only": True, "kernel": "sort",
         "bytes": rounds * round_bytes, "parent": "diff.classify",
         "request_id": root["args"]["request_id"],
         "trace_id": classify["args"]["trace_id"],

@@ -1120,7 +1120,7 @@ def test_accept_q_zero_refuses_raw_mvt(served_points):
 
 def test_project_envelopes_respects_mesh_readiness(monkeypatch):
     """Review regression: the export projection seam consults the classify
-    path's full readiness ladder (should_shard) — on a CPU-default box the
+    path's full readiness ladder (kart_tpu.routing) — on a CPU-default box the
     shard_map route must NOT engage, and the host transform serves."""
     from kart_tpu.diff import backend as B
 
@@ -1132,9 +1132,7 @@ def test_project_envelopes_respects_mesh_readiness(monkeypatch):
         return real(self, env)
 
     monkeypatch.setattr(B.ShardedJaxBackend, "merc_envelopes", spying)
-    monkeypatch.setattr(
-        "kart_tpu.parallel.sharded_diff.should_shard", lambda n: False
-    )
+    monkeypatch.setattr("kart_tpu.routing.mesh_open", lambda n: False)
     env = np.random.RandomState(0).uniform(-80, 80, (2000, 4))
     host = B.BACKENDS["host_native"].merc_envelopes(env)
     got = B.project_envelopes(env)
@@ -1142,8 +1140,6 @@ def test_project_envelopes_respects_mesh_readiness(monkeypatch):
     for h, g in zip(host, got):
         assert np.array_equal(h, g)
     # and when the ladder says yes, the sharded backend is consulted
-    monkeypatch.setattr(
-        "kart_tpu.parallel.sharded_diff.should_shard", lambda n: True
-    )
+    monkeypatch.setattr("kart_tpu.routing.mesh_open", lambda n: True)
     B.project_envelopes(env)
     assert calls == [2000]

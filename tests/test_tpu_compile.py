@@ -247,14 +247,14 @@ def test_merge_classify_compiles(one_chip, bucket):
     [1024, pytest.param(DEVICE_BATCH_ROWS, marks=pytest.mark.slow)],
 )
 def test_record_batch_classify_compiles(mesh, batch_rows, counts_only):
-    """The sharded backend's shard_map classify (sort kernel, as routing
-    picks on an accelerator) over a one- and a four-device mesh."""
+    """The sharded backend's shard_map classify (the sort-join) over a one-
+    and a four-device mesh."""
     from kart_tpu.diff.device_batch import make_batched_classify
 
     n = int(mesh.devices.size)
     sharded, _ = _sharded(mesh)
     side = _block_shapes(batch_rows, sharded, lead=(n,))
-    fn = make_batched_classify(mesh, "sort", counts_only)
+    fn = make_batched_classify(mesh, counts_only)
     # arg order: old keys, old oids, new keys, new oids, old count, new count
     fn.lower(side[0], side[1], side[0], side[1], side[2], side[2]).compile()
 
@@ -289,7 +289,7 @@ def test_sharded_mercator_compiles(mesh):
     """`sharded_merc_envelopes` at DEVICE_MIN_ENVELOPES rows: f64
     sin/log on a chip that emulates f64."""
     from kart_tpu.diff.backend import _make_sharded_merc
-    from kart_tpu.ops.bbox import DEVICE_MIN_ENVELOPES
+    from kart_tpu.routing import DEVICE_MIN_ENVELOPES
 
     n = int(mesh.devices.size)
     sharded, _ = _sharded(mesh)
