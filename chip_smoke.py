@@ -84,8 +84,8 @@ def parse_args(argv=None):
     p.add_argument("--merge-conflicts", type=int, default=1_000_000)
     p.add_argument("--envelopes", type=int, default=10_000_000,
                    help="envelopes of the spatial-filter scan")
-    p.add_argument("--fork-rows", type=int, default=250_000,
-                   help="changed rows of the json-lines fork fan-out check")
+    p.add_argument("--jsonl-rows", type=int, default=250_000,
+                   help="changed rows of the json-lines materialise check")
     return p.parse_args(argv)
 
 
@@ -582,38 +582,38 @@ def phase_bbox(smoke):
         )
 
 
-def phase_fork(smoke):
-    """`-o json-lines` forks materialisation workers once the changed set
-    passes 200k rows — here after this process has touched the device, which
-    is a known way for a child to hang. The writer would redo a hung child's
-    range in-process and still exit 0, so count the children that finished:
-    each leaves its spans in the trace under its own pid."""
+def phase_materialise(smoke):
+    """`-o json-lines` makes its lines in native calls on a pool of threads
+    — here after this process has touched the device. The bytes must equal
+    the delta path's (KART_FUSED_JSONL=0), every row must have been made
+    natively, and on a host with more than one core more than one thread
+    must have made them: each leaves its `serialise.chunk` spans in the
+    trace under its own thread id."""
     from kart_tpu.synth import synth_repo
 
-    n = smoke.args.fork_rows
-    with smoke.phase("fork", rows=n) as rec:
-        repo_path = os.path.join(smoke.work, "fork")
+    n = smoke.args.jsonl_rows
+    with smoke.phase("materialise", rows=n) as rec:
+        repo_path = os.path.join(smoke.work, "materialise")
         synth_repo(
             repo_path, n, edit_frac=1.0, seed=smoke.args.seed,
             blobs="changed", ds_path=DS_PATH, spatial=True,
         )
-        forked_out = os.path.join(smoke.work, "fork-forked.jsonl")
-        serial_out = os.path.join(smoke.work, "fork-serial.jsonl")
+        fused_out = os.path.join(smoke.work, "materialise-fused.jsonl")
+        delta_out = os.path.join(smoke.work, "materialise-delta.jsonl")
         cmd = ["-C", repo_path, "diff", "HEAD^...HEAD", "-o", "json-lines"]
         _, (rec["wall_seconds"], events) = smoke.kart(
-            cmd + ["--output", forked_out]
+            cmd + ["--output", fused_out]
         )
-        smoke.kart(cmd + ["--output", serial_out], env={"KART_FUSED_PROCS": "1"})
-        children = {e["pid"] for e in events if "pid" in e} - {os.getpid()}
-        cpus = os.cpu_count() or 1
-        rec["children_finished"] = len(children)
-        rec["children_expected"] = (min(cpus, 4) if cpus >= 3 else 1) - 1
-        rec["checks"]["forked"] = rec["children_expected"] > 0
-        rec["checks"]["no_child_hung"] = (
-            rec["children_finished"] == rec["children_expected"]
+        smoke.kart(cmd + ["--output", delta_out], env={"KART_FUSED_JSONL": "0"})
+        chunks = [e for e in events if e.get("name") == "serialise.chunk"]
+        rec["chunk_threads"] = len({e["tid"] for e in chunks})
+        rec["native_rows"] = sum(e["args"].get("native_rows", 0) for e in chunks)
+        rec["checks"]["all_rows_native"] = rec["native_rows"] == n
+        rec["checks"]["threads"] = rec["chunk_threads"] >= min(
+            2, os.cpu_count() or 1, len(chunks)
         )
-        rec["checks"]["equals_serial"] = filecmp.cmp(
-            forked_out, serial_out, shallow=False
+        rec["checks"]["equals_delta_path"] = filecmp.cmp(
+            fused_out, delta_out, shallow=False
         )
 
 
@@ -757,7 +757,7 @@ def run(args, work):
     phase_merge(smoke)
     if args.chips == 1:
         phase_bbox(smoke)
-        phase_fork(smoke)
+        phase_materialise(smoke)
         phase_one_device_mesh(smoke)
 
     with smoke.phase("summary") as rec:

@@ -71,7 +71,6 @@ ENV_VARS = {
     "KART_DEVICE_BATCH_ROWS": "source",
     "KART_BLOCK_PRUNE": "source",
     "KART_FUSED_JSONL": "source",
-    "KART_FUSED_PROCS": "source",
     # import / store
     "KART_IMPORT_WORKERS": "source",
     "KART_IMPORT_FAST": "source",

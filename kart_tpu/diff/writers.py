@@ -608,6 +608,54 @@ class JsonDiffWriter(BaseDiffWriter):
         return out
 
 
+def _map_in_order(fn, items, workers):
+    """``fn(item)`` for each item on a pool of ``workers`` threads, the
+    results yielded in item order. The next item is submitted when the
+    consumer comes back for more, so at most ``workers + 1`` results exist
+    at a time (the one being consumed among them) however far the pool
+    could run ahead. An exception of ``fn`` is raised where its result
+    would have been yielded."""
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    items = iter(items)
+    pool = ThreadPoolExecutor(workers, thread_name_prefix="kart-jsonl")
+    try:
+        in_flight = deque(
+            pool.submit(fn, item) for item in itertools.islice(items, workers + 1)
+        )
+        while in_flight:
+            yield in_flight.popleft().result()
+            for item in itertools.islice(items, 1):
+                in_flight.append(pool.submit(fn, item))
+    finally:
+        pool.shutdown(wait=True, cancel_futures=True)
+
+
+def _ascii_sink(fp):
+    """``write(buffer)`` for pure-ASCII bytes bound for the text file
+    ``fp``: straight to the binary file under it where it has one whose
+    encoding and newlines leave ASCII as it is, decoded into ``fp.write``
+    otherwise. Text written to ``fp`` before is flushed down here, so order
+    holds as long as ``fp`` itself is not written to between these writes."""
+    import codecs
+    import os
+
+    raw = getattr(fp, "buffer", None)
+    try:
+        plain = (
+            raw is not None
+            and os.linesep == "\n"
+            and codecs.lookup(fp.encoding).name in ("utf-8", "ascii", "iso8859-1")
+        )
+    except (LookupError, TypeError):
+        plain = False
+    if not plain:
+        return lambda piece: fp.write(bytes(piece).decode("ascii"))
+    fp.flush()
+    return raw.write
+
+
 class JsonLinesDiffWriter(BaseDiffWriter):
     """Streaming: one JSON object per line (reference: json_diff_writers.py:279)."""
 
@@ -683,7 +731,7 @@ class JsonLinesDiffWriter(BaseDiffWriter):
             return True
         self.has_changes = True
         with tm.span("serialise.features", dataset=ds_path, rows=int(m)):
-            self._materialise_fanout(
+            self._materialise_rows(
                 rows, base_ds, target_ds, self._feature_head(ds_path)
             )
         tm.incr("serialise.features_materialised", int(m))
@@ -704,189 +752,124 @@ class JsonLinesDiffWriter(BaseDiffWriter):
                 obj["change"]["+"] = delta.new_value
             self._writeln(obj)
 
-    #: fork a second materialiser process above this many rows (linux only;
-    #: each worker serialises a contiguous row range into a temp file that
-    #: the parent streams out in order — byte-identical by construction)
-    FANOUT_MIN_ROWS = 200_000
+    #: bytes one native chunk call may write; a chunk that outgrows it comes
+    #: back in several calls (8,192 polygon rows are ~4 MB)
+    CHUNK_BUFFER_BYTES = 8 << 20
 
-    def _materialise_fanout(self, rows, base_ds, target_ds, head):
-        """Materialise all rows to self.fp, fanning the row range out over
-        cpu_count fork workers when it is large enough to pay for them (the
-        serialise loop is pure-Python and GIL-bound — a second process is
-        the only real second core at 1M-changed scale)."""
+    def _materialise_rows(self, rows, base_ds, target_ds, head):
+        """Stream a columnar row plan to ``self.fp``: chunks of
+        ``PREFETCH_CHUNK`` rows, each made into finished lines by one native
+        call that holds no GIL (``native.jsonl_chunk``: pack record ->
+        inflate -> msgpack walk -> JSON line), on as many pool threads as
+        the host has cores, at most four; this thread writes the buffers in
+        row order. At most workers + 1 chunks are in flight.
+
+        The native walk declines what it does not cover byte-exactly (a
+        delta, loose or promised record, a legend it has no plan for, a
+        value or geometry outside its fast paths, invalid UTF-8):
+        :meth:`python_line` makes exactly those rows, spliced in place —
+        and every row where ``libkart_io`` is unavailable."""
         import os
-        import tempfile
+
+        import numpy as np
+
+        from kart_tpu import native
+        from kart_tpu.ops.blocks import oid_rows_u8
 
         m = rows["count"]
-        # default only on >= 3 cpus: on a 2-vcpu box the second "core" is
-        # usually an SMT sibling or an oversubscribed host thread (measured
-        # here: two forked halves each ran at full-serial wall), so the
-        # fork+merge overhead buys nothing. KART_FUSED_PROCS forces a
-        # worker count (0/1 disables).
-        env = os.environ.get("KART_FUSED_PROCS")
-        if env is not None:
-            try:
-                n_procs = max(1, int(env))
-            except ValueError:
-                n_procs = 1
-        else:
-            cpus = os.cpu_count() or 1
-            n_procs = min(cpus, 4) if cpus >= 3 else 1
-        if (
-            m < self.FANOUT_MIN_ROWS
-            or n_procs < 2
-            or not hasattr(os, "fork")
-        ):
-            self._materialise_rows(rows, base_ds, target_ds, head, 0, m, self.fp)
-            return
-        import multiprocessing
+        pks = rows["pks"]
+        sides = [
+            (rows["old_block"], rows["old_rows"], base_ds._feature_odb(),
+             base_ds.feature_json_str_from_data),
+            (rows["new_block"], rows["new_rows"], target_ds._feature_odb(),
+             target_ds.feature_json_str_from_data),
+        ]
+        plans = [base_ds.jsonl_native_plans(), target_ds.jsonl_native_plans()]
+        head_bytes = head.encode("ascii")
+        chunk_rows = self.PREFETCH_CHUNK
 
-        # flush before forking: children inherit a copy of fp's buffer and
-        # flush it at interpreter shutdown — unflushed bytes would land in
-        # the shared file description twice
-        try:
-            self.fp.flush()
-        except (AttributeError, OSError):
-            pass
-        ctx = multiprocessing.get_context("fork")  # kart: noqa(KTL005): fork of a maybe-threaded process is tolerated by design — a child inheriting a wedged lock hangs, and the bounded join below terminates it and redoes its range in-process
-        bounds = [m * w // n_procs for w in range(n_procs + 1)]
-        workers = []
-        for w in range(1, n_procs):
-            tmp = tempfile.NamedTemporaryFile(
-                mode="w", suffix=".jsonl", delete=False
-            )
-            tmp.close()
-            lo, hi = bounds[w], bounds[w + 1]
+        def python_line(i):
+            """Row i by the per-object read and the compiled serialiser."""
+            pkv = (int(pks[i]),)
+            parts = []
+            for key, (block, side_rows, odb, to_json) in zip('-+', sides):
+                if side_rows[i] >= 0:
+                    sha = oid_rows_u8(block.oids[side_rows[i]]).tobytes()
+                    parts.append(f'"{key}":' + to_json(pkv, odb.read_blob(sha.hex())))
+            return (head + ",".join(parts) + "}}\n").encode("ascii")
 
-            def _run(path=tmp.name, lo=lo, hi=hi):
-                # the child inherited the parent's span buffer: drop it and
-                # record only this worker's spans, dumped to a trace
-                # side-file the exporter merges — the fork fan-out shows up
-                # as its own process lane in the Chrome trace
-                tm.begin_fork_child()
-                with open(path, "w") as f:
-                    self._materialise_rows(
-                        rows, base_ds, target_ds, head, lo, hi, f
-                    )
-                tm.dump_fork_child()
+        # per side, the pack that held most of the last chunk: probed first
+        first_pack = [None, None]
 
-            p = ctx.Process(target=_run, daemon=True)
-            p.start()
-            workers.append((p, tmp.name, lo, hi))
-        try:
-            import time
-
-            t0 = time.monotonic()
-            self._materialise_rows(
-                rows, base_ds, target_ds, head, bounds[0], bounds[1], self.fp
-            )
-            # a sibling range should take about as long as the parent's own;
-            # a child that inherited a wedged lock from a runtime thread
-            # (fork of a multithreaded process) hangs rather than dies, so
-            # bound the wait and redo its range in-process — the fallback
-            # must cover hangs, not just crashes
-            deadline = 10.0 * (time.monotonic() - t0) + 60.0
-            for p, path, lo, hi in workers:
-                p.join(deadline)
-                if p.is_alive():
-                    p.terminate()
-                    p.join(10)
-                if p.exitcode == 0:
-                    with open(path) as f:
-                        while True:
-                            buf = f.read(1 << 20)
-                            if not buf:
-                                break
-                            self.fp.write(buf)
-                else:  # worker died or hung: redo its range in-process
-                    self._materialise_rows(
-                        rows, base_ds, target_ds, head, lo, hi, self.fp
-                    )
-        finally:
-            for _p, path, _lo, _hi in workers:
-                try:
-                    os.unlink(path)
-                except OSError:
-                    pass
-
-    def _materialise_rows(self, rows, base_ds, target_ds, head, lo_row,
-                          hi_row, fp):
-        """Stream rows [lo_row, hi_row) of a columnar row plan to ``fp``."""
-        from concurrent.futures import ThreadPoolExecutor
-
-        from kart_tpu.ops.blocks import unpack_oid_bytes
-
-        old_block, new_block = rows["old_block"], rows["new_block"]
-        pks, old_rows, new_rows = rows["pks"], rows["old_rows"], rows["new_rows"]
-        old_odb = base_ds._feature_odb()
-        new_odb = target_ds._feature_odb()
-        old_json = base_ds.feature_json_str_from_data
-        new_json = target_ds.feature_json_str_from_data
-        write = fp.write
-        chunk_size = self.PREFETCH_CHUNK
-
-        def read_chunk(lo):
-            """Ordered blob data for one chunk: (pk list, old data+shas,
-            new data+shas, presence masks). The native batch inflate behind
-            read_blobs_data_ordered releases the GIL, so prefetching chunk
-            i+1 on the pool thread overlaps chunk i's serialisation."""
-            hi = min(lo + chunk_size, hi_row)
-            o_sel = old_rows[lo:hi]
-            n_sel = new_rows[lo:hi]
-            o_shas = unpack_oid_bytes(old_block.oids[o_sel[o_sel >= 0]])
-            n_shas = unpack_oid_bytes(new_block.oids[n_sel[n_sel >= 0]])
-            if old_odb is new_odb:
-                datas = old_odb.read_blobs_data_ordered(o_shas + n_shas)
-                o_data = datas[: len(o_shas)]
-                n_data = datas[len(o_shas) :]
-            else:
-                o_data = old_odb.read_blobs_data_ordered(o_shas)
-                n_data = new_odb.read_blobs_data_ordered(n_shas)
-            return (
-                pks[lo:hi].tolist(),
-                o_data,
-                o_shas,
-                n_data,
-                n_shas,
-                (o_sel >= 0).tolist(),
-                (n_sel >= 0).tolist(),
-            )
-
-        with ThreadPoolExecutor(1) as pool:
-            fut = pool.submit(read_chunk, lo_row)
-            for lo in range(lo_row, hi_row, chunk_size):
-                pk_chunk, o_data, o_shas, n_data, n_shas, o_mask, n_mask = (
-                    fut.result()
+        def locate(lo, hi):
+            """-> (pack mmaps, [old, new] x (which int32, offsets int64))
+            for rows lo..hi: -1 where the row lacks the side."""
+            bufs, located = [], []
+            for s, (block, side_rows, odb, _) in enumerate(sides):
+                sel = side_rows[lo:hi]
+                has = sel >= 0
+                packs, which, offsets = odb.packs.locate_blobs(
+                    oid_rows_u8(block.oids[sel[has]]), first=first_pack[s]
                 )
-                if lo + chunk_size < hi_row:
-                    fut = pool.submit(read_chunk, lo + chunk_size)
-                with tm.span("serialise.chunk", rows=len(pk_chunk)):
-                    lines = []
-                    append = lines.append
-                    oi = ni = 0
-                    for j, pk in enumerate(pk_chunk):
-                        pkv = (pk,)
-                        if o_mask[j]:
-                            data = o_data[oi]
-                            if data is None:
-                                # loose / delta / promised: per-object fallback
-                                data = old_odb.read_blob(o_shas[oi].hex())
-                            oi += 1
-                            body = '"-":' + old_json(pkv, data)
-                            if n_mask[j]:
-                                data = n_data[ni]
-                                if data is None:
-                                    data = new_odb.read_blob(n_shas[ni].hex())
-                                ni += 1
-                                body += ',"+":' + new_json(pkv, data)
-                        else:
-                            data = n_data[ni]
-                            if data is None:
-                                data = new_odb.read_blob(n_shas[ni].hex())
-                            ni += 1
-                            body = '"+":' + new_json(pkv, data)
-                        append(head + body + "}}\n")
-                    write("".join(lines))
+                held = np.bincount(which[which >= 0], minlength=1)
+                if held.any():
+                    first_pack[s] = packs[int(held.argmax())]
+                which[which >= 0] += len(bufs)
+                bufs += [p._mm for p in packs]
+                full_which = np.full(hi - lo, -1, dtype=np.int32)
+                full_offsets = np.full(hi - lo, -1, dtype=np.int64)
+                full_which[has] = which
+                full_offsets[has] = offsets
+                located.append((full_which, full_offsets))
+            return bufs, located
+
+        def materialise_chunk(lo):
+            """Rows lo..lo+chunk_rows as byte pieces, in order."""
+            hi = min(lo + chunk_rows, m)
+            # a pool thread has no open span: name the parent
+            with tm.span(
+                "serialise.chunk", rows=hi - lo, parent="serialise.features"
+            ) as sp:
+                bufs, ((o_which, o_off), (n_which, n_off)) = locate(lo, hi)
+                pieces, pos, declined = [], lo, {}
+                while pos < hi:
+                    at = pos - lo
+                    out = np.empty(self.CHUNK_BUFFER_BYTES, dtype=np.uint8)
+                    res = native.jsonl_chunk(
+                        bufs, o_which[at:], o_off[at:], n_which[at:],
+                        n_off[at:], pks[pos:hi], head_bytes, plans[0],
+                        plans[1], out,
+                    )
+                    if res is None:  # no library
+                        declined["no_native"] = hi - pos
+                        pieces += [python_line(i) for i in range(pos, hi)]
+                        break
+                    total, done, row_end, status = res
+                    start = 0
+                    for r in np.nonzero(status[:done])[0].tolist():
+                        why = native.JSONL_WHY[status[r]]
+                        declined[why] = declined.get(why, 0) + 1
+                        pieces += [out[start:row_end[r]], python_line(pos + r)]
+                        start = row_end[r]
+                    pieces.append(out[start:total])
+                    pos += done
+                pieces = [p for p in pieces if len(p)]
+                n_python = sum(declined.values())
+                n_bytes = sum(len(p) for p in pieces)
+                sp.set(native_rows=hi - lo - n_python, bytes=n_bytes)
+            tm.incr("serialise.rows_native", hi - lo - n_python)
+            for why, n in declined.items():
+                tm.incr("serialise.rows_python", n, why=why)
+            return pieces, n_bytes
+
+        write = _ascii_sink(self.fp)
+        workers = max(1, min(os.cpu_count() or 1, 4))
+        for pieces, n_bytes in _map_in_order(
+            materialise_chunk, range(0, m, chunk_rows), workers
+        ):
+            with tm.span("serialise.write", bytes=n_bytes):
+                for piece in pieces:
+                    write(piece)
 
     def write_ds_diff(self, ds_path, ds_diff):
         import os

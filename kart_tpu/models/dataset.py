@@ -484,6 +484,20 @@ class Dataset3:
             plans[legend_hash] = plan
         return plan
 
+    def jsonl_native_plans(self):
+        """The :meth:`_jsonl_plan` of every legend under ``meta/legend/``,
+        packed for ``native.jsonl_chunk``: what the native materialiser
+        serialises a blob by. A blob whose legend is not among them comes
+        back declined and takes :meth:`feature_json_str_from_data`."""
+        from kart_tpu import native
+
+        inner = self.inner_tree
+        legends = None
+        if inner is not None:
+            legends = inner.get_or_none(self.LEGEND_PATH.rstrip("/"))
+        names = [e.name for e in legends.entries()] if legends is not None else []
+        return native.pack_jsonl_plans({h: self._jsonl_plan(h) for h in names})
+
     def _jsonl_serializer(self, legend_hash):
         """Per-legend *compiled* serialiser ``fn(pk_values, non_pk_values)
         -> json object text``: the column plan unrolled into straight-line

@@ -196,7 +196,7 @@ def ensure_built():
 # -- object-store IO core (native/kart_io.cpp) ------------------------------
 
 _IO_LIB_NAME = "libkart_io.so"
-_IO_ABI_VERSION = 7  # v7: io_leaf_payloads leaf-tree kernel
+_IO_ABI_VERSION = 8  # v8: io_idx_probe, io_jsonl_chunk
 
 _io_lib = None
 _io_load_attempted = False
@@ -283,6 +283,21 @@ def load_io():
             ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
             ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_void_p,
+        ]
+        lib.io_idx_probe.restype = ctypes.c_int64
+        lib.io_idx_probe.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+            ctypes.c_int64, ctypes.c_void_p,
+        ]
+        lib.io_jsonl_chunk.restype = ctypes.c_int64
+        lib.io_jsonl_chunk.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_char_p, ctypes.c_int64,
+            ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ]
         _io_lib = lib
     except (OSError, AttributeError) as e:
@@ -591,6 +606,83 @@ def inflate_pack_batch(pack_buf, offsets, max_total=None):
     if rc < 0:
         return None
     return take, types, out, out_offsets
+
+
+def idx_probe(idx_u8, shas_u8):
+    """.idx v2 file as a uint8 array + (n, 20) uint8 shas -> int64 pack
+    offsets (-1 where the index does not hold the sha), or None when the
+    lib is unavailable or refuses the index (the numpy probe answers)."""
+    lib = load_io()
+    if lib is None:
+        return None
+    shas_u8 = np.ascontiguousarray(shas_u8, dtype=np.uint8).reshape(-1, 20)
+    out = np.empty(len(shas_u8), dtype=np.int64)
+    rc = lib.io_idx_probe(
+        idx_u8.ctypes.data, len(idx_u8), shas_u8.ctypes.data, len(shas_u8),
+        out.ctypes.data,
+    )
+    return out if rc == 0 else None
+
+
+#: why io_jsonl_chunk declined a row (kart_io.cpp JsonlWhy), as the
+#: ``why`` label of ``serialise.rows_python``
+JSONL_WHY = ("", "record", "legend", "type", "geometry", "utf8", "size")
+
+
+def pack_jsonl_plans(plans):
+    """{legend hash: Dataset3._jsonl_plan(hash)} -> the plans blob
+    io_jsonl_chunk reads (layout: kart_io.cpp parse_jsonl_plans)."""
+    import struct
+
+    out = [struct.pack("<I", len(plans))]
+    for legend_hash, cols in plans.items():
+        h = legend_hash.encode()
+        out.append(struct.pack("<I", len(h)) + h + struct.pack("<I", len(cols)))
+        for prefix, src, is_geom in cols:
+            kind, idx = (0, 0) if src is None else (1 if src[0] else 2, src[1])
+            pre = prefix.encode("ascii")
+            out.append(struct.pack("<IIBI", kind, idx, bool(is_geom), len(pre)) + pre)
+    return b"".join(out)
+
+
+def jsonl_chunk(pack_bufs, old_pack, old_off, new_pack, new_off, pks, head,
+                old_plans, new_plans, out):
+    """Feature lines of one chunk of the json-lines row plan, written into
+    the uint8 array ``out`` with the GIL released (kart_io.cpp
+    io_jsonl_chunk). ``old_pack``/``new_pack`` int32: index into
+    ``pack_bufs`` (mmaps of whole packfiles), -1 side absent, -2 in no pack;
+    ``*_off`` int64 record offsets; ``*_plans`` from :func:`pack_jsonl_plans`.
+    -> (bytes written, rows consumed, row_end int64, status uint8), rows
+    consumed < len(pks) when ``out`` filled up first; None when the lib is
+    unavailable or refuses the arguments."""
+    lib = load_io()
+    if lib is None:
+        return None
+    n = len(pks)
+    pks = np.ascontiguousarray(pks, dtype=np.int64)
+    old_pack = np.ascontiguousarray(old_pack, dtype=np.int32)
+    new_pack = np.ascontiguousarray(new_pack, dtype=np.int32)
+    old_off = np.ascontiguousarray(old_off, dtype=np.int64)
+    new_off = np.ascontiguousarray(new_off, dtype=np.int64)
+    if not (len(old_pack) == len(old_off) == len(new_pack) == len(new_off) == n):
+        raise ValueError("jsonl_chunk: row arrays differ in length")
+    bufs = [np.frombuffer(b, dtype=np.uint8) for b in pack_bufs]
+    ptrs = (ctypes.c_void_p * max(1, len(bufs)))(*[b.ctypes.data for b in bufs])
+    lens = np.array([len(b) for b in bufs], dtype=np.int64)
+    row_end = np.empty(n, dtype=np.int64)
+    status = np.empty(n, dtype=np.uint8)
+    done = ctypes.c_int64(0)
+    total = lib.io_jsonl_chunk(
+        ptrs, lens.ctypes.data, len(bufs), n,
+        old_pack.ctypes.data, old_off.ctypes.data,
+        new_pack.ctypes.data, new_off.ctypes.data, pks.ctypes.data,
+        head, len(head), old_plans, len(old_plans), new_plans, len(new_plans),
+        out.ctypes.data, len(out), row_end.ctypes.data, status.ctypes.data,
+        ctypes.byref(done),
+    )
+    if total < 0:
+        return None
+    return int(total), done.value, row_end, status
 
 
 def _store_max():

@@ -88,11 +88,16 @@ def unpack_oid_hex(oid_rows):
     return [h[i : i + 40] for i in range(0, len(h), 40)]
 
 
+def oid_rows_u8(oid_rows):
+    """(N, 5) uint32 -> (N, 20) uint8: the shas as bytes, one row each."""
+    return np.ascontiguousarray(oid_rows).astype("<u4").view(np.uint8).reshape(-1, 20)
+
+
 def unpack_oid_bytes(oid_rows):
     """(N, 5) uint32 -> list of 20-byte shas (one buffer copy + slices)."""
     if not len(oid_rows):
         return []
-    b = np.ascontiguousarray(oid_rows).astype("<u4").view(np.uint8).tobytes()
+    b = oid_rows_u8(oid_rows).tobytes()
     return [b[i : i + 20] for i in range(0, len(b), 20)]
 
 

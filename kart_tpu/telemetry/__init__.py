@@ -23,11 +23,9 @@ from kart_tpu.telemetry.core import (  # noqa: F401
     SUBSYSTEMS,
     Phases,
     all_metric_names,
-    begin_fork_child,
     counters_snapshot,
     default_trace_path,
     drain_events,
-    dump_fork_child,
     enable,
     enable_from_env,
     events_dropped_count,

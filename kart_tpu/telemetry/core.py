@@ -12,9 +12,8 @@ observability they aren't using. The enablement ladder:
 * ``KART_TRACE=<path|1>`` or ``kart --trace <cmd>`` additionally records
   **span events** (begin/end timestamps, thread + process ids) for the
   Chrome trace-event export (:mod:`kart_tpu.telemetry.sinks`), loadable in
-  Perfetto / ``chrome://tracing``. Thread ids are real, so the PR 1
-  prefetch thread shows up as its own lane; fork fan-out workers dump
-  side-files the exporter merges.
+  Perfetto / ``chrome://tracing``. Thread ids are real, so the json-lines
+  chunk workers show up as their own lanes.
 * ``-v`` on the CLI enables span aggregation only, feeding the
   end-of-command phase summary.
 
@@ -37,7 +36,6 @@ matching :data:`NAME_RE`, with the first segment drawn from
 ``kart_a_b`` (``_total`` suffix for counters).
 """
 
-import json
 import logging
 import os
 import threading
@@ -461,39 +459,6 @@ def drain_events():
         out = list(_events)
         _events.clear()
     return out
-
-
-def child_trace_sidecar_path(path=None):
-    """Where a fork worker dumps its events for the parent exporter to
-    merge."""
-    base = path or _trace_path or default_trace_path()
-    return f"{base}.child-{os.getpid()}"
-
-
-def begin_fork_child():
-    """Call at the top of a forked worker: drop the inherited event buffer
-    (the parent keeps the originals) so the child records only its own
-    spans."""
-    with _lock:
-        _events.clear()
-
-
-def dump_fork_child():
-    """Write a forked worker's events to the trace side-file (merged by
-    ``sinks.write_chrome_trace``). Safe no-op when not tracing."""
-    if not _TRACE_ON:
-        return
-    events = drain_events()
-    if not events:
-        return
-    path = child_trace_sidecar_path()
-    try:
-        with open(path, "w") as f:
-            json.dump(events, f)
-    except OSError as e:
-        # best-effort stays best-effort (a worker must never die for its
-        # trace), but the loss is no longer silent
-        L.warning("trace side-file %s not written: %s", path, e)
 
 
 # -- explicit phase accounting ---------------------------------------------

@@ -6,10 +6,7 @@ Formats (documented in docs/OBSERVABILITY.md):
 * **Chrome trace** — the ``{"traceEvents": [...]}`` JSON object format,
   loadable in Perfetto / ``chrome://tracing``. Every span is a complete
   ``"ph": "X"`` event carrying real pid/tid, plus ``thread_name`` metadata
-  events so the prefetch thread and fork workers render as named lanes.
-  Fork workers dump their events to ``<path>.child-<pid>`` side-files
-  (:func:`kart_tpu.telemetry.core.dump_fork_child`); the exporter merges
-  and removes them.
+  events so worker threads render as named lanes.
 * **Prometheus exposition** — ``kart_<name with dots as underscores>``;
   counters get a ``_total`` suffix, histograms emit ``_count`` and
   ``_sum``. Served by the transport servers at ``GET /api/v1/stats`` (and
@@ -18,7 +15,6 @@ Formats (documented in docs/OBSERVABILITY.md):
   counts, printed to stderr on ``-v``.
 """
 
-import glob
 import json
 import logging
 import os
@@ -29,25 +25,13 @@ L = logging.getLogger("kart_tpu.telemetry.sinks")
 
 
 def write_chrome_trace(path=None):
-    """Write every recorded span event (plus any fork-worker side-files) as
-    Chrome trace-event JSON. Events dropped at the buffer cap are surfaced
-    as a ``kart_events_dropped`` metadata event so a truncated trace says
-    so. -> the path written, or None when there was nothing to write."""
+    """Write every recorded span event as Chrome trace-event JSON. Events
+    dropped at the buffer cap are surfaced as a ``kart_events_dropped``
+    metadata event so a truncated trace says so. -> the path written, or
+    None when there was nothing to write."""
     path = path or core.trace_path() or core.default_trace_path()
     dropped = core.events_dropped_count()
     events = core.drain_events()
-    for side in sorted(glob.glob(f"{path}.child-*")):
-        try:
-            with open(side) as f:
-                events.extend(json.load(f))
-        except (OSError, ValueError) as e:
-            # the merge stays best-effort (a bad side-file must not kill
-            # the parent's trace) but the skip is no longer silent
-            L.warning("trace side-file %s unreadable; skipped: %s", side, e)
-        try:
-            os.unlink(side)
-        except OSError as e:
-            L.warning("merged trace side-file %s not removed: %s", side, e)
     if not events:
         return None
     # name the lanes: one metadata event per (pid, tid) observed

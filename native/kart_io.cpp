@@ -10,9 +10,12 @@
 // fallback of identical behavior. ABI: see io_abi_version.
 
 #include <algorithm>
+#include <charconv>
+#include <cmath>
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <new>
 #include <vector>
 
 #include <dlfcn.h>
@@ -680,11 +683,578 @@ int encode_row(GpkgReader* r, SqliteApi* sq) {
     return 0;
 }
 
+
+// ---------------------------------------------------------------------------
+// json-lines materialisation (the fused `kart diff -o json-lines` row plan):
+// the .idx probe, and one chunk of feature lines from pack records — zlib
+// inflate into scratch, a walk over the msgpack feature blob in place, the
+// finished line appended to the caller's buffer. Both run without the GIL.
+//
+// The contract is byte-identity with Dataset3.feature_json_str_from_data
+// (the stdlib JSON encoder with separators=(",", ":"), ensure_ascii=True).
+// Whatever this walk does not cover it DECLINES, row by row, and the Python
+// caller produces that row itself: see JsonlWhy.
+// ---------------------------------------------------------------------------
+
+inline uint64_t load_be64(const uint8_t* p) {
+    uint64_t v;
+    std::memcpy(&v, p, 8);
+    return __builtin_bswap64(v);
+}
+
+inline uint32_t load_be32(const uint8_t* p) {
+    uint32_t v;
+    std::memcpy(&v, p, 4);
+    return __builtin_bswap32(v);
+}
+
+inline uint16_t load_be16(const uint8_t* p) {
+    return uint16_t((uint16_t(p[0]) << 8) | p[1]);
+}
+
+// 20-byte shas in .idx order (memcmp order), compared as big-endian words
+inline int sha_cmp(const uint8_t* a, const uint8_t* b) {
+    uint64_t x = load_be64(a), y = load_be64(b);
+    if (x != y) return x < y ? -1 : 1;
+    x = load_be64(a + 8);
+    y = load_be64(b + 8);
+    if (x != y) return x < y ? -1 : 1;
+    uint32_t u = load_be32(a + 16), v = load_be32(b + 16);
+    if (u != v) return u < v ? -1 : 1;
+    return 0;
+}
+
+// Position of sha in table[lo, hi) (sorted 20-byte entries that share the
+// first byte), or -1. A binary search, entered where a uniformly spread sha
+// would lie: shas are uniform, so the first guess lands within a few
+// hundred entries of a 10M-entry table's answer and the gallop out of it
+// brackets the answer inside one or two pages — where a search from the
+// bucket's middle takes a cache miss a level (~15 in that table). Correct
+// for any table; only the speed leans on the spread.
+int64_t idx_find(const uint8_t* table, int64_t lo, int64_t hi,
+                 const uint8_t* sha) {
+    if (lo >= hi) return -1;
+    uint64_t frac = load_be64(sha) << 8;  // what follows the bucket's byte
+    int64_t at = lo + int64_t((__uint128_t(frac) * uint64_t(hi - lo)) >> 64);
+    int c = sha_cmp(table + 20 * at, sha);
+    if (c == 0) return at;
+    if (c < 0) {  // everything below lo is smaller than sha
+        lo = at + 1;
+        for (int64_t step = 1; lo + step - 1 < hi; step *= 2) {
+            at = lo + step - 1;
+            c = sha_cmp(table + 20 * at, sha);
+            if (c == 0) return at;
+            if (c > 0) {
+                hi = at;
+                break;
+            }
+            lo = at + 1;
+        }
+    } else {  // everything from hi on is larger than sha
+        hi = at;
+        for (int64_t step = 1; hi - step >= lo; step *= 2) {
+            at = hi - step;
+            c = sha_cmp(table + 20 * at, sha);
+            if (c == 0) return at;
+            if (c < 0) {
+                lo = at + 1;
+                break;
+            }
+            hi = at;
+        }
+    }
+    while (lo < hi) {
+        at = lo + (hi - lo) / 2;
+        c = sha_cmp(table + 20 * at, sha);
+        if (c == 0) return at;
+        if (c < 0) lo = at + 1;
+        else hi = at;
+    }
+    return -1;
+}
+
+// why a row was declined (status_out of io_jsonl_chunk; 0 = line written).
+// kart_tpu/native/__init__.py JSONL_WHY names them for the counter label.
+enum JsonlWhy : uint8_t {
+    JSONL_OK = 0,
+    JSONL_RECORD = 1,    // not a plain blob record in a pack: delta, loose,
+                         // promised, another object type, a failed inflate
+    JSONL_LEGEND = 2,    // blob head unreadable, or no plan for its legend
+    JSONL_TYPE = 3,      // a msgpack value outside nil/bool/int/float/str/
+                         // bin/geometry ext, or bytes after the blob
+    JSONL_GEOMETRY = 4,  // outside gpkg_hex_wkb's fast path (big-endian WKB,
+                         // extended header, bad envelope code, truncated)
+    JSONL_UTF8 = 5,      // a str that strict UTF-8 decoding refuses
+    JSONL_SIZE = 6,      // a blob or its line larger than the whole output buffer
+};
+
+struct JsonlCol {
+    int32_t kind;  // 0: no source (null), 1: pk value, 2: blob value
+    int32_t idx;
+    bool geom;
+    const uint8_t* prefix;  // pre-escaped `,"name":`
+    uint32_t prefix_len;
+};
+
+struct JsonlPlan {
+    const uint8_t* hash;
+    uint32_t hash_len;
+    std::vector<JsonlCol> cols;
+};
+
+// plans blob (kart_tpu.native.pack_jsonl_plans): u32 n_legends, then per
+// legend u32 hash_len, hash, u32 n_cols, per col i32 kind, i32 idx, u8
+// geom, u32 prefix_len, prefix. All little-endian.
+bool parse_jsonl_plans(const uint8_t* p, int64_t len,
+                       std::vector<JsonlPlan>& out) {
+    const uint8_t* end = p + len;
+    auto u32 = [&](uint32_t* v) {
+        if (end - p < 4) return false;
+        std::memcpy(v, p, 4);
+        p += 4;
+        return true;
+    };
+    uint32_t n_legends;
+    if (!u32(&n_legends)) return false;
+    for (uint32_t l = 0; l < n_legends; l++) {
+        JsonlPlan plan;
+        uint32_t n_cols;
+        if (!u32(&plan.hash_len) || end - p < int64_t(plan.hash_len))
+            return false;
+        plan.hash = p;
+        p += plan.hash_len;
+        if (!u32(&n_cols)) return false;
+        for (uint32_t c = 0; c < n_cols; c++) {
+            JsonlCol col;
+            uint32_t kind, idx;
+            if (!u32(&kind) || !u32(&idx) || end - p < 1) return false;
+            col.kind = int32_t(kind);
+            col.idx = int32_t(idx);
+            col.geom = *p++ != 0;
+            if (!u32(&col.prefix_len) || end - p < int64_t(col.prefix_len))
+                return false;
+            col.prefix = p;
+            p += col.prefix_len;
+            if (col.kind < 0 || col.kind > 2 || col.idx < 0) return false;
+            plan.cols.push_back(col);
+        }
+        out.push_back(std::move(plan));
+    }
+    return p == end;
+}
+
+// one line under construction; grows as needed, reused across rows
+struct LineBuf {
+    std::vector<uint8_t> v;
+    size_t n = 0;
+    uint8_t* room(size_t extra) {
+        if (n + extra > v.size()) v.resize((n + extra) * 2 + 256);
+        return v.data() + n;
+    }
+    void put(const uint8_t* p, size_t len) {
+        std::memcpy(room(len), p, len);
+        n += len;
+    }
+    void lit(const char* s) { put(reinterpret_cast<const uint8_t*>(s), std::strlen(s)); }
+};
+
+enum ValKind : uint8_t { V_NIL, V_FALSE, V_TRUE, V_INT, V_UINT, V_F64, V_STR, V_BIN, V_GEOM };
+
+struct Val {
+    ValKind kind;
+    bool used;  // some column of the plan emitted it
+    union {
+        int64_t i;
+        uint64_t u;
+        double d;
+    };
+    const uint8_t* p;
+    uint32_t n;
+};
+
+// Python's float.__repr__: the shortest digits that round-trip (to_chars
+// gives the same digits as dtoa mode 0), fixed notation with a '.0' for
+// -4 < decimal point position <= 16, else d[.ddd]e+XX with >= 2 exponent
+// digits; json's names for the non-finite.
+void put_float(LineBuf& o, double d) {
+    if (d != d) return o.lit("NaN");
+    if (d == HUGE_VAL) return o.lit("Infinity");
+    if (d == -HUGE_VAL) return o.lit("-Infinity");
+    char sci[40];
+    auto res = std::to_chars(sci, sci + sizeof(sci), d, std::chars_format::scientific);
+    char digits[24];
+    int nd = 0;
+    const char* c = sci;
+    bool neg = *c == '-';
+    if (neg) c++;
+    for (; *c != 'e'; c++)
+        if (*c != '.') digits[nd++] = *c;
+    int exp10 = 0;
+    std::from_chars(c + (c[1] == '+' ? 2 : 1), res.ptr, exp10);
+    int decpt = exp10 + 1;
+    uint8_t* w = o.room(48);
+    uint8_t* w0 = w;
+    if (neg) *w++ = '-';
+    if (decpt <= -4 || decpt > 16) {
+        *w++ = uint8_t(digits[0]);
+        if (nd > 1) {
+            *w++ = '.';
+            std::memcpy(w, digits + 1, size_t(nd - 1));
+            w += nd - 1;
+        }
+        *w++ = 'e';
+        int e = decpt - 1;
+        *w++ = e < 0 ? '-' : '+';
+        if (e < 0) e = -e;
+        if (e < 10) *w++ = '0';
+        w = reinterpret_cast<uint8_t*>(
+            std::to_chars(reinterpret_cast<char*>(w), reinterpret_cast<char*>(w) + 8, e).ptr);
+    } else if (decpt <= 0) {
+        *w++ = '0';
+        *w++ = '.';
+        for (int z = 0; z < -decpt; z++) *w++ = '0';
+        std::memcpy(w, digits, size_t(nd));
+        w += nd;
+    } else if (decpt >= nd) {
+        std::memcpy(w, digits, size_t(nd));
+        w += nd;
+        for (int z = nd; z < decpt; z++) *w++ = '0';
+        *w++ = '.';
+        *w++ = '0';
+    } else {
+        std::memcpy(w, digits, size_t(decpt));
+        w += decpt;
+        *w++ = '.';
+        std::memcpy(w, digits + decpt, size_t(nd - decpt));
+        w += nd - decpt;
+    }
+    o.n += size_t(w - w0);
+}
+
+inline void put_u4(uint8_t* w, uint32_t cu) {  // \uXXXX, lower hex
+    static const char H[] = "0123456789abcdef";
+    w[0] = '\\';
+    w[1] = 'u';
+    w[2] = uint8_t(H[(cu >> 12) & 15]);
+    w[3] = uint8_t(H[(cu >> 8) & 15]);
+    w[4] = uint8_t(H[(cu >> 4) & 15]);
+    w[5] = uint8_t(H[cu & 15]);
+}
+
+// json.encoder.encode_basestring_ascii over strictly-validated UTF-8 (what
+// msgpack's raw=False decode accepts: no overlongs, no surrogates, nothing
+// past U+10FFFF). false: invalid — the caller declines the row.
+bool put_json_str(LineBuf& o, const uint8_t* s, uint32_t n) {
+    uint8_t* w0 = o.room(size_t(n) * 6 + 2);  // worst case: every byte \uXXXX
+    uint8_t* w = w0;
+    *w++ = '"';
+    const uint8_t* end = s + n;
+    while (s < end) {
+        uint8_t b = *s;
+        if (b >= 0x20 && b <= 0x7E) {
+            if (b == '"' || b == '\\') *w++ = '\\';
+            *w++ = b;
+            s++;
+            continue;
+        }
+        if (b < 0x80) {  // controls and DEL
+            char e = 0;
+            switch (b) {
+                case '\n': e = 'n'; break;
+                case '\r': e = 'r'; break;
+                case '\t': e = 't'; break;
+                case '\b': e = 'b'; break;
+                case '\f': e = 'f'; break;
+            }
+            if (e) {
+                *w++ = '\\';
+                *w++ = uint8_t(e);
+            } else {
+                put_u4(w, b);
+                w += 6;
+            }
+            s++;
+            continue;
+        }
+        uint32_t cp;
+        int extra;
+        uint8_t lo = 0x80, hi = 0xBF;  // bounds of the first continuation
+        if (b >= 0xC2 && b <= 0xDF) {
+            cp = b & 0x1F;
+            extra = 1;
+        } else if (b >= 0xE0 && b <= 0xEF) {
+            cp = b & 0x0F;
+            extra = 2;
+            if (b == 0xE0) lo = 0xA0;
+            if (b == 0xED) hi = 0x9F;
+        } else if (b >= 0xF0 && b <= 0xF4) {
+            cp = b & 0x07;
+            extra = 3;
+            if (b == 0xF0) lo = 0x90;
+            if (b == 0xF4) hi = 0x8F;
+        } else {
+            return false;
+        }
+        if (end - s <= extra) return false;
+        for (int k = 1; k <= extra; k++) {
+            uint8_t c = s[k];
+            if (c < lo || c > hi) return false;
+            cp = (cp << 6) | (c & 0x3F);
+            lo = 0x80;
+            hi = 0xBF;
+        }
+        s += extra + 1;
+        if (cp >= 0x10000) {
+            uint32_t v = cp - 0x10000;
+            put_u4(w, 0xD800 | (v >> 10));
+            put_u4(w + 6, 0xDC00 | (v & 0x3FF));
+            w += 12;
+        } else {
+            put_u4(w, cp);
+            w += 6;
+        }
+    }
+    *w++ = '"';
+    o.n += size_t(w - w0);
+    return true;
+}
+
+void put_hex(LineBuf& o, const uint8_t* p, uint32_t n, bool upper) {
+    const char* H = upper ? "0123456789ABCDEF" : "0123456789abcdef";
+    uint8_t* w = o.room(size_t(n) * 2 + 2);
+    *w++ = '"';
+    for (uint32_t k = 0; k < n; k++) {
+        *w++ = uint8_t(H[p[k] >> 4]);
+        *w++ = uint8_t(H[p[k] & 15]);
+    }
+    *w++ = '"';
+    o.n += size_t(n) * 2 + 2;
+}
+
+// kart_tpu.geometry.gpkg_hex_wkb's fast path, and nothing else: "GP",
+// version 0, not extended, a known envelope code, then nothing or
+// little-endian WKB -> upper hex of what follows the header.
+bool put_geometry(LineBuf& o, const uint8_t* g, uint32_t n) {
+    static const int ENVELOPE_DOUBLES[8] = {0, 4, 6, 6, 8, -1, -1, -1};
+    if (n < 9 || g[0] != 'G' || g[1] != 'P' || g[2] != 0) return false;
+    uint8_t flags = g[3];
+    if (flags & 0x20) return false;  // extended
+    int doubles = ENVELOPE_DOUBLES[(flags & 0x0E) >> 1];
+    if (doubles < 0) return false;
+    uint32_t off = 8 + uint32_t(doubles) * 8;
+    if (n < off || (n > off && g[off] != 1)) return false;
+    put_hex(o, g + off, n - off, true);
+    return true;
+}
+
+// One msgpack value of the kinds a feature blob holds; false = anything
+// else (or truncated). Strings are validated when they are emitted, and
+// once here when no column reads them (Python would refuse the blob).
+bool read_val(const uint8_t*& p, const uint8_t* end, Val* v) {
+    if (p >= end) return false;
+    uint8_t t = *p++;
+    auto need = [&](int64_t k) { return end - p >= k; };
+    auto bytes = [&](ValKind kind, uint64_t len) {
+        if (len > uint64_t(end - p)) return false;
+        v->kind = kind;
+        v->p = p;
+        v->n = uint32_t(len);
+        p += len;
+        return true;
+    };
+    auto ext = [&](uint64_t len) {
+        if (!need(1) || int8_t(*p) != 0x47) return false;
+        p++;
+        return bytes(V_GEOM, len);
+    };
+    if (t <= 0x7F) {
+        v->kind = V_INT;
+        v->i = t;
+        return true;
+    }
+    if (t >= 0xE0) {
+        v->kind = V_INT;
+        v->i = int8_t(t);
+        return true;
+    }
+    if (t >= 0xA0 && t <= 0xBF) return bytes(V_STR, t & 0x1F);
+    switch (t) {
+        case 0xC0: v->kind = V_NIL; return true;
+        case 0xC2: v->kind = V_FALSE; return true;
+        case 0xC3: v->kind = V_TRUE; return true;
+        case 0xC4: return need(1) && bytes(V_BIN, *p++);
+        case 0xC5: if (!need(2)) return false; p += 2; return bytes(V_BIN, load_be16(p - 2));
+        case 0xC6: if (!need(4)) return false; p += 4; return bytes(V_BIN, load_be32(p - 4));
+        case 0xC7: return need(1) && ext(*p++);
+        case 0xC8: if (!need(2)) return false; p += 2; return ext(load_be16(p - 2));
+        case 0xC9: if (!need(4)) return false; p += 4; return ext(load_be32(p - 4));
+        case 0xCA: {
+            if (!need(4)) return false;
+            uint32_t bits = load_be32(p);
+            float f;
+            std::memcpy(&f, &bits, 4);
+            p += 4;
+            v->kind = V_F64;
+            v->d = double(f);
+            return true;
+        }
+        case 0xCB: {
+            if (!need(8)) return false;
+            uint64_t bits = load_be64(p);
+            std::memcpy(&v->d, &bits, 8);
+            p += 8;
+            v->kind = V_F64;
+            return true;
+        }
+        case 0xCC: if (!need(1)) return false; v->kind = V_UINT; v->u = *p++; return true;
+        case 0xCD: if (!need(2)) return false; v->kind = V_UINT; v->u = load_be16(p); p += 2; return true;
+        case 0xCE: if (!need(4)) return false; v->kind = V_UINT; v->u = load_be32(p); p += 4; return true;
+        case 0xCF: if (!need(8)) return false; v->kind = V_UINT; v->u = load_be64(p); p += 8; return true;
+        case 0xD0: if (!need(1)) return false; v->kind = V_INT; v->i = int8_t(*p++); return true;
+        case 0xD1: if (!need(2)) return false; v->kind = V_INT; v->i = int16_t(load_be16(p)); p += 2; return true;
+        case 0xD2: if (!need(4)) return false; v->kind = V_INT; v->i = int32_t(load_be32(p)); p += 4; return true;
+        case 0xD3: if (!need(8)) return false; v->kind = V_INT; v->i = int64_t(load_be64(p)); p += 8; return true;
+        case 0xD4: return ext(1);
+        case 0xD5: return ext(2);
+        case 0xD6: return ext(4);
+        case 0xD7: return ext(8);
+        case 0xD8: return ext(16);
+        case 0xD9: return need(1) && bytes(V_STR, *p++);
+        case 0xDA: if (!need(2)) return false; p += 2; return bytes(V_STR, load_be16(p - 2));
+        case 0xDB: if (!need(4)) return false; p += 4; return bytes(V_STR, load_be32(p - 4));
+    }
+    return false;  // arrays, maps, the reserved 0xC1
+}
+
+template <class Int>  // int64_t or uint64_t: Python's str(int)
+void put_int(LineBuf& o, Int i) {
+    char* w = reinterpret_cast<char*>(o.room(24));
+    o.n += size_t(std::to_chars(w, w + 24, i).ptr - w);
+}
+
+// The JSON object of one feature blob `[legend_hash, [values...]]` under
+// its legend's plan, appended to o. -> JSONL_OK or why not.
+JsonlWhy put_feature(LineBuf& o, const uint8_t* blob, int64_t len,
+                     const std::vector<JsonlPlan>& plans, int64_t pk,
+                     std::vector<Val>& vals, LineBuf& unread) {
+    const uint8_t* p = blob;
+    const uint8_t* end = blob + len;
+    Val hash;
+    if (len < 2 || *p++ != 0x92 || !read_val(p, end, &hash) || hash.kind != V_STR)
+        return JSONL_LEGEND;
+    const JsonlPlan* plan = nullptr;
+    for (const JsonlPlan& c : plans)
+        if (c.hash_len == hash.n && std::memcmp(c.hash, hash.p, hash.n) == 0) {
+            plan = &c;
+            break;
+        }
+    if (plan == nullptr) return JSONL_LEGEND;
+    if (p >= end) return JSONL_TYPE;
+    uint64_t n_vals;
+    uint8_t t = *p++;
+    if (t >= 0x90 && t <= 0x9F) {
+        n_vals = t & 0x0F;
+    } else if (t == 0xDC && end - p >= 2) {
+        n_vals = load_be16(p);
+        p += 2;
+    } else if (t == 0xDD && end - p >= 4) {
+        n_vals = load_be32(p);
+        p += 4;
+    } else {
+        return JSONL_TYPE;
+    }
+    if (n_vals > uint64_t(end - p)) return JSONL_TYPE;  // >= 1 byte a value
+    vals.resize(size_t(n_vals));
+    for (Val& v : vals) {
+        if (!read_val(p, end, &v)) return JSONL_TYPE;
+        v.used = false;
+    }
+    if (p != end) return JSONL_TYPE;  // msgpack's ExtraData
+    o.lit("{");
+    for (const JsonlCol& col : plan->cols) {
+        o.put(col.prefix, col.prefix_len);
+        if (col.kind == 1) {
+            if (col.idx == 0) put_int(o, pk);  // the row plan's pk tuple is (pk,)
+            else o.lit("null");
+            continue;
+        }
+        if (col.kind == 0 || uint64_t(col.idx) >= n_vals) {
+            o.lit("null");
+            continue;
+        }
+        Val& v = vals[size_t(col.idx)];
+        v.used = true;
+        if (v.kind == V_NIL) {
+            o.lit("null");
+        } else if (col.geom) {
+            if (v.kind != V_GEOM) return JSONL_TYPE;
+            if (!put_geometry(o, v.p, v.n)) return JSONL_GEOMETRY;
+        } else {
+            switch (v.kind) {
+                case V_FALSE: o.lit("false"); break;
+                case V_TRUE: o.lit("true"); break;
+                case V_INT: put_int(o, v.i); break;
+                case V_UINT: put_int(o, v.u); break;
+                case V_F64: put_float(o, v.d); break;
+                case V_STR:
+                    if (!put_json_str(o, v.p, v.n)) return JSONL_UTF8;
+                    break;
+                case V_BIN: put_hex(o, v.p, v.n, false); break;
+                default: return JSONL_TYPE;  // geometry ext in a plain column
+            }
+        }
+    }
+    o.lit("}");
+    // a str no column reads still has to decode, or Python refuses the blob
+    for (const Val& v : vals)
+        if (!v.used && v.kind == V_STR) {
+            unread.n = 0;
+            if (!put_json_str(unread, v.p, v.n)) return JSONL_UTF8;
+        }
+    return JSONL_OK;
+}
+
+// Inflate the plain blob record at `off` of a pack into scratch. JSONL_RECORD:
+// a delta, another object type, a broken header or stream; JSONL_SIZE: a
+// blob of more than max_size bytes (its line could not fit the output
+// either, and a header may claim any size: nothing is allocated for it).
+JsonlWhy inflate_blob_record(z_stream* zs, const uint8_t* pack,
+                             int64_t pack_len, int64_t off, int64_t max_size,
+                             std::vector<uint8_t>& scratch,
+                             int64_t* size_out) {
+    if (off < 0 || off >= pack_len) return JSONL_RECORD;
+    int64_t pos = off;
+    uint8_t byte = pack[pos++];
+    int type = (byte >> 4) & 7;
+    uint64_t size = byte & 0x0F;
+    int shift = 4;
+    while (byte & 0x80) {
+        if (pos >= pack_len || shift > 60) return JSONL_RECORD;
+        byte = pack[pos++];
+        size |= uint64_t(byte & 0x7F) << shift;
+        shift += 7;
+    }
+    if (type != 3) return JSONL_RECORD;
+    if (size > uint64_t(max_size)) return JSONL_SIZE;
+    if (scratch.size() < size + 1) scratch.resize(size_t(size) * 2 + 256);
+    int64_t avail = pack_len - pos;
+    zs->next_in = const_cast<Bytef*>(pack + pos);
+    zs->avail_in = uInt(avail > int64_t(0x7FFFFFFF) ? 0x7FFFFFFF : avail);
+    zs->next_out = scratch.data();
+    zs->avail_out = uInt(size);
+    int rc = inflate(zs, Z_FINISH);
+    bool ok = (rc == Z_STREAM_END || (rc == Z_BUF_ERROR && size == 0)) &&
+              zs->total_out == size;
+    inflateReset(zs);
+    *size_out = int64_t(size);
+    return ok ? JSONL_OK : JSONL_RECORD;
+}
+
 }  // namespace
 
 extern "C" {
 
-int io_abi_version() { return 7; }  // v7: io_leaf_payloads leaf-tree kernel
+int io_abi_version() { return 8; }  // v8: io_idx_probe, io_jsonl_chunk
 
 // Zero-copy variant: payloads stay in the caller's buffers (an array of
 // pointers — CPython bytes objects expose theirs directly), and the git
@@ -1207,6 +1777,136 @@ int64_t io_leaf_payloads(const int64_t* pks, const uint8_t* oids, int64_t n,
     }
     *n_leaves_out = n_leaves;
     return pos;
+}
+
+// .idx v2 probe: n 20-byte shas -> the pack offset of each, -1 where the
+// index does not hold it. Narrowed by the fanout, then idx_find; offsets
+// with the high bit set resolve through the 64-bit table (packs of 2 GiB
+// and more). -2: not a well-formed v2 index.
+int64_t io_idx_probe(const uint8_t* idx, int64_t idx_len, const uint8_t* shas,
+                     int64_t n, int64_t* out) {
+    const int64_t sha_base = 8 + 1024;
+    if (idx_len < sha_base || std::memcmp(idx, "\377tOc", 4) != 0 ||
+        load_be32(idx + 4) != 2)
+        return -2;
+    const uint8_t* fanout = idx + 8;
+    const int64_t count = load_be32(fanout + 255 * 4);
+    const int64_t off_base = sha_base + 24 * count;  // shas, then crc32s
+    const int64_t off64_base = off_base + 4 * count;
+    if (off64_base > idx_len) return -2;
+    const uint8_t* table = idx + sha_base;
+    for (int64_t k = 0; k < n; k++) {
+        const uint8_t* sha = shas + 20 * k;
+        int64_t lo = sha[0] ? load_be32(fanout + 4 * (sha[0] - 1)) : 0;
+        int64_t hi = load_be32(fanout + 4 * sha[0]);
+        if (lo > hi || hi > count) return -2;
+        int64_t found = idx_find(table, lo, hi, sha);
+        if (found < 0) {
+            out[k] = -1;
+            continue;
+        }
+        uint32_t off = load_be32(idx + off_base + 4 * found);
+        if (off & 0x80000000u) {
+            int64_t b64 = off64_base + 8 * int64_t(off & 0x7FFFFFFFu);
+            if (b64 + 8 > idx_len) return -2;
+            out[k] = int64_t(load_be64(idx + b64));
+        } else {
+            out[k] = int64_t(off);
+        }
+    }
+    return 0;
+}
+
+// One chunk of json-lines feature lines. Row r has an old side when
+// old_pack[r] >= 0 (the record at old_off[r] of packs[old_pack[r]]), none
+// when it is -1, and one no pack holds when it is -2 (declined); the new
+// side alike. A written line is
+//   head + ["-":{old}] + [,] + ["+":{new}] + "}}\n"
+// appended to out; row_end[r] is the byte count after row r, status[r] 0 or
+// the JsonlWhy of a declined row, which appends nothing. Stops before the
+// row that would not fit: *rows_done rows were consumed, and the caller
+// hands the rest to the next call. -> bytes written; -2 on a bad plan blob,
+// pack id or size, -3 when zlib cannot start or memory runs out.
+int64_t io_jsonl_chunk(const uint8_t* const* packs, const int64_t* pack_lens,
+                       int32_t n_packs, int64_t n_rows,
+                       const int32_t* old_pack, const int64_t* old_off,
+                       const int32_t* new_pack, const int64_t* new_off,
+                       const int64_t* pks, const uint8_t* head,
+                       int64_t head_len, const uint8_t* old_plans,
+                       int64_t old_plans_len, const uint8_t* new_plans,
+                       int64_t new_plans_len, uint8_t* out, int64_t out_cap,
+                       int64_t* row_end, uint8_t* status,
+                       int64_t* rows_done) {
+    if (n_rows < 0 || out_cap < 0 || out_cap > int64_t(0x7FFFFFFF)) return -2;
+    struct Inflater {  // inflateEnd on every way out
+        z_stream zs;
+        bool ready;
+        Inflater() {
+            std::memset(&zs, 0, sizeof(zs));
+            ready = inflateInit(&zs) == Z_OK;
+        }
+        ~Inflater() {
+            if (ready) inflateEnd(&zs);
+        }
+    } inflater;
+    if (!inflater.ready) return -3;
+    try {
+        std::vector<JsonlPlan> plans[2];
+        if (!parse_jsonl_plans(old_plans, old_plans_len, plans[0]) ||
+            !parse_jsonl_plans(new_plans, new_plans_len, plans[1]))
+            return -2;
+        std::vector<uint8_t> scratch;
+        std::vector<Val> vals;
+        LineBuf line, unread;
+        const int32_t* side_pack[2] = {old_pack, new_pack};
+        const int64_t* side_off[2] = {old_off, new_off};
+        static const char* const SIDE_KEY[2] = {"\"-\":", "\"+\":"};
+        int64_t total = 0;
+        int64_t r = 0;
+        for (; r < n_rows; r++) {
+            line.n = 0;
+            line.put(head, size_t(head_len));
+            JsonlWhy why = JSONL_OK;
+            bool any = false;
+            for (int s = 0; s < 2 && why == JSONL_OK; s++) {
+                int32_t k = side_pack[s][r];
+                if (k == -1) continue;
+                if (k == -2) {
+                    why = JSONL_RECORD;
+                    break;
+                }
+                if (k < 0 || k >= n_packs) return -2;
+                int64_t size;
+                why = inflate_blob_record(&inflater.zs, packs[k], pack_lens[k],
+                                          side_off[s][r], out_cap, scratch,
+                                          &size);
+                if (why != JSONL_OK) break;
+                if (any) line.lit(",");
+                line.lit(SIDE_KEY[s]);
+                why = put_feature(line, scratch.data(), size, plans[s], pks[r],
+                                  vals, unread);
+                any = true;
+            }
+            if (why == JSONL_OK && !any) why = JSONL_RECORD;
+            if (why == JSONL_OK) {
+                line.lit("}}\n");
+                if (int64_t(line.n) > out_cap) {
+                    why = JSONL_SIZE;
+                } else if (total + int64_t(line.n) > out_cap) {
+                    break;  // full: the caller takes this row up again
+                } else {
+                    std::memcpy(out + total, line.v.data(), line.n);
+                    total += int64_t(line.n);
+                }
+            }
+            status[r] = why;
+            row_end[r] = total;
+        }
+        *rows_done = r;
+        return total;
+    } catch (const std::bad_alloc&) {
+        return -3;
+    }
 }
 
 }  // extern "C"

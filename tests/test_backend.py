@@ -563,7 +563,7 @@ def test_chip_smoke_walks_every_phase_and_fails_without_a_chip(tmp_path):
             "--rows", "20000", "--cli-merge-rows", "3000",
             "--cli-merge-conflicts", "40", "--merge-rows", "20000",
             "--merge-conflicts", "4000", "--envelopes", "30000",
-            "--fork-rows", "3000",
+            "--jsonl-rows", "3000",
         ],
         env=env, capture_output=True, text=True, timeout=600,
     )
@@ -578,7 +578,7 @@ def test_chip_smoke_walks_every_phase_and_fails_without_a_chip(tmp_path):
     assert not [p for p, r in phases.items() if "error" in r], proc.stderr[-3000:]
     assert {
         "native", "device", "diff.feature_count", "diff.json_lines",
-        "merge.cli", "merge.blocks", "bbox", "fork",
+        "merge.cli", "merge.blocks", "bbox", "materialise",
         "mesh1.classify_batched", "mesh1.sampled_counts_pmapped",
         "mesh1.envelope_hits", "mesh1.merc_envelopes", "mesh1.join_counts",
         "mesh1.refine_pairs", "summary",

@@ -210,34 +210,6 @@ def test_event_buffer_saturation_is_counted(monkeypatch, caplog, tmp_path):
     assert metas and metas[0]["args"]["dropped"] == 6
 
 
-def test_fork_child_dump_failure_warns(tmp_path, caplog):
-    telemetry.enable(
-        trace=True, trace_path=str(tmp_path / "no-such-dir" / "t.json")
-    )
-    with telemetry.span("diff.classify"):
-        pass
-    with caplog.at_level("WARNING", logger="kart_tpu.telemetry.core"):
-        telemetry.dump_fork_child()
-    assert any(
-        "side-file" in r.getMessage() and "not written" in r.getMessage()
-        for r in caplog.records
-    )
-
-
-def test_sidecar_merge_failure_warns(tmp_path, caplog):
-    path = str(tmp_path / "trace.json")
-    telemetry.enable(trace=True, trace_path=path)
-    with telemetry.span("diff.classify"):
-        pass
-    side = f"{path}.child-999"
-    with open(side, "w") as f:
-        f.write("not json")
-    with caplog.at_level("WARNING", logger="kart_tpu.telemetry.sinks"):
-        assert sinks.write_chrome_trace() == path
-    assert any("unreadable" in r.getMessage() for r in caplog.records)
-    assert not os.path.exists(side)
-
-
 # -- access log / windows helpers -------------------------------------------
 
 

@@ -134,36 +134,6 @@ def test_trace_events_and_chrome_export(tmp_path):
     assert sinks.write_chrome_trace() is None
 
 
-def test_chrome_export_merges_fork_child_sidecars(tmp_path):
-    path = str(tmp_path / "trace.json")
-    telemetry.enable(trace=True, trace_path=path)
-    with telemetry.span("serialise.parent"):
-        pass
-    side = core.child_trace_sidecar_path()
-    with open(side, "w") as f:
-        json.dump(
-            [
-                {
-                    "name": "serialise.chunk",
-                    "cat": "serialise",
-                    "ph": "X",
-                    "ts": 1.0,
-                    "dur": 2.0,
-                    "pid": os.getpid() + 1,
-                    "tid": 1,
-                    "tname": "worker",
-                    "args": {},
-                }
-            ],
-            f,
-        )
-    sinks.write_chrome_trace()
-    doc = json.load(open(path))
-    names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
-    assert names == {"serialise.parent", "serialise.chunk"}
-    assert not os.path.exists(side)  # merged side-files are removed
-
-
 def test_prometheus_exposition_format():
     telemetry.enable(metrics=True)
     telemetry.incr("transport.retries", 2, verb='fetch"pack')
