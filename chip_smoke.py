@@ -617,6 +617,94 @@ def phase_materialise(smoke):
         )
 
 
+def _churn_blocks(builder, branch, params, seed):
+    """(old, new) unpadded blocks with the key columns the benchmark's
+    republish builder gives the sidecars of its commit ``branch``, and oids
+    that differ on its updates."""
+    from kart_tpu.ops.blocks import FeatureBlock
+
+    edits = builder.edit_sets(params, seed)[branch]
+    old_keys, keep, inserted = builder.key_columns(params, edits)
+    rng = np.random.default_rng(seed)
+    old_oids = rng.integers(0, 2**32, size=(len(old_keys), 5), dtype=np.uint32)
+    new_oids = old_oids.copy()
+    new_oids[edits[0], 4] ^= 1  # the updated rows
+    new_keys = np.concatenate([old_keys[keep], inserted])
+    new_oids = np.concatenate(
+        [new_oids[keep], rng.integers(0, 2**32, size=(len(inserted), 5), dtype=np.uint32)]
+    )
+    return (
+        FeatureBlock(old_keys, old_oids, None, len(old_keys)),
+        FeatureBlock(new_keys, new_oids, None, len(new_keys)),
+    )
+
+
+def phase_churn(smoke):
+    """The republish deployment's two commits (benchmarks/configs/
+    baseline2_points_10m_churn.json: uniform updates + deletes + appended
+    inserts; one contiguous run deleted) through ``classify_blocks`` under
+    auto routing: classes and counts equal the host engine's, and the
+    windowed join's tile census (``dense_tiles``, ``overflow_tiles`` of the
+    ``diff.device.kernel`` span) equals a numpy recount of the same key
+    columns. Uniform churn must stay on the windowed join; which program
+    answers the bulk delete is recorded, not checked."""
+    import importlib.util
+
+    from kart_tpu import telemetry as tm
+    from kart_tpu.ops.diff_kernel import (
+        classify_blocks,
+        classify_blocks_host,
+        join_census_reference,
+    )
+
+    bench = os.path.join(REPO_ROOT, "benchmarks")
+    spec = importlib.util.spec_from_file_location(
+        "bench_layers_int_pk_churn_layer",
+        os.path.join(bench, "layers", "int_pk_churn_layer.py"),
+    )
+    builder = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(builder)
+    with open(os.path.join(bench, "configs", "baseline2_points_10m_churn.json")) as f:
+        params = dict(json.load(f)["layer"]["params"], rows=smoke.args.rows)
+
+    for branch in builder.BRANCHES:
+        with smoke.phase(f"churn.{branch}", rows=params["rows"]) as rec:
+            old, new = _churn_blocks(builder, branch, params, smoke.args.seed)
+            tm.drain_events()
+            tm.enable(trace=True)
+            try:
+                (old_class, new_class, counts), rec["wall_seconds"] = _timed(
+                    classify_blocks, old, new
+                )
+                again, rec["second_wall_seconds"] = _timed(classify_blocks, old, new)
+                kernels = [
+                    e["args"] for e in tm.drain_events()
+                    if e.get("name") == "diff.device.kernel"
+                ]
+            finally:
+                tm.enable(trace=False)
+            host_old, host_new, host_counts = classify_blocks_host(old, new)
+            rec["counts"] = counts
+            rec["kernel_spans"] = kernels
+            rec["checks"]["ran_on_device"] = len(kernels) == 2
+            rec["checks"]["equals_host_engine"] = (
+                np.array_equal(old_class, host_old)
+                and np.array_equal(new_class, host_new)
+                and counts == host_counts
+                and np.array_equal(again[0], host_old)
+                and np.array_equal(again[1], host_new)
+            )
+            rec["census_recount"] = join_census_reference(old, new)
+            rec["checks"]["census_equals_recount"] = all(
+                (k.get("dense_tiles"), k.get("overflow_tiles")) == rec["census_recount"]
+                for k in kernels
+            )
+            if branch == "churn":
+                rec["checks"]["windowed_join_answered"] = all(
+                    k.get("join") == "window" for k in kernels
+                )
+
+
 def phase_one_device_mesh(smoke):
     """The device programs auto routing cannot reach on one chip —
     routing.mesh_open wants two devices — each run once on a one-device mesh
@@ -758,6 +846,7 @@ def run(args, work):
     if args.chips == 1:
         phase_bbox(smoke)
         phase_materialise(smoke)
+        phase_churn(smoke)
         phase_one_device_mesh(smoke)
 
     with smoke.phase("summary") as rec:

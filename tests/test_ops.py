@@ -267,7 +267,9 @@ def test_jitted_kernels_match_reference_directly(kernel_name):
         old.keys, old.oids, new.keys, new.oids, old.count, new.count
     )
     if kernel_name == "window":
-        assert not bool(rest[1])  # no overflow: the classes stand
+        dense_tiles, overflow_tiles = np.asarray(rest[1])
+        assert overflow_tiles == 0  # no overflow: the classes stand
+        assert dense_tiles > 0  # every 7th row dropped: the dense join ran
     np.testing.assert_array_equal(np.asarray(oc)[: old.count], ref_old)
     np.testing.assert_array_equal(np.asarray(nc)[: new.count], ref_new)
 
@@ -693,8 +695,9 @@ def _split_case(case):
     return old, new
 
 
-# -- what the windowed join must get right and the cells never send (ISSUE
-# 29): inserts and deletes, appended ranges, hashed keys, the overflow branch
+# -- what the windowed join must get right beyond attribute-only commits
+# (ISSUE 29; two benchmark cells send two of these shapes since ISSUE 32):
+# inserts and deletes, appended ranges, hashed keys, the overflow branch
 
 
 def _block(keys, oids):
@@ -939,12 +942,27 @@ def test_device_classify_split_matches_reference(case, route, monkeypatch):
     (attrs,) = kernel
     overflowed = counters.get(("diff.device.join_overflows", ()), 0)
     if route == "window":
-        from kart_tpu.ops.diff_kernel import JOIN_TILE
+        from kart_tpu.ops.diff_kernel import (
+            JOIN_STEP_TILES,
+            JOIN_TILE,
+            join_census_reference,
+        )
 
         assert attrs["program"] == "mergesort"
         assert attrs["join"] == ("sort" if overflows else "window")
-        assert attrs["tiles"] == 2 * -(-attrs["bucket"] // JOIN_TILE)
+        # the tiles of the grid: whole steps of 32 over the bucket's tiles
+        assert attrs["tiles"] % (2 * JOIN_STEP_TILES) == 0
+        assert 0 <= attrs["tiles"] // 2 - -(-attrs["bucket"] // JOIN_TILE) < JOIN_STEP_TILES
         assert overflowed == (1 if overflows else 0)
+        # the kernel's own census equals a numpy recount of the same blocks
+        assert (attrs["dense_tiles"], attrs["overflow_tiles"]) == (
+            join_census_reference(old, new)
+        )
+        assert (attrs["overflow_tiles"] > 0) == overflows
+        assert attrs.get("window_ran", False) == overflows
+        assert counters.get(("diff.device.join_dense_tiles", ()), 0) == (
+            attrs["dense_tiles"]
+        )
     else:
         assert attrs["program"] == "mergesort" and "join" not in attrs
         assert overflowed == 0
