@@ -235,10 +235,11 @@ class Smoke:
 
         return dict(STATS)
 
-    def mesh_checks(self, rec, stats_before, counter):
+    def mesh_checks(self, rec, stats_before, counter, events=()):
         """Four chips: the mesh path ran (its STATS counter rose; the diff
         dealt its batches onto four shards), and the arrays really landed on
-        every device, not all on the first."""
+        every device, not all on the first. Of the diff's rounds, how many
+        went over as views of the sidecar's pages (recorded, not checked)."""
         from kart_tpu import telemetry as tm
 
         if self.args.chips == 1:
@@ -250,6 +251,8 @@ class Smoke:
                 name: value for name, _labels, value in tm.snapshot()["gauges"]
             }.get("diff.device.shards")
             rec["checks"]["shards"] = rec["device_shards"] == self.args.chips
+            for attr in ("view_rounds", "rounds"):
+                rec[attr] = self.span_attr(events, "diff.device.classify", attr)
         peaks = self.peak_bytes()
         rec["checks"]["every_device_held_data"] = bool(
             peaks and len(peaks) == self.args.chips and min(peaks) >= 1 << 20
@@ -362,7 +365,7 @@ def phase_diff(smoke):
         rec["checks"]["equals_synth"] = (
             f"{info['n_edits']} features changed" in dev.stdout
         )
-        smoke.mesh_checks(rec, stats, "sharded_classify_calls")
+        smoke.mesh_checks(rec, stats, "sharded_classify_calls", dev_run[1])
 
     with smoke.phase("diff.json_lines", rows=args.rows) as rec:
         dev_out = os.path.join(smoke.work, "diff-device.jsonl")
@@ -379,7 +382,7 @@ def phase_diff(smoke):
         rec["checks"]["equals_synth"] = (
             np.array_equal(pks, info["edit_pks"]) and values_ok
         )
-        smoke.mesh_checks(rec, stats, "sharded_classify_calls")
+        smoke.mesh_checks(rec, stats, "sharded_classify_calls", dev_run[1])
 
 
 def _merge_index(repo_path):

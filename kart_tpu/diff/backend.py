@@ -818,8 +818,8 @@ def sampled_counts_pmapped(old_block, new_block):
             break
         cap *= 2
     bucket = bucket_size(cap)
-    ok, oo, oc = pack_round(old_keys, old_block.oids, old_splits, 0, n_dev, bucket)
-    nk, no, nc = pack_round(new_keys, new_block.oids, new_splits, 0, n_dev, bucket)
+    ok, oo, oc, _ = pack_round(old_keys, old_block.oids, old_splits, 0, n_dev, bucket)
+    nk, no, nc, _ = pack_round(new_keys, new_block.oids, new_splits, 0, n_dev, bucket)
     fn = _make_pmapped_counts(n_dev)
     with tm.span("diff.device.classify", rows=int(max(n_old, n_new)), shards=n_dev):
         counts = np.asarray(fn(ok, oo, nk, no, oc, nc))[0]

@@ -20,7 +20,18 @@ fixed-shape record batches**:
   fully shard-local, only the 3-scalar count vector is ``psum``-reduced
   over the interconnect;
 * transfers are double-buffered: ``jax.device_put`` is asynchronous, so
-  round ``r+1``'s host→HBM copy overlaps round ``r``'s on-device classify.
+  round ``r+1``'s host→HBM copy overlaps round ``r``'s on-device classify;
+* a **full round costs the host no array work** (ISSUE 33): where a side's
+  ``S`` chunks of a round each hold exactly ``B`` rows they are ``S x B``
+  consecutive rows of its columns, and the round is handed to
+  ``device_put`` as a reshaped *view* of those columns — a sidecar's mmap'd
+  pages, unaligned and read-only as they are. Only a round that needs
+  padding (the ragged last one; a side whose key-aligned chunks come short
+  because the commit changed the key set) is packed into fresh arrays.
+  Decided per side and per round from the chunk lengths alone
+  (:func:`pack_round`); the devices receive byte-identical arrays either
+  way. Span attribute ``view_sides`` / ``view_rounds`` and counter
+  ``diff.device.view_rounds`` say how often it engaged.
 
 Faults: the ``diff.device_transfer`` point fires at every round's
 host→device transfer; an injected (or real) failure aborts the whole device
@@ -103,11 +114,55 @@ def batch_splits(key_arrays, batch_rows):
     return [np.asarray(s, dtype=np.int64) for s in splits], n_chunks
 
 
+def _round_views(keys, oids, splits, chunk0, n_shards, batch_rows):
+    """A **full** round of one block side as views of its columns, or None.
+
+    When shard slots ``chunk0 .. chunk0+n_shards-1`` all exist and each holds
+    exactly ``batch_rows`` rows, the round's rows are ``n_shards * batch_rows``
+    consecutive rows of the column, and the stacked arrays :func:`pack_round`
+    would build are already there: a reshape of the slice. No chunk holds
+    more than ``batch_rows`` rows (:func:`batch_splits`' capacity), so the
+    round's row count alone says every one of them is full. The column has
+    to be laid out as the device program reads it (C-contiguous ``int64`` /
+    ``(n, 5) uint32``); alignment and writeability do not matter — a
+    sidecar's mmap'd sections are unaligned and read-only, and the transfer
+    takes them as they are (PERF.md §6, PR 27)."""
+    if chunk0 + n_shards > len(splits) - 1:
+        return None
+    lo = int(splits[chunk0])
+    hi = lo + n_shards * batch_rows
+    if int(splits[chunk0 + n_shards]) != hi:
+        return None
+    if not (
+        keys.dtype == np.int64
+        and keys.flags.c_contiguous
+        and oids.dtype == np.uint32
+        and oids.flags.c_contiguous
+        and oids.shape[1:] == (5,)
+    ):
+        return None
+    return (
+        keys[lo:hi].reshape(n_shards, batch_rows),
+        oids[lo:hi].reshape(n_shards, batch_rows, 5),
+    )
+
+
 def pack_round(keys, oids, splits, chunk0, n_shards, batch_rows):
     """Stack shard slots ``chunk0 .. chunk0+n_shards-1`` of one block side
     into fixed-shape arrays: (S, B) int64 keys (PAD_KEY padding),
     (S, B, 5) uint32 oids, (S,) int64 validity counts. Chunks beyond the
-    plan are empty slots (count 0)."""
+    plan are empty slots (count 0).
+
+    -> (keys, oids, counts, copied): ``copied`` is the bytes the host wrote
+    to make them. A full round (:func:`_round_views`) costs no array work —
+    its keys and oids are **views of the caller's columns**, ``copied`` is 0,
+    and they stay valid only as long as the columns do. Any other round (the
+    ragged last one; a side whose key-aligned chunks come short because the
+    commit changed the key set; a column of another dtype or layout) is
+    three fresh arrays, padding included."""
+    views = _round_views(keys, oids, splits, chunk0, n_shards, batch_rows)
+    if views is not None:
+        return *views, np.full(n_shards, batch_rows, dtype=np.int64), 0
     k_out = np.full((n_shards, batch_rows), PAD_KEY, dtype=np.int64)
     o_out = np.zeros((n_shards, batch_rows, 5), dtype=np.uint32)
     counts = np.zeros(n_shards, dtype=np.int64)
@@ -122,7 +177,7 @@ def pack_round(keys, oids, splits, chunk0, n_shards, batch_rows):
         if m:
             k_out[s, :m] = keys[lo:hi]
             o_out[s, :m] = oids[lo:hi]
-    return k_out, o_out, counts
+    return k_out, o_out, counts, k_out.nbytes + o_out.nbytes + counts.nbytes
 
 
 def unpack_round(dest, shard_classes, splits, chunk0, n_shards):
@@ -148,7 +203,9 @@ def roundtrip_arrays(keys, oids, batch_rows, n_shards=1):
     out_keys = np.empty(len(keys), dtype=np.int64)
     out_oids = np.empty((len(keys), 5), dtype=np.uint32)
     for chunk0 in range(0, max(n_chunks, 1), n_shards):
-        ks, os_, counts = pack_round(keys, oids, splits, chunk0, n_shards, batch_rows)
+        ks, os_, counts, _ = pack_round(
+            keys, oids, splits, chunk0, n_shards, batch_rows
+        )
         for s in range(n_shards):
             c = chunk0 + s
             if c >= n_chunks:
@@ -331,6 +388,30 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
             totals[:] += counts
 
     h2d_bytes = 0
+    view_rounds = 0
+    full_counts = None  # a full round-side's count vector, on the mesh
+
+    def _put_side(keys, oids, counts, copied):
+        """One side of a round onto the mesh -> (its three device arrays,
+        the bytes put). ``device_put`` is asynchronous and nothing waits for
+        it here: round r+1's copy overlaps round r's program. Where keys
+        and oids are views they alias the block's own storage — a sidecar's
+        mapping: the blocks are this call's arguments and every round is
+        drained before it returns, so the pages outlive every transfer. A
+        full side's counts are ``batch_rows`` on every shard, every round:
+        put once a command, the same device array handed to each."""
+        nonlocal full_counts
+        host = [keys, oids]
+        if copied or full_counts is None:
+            host.append(counts)
+        side = [jax.device_put(a, sharding) for a in host]
+        if not copied:
+            if len(side) == 3:
+                full_counts = side[2]
+            else:
+                side.append(full_counts)
+        return side, sum(a.nbytes for a in host)
+
     with tm.span(
         "diff.device.classify",
         rows=int(max(n_old, n_new)),
@@ -349,35 +430,39 @@ def classify_blocks_batched(old_block, new_block, mesh=None, batch_rows=None,
         for r in range(n_rounds):
             chunk0 = r * n_shards
             with tm.span("diff.device.pack", round=r) as pack:
-                ok, oo, oc = pack_round(
+                old_host = pack_round(
                     old_keys, old_oids, old_splits, chunk0, n_shards, batch_rows
                 )
-                nk, no, nc = pack_round(
+                new_host = pack_round(
                     new_keys, new_oids, new_splits, chunk0, n_shards, batch_rows
                 )
-                packed = (ok, oo, nk, no, oc, nc)
-                # all six arrays are made here, padding and all
-                round_bytes = sum(a.nbytes for a in packed)
-                pack.set(bytes=round_bytes)
-            # device_put is asynchronous and nothing waits for it here (round
-            # r+1's copy overlaps round r's program): the span is the enqueue
-            with tm.span("diff.device.transfer", round=r, bytes=round_bytes):
+                # what the host copied: nothing for a side of views
+                copied = (old_host[3], new_host[3])
+                view_sides = copied.count(0)
+                pack.set(bytes=sum(copied), view_sides=view_sides)
+            view_rounds += view_sides == 2
+            # the span is the enqueue; its bytes are what was put
+            with tm.span("diff.device.transfer", round=r) as transfer:
                 if transfer_hook is not None:
                     transfer_hook()
-                args = [jax.device_put(a, sharding) for a in packed]
-            h2d_bytes += round_bytes
+                (ok, oo, oc), old_put = _put_side(*old_host)
+                (nk, no, nc), new_put = _put_side(*new_host)
+                transfer.set(bytes=old_put + new_put)
+            h2d_bytes += old_put + new_put
             with tm.span("diff.device.kernel", round=r, program="mesh_classify"):
-                out = fn(*args)  # the enqueue only; the wait is in the fetch
+                # the enqueue only; the wait is in the fetch
+                out = fn(ok, oo, nk, no, oc, nc)
             in_flight.append((out, chunk0, r))
             if len(in_flight) >= 2:
                 _drain()
         while in_flight:
             _drain()
-        root.set(bytes=h2d_bytes)
+        root.set(bytes=h2d_bytes, view_rounds=view_rounds)
 
     tm.incr("diff.device.batches", n_rounds * n_shards)
     tm.incr("diff.device.rounds", n_rounds)
     tm.incr("diff.device.h2d_bytes", h2d_bytes)
+    tm.incr("diff.device.view_rounds", view_rounds)
     return (
         old_class,
         new_class,

@@ -15,8 +15,9 @@ from kart_tpu.diff.device_batch import (
     pack_round,
     roundtrip_arrays,
 )
+from kart_tpu import telemetry
 from kart_tpu.ops.blocks import PAD_KEY, FeatureBlock
-from kart_tpu.ops.diff_kernel import classify_blocks_host
+from kart_tpu.ops.diff_kernel import classify_blocks_host, classify_blocks_reference
 from kart_tpu.parallel.mesh import make_mesh
 
 
@@ -155,7 +156,8 @@ def test_pack_round_validity_masks():
     rng = np.random.default_rng(3)
     keys, oids = _random_keys_oids(rng, 300)
     (splits,), n_chunks = batch_splits((keys,), 128)
-    ks, os_, counts = pack_round(keys, oids, splits, 0, 4, 128)
+    ks, os_, counts, copied = pack_round(keys, oids, splits, 0, 4, 128)
+    assert copied == ks.nbytes + os_.nbytes + counts.nbytes  # 300 rows: ragged
     assert ks.shape == (4, 128) and os_.shape == (4, 128, 5)
     for s in range(4):
         c = int(counts[s])
@@ -175,7 +177,7 @@ def test_fixed_shapes_across_blocks():
     for n in (100, 999, 4567):
         keys, oids = _random_keys_oids(rng, n)
         (splits,), _ = batch_splits((keys,), 256)
-        ks, os_, counts = pack_round(keys, oids, splits, 0, 2, 256)
+        ks, os_, counts, _ = pack_round(keys, oids, splits, 0, 2, 256)
         shapes.add((ks.shape, os_.shape, counts.shape))
     assert len(shapes) == 1
 
@@ -316,3 +318,264 @@ def test_four_device_mesh_equals_the_plain_reference(case, counts_only):
     else:
         np.testing.assert_array_equal(got_old, want_old)
         np.testing.assert_array_equal(got_new, want_new)
+
+
+# --- a full round is views of the columns (ISSUE 33) -------------------------
+
+def _mapped_columns(tmp_path, keys, oids, name="side"):
+    """The two columns as a sidecar has them: sections of one read-only
+    mapping that start at an odd byte (the header's length decides)."""
+    n = len(keys)
+    path = tmp_path / f"{name}.kcol"
+    path.write_bytes(bytes(7) + keys.tobytes() + oids.tobytes())
+    mm = np.memmap(path, dtype=np.uint8, mode="r")
+    k = np.frombuffer(mm, dtype="<i8", count=n, offset=7)
+    o = np.frombuffer(mm, dtype=np.uint8, count=20 * n, offset=7 + 8 * n)
+    o = o.reshape(n, 5, 4).view(np.uint32).reshape(n, 5)
+    assert not k.flags.aligned and not k.flags.writeable and not k.flags.owndata
+    return k, o
+
+
+def _assert_packed(ks, os_, counts, copied, keys, oids, splits, chunk0, n_shards, b):
+    """Today's validity invariants of a copied round."""
+    assert ks.flags.owndata and os_.flags.owndata
+    assert not np.shares_memory(ks, keys) and not np.shares_memory(os_, oids)
+    assert ks.shape == (n_shards, b) and os_.shape == (n_shards, b, 5)
+    assert copied == ks.nbytes + os_.nbytes + counts.nbytes > 0
+    n_chunks = len(splits) - 1
+    for s in range(n_shards):
+        c, m = chunk0 + s, int(counts[s])
+        want = int(splits[c + 1] - splits[c]) if c < n_chunks else 0
+        assert m == want
+        assert np.all(ks[s, m:] == PAD_KEY) and not np.any(os_[s, m:])
+        if m:
+            lo = int(splits[c])
+            np.testing.assert_array_equal(ks[s, :m], keys[lo : lo + m])
+            np.testing.assert_array_equal(os_[s, :m], oids[lo : lo + m])
+
+
+def _assert_views(ks, os_, counts, copied, keys, oids, splits, chunk0, n_shards, b):
+    assert copied == 0
+    assert not ks.flags.owndata and not os_.flags.owndata
+    assert np.shares_memory(ks, keys) and np.shares_memory(os_, oids)
+    assert ks.shape == (n_shards, b) and ks.dtype == np.int64
+    assert os_.shape == (n_shards, b, 5) and os_.dtype == np.uint32
+    assert counts.tolist() == [b] * n_shards and counts.dtype == np.int64
+    lo = int(splits[chunk0])
+    np.testing.assert_array_equal(ks.reshape(-1), keys[lo : lo + n_shards * b])
+    np.testing.assert_array_equal(os_.reshape(-1, 5), oids[lo : lo + n_shards * b])
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["full_round", "ragged_last_round", "short_chunk", "beyond_the_plan",
+     "int32_keys", "strided_keys", "strided_oids", "one_shard"],
+)
+def test_pack_round_hands_a_full_round_over_as_views(case, tmp_path):
+    """A round whose every shard slot is full is the column itself,
+    reshaped: nothing allocated, filled or copied — also when the column is
+    an unaligned read-only mapping, as a sidecar's are. Any other round, and
+    any column the device program could not read as it lies, is packed into
+    fresh padded arrays as before."""
+    rng = np.random.default_rng(33)
+    b, n_shards = 64, 1 if case == "one_shard" else 4
+    n = 2 * n_shards * b + 37  # two full rounds and a ragged third
+    keys, oids = _random_keys_oids(rng, n)
+    keys, oids = _mapped_columns(tmp_path, keys, oids)
+    (splits,), n_chunks = batch_splits((keys,), b)
+    assert n_chunks == 2 * n_shards + 1
+    chunk0, views = n_shards, True  # the second round
+    if case == "ragged_last_round":
+        chunk0, views = 2 * n_shards, False
+    elif case == "short_chunk":
+        # a side whose key-aligned chunks come short (the other side had
+        # more keys under the boundary): one row fewer in one slot
+        splits = splits.copy()
+        splits[chunk0 + 2 :] -= 1
+        views = False
+    elif case == "beyond_the_plan":
+        chunk0, views = 3 * n_shards, False
+    elif case == "int32_keys":
+        keys, views = keys.astype(np.int32), False
+    elif case == "strided_keys":
+        keys, views = np.repeat(keys, 2)[::2], False
+        assert not keys.flags.c_contiguous
+    elif case == "strided_oids":
+        oids, views = np.repeat(oids, 2, axis=1)[:, ::2], False
+        assert not oids.flags.c_contiguous
+    got = pack_round(keys, oids, splits, chunk0, n_shards, b)
+    check = _assert_views if views else _assert_packed
+    check(*got, keys, oids, splits, chunk0, n_shards, b)
+
+
+def test_pack_round_decides_for_each_side_alone(tmp_path):
+    """Deletes on one side only: the old side's chunks stay full (views),
+    the new side's come short under the same key boundaries (copied)."""
+    rng = np.random.default_rng(34)
+    b, n_shards = 64, 4
+    keys, oids = _random_keys_oids(rng, 3 * n_shards * b)
+    keep = np.ones(len(keys), dtype=bool)
+    keep[5::17] = False
+    old = _mapped_columns(tmp_path, keys, oids, "old")
+    new = _mapped_columns(tmp_path, keys[keep], oids[keep], "new")
+    (old_splits, new_splits), _ = batch_splits((old[0], new[0]), b)
+    got_old = pack_round(*old, old_splits, 0, n_shards, b)
+    got_new = pack_round(*new, new_splits, 0, n_shards, b)
+    _assert_views(*got_old, *old, old_splits, 0, n_shards, b)
+    _assert_packed(*got_new, *new, new_splits, 0, n_shards, b)
+
+
+def _view_case(name, rng, tmp_path, b, n_shards):
+    """(old, new) blocks over mapped columns for one pattern of full and
+    short rounds, ``b`` rows a shard."""
+    per_round = n_shards * b
+    if name == "every_round_full":
+        keys, oids = _random_keys_oids(rng, 3 * per_round)
+        new_keys, new_oids = keys, oids.copy()
+        new_oids[7::97, 1] ^= 1
+    elif name == "ragged_last_round":
+        keys, oids = _random_keys_oids(rng, 2 * per_round + b + 19)
+        new_keys, new_oids = keys, oids.copy()
+        new_oids[3::89, 0] ^= 1
+    elif name == "deletes_and_appended_inserts":
+        # uniform deletes + inserts appended past the old side's range:
+        # which side is full differs round by round
+        keys, oids = _random_keys_oids(rng, 4 * per_round)
+        keep = np.ones(len(keys), dtype=bool)
+        keep[rng.choice(len(keys), size=len(keys) // 50, replace=False)] = False
+        ins = np.arange(keys[-1] + 1, keys[-1] + 1 + per_round + 11, dtype=np.int64)
+        new_keys = np.concatenate([keys[keep], ins])
+        new_oids = np.concatenate(
+            [oids[keep], rng.integers(0, 2**32, size=(len(ins), 5), dtype=np.uint32)]
+        )
+        new_oids[11::101, 2] ^= 1
+    elif name == "old_side_empty":
+        new_keys, new_oids = _random_keys_oids(rng, 2 * per_round)
+        keys, oids = np.zeros(0, dtype=np.int64), np.zeros((0, 5), dtype=np.uint32)
+    else:
+        raise AssertionError(name)
+    old_cols = _mapped_columns(tmp_path, keys, oids, "old") if len(keys) else (keys, oids)
+    new_cols = _mapped_columns(tmp_path, new_keys, new_oids, "new")
+    return (
+        FeatureBlock(*old_cols, None, len(keys)),
+        FeatureBlock(*new_cols, None, len(new_keys)),
+    )
+
+
+def _recount(old, new, b, n_shards):
+    """What the spans must say, from `batch_splits`' output alone:
+    per round (view sides, bytes copied, bytes put), view rounds."""
+    (so, sn), n_chunks = batch_splits(
+        (old.keys[: old.count], new.keys[: new.count]), b
+    )
+    side_bytes = n_shards * b * 28 + n_shards * 8
+    rounds, counts_put = [], False
+    for r in range(max(-(-n_chunks // n_shards), 1)):
+        c0, sides, put = r * n_shards, 0, 0
+        for splits in (so, sn):
+            full = c0 + n_shards <= n_chunks and np.all(
+                np.diff(splits[c0 : c0 + n_shards + 1]) == b
+            )
+            sides += bool(full)
+            put += side_bytes
+            if full and counts_put:
+                put -= n_shards * 8  # the full count vector is on the mesh
+            counts_put = counts_put or bool(full)
+        rounds.append((sides, (2 - sides) * side_bytes, put))
+    return rounds, sum(s == 2 for s, _, _ in rounds)
+
+
+VIEW_CASES = [
+    "every_round_full", "ragged_last_round", "deletes_and_appended_inserts",
+    "old_side_empty",
+]
+
+
+@pytest.mark.parametrize("counts_only", [False, True], ids=["classes", "counts"])
+@pytest.mark.parametrize("n_shards", [1, 2, 4])
+@pytest.mark.parametrize("case", VIEW_CASES)
+def test_rounds_of_views_classify_like_the_plain_reference(
+    case, n_shards, counts_only, tmp_path
+):
+    """`classify_blocks_batched` over mapped columns, rounds of views and
+    copied rounds mixed (and sides mixed within a round), against
+    `classify_blocks_reference`, bit for bit; and its spans and counter say
+    how often the views engaged: a recount from `batch_splits`' output."""
+    from kart_tpu.ops.diff_kernel import DELETE, INSERT, UPDATE
+
+    if jax.device_count() < n_shards:
+        pytest.skip(f"needs {n_shards} devices")
+    b = 128
+    old, new = _view_case(case, np.random.default_rng(35), tmp_path, b, n_shards)
+    want_old, want_new = classify_blocks_reference(old, new)
+    want_counts = {
+        "inserts": int(np.sum(want_new == INSERT)),
+        "updates": int(np.sum(want_new == UPDATE)),
+        "deletes": int(np.sum(want_old == DELETE)),
+    }
+    assert sum(want_counts.values()) > 0
+    want_rounds, want_view_rounds = _recount(old, new, b, n_shards)
+    sides = [s for s, _, _ in want_rounds]
+    if case == "every_round_full":
+        assert sides == [2, 2, 2]
+    elif case == "ragged_last_round":
+        assert set(sides[:-1]) == {2} and sides[-1] == 0
+    elif case == "deletes_and_appended_inserts":
+        assert {0, 1} <= set(sides)  # a round of one view side and one copied
+    else:
+        assert sides == [1, 1]
+
+    telemetry.reset()
+    telemetry.enable(metrics=True, trace=True)
+    try:
+        got_old, got_new, got_counts = classify_blocks_batched(
+            old, new, mesh=make_mesh(n_shards), batch_rows=b, counts_only=counts_only
+        )
+        counters = {name: v for name, _, v in telemetry.snapshot()["counters"]}
+        events = telemetry.drain_events()
+    finally:
+        telemetry.reset()
+    assert got_counts == want_counts
+    if counts_only:
+        assert got_old is None and got_new is None
+    else:
+        np.testing.assert_array_equal(got_old, want_old)
+        np.testing.assert_array_equal(got_new, want_new)
+
+    spans = {}
+    for e in events:
+        spans.setdefault(e["name"], []).append(e["args"])
+    (root,) = spans["diff.device.classify"]
+    assert root["rounds"] == len(want_rounds)
+    assert root["view_rounds"] == want_view_rounds
+    assert counters.get("diff.device.view_rounds", 0) == want_view_rounds
+    assert [(p["view_sides"], p["bytes"]) for p in spans["diff.device.pack"]] == [
+        (s, copied) for s, copied, _ in want_rounds
+    ]
+    assert [t["bytes"] for t in spans["diff.device.transfer"]] == [
+        put for _, _, put in want_rounds
+    ]
+    assert root["bytes"] == counters["diff.device.h2d_bytes"] == sum(
+        put for _, _, put in want_rounds
+    )
+
+
+@pytest.mark.parametrize("case", VIEW_CASES)
+def test_every_host_to_device_byte_goes_through_the_transfer_span(case, tmp_path):
+    """The jitted call is handed device arrays and moves nothing itself:
+    with implicit host→device transfers disallowed (an explicit
+    ``device_put`` is not one) the classify still runs, rounds of views and
+    copied rounds alike — so ``diff.device.transfer`` times, and its
+    ``bytes`` count, everything that crosses. (A round's host arrays handed
+    to the call instead are transferred a second time inside
+    ``diff.device.kernel``: the answer is the same and only the chip's clock
+    shows it — PERF.md §6, PR 33.)"""
+    n_shards = min(jax.device_count(), 4)
+    old, new = _view_case(case, np.random.default_rng(36), tmp_path, 128, n_shards)
+    want_old, want_new = classify_blocks_reference(old, new)
+    with jax.transfer_guard_host_to_device("disallow"):
+        got_old, got_new, _ = classify_blocks_batched(
+            old, new, mesh=make_mesh(n_shards), batch_rows=128
+        )
+    np.testing.assert_array_equal(got_old, want_old)
+    np.testing.assert_array_equal(got_new, want_new)

@@ -972,24 +972,50 @@ def test_device_transfer_fault_falls_back_bit_identical(monkeypatch):
     np.testing.assert_array_equal(got_new, want_new)
 
 
+def _full_rounds_block_pair(n=2 * 256 * 3 + 100, seed=14):
+    """(old, new) over one key column, attribute edits only: every chunk but
+    the last is full on both sides, so all rounds but the last are handed
+    to the devices as views of these columns."""
+    import numpy as np
+
+    from kart_tpu.ops.blocks import FeatureBlock
+
+    rng = np.random.default_rng(seed)
+    keys = np.sort(rng.choice(20 * n, size=n, replace=False)).astype(np.int64)
+    oids = rng.integers(0, 2**32, size=(n, 5), dtype=np.uint32)
+    new_oids = oids.copy()
+    new_oids[::31, 3] ^= 1
+    for a in (keys, oids, new_oids):
+        a.flags.writeable = False  # as a sidecar's mapping is
+    return FeatureBlock(keys, oids, None, n), FeatureBlock(keys, new_oids, None, n)
+
+
+@pytest.mark.parametrize(
+    "make_pair,view_rounds",
+    [(_edited_block_pair, 0), (_full_rounds_block_pair, 3)],
+    ids=["copied_rounds", "view_rounds"],
+)
 def test_device_transfer_killed_at_every_round_leaves_no_partial_state(
-    monkeypatch,
+    monkeypatch, make_pair, view_rounds
 ):
     """Kill matrix over transfer rounds: for every round N of a multi-round
     batched classify, an injected crash at round N's host->device transfer
     raises out of the device attempt with nothing published, and the very
     next (uninjected) call over the same blocks is bit-identical to
-    host-native — no partial state survives the crash."""
+    host-native — no partial state survives the crash. A round of views
+    (nothing packed, the columns themselves handed over) aborts like a
+    copied one."""
     import jax
     import numpy as np
 
+    from kart_tpu import telemetry
     from kart_tpu.diff.device_batch import batch_splits, classify_blocks_batched
     from kart_tpu.ops.diff_kernel import classify_blocks_host
     from kart_tpu.parallel.mesh import make_mesh
 
     if jax.device_count() < 2:
         pytest.skip("needs >= 2 devices")
-    old, new = _edited_block_pair()
+    old, new = make_pair()
     want = classify_blocks_host(old, new)
     n_shards, batch_rows = 2, 256
     _, n_chunks = batch_splits(
@@ -998,15 +1024,26 @@ def test_device_transfer_killed_at_every_round_leaves_no_partial_state(
     n_rounds = -(-n_chunks // n_shards)
     assert n_rounds >= 3, "fixture too small to exercise mid-stream rounds"
     mesh = make_mesh(n_shards)
-    for r in range(1, n_rounds + 1):
-        monkeypatch.setenv("KART_FAULTS", f"diff.device_transfer:{r}")
-        with pytest.raises(faults.InjectedFault):
-            classify_blocks_batched(old, new, mesh=mesh, batch_rows=batch_rows)
-        monkeypatch.delenv("KART_FAULTS")
-        got = classify_blocks_batched(old, new, mesh=mesh, batch_rows=batch_rows)
-        assert got[2] == want[2]
-        np.testing.assert_array_equal(got[0], want[0])
-        np.testing.assert_array_equal(got[1], want[1])
+    def views_counted():
+        return telemetry.counters_snapshot().get(("diff.device.view_rounds", ()), 0)
+
+    telemetry.reset()
+    telemetry.enable(metrics=True)
+    try:
+        for r in range(1, n_rounds + 1):
+            monkeypatch.setenv("KART_FAULTS", f"diff.device_transfer:{r}")
+            with pytest.raises(faults.InjectedFault):
+                classify_blocks_batched(old, new, mesh=mesh, batch_rows=batch_rows)
+            monkeypatch.delenv("KART_FAULTS")
+            # an aborted call counts nothing: the counters move with results
+            assert views_counted() == (r - 1) * view_rounds
+            got = classify_blocks_batched(old, new, mesh=mesh, batch_rows=batch_rows)
+            assert views_counted() == r * view_rounds
+            assert got[2] == want[2]
+            np.testing.assert_array_equal(got[0], want[0])
+            np.testing.assert_array_equal(got[1], want[1])
+    finally:
+        telemetry.reset()
 
 
 def test_cli_diff_survives_device_transfer_fault(tmp_path, monkeypatch):

@@ -523,8 +523,10 @@ def test_mesh_count_command_names_every_stage(tmp_path, cli_runner, monkeypatch)
     devices, 256 rows a shard, so several rounds): ``diff.classify`` says
     ``sharded_jax``; under it one ``diff.device.classify`` whose children
     are the splits and, once per round, pack -> transfer -> kernel with the
-    fetch of the round before — each with its attributes, the packs' bytes
-    adding up to the root's — and the answer is the host engine's."""
+    fetch of the round before — each with its attributes: a pack's bytes
+    are what the host copied (nothing for a round of views), the transfers'
+    bytes are what was put and add up to the root's — and the answer is the
+    host engine's."""
     import jax
 
     from kart_tpu.diff import device_batch
@@ -552,11 +554,24 @@ def test_mesh_count_command_names_every_stage(tmp_path, cli_runner, monkeypatch)
     shards, chunks = jax.device_count(), -(-rows // batch_rows)
     rounds = -(-chunks // shards)
     assert rounds > 2
-    round_bytes = 2 * (shards * batch_rows * (8 + 5 * 4) + shards * 8)
+    # the commit rewrites attributes only: both sides have the one key
+    # column, every chunk but the last is full, so every round but the last
+    # goes over as views of the sidecar's pages — the host copies nothing
+    # for it, and puts its count vector (the same for every full round-side)
+    # once a command, in the first round
+    assert rows % batch_rows and chunks % shards
+    counts_bytes = shards * 8
+    round_bytes = 2 * (shards * batch_rows * (8 + 5 * 4) + counts_bytes)
+    put_bytes = (
+        [round_bytes - counts_bytes]
+        + [round_bytes - 2 * counts_bytes] * (rounds - 2)
+        + [round_bytes]
+    )
     assert root["args"] == {
         "rows": rows, "shards": shards, "rounds": rounds, "chunks": chunks,
         "batch_rows": batch_rows, "counts_only": True, "kernel": "sort",
-        "bytes": rounds * round_bytes, "parent": "diff.classify",
+        "bytes": sum(put_bytes), "view_rounds": rounds - 1,
+        "parent": "diff.classify",
         "request_id": root["args"]["request_id"],
         "trace_id": classify["args"]["trace_id"],
     }
@@ -571,14 +586,15 @@ def test_mesh_count_command_names_every_stage(tmp_path, cli_runner, monkeypatch)
     assert len(children) == 1 + 4 * rounds
     for name, spans in by_name.items():
         assert [e["args"]["round"] for e in spans] == list(range(rounds)), name
-    assert [e["args"]["bytes"] for e in by_name["diff.device.pack"]] == (
-        [round_bytes] * rounds
-    )
-    assert sum(e["args"]["bytes"] for e in by_name["diff.device.pack"]) == (
+    # pack: what the host copied; transfer: what was put — the transfers,
+    # not the packs, add up to the root's
+    assert [
+        (e["args"]["view_sides"], e["args"]["bytes"])
+        for e in by_name["diff.device.pack"]
+    ] == [(2, 0)] * (rounds - 1) + [(0, round_bytes)]
+    assert [e["args"]["bytes"] for e in by_name["diff.device.transfer"]] == put_bytes
+    assert sum(e["args"]["bytes"] for e in by_name["diff.device.transfer"]) == (
         root["args"]["bytes"]
-    )
-    assert [e["args"]["bytes"] for e in by_name["diff.device.transfer"]] == (
-        [round_bytes] * rounds
     )
     assert {e["args"]["program"] for e in by_name["diff.device.kernel"]} == {
         "mesh_classify"
@@ -612,7 +628,12 @@ def test_mesh_classify_counts_rounds_and_bytes():
     assert gauges["diff.device.batch_rows"] == 256
     assert counters["diff.device.rounds"] == rounds == 3
     assert counters["diff.device.batches"] == rounds * 4
-    assert counters["diff.device.h2d_bytes"] == rounds * 2 * (4 * 256 * 28 + 4 * 8)
+    # two rounds of views and a ragged third; the full rounds' count vector
+    # is put once, so three of the four view round-sides put 32 bytes fewer
+    assert counters["diff.device.view_rounds"] == 2
+    assert counters["diff.device.h2d_bytes"] == (
+        rounds * 2 * (4 * 256 * 28 + 4 * 8) - 3 * 4 * 8
+    )
 
 
 def test_mesh_classify_disabled_records_nothing(monkeypatch):
