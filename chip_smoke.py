@@ -2,8 +2,8 @@
 """chip_smoke.py — the quickest proof that kart-tpu still starts on the chip.
 
 Drives the system's main path once, in ONE process, through the entry
-points a user calls (``kart diff``, ``kart merge``, the spatial-filter
-envelope scan) at the size of ``BASELINE.json`` config 2 — a 10M-row point
+points a user calls (``kart diff``, with and without a spatial filter set, ``kart merge``, the
+spatial-filter envelope scan) at the size of ``BASELINE.json`` config 2 — a 10M-row point
 layer with a 1% edit commit — under default (auto) routing, and compares
 every answer with the host engine's. Then it runs, directly on a one-device
 mesh, the device programs auto routing cannot reach on one chip
@@ -86,6 +86,9 @@ def parse_args(argv=None):
                    help="envelopes of the spatial-filter scan")
     p.add_argument("--jsonl-rows", type=int, default=250_000,
                    help="changed rows of the json-lines materialise check")
+    p.add_argument("--filtered-rows", type=int, default=8_000_000,
+                   help="rows of the layer the filtered count runs on (29%% of "
+                   "them survive the prefilter: enough for the device route)")
     return p.parse_args(argv)
 
 
@@ -651,8 +654,6 @@ def phase_churn(smoke):
     ``diff.device.kernel`` span) equals a numpy recount of the same key
     columns. Uniform churn must stay on the windowed join; which program
     answers the bulk delete is recorded, not checked."""
-    import importlib.util
-
     from kart_tpu import telemetry as tm
     from kart_tpu.ops.diff_kernel import (
         classify_blocks,
@@ -660,14 +661,11 @@ def phase_churn(smoke):
         join_census_reference,
     )
 
-    bench = os.path.join(REPO_ROOT, "benchmarks")
-    spec = importlib.util.spec_from_file_location(
-        "bench_layers_int_pk_churn_layer",
-        os.path.join(bench, "layers", "int_pk_churn_layer.py"),
+    builder = _bench_module("layers", "int_pk_churn_layer")
+    config = os.path.join(
+        REPO_ROOT, "benchmarks", "configs", "baseline2_points_10m_churn.json"
     )
-    builder = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(builder)
-    with open(os.path.join(bench, "configs", "baseline2_points_10m_churn.json")) as f:
+    with open(config) as f:
         params = dict(json.load(f)["layer"]["params"], rows=smoke.args.rows)
 
     for branch in builder.BRANCHES:
@@ -706,6 +704,75 @@ def phase_churn(smoke):
                 rec["checks"]["windowed_join_answered"] = all(
                     k.get("join") == "window" for k in kernels
                 )
+
+
+def _bench_module(kind, name):
+    """benchmarks/<kind>/<name>.py, loaded as benchmarks/run.py loads it."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name}",
+        os.path.join(REPO_ROOT, "benchmarks", kind, name + ".py"),
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+#: the attributes the filtered count's spans carry (docs/OBSERVABILITY.md)
+FILTERED_SPANS = {
+    "diff.prefilter": ("rows",),
+    "diff.prefilter.scan": ("rows", "blocks", "blocks_scanned", "hits_old", "hits_new"),
+    "diff.prefilter.propagate": ("probed",),
+    "diff.prefilter.compact": ("rows", "survivors", "bytes", "runs"),
+    "diff.classify": ("rows", "backend", "counts_only"),
+    "diff.refine": ("candidates", "inside", "outside", "residue", "blobs_read"),
+}
+
+
+def phase_filtered(smoke):
+    """The filtered-clone deployment (benchmarks/configs/
+    baseline4_nodes_10m_filtered.json) through its cell's command: `kart diff
+    -o feature-count` in a repository with the configuration's polygonal
+    spatial filter set, under auto routing — the count against the
+    benchmark's reference (the edited points inside the polygon, by its own
+    ray cast) and against the host engine's, and every stage's span with its
+    attributes."""
+    builder = _bench_module("layers", "nodes_filtered_layer")
+    reference = _bench_module("references", "feature_count_filtered")
+    config = os.path.join(
+        REPO_ROOT, "benchmarks", "configs", "baseline4_nodes_10m_filtered.json"
+    )
+    with open(config) as f:
+        params = dict(json.load(f)["layer"]["params"], rows=smoke.args.filtered_rows)
+
+    with smoke.phase("filtered.layer", rows=params["rows"]) as rec:
+        base = os.path.join(smoke.work, "filtered-base")
+        os.makedirs(base)
+        builder.build_base(base, params)
+        repo_path, info = builder.add_edit_commit(
+            base, os.path.join(smoke.work, "filtered"), params, smoke.args.seed
+        )
+        rec["n_edits"] = info["n_edits"]
+
+    with smoke.phase("filtered.feature_count", rows=params["rows"]) as rec:
+        command = ["-C", repo_path, "diff", "HEAD^...HEAD", "-o", "feature-count"]
+        dev, dev_run = smoke.kart(command)
+        host, host_run = smoke.kart(command, env=HOST_TWIN_ENV)
+        smoke.routed(rec, "diff.classify", dev_run, host_run)
+        rec["output"] = dev.stdout.strip()
+        rec["edits_in_polygon"] = reference.edits_in_polygon(info)
+        rec["edits_in_box"] = info["n_edits_in_box"]
+        rec["checks"]["equals_twin"] = dev.stdout == host.stdout
+        rec["checks"].update(reference.check(dev.stdout_bytes, info))
+        spans = {e["name"]: e.get("args", {}) for e in dev_run[1] if e.get("ph") == "X"}
+        rec["spans"] = {
+            name: {a: spans.get(name, {}).get(a) for a in attrs}
+            for name, attrs in FILTERED_SPANS.items()
+        }
+        rec["checks"]["spans_carry_their_attributes"] = all(
+            set(attrs) <= set(spans.get(name, ())) for name, attrs in FILTERED_SPANS.items()
+        )
 
 
 def phase_one_device_mesh(smoke):
@@ -850,6 +917,7 @@ def run(args, work):
         phase_bbox(smoke)
         phase_materialise(smoke)
         phase_churn(smoke)
+        phase_filtered(smoke)
         phase_one_device_mesh(smoke)
 
     with smoke.phase("summary") as rec:

@@ -105,6 +105,19 @@ class DiffBackend:
 
         return bbox_intersects_f32(block.envelopes, query)
 
+    def envelope_census(self, block, query):
+        """(aggregate blocks, blocks on the query's boundary) of one sidecar
+        block: the boundary blocks are the ones the block-pruned scan reads
+        row by row (all-in and all-out blocks are decided from their
+        aggregates alone). (0, 0) for a sidecar without aggregates."""
+        if block.env_blocks is None:
+            return 0, 0
+        from kart_tpu.ops.bbox import BLOCK_BOUNDARY, classify_env_blocks_np
+
+        agg, flags, _ = block.env_blocks
+        cls = classify_env_blocks_np(agg, flags, np.asarray(query, dtype=np.float64))
+        return len(cls), int(np.count_nonzero(cls == BLOCK_BOUNDARY))
+
     def join_counts(self, build_env, probe_env):
         """Spatial-join batch kernel (ISSUE 16): (T, 4) f32 build-tile
         envelopes x (B, 4) f32 probe-batch envelopes -> (per-probe match

@@ -11,6 +11,8 @@ Two layers:
   kart_tpu/ops/diff_kernel.py for the device kernels.
 """
 
+import threading
+
 import numpy as np
 
 from kart_tpu import telemetry as tm
@@ -281,6 +283,47 @@ def _envelope_hits(block, query):
     return select_backend(block.count).envelope_hits(block, query)
 
 
+def _run_count(idx):
+    """Runs of consecutive row numbers in a sorted index array."""
+    return int(np.count_nonzero(idx[1:] - idx[:-1] != 1)) + 1 if len(idx) else 0
+
+
+#: per thread, the buffers :func:`_take_rows` was asked to keep, by name
+_kept = threading.local()
+
+
+def _take_rows(column, idx, keep=None):
+    """``column[idx]`` for a sidecar column: gathered through a byte view,
+    which is aligned whatever the column's offset in its file — numpy
+    copies an unaligned column whole before it gathers from it (280 MB a
+    side at 10M rows, for 3M survivors).
+
+    ``keep``: a name under which the result's memory is kept by this module
+    and handed out again to the next call with that name on this thread,
+    which overwrites it — for a result of tens of megabytes that is dead by
+    then. An array that large is mapped afresh by the allocator when it
+    finds no room in its heap, and first touches of fresh pages are what a
+    gather into it then costs: 0.062 s for a 59 MB oid column on the
+    benchmark's host (3.5 us a page) against 0.017 s for the gather — and
+    whether it found room differed from process to process (PERF.md §6,
+    PR 35)."""
+    column = np.asarray(column)
+    row_bytes = column.dtype.itemsize * int(np.prod(column.shape[1:], dtype=np.int64))
+    source = column.view(np.uint8).reshape(len(column), row_bytes)
+    if keep is None:
+        rows = np.take(source, idx, axis=0)
+    else:
+        kept = getattr(_kept, keep, None)
+        if kept is None or kept.size < len(idx) * row_bytes:
+            kept = np.empty(len(idx) * row_bytes, dtype=np.uint8)
+            setattr(_kept, keep, kept)
+        rows = kept[: len(idx) * row_bytes].reshape(len(idx), row_bytes)
+        # the indices are row numbers of the column: "clip" only spares
+        # numpy the buffered copy that "raise" makes when given an out=
+        np.take(source, idx, axis=0, out=rows, mode="clip")
+    return rows.view(column.dtype).reshape((len(idx),) + column.shape[1:])
+
+
 def spatial_prefilter_blocks(old_block, new_block, rect_wsen):
     """Envelope prefilter for a sidecar block pair (both sides must carry
     envelope columns, else None): a key survives in BOTH blocks when EITHER
@@ -288,64 +331,92 @@ def spatial_prefilter_blocks(old_block, new_block, rect_wsen):
     aligned, so the classify semantics on the subset equal classifying the
     whole pair then dropping out-of-filter deltas (the reference's
     delta-level filter, kart/base_diff_writer.py:279-341, evaluated on the
-    envelope index instead of materialised values). -> (old_sub, new_sub)
-    unpadded-path FeatureBlocks, or None when envelopes are missing.
+    envelope index instead of materialised values).
+    -> ((old_sub, new_sub), (old_rows, new_rows)): the survivors as
+    unpadded FeatureBlocks of keys and oids, and the row number each
+    survivor has in the block it came from (where its envelope and its
+    blob's oid are); or None when envelopes are missing.
 
     Everything after the (block-pruned) envelope scan works on hit *indices*
     rather than full-width masks: the cross-side key propagation probes only
     the hit keys and the compaction gathers only surviving rows, so at 100M
-    rows the key/oid pages of out-of-filter regions are never faulted in."""
+    rows the key/oid pages of out-of-filter regions are never faulted in.
+    The survivors are gathered once, into arrays of their own size: the
+    device classify pads a block's tail itself. The sub-blocks' oid columns
+    are memory this module keeps (:func:`_take_rows`): they hold until the
+    next call on this thread, which every caller is done with them by."""
     if old_block.envelopes is None or new_block.envelopes is None:
         return None
+    from kart_tpu.diff.backend import select_backend
+    from kart_tpu.ops.blocks import FeatureBlock
+
     o_n, n_n = old_block.count, new_block.count
     query = np.asarray(rect_wsen, dtype=np.float64)
     with tm.span("diff.prefilter", rows=max(o_n, n_n)):
-        o_idx = np.flatnonzero(_envelope_hits(old_block, query))
-        n_idx = np.flatnonzero(_envelope_hits(new_block, query))
+        with tm.span("diff.prefilter.scan", rows=o_n + n_n) as sp:
+            o_idx = np.flatnonzero(_envelope_hits(old_block, query))
+            n_idx = np.flatnonzero(_envelope_hits(new_block, query))
+            census = [
+                select_backend(b.count).envelope_census(b, query)
+                for b in (old_block, new_block)
+            ]
+            sp.set(
+                blocks=census[0][0] + census[1][0],
+                blocks_scanned=census[0][1] + census[1][1],
+                hits_old=len(o_idx),
+                hits_new=len(n_idx),
+            )
         o_keys = old_block.keys[:o_n]
         n_keys = new_block.keys[:n_n]
         # propagate hits to the other side's matching keys (both key-sorted):
-        # binary-search the (few) hit keys into the other side, union the
-        # matching row indices in
-        if o_n and n_n:
-            n_hit_keys = np.asarray(n_keys[n_idx])
-            o_hit_keys = np.asarray(o_keys[o_idx])
-            if o_n == n_n and np.array_equal(o_hit_keys, n_hit_keys):
-                # identical hit-key sets on both sides (edits that don't move
-                # geometry — the overwhelmingly common case): each side's rows
-                # matching the other's hit keys ARE its own hit rows (keys are
-                # unique and sorted), so the binary-search probe storm into the
-                # 100M-row key mmaps — scattered page faults at north-star
-                # scale — is skipped entirely
-                o_surv, n_surv = o_idx, n_idx
-            else:
-                pos = np.searchsorted(o_keys, n_hit_keys)
-                pos_c = np.minimum(pos, o_n - 1)
-                shared = (np.asarray(o_keys[pos_c]) == n_hit_keys) & (pos < o_n)
-                o_surv = np.union1d(o_idx, pos_c[shared])
-                pos2 = np.searchsorted(n_keys, o_hit_keys)
-                pos2_c = np.minimum(pos2, n_n - 1)
-                shared2 = (np.asarray(n_keys[pos2_c]) == o_hit_keys) & (pos2 < n_n)
-                n_surv = np.union1d(n_idx, pos2_c[shared2])
-        else:
-            o_surv, n_surv = o_idx, n_idx
+        # binary-search the keys that hit on one side only into the other
+        # side, union the rows found in
+        with tm.span("diff.prefilter.propagate") as sp:
+            o_hit_keys = _take_rows(o_keys, o_idx)
+            n_hit_keys = _take_rows(n_keys, n_idx)
+            probed = 0
+            if o_n and n_n and not np.array_equal(o_hit_keys, n_hit_keys):
+                # (identical hit keys on both sides — edits that don't move
+                # geometry, the overwhelmingly common case — need nothing:
+                # keys are unique and sorted, so each side's rows matching
+                # the other's hit keys ARE its own hit rows, and the
+                # binary-search probe storm into the 100M-row key mmaps —
+                # scattered page faults at north-star scale — is skipped)
+                def found(keys, n, probes):
+                    pos = np.minimum(np.searchsorted(keys, probes), n - 1)
+                    return pos[np.asarray(keys[pos]) == probes]
 
-        def compact(block, idx):
-            from kart_tpu.ops.blocks import PAD_KEY, FeatureBlock, bucket_size
-
-            k = np.asarray(block.keys[idx])
-            o = np.asarray(block.oids[idx])
-            size = bucket_size(max(len(k), 1))
-            kp = np.full(size, PAD_KEY, dtype=np.int64)
-            kp[: len(k)] = k
-            op = np.zeros((size, 5), dtype=np.uint32)
-            op[: len(k)] = o
-            # envelopes deliberately dropped: nothing downstream of the
-            # prefilter reads them (classify uses keys/oids; writers' exact
-            # residue reads feature values)
-            return FeatureBlock(kp, op, None, len(k))
-
-        return compact(old_block, o_surv), compact(new_block, n_surv)
+                o_only = np.setdiff1d(o_hit_keys, n_hit_keys, assume_unique=True)
+                n_only = np.setdiff1d(n_hit_keys, o_hit_keys, assume_unique=True)
+                probed = len(o_only) + len(n_only)
+                o_idx = np.union1d(o_idx, found(o_keys, o_n, n_only))
+                n_idx = np.union1d(n_idx, found(n_keys, n_n, o_only))
+                o_hit_keys = _take_rows(o_keys, o_idx)
+                n_hit_keys = _take_rows(n_keys, n_idx)
+            sp.set(probed=probed)
+        with tm.span("diff.prefilter.compact", rows=o_n + n_n) as sp:
+            old_sub = FeatureBlock(
+                o_hit_keys,
+                _take_rows(old_block.oids[:o_n], o_idx, keep="old_oids"),
+                None,
+                len(o_idx),
+            )
+            new_sub = FeatureBlock(
+                n_hit_keys,
+                _take_rows(new_block.oids[:n_n], n_idx, keep="new_oids"),
+                None,
+                len(n_idx),
+            )
+            sp.set(
+                survivors=len(o_idx) + len(n_idx),
+                bytes=sum(
+                    a.nbytes
+                    for a in (old_sub.keys, old_sub.oids, new_sub.keys, new_sub.oids)
+                ),
+                runs=_run_count(o_idx) + _run_count(n_idx),
+            )
+        tm.incr("diff.prefilter.rows_kept", len(o_idx) + len(n_idx))
+        return (old_sub, new_sub), (o_idx, n_idx)
 
 
 #: query-rect pad for the envelope prefilter: sidecar envelopes are rounded
@@ -419,11 +490,99 @@ def _feature_diff_routed(base_ds, target_ds, ds_filter=None, spatial_filter_spec
                 if rect is not None and base_ds.path_encoder.scheme == "int":
                     filtered = spatial_prefilter_blocks(old_block, new_block, rect)
                     if filtered is not None:
-                        old_block, new_block = filtered
+                        # the writers refine delta by delta, on the values
+                        (old_block, new_block), _ = filtered
                 return get_feature_diff_columnar(
                     base_ds, target_ds, ds_filter, blocks=(old_block, new_block)
                 )
     return get_feature_diff(base_ds, target_ds, ds_filter)
+
+
+def _envelope_verdicts(sf, spatial_filter_spec, block, rows):
+    """ENV_* verdict of the filter polygon on the sidecar envelope of each of
+    ``rows`` (row numbers of ``block``). The envelope column is float32,
+    rounded to nearest, so an envelope is judged padded by the prefilter's
+    pad: wholly inside the polygon even so, the geometry in it intersects
+    the filter; wholly outside, it cannot. Everything else is ENV_PARTIAL,
+    as is every row when the polygon does not lie in the envelope column's
+    CRS (a reprojected or projected filter): the geometry decides."""
+    from kart_tpu.spatial_filter import ENV_PARTIAL, polygon_set_env_relations
+
+    if sf.reprojected or not spatial_filter_spec.crs.is_geographic:
+        return np.full(len(rows), ENV_PARTIAL, dtype=np.uint8)
+    env = _take_rows(block.envelopes, rows).astype(np.float64)  # w s e n
+    return polygon_set_env_relations(
+        sf.filter_parts(),
+        env[:, 0] - _PREFILTER_PAD, env[:, 2] + _PREFILTER_PAD,
+        env[:, 1] - _PREFILTER_PAD, env[:, 3] + _PREFILTER_PAD,
+    )
+
+
+def _blob_matches(sf, ds, block, row):
+    """The exact answer for one row: its blob's geometry against the filter.
+    A blob that is not here (promised) cannot be tested and matches, as in
+    the writers' delta filter."""
+    from kart_tpu.core.odb import ObjectMissing, ObjectPromised
+    from kart_tpu.ops.blocks import unpack_oid_hex
+    from kart_tpu.spatial_filter import MatchResult
+
+    oid_hex = unpack_oid_hex(np.asarray(block.oids[row : row + 1]))[0]
+    try:
+        feature = ds.get_feature_promise_from_oid((int(block.keys[row]),), oid_hex)()
+    except (ObjectPromised, ObjectMissing):
+        return True
+    return sf.match_result(feature) is MatchResult.MATCHED
+
+
+def refine_changed_count(spatial_filter_spec, sides, classes, changed):
+    """Exact count of the changed features that match the spatial filter,
+    from the classify of the prefilter's survivors. ``sides``: for old and
+    new, (dataset, the whole sidecar block, the survivors' row numbers in
+    it); ``classes`` / ``changed``: the survivors' class arrays and
+    :func:`changed_indices` of them. Only changed rows are looked at: the
+    sidecar envelope decides the ones wholly inside or outside the filter
+    polygon, the blob's geometry the residue. An update counts once, and
+    counts if either of its sides matches (the reference's delta filter,
+    kart/base_diff_writer.py:279-341)."""
+    from kart_tpu.ops.diff_kernel import UPDATE
+    from kart_tpu.spatial_filter import ENV_CONTAINS, ENV_DISJOINT, ENV_PARTIAL
+
+    with tm.span(
+        "diff.refine", candidates=sum(len(idx) for idx in changed)
+    ) as sp:
+        # one filter for both sides of a delta, the new side's dataset first
+        # (as the writers resolve it)
+        sf = spatial_filter_spec.resolve_for_dataset(sides[1][0])
+        inside = outside = blobs_read = 0
+        matched = []
+        for (ds, block, rows), idx in zip(sides, changed):
+            if not sf:  # no geometry column, or no way into its CRS: all match
+                inside += len(idx)
+                matched.append(np.ones(len(idx), dtype=bool))
+                continue
+            rows = rows[idx]
+            verdict = _envelope_verdicts(sf, spatial_filter_spec, block, rows)
+            match = verdict == ENV_CONTAINS
+            inside += int(np.count_nonzero(verdict == ENV_CONTAINS))
+            outside += int(np.count_nonzero(verdict == ENV_DISJOINT))
+            for i in np.flatnonzero(verdict == ENV_PARTIAL):
+                match[i] = _blob_matches(sf, ds, block, int(rows[i]))
+                blobs_read += 1
+            matched.append(match)
+        sp.set(
+            inside=inside, outside=outside, residue=blobs_read,
+            blobs_read=blobs_read,
+        )
+        tm.incr("diff.refine.residue_rows", blobs_read)
+        # both sides are key-sorted: the k-th update of one is the k-th of
+        # the other
+        old_match, new_match = matched
+        old_upd, new_upd = (cls[idx] == UPDATE for cls, idx in zip(classes, changed))
+        return int(
+            np.count_nonzero(old_match[~old_upd])
+            + np.count_nonzero(new_match[~new_upd])
+            + np.count_nonzero(old_match[old_upd] | new_match[new_upd])
+        )
 
 
 def get_dataset_feature_count_fast(
@@ -435,13 +594,14 @@ def get_dataset_feature_count_fast(
     reference analog: exact diff estimation, kart/diff_estimation.py:51-76).
 
     With an active spatial_filter_spec the count requires envelope sidecar
-    columns (prefilter before classify); otherwise returns None so the
-    delta path can apply the value-level filter. The filtered count is
-    envelope-precision: a changed feature whose (padded) envelope clips the
-    filter's bounding rectangle counts even when its exact geometry
-    wouldn't match a polygonal filter — a deliberate fail-open upper bound,
-    matching what's knowable without materialising values (at the promised-
-    blob scale this path exists for, values aren't readable at all).
+    columns; otherwise returns None so the delta path can apply the
+    value-level filter. The filtered count is exact, the number of features
+    `-o json-lines` lists: the envelope prefilter drops the rows outside
+    the filter's padded bounding rectangle before the transfer, the
+    survivors are classified, and the changed ones alone are refined against
+    the filter geometry (:func:`refine_changed_count`: envelope verdicts,
+    blob reads for the residue; NULL and empty geometries match, a blob
+    that is promised matches).
 
     -> int, or None when the count can't be taken from the columnar route
     with delta-path parity (dataset added/removed, hash-keyed identities,
@@ -485,13 +645,29 @@ def get_dataset_feature_count_fast(
     if old_block is None or new_block is None:
         return None
 
+    from kart_tpu.diff.backend import select_backend
+    from kart_tpu.ops.diff_kernel import changed_indices
+
     if rect is not None:
         filtered = spatial_prefilter_blocks(old_block, new_block, rect)
         if filtered is None:
             return None  # no envelope columns: delta path applies the filter
-        old_block, new_block = filtered
-
-    from kart_tpu.diff.backend import select_backend
+        (old_sub, new_sub), (old_rows, new_rows) = filtered
+        backend = select_backend(max(old_sub.count, new_sub.count))
+        with tm.span(
+            "diff.classify",
+            rows=max(old_sub.count, new_sub.count),
+            backend=backend.name,
+            counts_only=False,
+        ):
+            classes = backend.classify(old_sub, new_sub)[:2]
+            changed = changed_indices(*classes)
+        return refine_changed_count(
+            spatial_filter_spec,
+            ((base_ds, old_block, old_rows), (target_ds, new_block, new_rows)),
+            classes,
+            changed,
+        )
 
     backend = select_backend(max(old_block.count, new_block.count))
     with tm.span(

@@ -167,11 +167,15 @@ class SpatialFilter:
 
     MATCH_ALL = None  # set below
 
-    def __init__(self, rect_wesn=None, geom_column_name=None, polygon_parts=None):
+    def __init__(self, rect_wesn=None, geom_column_name=None, polygon_parts=None,
+                 reprojected=False):
         self.match_all = rect_wesn is None
         self.rect = rect_wesn  # (w, e, s, n) in dataset CRS
         self.geom_column_name = geom_column_name
         self.polygon_parts = polygon_parts  # [(outer, [holes]), ...] dataset CRS
+        # True when the filter was transformed out of its own CRS: its
+        # polygon then no longer lies in the CRS of the filter's envelope
+        self.reprojected = reprojected
         self._rect_parts = None  # lazy: the rect as a polygon part
 
     @classmethod
@@ -182,6 +186,7 @@ class SpatialFilter:
         x0, x1, y0, y1 = spec.envelope_native
         parts = _polygon_parts(spec.geometry)
         ds_crs_wkt = None
+        reprojected = False
         try:
             ids = dataset.crs_identifiers()
             if ids:
@@ -193,6 +198,7 @@ class SpatialFilter:
             if ds_crs != spec.crs:
                 try:
                     t = Transform(spec.crs, ds_crs)
+                    reprojected = True
                     x0, x1, y0, y1 = t.transform_envelope((x0, x1, y0, y1))
                     if parts is not None:
                         parts = [
@@ -213,7 +219,7 @@ class SpatialFilter:
                         e,
                     )
                     return cls.MATCH_ALL
-        return cls((x0, x1, y0, y1), geom_col, parts)
+        return cls((x0, x1, y0, y1), geom_col, parts, reprojected)
 
     def matches(self, feature):
         result = self.match_result(feature)
@@ -266,6 +272,11 @@ class SpatialFilter:
         if _geom_intersects_polygon_set(feat, filter_parts):
             return MatchResult.MATCHED
         return MatchResult.NOT_MATCHED
+
+    def filter_parts(self):
+        """The filter as polygon parts [(outer, [holes]), ...] in the
+        dataset's CRS: its polygon's, or its rectangle's."""
+        return self.polygon_parts or self._rect_as_parts()
 
     def _rect_as_parts(self):
         """The rect filter as a polygon part, for the exact residue test."""
@@ -348,6 +359,89 @@ def _polygon_set_env_relation(parts, env):
     if not any_hit:
         return "disjoint"
     return "partial"
+
+
+#: verdicts of :func:`polygon_set_env_relations`, one a row
+ENV_DISJOINT, ENV_CONTAINS, ENV_PARTIAL = 0, 1, 2
+
+
+def polygon_set_env_relations(parts, x0, x1, y0, y1):
+    """Batched :func:`_polygon_set_env_relation`: the filter polygon set
+    against m envelopes at once (float64 (m,) columns min-x, max-x, min-y,
+    max-y) -> uint8 (m,) of ENV_DISJOINT / ENV_CONTAINS / ENV_PARTIAL, the
+    verdict the scalar form gives row by row. An envelope that wraps the
+    anti-meridian (max-x < min-x) or is not finite is ENV_PARTIAL: only the
+    geometry itself can say.
+
+    The envelopes are sorted by min-y once, so the envelopes a segment can
+    touch — those whose corner its y-range straddles (the even-odd ray),
+    those whose y-range it meets (the clip) — are one slice of that order,
+    found by bisection: the cost is a few vector operations a segment over
+    the envelopes near it, not a Python call a feature."""
+    x0, x1, y0, y1 = (np.asarray(v, dtype=np.float64) for v in (x0, x1, y0, y1))
+    out = np.full(len(x0), ENV_PARTIAL, dtype=np.uint8)
+    decidable = np.flatnonzero((x1 >= x0) & np.isfinite(x0 + x1 + y0 + y1))
+    decidable = decidable[np.argsort(y0[decidable], kind="stable")]
+    out[decidable] = _sorted_env_relations(
+        parts, x0[decidable], x1[decidable], y0[decidable], y1[decidable]
+    )
+    return out
+
+
+def _sorted_env_relations(parts, x0, x1, y0, y1):
+    """:func:`polygon_set_env_relations` for finite, non-wrapping envelopes
+    in ascending order of y0."""
+    m = len(x0)
+    tallest = float(np.max(y1 - y0)) if m else 0.0
+    contains = np.zeros(m, dtype=bool)
+    any_hit = np.zeros(m, dtype=bool)
+    for outer, holes in parts:
+        crossing = np.zeros(m, dtype=bool)
+        in_part = None  # the corner (x0, y0): in the outer ring, in no hole
+        for ring in (outer, *holes):
+            ax, ay = ring[:, 0], ring[:, 1]
+            bx, by = np.roll(ax, -1), np.roll(ay, -1)
+            lo_x, hi_x = np.minimum(ax, bx), np.maximum(ax, bx)
+            lo_y, hi_y = np.minimum(ay, by), np.maximum(ay, by)
+            # the ray from the corner crosses a segment only if
+            # lo_y <= y0 < hi_y; the segment clips the envelope only if
+            # lo_y - tallest <= y0 <= hi_y (and then y1, x0, x1 decide)
+            ray = np.searchsorted(y0, [lo_y, hi_y], side="left")
+            near = (
+                np.searchsorted(y0, lo_y - tallest, side="left"),
+                np.searchsorted(y0, hi_y, side="right"),
+            )
+            parity = np.zeros(m, dtype=np.uint8)
+            rows, segs = [], []
+            for k in range(len(ax)):
+                sl = slice(ray[0][k], ray[1][k])
+                if sl.start < sl.stop:
+                    # even-odd, as _point_in_ring has it (segment b -> a)
+                    py = y0[sl]
+                    parity[sl] += x0[sl] < (ax[k] - bx[k]) * (py - by[k]) / (
+                        ay[k] - by[k]
+                    ) + bx[k]
+                sl = slice(near[0][k], near[1][k])
+                met = np.flatnonzero(
+                    (y1[sl] >= lo_y[k]) & (x0[sl] <= hi_x[k]) & (x1[sl] >= lo_x[k])
+                )
+                if len(met):
+                    rows.append(met + sl.start)
+                    segs.append(np.full(len(met), k))
+            if rows:
+                row, seg = np.concatenate(rows), np.concatenate(segs)
+                hits = _segment_hits_rect(
+                    ax[seg], ay[seg], bx[seg], by[seg],
+                    x0[row], x1[row], y0[row], y1[row],
+                )
+                crossing[row[hits]] = True
+            inside = parity % 2 == 1
+            in_part = inside if in_part is None else in_part & ~inside
+        any_hit |= crossing
+        contains |= ~crossing & in_part
+    return np.where(
+        contains, ENV_CONTAINS, np.where(any_hit, ENV_PARTIAL, ENV_DISJOINT)
+    ).astype(np.uint8)
 
 
 def _point_in_polygon_set(parts, px, py):

@@ -187,6 +187,8 @@ def test_classify_split_entry_compiles(one_chip, bucket):
         # program compiles in ~6 s at any bucket
         bucket_size(10_000_000),
         bucket_size(8_000_000),
+        # the filtered cell's survivors: 2.94M of 10M rows (PR 35)
+        bucket_size(2_942_000),
     ],
 )
 def test_classify_window_entry_compiles(one_chip, bucket, monkeypatch):
