@@ -649,15 +649,18 @@ def phase_churn(smoke):
     """The republish deployment's two commits (benchmarks/configs/
     baseline2_points_10m_churn.json: uniform updates + deletes + appended
     inserts; one contiguous run deleted) through ``classify_blocks`` under
-    auto routing: classes and counts equal the host engine's, and the
-    windowed join's tile census (``dense_tiles``, ``overflow_tiles`` of the
-    ``diff.device.kernel`` span) equals a numpy recount of the same key
-    columns. Uniform churn must stay on the windowed join; which program
-    answers the bulk delete is recorded, not checked."""
+    auto routing: classes and counts equal the host engine's, and every
+    key-range chunk's tile census (``dense_tiles``, ``overflow_tiles`` of
+    its ``diff.device.kernel`` span) equals a numpy recount over that
+    chunk's rows. Uniform churn must stay on the windowed join in every
+    chunk; the bulk delete must send exactly one chunk a call, the one its
+    hole is in, to the sort-join."""
     from kart_tpu import telemetry as tm
+    from kart_tpu.ops.blocks import FeatureBlock
     from kart_tpu.ops.diff_kernel import (
         classify_blocks,
         classify_blocks_host,
+        classify_chunk_plan,
         join_census_reference,
     )
 
@@ -687,7 +690,10 @@ def phase_churn(smoke):
             host_old, host_new, host_counts = classify_blocks_host(old, new)
             rec["counts"] = counts
             rec["kernel_spans"] = kernels
-            rec["checks"]["ran_on_device"] = len(kernels) == 2
+            plan = classify_chunk_plan(old, new)
+            rec["chunks"] = len(plan)
+            # two calls: a kernel span a chunk each
+            rec["checks"]["ran_on_device"] = len(kernels) == 2 * len(plan)
             rec["checks"]["equals_host_engine"] = (
                 np.array_equal(old_class, host_old)
                 and np.array_equal(new_class, host_new)
@@ -695,14 +701,30 @@ def phase_churn(smoke):
                 and np.array_equal(again[0], host_old)
                 and np.array_equal(again[1], host_new)
             )
-            rec["census_recount"] = join_census_reference(old, new)
-            rec["checks"]["census_equals_recount"] = all(
-                (k.get("dense_tiles"), k.get("overflow_tiles")) == rec["census_recount"]
-                for k in kernels
-            )
+            rec["census_recount"] = [
+                join_census_reference(
+                    *(
+                        FeatureBlock(b.keys[lo:hi], b.oids[lo:hi], None, hi - lo)
+                        for b, (lo, hi) in ((old, old_rows), (new, new_rows))
+                    ),
+                    sizes,
+                )
+                for old_rows, new_rows, sizes in plan
+            ]
+            rec["checks"]["census_equals_recount"] = [
+                (k.get("dense_tiles"), k.get("overflow_tiles")) for k in kernels
+            ] == 2 * rec["census_recount"]
+            sorted_chunks = [k.get("chunk", 0) for k in kernels if k.get("join") == "sort"]
             if branch == "churn":
                 rec["checks"]["windowed_join_answered"] = all(
                     k.get("join") == "window" for k in kernels
+                )
+            else:
+                # the hole's chunk, in each of the two calls, and no other
+                rec["checks"]["one_chunk_to_the_sort_join"] = (
+                    len(sorted_chunks) == 2
+                    and len(set(sorted_chunks)) == 1
+                    and len(plan) > 1
                 )
 
 

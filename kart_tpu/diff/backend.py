@@ -6,8 +6,8 @@ and counts, pinned by tests):
 
 * ``host_native`` — the C++ streaming merge-join (numpy twin beneath it).
   Owns small blocks, CPU-only deployments and every fallback.
-* ``device_jax`` — the single-device jitted kernels with their own
-  monolithic/streamed routing (``ops.diff_kernel.classify_blocks``).
+* ``device_jax`` — the single-device jitted kernels: one route, a pipeline
+  of key-range chunks (``ops.diff_kernel.classify_blocks``).
 * ``sharded_jax`` — the multi-device execution layer: KCOL blocks stream
   through :mod:`kart_tpu.diff.device_batch` as fixed-shape record batches,
   classified shard-local with ``shard_map`` over the ``features`` mesh
@@ -151,7 +151,7 @@ class HostNativeBackend(DiffBackend):
 @_register
 class DeviceJaxBackend(DiffBackend):
     """Single-device kernels; classify_blocks keeps its own cost-model
-    routing (monolithic vs streamed vs host) and host fallback."""
+    routing (the chunked device route or the host) and host fallback."""
 
     name = "device_jax"
 
@@ -309,9 +309,10 @@ def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
     it): mesh when it exists and pays, one device when profitable, the host
     engine otherwise and beneath every device rung."""
     from kart_tpu.ops.blocks import PAD_KEY, bucket_size
-    from kart_tpu.ops.diff_kernel import STREAM_MIN_ROWS, note_device_fallback
+    from kart_tpu.ops.diff_kernel import note_device_fallback
     from kart_tpu.ops.merge_kernel import (
         CONFLICT,
+        MERGE_STREAMED_MIN_ROWS,
         TAKE_THEIRS,
         _merge_classify_np,
         _merge_classify_padded,
@@ -331,7 +332,7 @@ def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
         except Exception as e:
             note_device_fallback("merge_sharded", e, "single-chip path")
 
-    if n_max >= STREAM_MIN_ROWS and routing.device_open(n_max):
+    if n_max >= MERGE_STREAMED_MIN_ROWS and routing.device_open(n_max):
         from kart_tpu.runtime import default_backend
 
         if default_backend() != "cpu":

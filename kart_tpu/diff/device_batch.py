@@ -10,7 +10,7 @@ fixed-shape record batches**:
   (keys int64 padded with PAD_KEY, oids uint32 (B, 5) zero-padded) plus a
   validity count — shapes never depend on the data, so XLA compiles the
   classify **once per mesh** and reuses it across batches,
-  commits and datasets (the monolithic kernel recompiles per bucket size);
+  commits and datasets (the one-device route compiles per bucket size);
 * batch boundaries are *key-aligned across both sides*
   (:func:`batch_splits`): a key present in either revision falls in the
   same chunk of both, so per-chunk merge-joins have identical semantics to
@@ -39,7 +39,6 @@ attempt and the backend falls back to host-native with no partial state —
 results are only ever published after the final round drains.
 """
 
-import bisect
 import functools
 import logging
 import os
@@ -48,7 +47,7 @@ import numpy as np
 
 from kart_tpu import faults
 from kart_tpu import telemetry as tm
-from kart_tpu.ops.blocks import PAD_KEY
+from kart_tpu.ops.blocks import PAD_KEY, batch_splits
 from kart_tpu.parallel.mesh import FEATURES_AXIS
 
 L = logging.getLogger("kart_tpu.diff.device_batch")
@@ -66,52 +65,6 @@ def _env_int(name, default):
 #: record-batch capacity (rows per mesh-shard slot). Default favours
 #: cache residency: 64 Ki rows = ~4 MB working set per side pair.
 DEVICE_BATCH_ROWS = _env_int("KART_DEVICE_BATCH_ROWS", 65536)
-
-
-def batch_splits(key_arrays, batch_rows):
-    """Key-aligned batch boundaries over N sorted key arrays.
-
-    -> (per-side split arrays, n_chunks): chunk ``c`` of side ``s`` is rows
-    ``splits[s][c]:splits[s][c+1]``. Guarantees, for every chunk:
-
-    * **capacity** — at most ``batch_rows`` rows on *every* side (the fixed
-      batch shape can always hold it);
-    * **alignment** — boundaries are key *values*: a key lands in the same
-      chunk on every side, so chunk-local joins equal the global join.
-
-    Greedy: the next boundary is the smallest key that would overflow any
-    side's capacity. A side with many keys below another side's boundary
-    may get several chunks while the other contributes empty ones — empty
-    is fine (count 0), overflow is not.
-    """
-    batch_rows = max(int(batch_rows), 1)
-    sides = [np.asarray(k) for k in key_arrays]
-    los = [0] * len(sides)
-    splits = [[0] for _ in sides]
-    while any(lo < len(k) for lo, k in zip(los, sides)):
-        cands = [
-            k[lo + batch_rows]
-            for lo, k in zip(los, sides)
-            if lo + batch_rows < len(k)
-        ]
-        if cands:
-            bound = min(cands)
-            # a bisection over each side's next batch_rows + 1 rows: the
-            # boundary cannot lie further on. Not np.searchsorted over the
-            # side: that copies an unaligned array first, and a sidecar's
-            # mmap'd key section starts where its header ends — 80 MB a
-            # call at 10M rows (PERF.md §6, PR 28)
-            his = [
-                bisect.bisect_left(k, bound, lo, min(lo + batch_rows + 1, len(k)))
-                for lo, k in zip(los, sides)
-            ]
-        else:
-            his = [len(k) for k in sides]
-        for i, (lo, hi) in enumerate(zip(los, his)):
-            splits[i].append(hi)
-            los[i] = hi
-    n_chunks = len(splits[0]) - 1
-    return [np.asarray(s, dtype=np.int64) for s in splits], n_chunks
 
 
 def _round_views(keys, oids, splits, chunk0, n_shards, batch_rows):

@@ -473,9 +473,9 @@ def _feature_diff_routed(base_ds, target_ds, ds_filter=None, spatial_filter_spec
             mode == "columnar"
             or (sidecar.has_sidecar(repo, base_ds) and sidecar.has_sidecar(repo, target_ds))
         ):
-            # unpadded mmap views: the host engine and the streamed/sharded
-            # device paths consume count-sliced views, and the monolithic
-            # device kernel pads lazily inside classify_blocks — at 100M the
+            # unpadded mmap views: the host engine and the sharded device
+            # path consume count-sliced views, and the one-device route
+            # pads chunk by chunk inside classify_blocks — at 100M the
             # two eager padded copies were ~5.6GB of memcpy before any work
             old_block = sidecar.ensure_block(repo, base_ds, pad=False)
             if old_block is not None:
@@ -632,10 +632,10 @@ def get_dataset_feature_count_fast(
     if not (sidecar.has_sidecar(repo, base_ds) and sidecar.has_sidecar(repo, target_ds)):
         return None
     rect = _prefilter_rect(spatial_filter_spec)
-    # no padded copies: the host engine and the streamed/sharded device
-    # paths consume count-sliced mmap views, and the monolithic device
-    # kernel pads lazily inside classify_blocks (at 100M the two padded
-    # copies were ~5.6GB of memcpy before any classification work)
+    # no padded copies: the host engine and the sharded device path
+    # consume count-sliced mmap views, and the one-device route pads chunk
+    # by chunk inside classify_blocks (at 100M the two padded copies were
+    # ~5.6GB of memcpy before any classification work)
     old_block = sidecar.load_block(repo, base_ds, pad=False)
     if old_block is not None:
         from kart_tpu.diff.backend import warm_probe

@@ -6,7 +6,7 @@ entirely on device with no Python per-feature work, no data-dependent control
 flow on the host, and static shapes. Two device programs with identical
 semantics:
 
-- ``_classify_mergesort_core_window`` (the monolithic route on a TPU,
+- ``_classify_mergesort_core_window`` (the device route on a TPU,
   :func:`classify_blocks`): a **windowed join**. Both sides
   arrive sorted by key with unique keys, so nothing is sorted: each side is
   cut into tiles of ``JOIN_TILE`` = 256 rows, a three-level compare-and-count
@@ -23,15 +23,16 @@ semantics:
   runs, PR 29; PERF.md §6), against the sort-join's 0.777 s. A tile whose
   partners cannot fit its window (more than 641 rows net inserted inside its
   key span: a bulk insert or delete of a contiguous key range) counts as
-  overflowing and the call is answered by the sort-join. The program
-  reports how many tiles took the dense join and how many overflowed
-  (``dense_tiles`` / ``overflow_tiles`` on the ``diff.device.kernel``
-  span); the benchmark cells ``points10m.diff_count.churn`` and
-  ``points10m.diff_count.churn.bulk`` send it such commits (PERF.md §4).
+  overflowing and its chunk of the call (below) is answered by the
+  sort-join. The program reports how many tiles took the dense join and how
+  many overflowed (``dense_tiles`` / ``overflow_tiles`` on the
+  ``diff.device.kernel`` span); the benchmark cells
+  ``points10m.diff_count.churn`` and ``points10m.diff_count.churn.bulk``
+  send it such commits (PERF.md §4).
 - ``_classify_mergesort_core`` (the sort-join: the windowed join's exact
   fallback, and the program of every other backend — XLA-CPU when forced,
-  i.e. the test suite, included — of the streamed chunks and of the mesh's
-  record batches): one 3-operand ``lax.sort`` of
+  i.e. the test suite, included — and of the mesh's record batches): one
+  3-operand ``lax.sort`` of
   the concatenated keys (with concat position for stability and a 64-bit oid
   fold as the payload) brings every old/new pair of the same key adjacent,
   then neighbour compares classify all keys at once and scatters return
@@ -44,10 +45,18 @@ Both are bit-identical to the numpy reference below: each compares full
 160-bit oids (the sort path re-verifies its 64-bit fold matches via a
 monotonic partner gather).
 
-The monolithic route (:func:`classify_blocks`) never copies a block to pad
-it: each column reaches the device as a body — a view of the caller's own
-pages — and one padded tail (:func:`_split_columns`), joined on the device
-by the ``*_split`` entries.
+There is one device route (:func:`classify_blocks` →
+:func:`classify_blocks_streamed`), at every size: the call is cut at common
+key values into chunks of at most ``CLASSIFY_CHUNK_ROWS`` rows a side, every
+chunk's arrays and program are enqueued ahead and the chunks are drained in
+order, so the host→device copy of chunk c+1 runs under the program of chunk
+c; a tile overflow sends its chunk, not the call, to the sort-join. A call
+of at most one chunk's rows is one put, one program, one fetch. No chunk is
+copied to pad it: each column reaches the device as a body and a tail
+(:func:`_split_columns`), joined on the device by the ``*_split`` entries —
+both views of the caller's own pages where the chunk fills its bucket (every
+chunk but the last of a commit that keeps the key set), else the body a view
+and the tail freshly padded.
 
 Classes: 0 = unchanged, 1 = insert, 2 = update, 3 = delete.
 """
@@ -174,9 +183,9 @@ _classify_padded = lazy_jit(_classify_mergesort_core)
 
 
 def _split_entry(core):
-    """The jitted entry of the monolithic route: ``core`` with each of its
+    """The jitted entry of the device route: ``core`` with each of its
     four columns arriving as (body, tail) — the sidecar's own pages and one
-    padded step of the bucket grid (:func:`_split_columns`) — joined on the
+    step of the bucket grid (:func:`_split_columns`) — joined on the
     device. Shapes depend on the bucket alone, so this compiles once per
     bucket as the six-argument core does. The program is named after the
     core (``jit__classify_mergesort_core_split``): the benchmark's kernel
@@ -205,7 +214,7 @@ def _split_entry(core):
 _classify_split = lazy_jit(_split_entry(_classify_mergesort_core))
 
 
-# -- the windowed join: the monolithic route's program on a TPU --------------
+# -- the windowed join: the device route's program on a TPU ------------------
 #
 # Both sides arrive sorted by key with unique keys, so a row's partner sits
 # where the inserts and deletes before it have pushed it. Each side is cut
@@ -639,220 +648,232 @@ def note_device_fallback(what, e, to):
     )
 
 
-# above this row count the accelerator path streams the blocks chunk-wise so
-# host->HBM transfer of chunk i+1 overlaps the sort of chunk i (SURVEY §2.3
-# "pipelined lazy diff streaming") instead of paying one monolithic upload.
-# Monolithic or streamed is this module's own choice from the size it
-# observes, not a routing decision (kart_tpu/routing.py)
-STREAM_MIN_ROWS = 16_000_000
-STREAM_CHUNK_ROWS = 8_000_000
+# -- the device route: a pipeline of key-range chunks -------------------------
+#
+# Rows a side of one chunk. A call is cut at common key values
+# (ops.blocks.batch_splits) into chunks of at most this many rows a side;
+# every chunk but the last shares the one compiled shape
+# ``bucket_size(CLASSIFY_CHUNK_ROWS)``, and the last takes the bucket of its
+# own rows, as a call of one chunk does. The chip chose the value (TPU v5e,
+# 10M rows a side, my chip runs, PR 36; PERF.md §6): a command of 0.5%
+# uniform churn took 0.113 s at 2,621,440 rows (the programs too long to
+# hide), 0.097 s at 1,310,720, 0.095 s at 1,048,576 and 0.099 s at 655,360
+# (2.8 ms of host time a chunk to enqueue it); a bulk delete 0.143 s at
+# 1,310,720 and 0.128 s at 1,048,576 (the sort-join re-joins one chunk). The
+# value lies on the bucket grid, so a full chunk is its bucket and goes
+# over as views.
+CLASSIFY_CHUNK_ROWS = 1_048_576
 
 
 def classify_blocks(old_block, new_block):
     """FeatureBlock x2 -> (old_class np.int8 (n_old,), new_class (n_new,),
     counts dict). Host wrapper: unpads and returns numpy. Routing is a cost
     model (:func:`kart_tpu.routing.device_open`): the host engine owns small
-    blocks, CPU backends and wedged accelerators; a TPU gets the windowed
-    join (the sort-join when a tile overflows its window, counted as
-    ``diff.device.join_overflows``; any other backend the sort-join) — and
-    the sort-join streamed in double-buffered chunks at north-star scale so
-    transfer overlaps compute. Bit-identical results on every route."""
+    blocks, CPU backends and wedged accelerators; a device gets
+    :func:`classify_blocks_streamed` — the one device route, whatever the
+    size — with the host engine beneath it should the device fail mid-call.
+    Bit-identical results on every route."""
     from kart_tpu import routing
-    from kart_tpu.runtime import default_backend
 
-    n_rows = max(old_block.count, new_block.count)
-    if not routing.device_open(n_rows):
+    if not routing.device_open(max(old_block.count, new_block.count)):
         # the host merge-join reads count-sliced views directly — callers
         # may pass unpadded (mmap-backed) blocks with no copy at all
         return classify_blocks_host(old_block, new_block)
     try:
-        if n_rows >= STREAM_MIN_ROWS and default_backend() != "cpu":
-            return classify_blocks_streamed(old_block, new_block)
-        import jax
+        return classify_blocks_streamed(old_block, new_block)
+    except Exception as e:
+        # device OOM / runtime failure mid-call: the CLI must still complete
+        # (north-star scale can exceed a single chip's HBM). Nothing was
+        # published: the classes are handed back only when every chunk is in
+        note_device_fallback("device_classify", e, "host path")
+        return classify_blocks_host(old_block, new_block)
 
-        from kart_tpu.ops.blocks import bucket_size
 
-        backend = default_backend()
-        # the four stages are statements of the program, each under its own
-        # span (docs/DEVICE.md §5). The two block_until_ready calls add no
-        # wait: the kernel cannot start before its arguments have landed,
-        # and np.asarray below would wait for the kernel anyway
+def classify_chunk_plan(old_block, new_block, chunk_rows=None):
+    """The key-range chunks :func:`classify_blocks_streamed` cuts a call
+    into -> ``[((old lo, old hi), (new lo, new hi), (old size, new size))]``:
+    each side's row range, and the padded rows it is sent as. Boundaries are
+    key values, so a key falls in the same chunk on both sides and the
+    chunks' joins are the call's join. A call of at most ``chunk_rows`` rows
+    a side is one chunk. Of several, every chunk but the last is sent at
+    ``bucket_size(chunk_rows)`` on both sides (one compiled shape, whatever
+    the commit did to the key set) and the last at each side's own bucket.
+    A side that runs out of keys first has empty chunks from there on."""
+    from kart_tpu.ops.blocks import batch_splits, bucket_size
+
+    chunk_rows = max(int(chunk_rows or CLASSIFY_CHUNK_ROWS), 1)
+    n_old, n_new = old_block.count, new_block.count
+    if max(n_old, n_new) <= chunk_rows:
+        old_splits, new_splits = (0, n_old), (0, n_new)
+    else:
+        (old_splits, new_splits), _ = batch_splits(
+            (old_block.keys[:n_old], new_block.keys[:n_new]), chunk_rows
+        )
+    last = len(old_splits) - 2
+    full = bucket_size(chunk_rows)
+    plan = []
+    for c in range(last + 1):
+        rows = (
+            (int(old_splits[c]), int(old_splits[c + 1])),
+            (int(new_splits[c]), int(new_splits[c + 1])),
+        )
+        if c == last:
+            sizes = tuple(bucket_size(max(hi - lo, 1)) for lo, hi in rows)
+        else:
+            sizes = (full, full)
+        plan.append((*rows, sizes))
+    return plan
+
+
+def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
+    """The device classify: a pipeline of key-range chunks
+    (:func:`classify_chunk_plan`), three deep. A chunk's eight arrays — a
+    body and a tail a column, views of the caller's pages wherever the
+    chunk fills its bucket (:func:`_split_columns`) — are handed to
+    ``jax.device_put`` and its program is dispatched, neither waited for.
+    Then the host waits for the chunk *before* to have landed, which paces
+    the copies — the runtime copies whatever it has been handed all at once,
+    so ten chunks enqueued together all land at the end (0.127 s a 10M-row
+    churn command against 0.116 s paced; my chip runs, PR 36) — and drains
+    the chunk before that, whose program has had a chunk's copy of time to
+    run. So chunk c+1's bytes land while chunk c's program runs and chunk
+    c-1's classes come home, and the device holds three chunks at most
+    whatever the call's size.
+
+    The program is the windowed join on a TPU, the sort-join on any other
+    backend. A chunk whose windowed join reports an overflowing tile is
+    answered by the sort-join from that chunk's arrays, still on the device
+    (``diff.device.join_overflows`` counts such chunks): a bulk insert or
+    delete costs its chunk, not the call.
+
+    Spans, one set a chunk (``chunk=c``): ``diff.device.pack`` (the views
+    and whatever had to be copied: ``bytes``), ``diff.device.enqueue`` (the
+    puts and the jitted call, no wait), ``diff.device.transfer`` (the wait
+    for the chunk's inputs, once the next chunk is on its way: what of the
+    copy nothing hid) and, at the drain, ``diff.device.kernel`` (the wait
+    for its program, the census read and, on overflow, the sort-join) and
+    ``diff.device.fetch``. A call of one chunk has nothing to overlap: it
+    puts under ``diff.device.transfer`` and calls under
+    ``diff.device.kernel``, with no ``chunk`` and no enqueue span. Counters
+    ``diff.device.chunks`` and ``diff.device.view_chunks`` (chunks that
+    copied nothing); the open ``diff.classify`` span gets both too.
+
+    Raises what the device raises; nothing is published before the last
+    chunk has drained. Bit-identical to the numpy reference (tested);
+    counts are the sum of the chunks' count vectors."""
+    import jax
+
+    from collections import deque
+    from types import SimpleNamespace
+
+    from kart_tpu.runtime import default_backend
+
+    # the windowed join's kernel is Mosaic's
+    entry = _classify_window_split if default_backend() == "tpu" else _classify_split
+    plan = classify_chunk_plan(old_block, new_block, chunk_rows)
+    ahead = len(plan) > 1
+    old_class = np.empty(old_block.count, dtype=np.int8)
+    new_class = np.empty(new_block.count, dtype=np.int8)
+    totals = np.zeros(3, dtype=np.int64)
+    in_flight = deque()  # enqueued and not drained, oldest first: two at most
+    view_chunks = 0
+
+    def put(chunk):
+        # asynchronous: queued here, copied when the transfer engine is free
+        chunk.dev = [jax.device_put(a) for a in chunk.host]
+
+    def call(chunk, program):
+        # asynchronous too: the program runs on the device once its
+        # arguments are there, and its answer starts for the host when it
+        # ends, not when the host asks (an np.asarray that has to ask costs
+        # a round trip an array: 2 ms a chunk)
+        out = program(*chunk.dev, *(hi - lo for lo, hi in chunk.rows))
+        if program is _classify_split:
+            old_part, new_part, _, counts = out
+            out = (old_part, new_part, counts, None)  # no census
+        for a in out:
+            if a is not None:
+                a.copy_to_host_async()
+        chunk.out = out
+
+    def landed(chunk):
+        with tm.span("diff.device.transfer", **chunk.label, bytes=chunk.put_bytes):
+            if chunk.dev is None:
+                put(chunk)
+            jax.block_until_ready(chunk.dev)
+        chunk.host = None  # landed: the views are done with
+
+    def drain():
+        chunk = in_flight.popleft()
+        if chunk.host is not None:
+            landed(chunk)
+        bucket = max(chunk.sizes)
         with tm.span(
-            "diff.device.pack", rows=old_block.count + new_block.count
+            "diff.device.kernel", **chunk.label, program="mergesort", bucket=bucket
         ) as sp:
-            host = _split_columns(old_block) + _split_columns(new_block)
-            bucket = bucket_size(max(n_rows, 1))  # the larger side's
-            # bytes the host copied: the tails it made; a view owns nothing
-            sp.set(
-                bucket=bucket,
-                bytes=sum(a.nbytes for a in host if a.flags.owndata),
-            )
-        with tm.span("diff.device.transfer", bytes=sum(a.nbytes for a in host)):
-            dev = jax.block_until_ready([jax.device_put(a) for a in host])
-        with tm.span("diff.device.kernel", program="mergesort", bucket=bucket) as sp:
-            args = (*dev, old_block.count, new_block.count)
-            join = None
-            if backend == "tpu":  # the windowed join's kernel is Mosaic's
-                old_class, new_class, counts, census = jax.block_until_ready(
-                    _classify_window_split(*args)
-                )
+            if chunk.out is None:
+                call(chunk, entry)
+            old_part, new_part, counts, census = jax.block_until_ready(chunk.out)
+            if census is not None:
                 dense_tiles, overflow_tiles = (int(t) for t in np.asarray(census))
-                join = "sort" if overflow_tiles else "window"
                 tm.incr("diff.device.join_dense_tiles", dense_tiles)
                 sp.set(
-                    join=join,
+                    join="sort" if overflow_tiles else "window",
                     tiles=2 * _join_grid_tiles(bucket),
                     dense_tiles=dense_tiles,
                     overflow_tiles=overflow_tiles,
                 )
                 if overflow_tiles:
                     # some tile's partners did not fit its window: the
-                    # sort-join answers from the arrays already on the
-                    # device. The device still answered, so this is no
-                    # fallback rung — but it is counted, and the span says
-                    # that both programs ran under it
+                    # sort-join answers from the chunk's arrays, which are
+                    # still on the device. The device still answered, so
+                    # this is no fallback rung — but it is counted, and the
+                    # span says that both programs ran under it
                     tm.incr("diff.device.join_overflows")
                     sp.set(window_ran=True)
-            if join != "window":
-                old_class, new_class, _, counts = jax.block_until_ready(
-                    _classify_split(*args)
-                )
-    except Exception as e:
-        # device OOM / runtime failure mid-call: the CLI must still complete
-        # (north-star scale can exceed a single chip's HBM)
-        note_device_fallback("device_classify", e, "host path")
-        return classify_blocks_host(old_block, new_block)
-    with tm.span("diff.device.fetch") as sp:
-        old_class = np.asarray(old_class)
-        new_class = np.asarray(new_class)
-        counts = np.asarray(counts)
-        sp.set(bytes=old_class.nbytes + new_class.nbytes + counts.nbytes)
-        old_class = old_class[: old_block.count]
-        new_class = new_class[: new_block.count]
-    return (
-        old_class,
-        new_class,
-        {"inserts": int(counts[0]), "updates": int(counts[1]), "deletes": int(counts[2])},
-    )
-
-
-def stream_chunk_splits(key_arrays, chunk_rows):
-    """Key-space chunking for the streamed device paths: sorted key arrays
-    (one per block side) -> (per-side split-point arrays, n_chunks), where
-    chunk c of side s is rows ``splits[s][c]:splits[s][c+1]``. A key falls
-    in the same chunk on every side, so merge-joins stay chunk-local.
-
-    Boundaries balance the *combined* population: quantiles of one side
-    alone collapse under key-range skew (e.g. a renumbered-PK revision
-    whose new keys all exceed the old range would pile every new row into
-    one chunk). Candidate keys are fine-grained quantiles of each side;
-    each target combined-rank picks the nearest candidate."""
-    chunk_rows = max(int(chunk_rows), 1)
-    n_chunks = max(1, -(-max(len(k) for k in key_arrays) // chunk_rows))
-    total = sum(len(k) for k in key_arrays)
-
-    def _quantile_keys(keys, m):
-        if not len(keys) or m <= 0:
-            return keys[:0]
-        return keys[(np.arange(1, m) * len(keys)) // m]
-
-    cand = np.unique(
-        np.concatenate([_quantile_keys(k, 4 * n_chunks) for k in key_arrays])
-    )
-    if len(cand):
-        ranks = sum(np.searchsorted(k, cand) for k in key_arrays)
-        targets = (np.arange(1, n_chunks) * total) // n_chunks
-        picks = np.searchsorted(ranks, targets)
-        bounds = np.unique(cand[np.minimum(picks, len(cand) - 1)])
-    else:
-        bounds = cand
-    splits = tuple(
-        np.concatenate(([0], np.searchsorted(k, bounds), [len(k)]))
-        for k in key_arrays
-    )
-    return splits, len(bounds) + 1
-
-
-def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
-    """Double-buffered chunked device classify for blocks too large to ship
-    to HBM as one upload (SURVEY §2.3 "pipelined lazy diff streaming").
-
-    Both blocks are key-sorted, so splitting the *key space* at common
-    boundary values (quantiles of the larger side) partitions the merge-join
-    into independent chunk-local joins: a key falls in the same chunk on both
-    sides, and no old/new pair ever straddles a boundary. Each chunk is
-    padded to one shared bucket size (a single compiled shape), transferred
-    with ``jax.device_put`` — which is asynchronous — and dispatched
-    immediately; with two chunks in flight, chunk i+1's host->HBM copy
-    overlaps chunk i's on-device sort. Results drain back in order.
-
-    Semantics identical to the monolithic kernel (tested); counts are the
-    sum of per-chunk count vectors."""
-    import jax
-
-    from collections import deque
-
-    from kart_tpu.ops.blocks import PAD_KEY, bucket_size as _bucket
-
-    if chunk_rows is None:
-        chunk_rows = STREAM_CHUNK_ROWS
-    n_old, n_new = old_block.count, new_block.count
-    old_keys = old_block.keys[:n_old]
-    new_keys = new_block.keys[:n_new]
-    (old_splits, new_splits), n_chunks = stream_chunk_splits(
-        (old_keys, new_keys), chunk_rows
-    )
-    max_len = max(
-        int(np.max(np.diff(old_splits))), int(np.max(np.diff(new_splits))), 1
-    )
-    bucket = _bucket(max_len)
-
-    def _padded(keys, oids, lo, hi):
-        k = np.full(bucket, PAD_KEY, dtype=np.int64)
-        o = np.zeros((bucket, 5), dtype=np.uint32)
-        k[: hi - lo] = keys[lo:hi]
-        o[: hi - lo] = oids[lo:hi]
-        return k, o
-
-    old_class = np.empty(n_old, dtype=np.int8)
-    new_class = np.empty(n_new, dtype=np.int8)
-    totals = np.zeros(3, dtype=np.int64)
-    in_flight = deque()
-
-    def _drain():
-        out, c, (olo, ohi), (nlo, nhi) = in_flight.popleft()
-        oc, nc, _, counts = out
-        with tm.span("diff.device.fetch", chunk=c) as sp:
-            oc, nc, counts = np.asarray(oc), np.asarray(nc), np.asarray(counts)
-            sp.set(bytes=oc.nbytes + nc.nbytes + counts.nbytes)
-            old_class[olo:ohi] = oc[: ohi - olo]
-            new_class[nlo:nhi] = nc[: nhi - nlo]
+                    call(chunk, _classify_split)
+                    old_part, new_part, counts, _ = jax.block_until_ready(chunk.out)
+        (old_lo, old_hi), (new_lo, new_hi) = chunk.rows
+        with tm.span("diff.device.fetch", **chunk.label) as sp:
+            old_part, new_part = np.asarray(old_part), np.asarray(new_part)
+            counts = np.asarray(counts)
+            sp.set(bytes=old_part.nbytes + new_part.nbytes + counts.nbytes)
+            old_class[old_lo:old_hi] = old_part[: old_hi - old_lo]
+            new_class[new_lo:new_hi] = new_part[: new_hi - new_lo]
             totals[:] += counts
 
-    # the monolithic path's four span names, per chunk. Nothing here waits
-    # for the device but the fetch: transfer and kernel time what the host
-    # spends enqueueing, and the overlap is read from the device trace
-    for c in range(n_chunks):
-        olo, ohi = int(old_splits[c]), int(old_splits[c + 1])
-        nlo, nhi = int(new_splits[c]), int(new_splits[c + 1])
+    for c, (*rows, sizes) in enumerate(plan):
+        label = {"chunk": c} if ahead else {}
         with tm.span(
-            "diff.device.pack", chunk=c, rows=ohi - olo + nhi - nlo, bucket=bucket
+            "diff.device.pack", **label, rows=sum(hi - lo for lo, hi in rows)
         ) as sp:
-            ok, oo = _padded(old_keys, old_block.oids, olo, ohi)
-            nk, no = _padded(new_keys, new_block.oids, nlo, nhi)
-            nbytes = ok.nbytes + oo.nbytes + nk.nbytes + no.nbytes
-            sp.set(bytes=nbytes)
-        with tm.span("diff.device.transfer", chunk=c, bytes=nbytes):
-            dev = [jax.device_put(a) for a in (ok, oo, nk, no)]
-        with tm.span(
-            "diff.device.kernel", chunk=c, program="mergesort", bucket=bucket
-        ):
-            out = _classify_padded(*dev, ohi - olo, nhi - nlo)
-        in_flight.append((out, c, (olo, ohi), (nlo, nhi)))
-        if len(in_flight) >= 2:
-            _drain()
+            host = [
+                a
+                for block, (lo, hi), size in zip((old_block, new_block), rows, sizes)
+                for a in _split_columns(block, lo, hi, size)
+            ]
+            # bytes the host copied: the tails it made; a view owns nothing
+            copied = sum(a.nbytes for a in host if a.flags.owndata)
+            sp.set(bucket=max(sizes), bytes=copied)
+        view_chunks += not copied
+        chunk = SimpleNamespace(
+            label=label, rows=rows, sizes=sizes, host=host,
+            put_bytes=sum(a.nbytes for a in host), dev=None, out=None,
+        )
+        if ahead:
+            with tm.span("diff.device.enqueue", **label, bytes=chunk.put_bytes):
+                put(chunk)
+                call(chunk, entry)
+            if in_flight:
+                landed(in_flight[-1])
+            if len(in_flight) > 1:
+                drain()
+        in_flight.append(chunk)
     while in_flight:
-        _drain()
+        drain()
+    tm.incr("diff.device.chunks", len(plan))
+    tm.incr("diff.device.view_chunks", view_chunks)
+    tm.annotate_span("diff.classify", chunks=len(plan), view_chunks=view_chunks)
     return (
         old_class,
         new_class,
@@ -864,28 +885,48 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     )
 
 
-def _split_columns(block):
-    """(keys body, keys tail, oids body, oids tail): the block's two columns
-    as the monolithic device kernels take them, copying at most one step of
-    the bucket grid. The body is the first ``bucket_body(bucket)`` rows,
-    which every block of that bucket has — a view of the caller's arrays
-    (the sidecar's mmap'd pages, read-only and unaligned as they come). The
-    tail is the rest of the bucket: a view too when the block arrives
-    padded, else freshly made — the block's last rows, then ``PAD_KEY`` /
-    zero oids."""
+def _split_columns(block, lo=0, hi=None, size=None):
+    """(keys body, keys tail, oids body, oids tail): rows ``lo:hi`` of the
+    block's two columns (the whole block by default) as the device programs
+    take them, ``size`` padded rows in all (the rows' own bucket by
+    default). The body is the first ``bucket_body(size)`` rows, the tail
+    the rest. Both are views of the caller's arrays (the sidecar's mmap'd
+    pages, read-only and unaligned as they come) where the block has all of
+    ``size`` rows there: a chunk that fills its bucket, or the end of a
+    block that arrives padded. A side that comes short of the bucket gets a
+    freshly made tail — its last rows, then ``PAD_KEY`` / zero oids — and
+    one that comes short of the body (a hole in the key range wider than a
+    step of the bucket grid, or a side that has run out) a fresh body too.
+    What was copied is what owns its data."""
     from kart_tpu.ops.blocks import PAD_KEY, bucket_body, bucket_size
 
-    n = block.count
-    size = bucket_size(max(n, 1))
+    if hi is None:
+        hi = block.count
+    if size is None:
+        size = bucket_size(max(hi - lo, 1))
     body = bucket_body(size)
     keys, oids = block.keys, block.oids
-    if len(keys) >= size:
-        return keys[:body], keys[body:size], oids[:body], oids[body:size]
-    keys_tail = np.full(size - body, PAD_KEY, dtype=np.int64)
-    keys_tail[: n - body] = keys[body:n]
-    oids_tail = np.zeros((size - body, 5), dtype=np.uint32)
-    oids_tail[: n - body] = oids[body:n]
-    return keys[:body], keys_tail, oids[:body], oids_tail
+    cut = lo + body
+    # rows past ``hi`` are the next chunk's; past the block's count they are
+    # the block's own padding
+    have = len(keys) - lo if hi >= block.count else hi - lo
+    if have >= size:
+        return keys[lo:cut], keys[cut : lo + size], oids[lo:cut], oids[cut : lo + size]
+
+    def fresh(start, rows):
+        # rows ``start:hi``, then padding
+        n = max(hi - start, 0)
+        keys_part = np.full(rows, PAD_KEY, dtype=np.int64)
+        keys_part[:n] = keys[start : start + n]
+        oids_part = np.zeros((rows, 5), dtype=np.uint32)
+        oids_part[:n] = oids[start : start + n]
+        return keys_part, oids_part
+
+    keys_tail, oids_tail = fresh(cut, size - body)
+    if hi >= cut:
+        return keys[lo:cut], keys_tail, oids[lo:cut], oids_tail
+    keys_body, oids_body = fresh(lo, body)
+    return keys_body, keys_tail, oids_body, oids_tail
 
 
 def classify_blocks_host(old_block, new_block):
@@ -949,10 +990,12 @@ def classify_blocks_reference(old_block, new_block):
     return old_class, new_class
 
 
-def join_census_reference(old_block, new_block):
+def join_census_reference(old_block, new_block, sizes=None):
     """Pure-numpy recount of the windowed join's tile census for the two
-    blocks as :func:`classify_blocks` sends them (each side padded to its
-    bucket): -> ``(dense_tiles, overflow_tiles)``, what the program's
+    blocks as one chunk of :func:`classify_blocks` sends them, each side
+    padded to ``sizes`` (its own bucket by default: a call of one chunk, or
+    the last of several; :func:`classify_chunk_plan` has every chunk's):
+    -> ``(dense_tiles, overflow_tiles)``, what the chunk's
     ``diff.device.kernel`` span must report. A tile is dense when its 256
     keys differ anywhere from the other side's rows at its lower bound (as
     the kernel's element-wise pass reads them: from its step's slab, the
@@ -963,9 +1006,11 @@ def join_census_reference(old_block, new_block):
         return np.concatenate([keys, np.full(n - len(keys), PAD_KEY, np.int64)])
 
     counts = (old_block.count, new_block.count)
+    if sizes is None:
+        sizes = [bucket_size(max(n, 1)) for n in counts]
     sides = [
-        padded_to(b.keys[:n], bucket_size(max(n, 1)))
-        for b, n in zip((old_block, new_block), counts)
+        padded_to(b.keys[:n], size)
+        for b, n, size in zip((old_block, new_block), counts, sizes)
     ]
     n_tiles = _join_grid_tiles(max(len(k) for k in sides))
     padded = n_tiles * JOIN_TILE_ROWS + JOIN_SLAB_ROWS

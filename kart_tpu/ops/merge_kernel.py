@@ -89,6 +89,53 @@ def _merge_classify_padded_core(
 _merge_classify_padded = lazy_jit(_merge_classify_padded_core)
 
 
+# from MERGE_STREAMED_MIN_ROWS rows the accelerator merge streams its three
+# blocks chunk-wise (kart_tpu/diff/backend.py), so that the host->HBM
+# transfer of chunk i+1 overlaps the joins of chunk i instead of one
+# monolithic upload. The module's own choice from the size it observes, not a
+# routing decision (kart_tpu/routing.py). The diff's device route is chunked
+# at every size and has constants of its own (ops/diff_kernel.py)
+MERGE_STREAMED_MIN_ROWS = 16_000_000
+MERGE_CHUNK_ROWS = 8_000_000
+
+
+def stream_chunk_splits(key_arrays, chunk_rows):
+    """Key-space chunking for the streamed device paths: sorted key arrays
+    (one per block side) -> (per-side split-point arrays, n_chunks), where
+    chunk c of side s is rows ``splits[s][c]:splits[s][c+1]``. A key falls
+    in the same chunk on every side, so merge-joins stay chunk-local.
+
+    Boundaries balance the *combined* population: quantiles of one side
+    alone collapse under key-range skew (e.g. a renumbered-PK revision
+    whose new keys all exceed the old range would pile every new row into
+    one chunk). Candidate keys are fine-grained quantiles of each side;
+    each target combined-rank picks the nearest candidate."""
+    chunk_rows = max(int(chunk_rows), 1)
+    n_chunks = max(1, -(-max(len(k) for k in key_arrays) // chunk_rows))
+    total = sum(len(k) for k in key_arrays)
+
+    def _quantile_keys(keys, m):
+        if not len(keys) or m <= 0:
+            return keys[:0]
+        return keys[(np.arange(1, m) * len(keys)) // m]
+
+    cand = np.unique(
+        np.concatenate([_quantile_keys(k, 4 * n_chunks) for k in key_arrays])
+    )
+    if len(cand):
+        ranks = sum(np.searchsorted(k, cand) for k in key_arrays)
+        targets = (np.arange(1, n_chunks) * total) // n_chunks
+        picks = np.searchsorted(ranks, targets)
+        bounds = np.unique(cand[np.minimum(picks, len(cand) - 1)])
+    else:
+        bounds = cand
+    splits = tuple(
+        np.concatenate(([0], np.searchsorted(k, bounds), [len(k)]))
+        for k in key_arrays
+    )
+    return splits, len(bounds) + 1
+
+
 def merge_classify_streamed(
     ancestor_block, ours_block, theirs_block, chunk_rows=None
 ):
@@ -104,10 +151,8 @@ def merge_classify_streamed(
 
     from collections import deque
 
-    from kart_tpu.ops.diff_kernel import STREAM_CHUNK_ROWS, stream_chunk_splits
-
     if chunk_rows is None:
-        chunk_rows = STREAM_CHUNK_ROWS
+        chunk_rows = MERGE_CHUNK_ROWS
     blocks = (ancestor_block, ours_block, theirs_block)
     reals = tuple(
         (b.keys[: b.count], b.oids[: b.count]) for b in blocks

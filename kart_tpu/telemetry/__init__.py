@@ -23,6 +23,7 @@ from kart_tpu.telemetry.core import (  # noqa: F401
     SUBSYSTEMS,
     Phases,
     all_metric_names,
+    annotate_span,
     counters_snapshot,
     default_trace_path,
     drain_events,

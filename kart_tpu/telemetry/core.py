@@ -376,6 +376,19 @@ def span(name, **attrs):
     return _Span(name, attrs)
 
 
+def annotate_span(name, **attrs):
+    """Set attributes on the innermost open span called ``name`` on this
+    thread, from code that runs under it without holding its handle (the
+    device classify says on the caller's ``diff.classify`` how many chunks
+    it made). No span of that name open, or spans off: nothing happens."""
+    if not _SPANS_ON:
+        return
+    for open_span in reversed(getattr(_tls, "stack", None) or ()):
+        if open_span.name == name:
+            open_span.attrs.update(attrs)
+            return
+
+
 # -- snapshots / export hooks ----------------------------------------------
 
 

@@ -1081,3 +1081,227 @@ def test_device_classify_pack_span_counts_only_the_tails(monkeypatch):
     assert events["diff.device.pack"]["bytes"] == 2 * step * 28
     assert events["diff.device.transfer"]["bytes"] == 2 * 5120 * 28
     assert events["diff.device.kernel"]["program"] == "mergesort"
+
+
+# -- the device route is a pipeline of key-range chunks (ISSUE 36) ----------
+
+_CHUNK = 10_240  # on the bucket grid: a full chunk is its bucket (step 1,024)
+_DEVICE_SPANS = [
+    "diff.device.pack", "diff.device.transfer", "diff.device.kernel",
+    "diff.device.fetch",
+]
+
+
+def _bulk_insert_case():
+    old = _spaced_block(12_000, seed=66, gap=40_000)
+    return old, _with_run(old, 6_000, 25_000)
+
+
+#: name -> (builder of (old, new), chunks, chunks the sort-join answers on
+#: the windowed route, chunks that copy nothing)
+CHUNK_CASES = {
+    # three full chunks and a ragged last one
+    "aligned_sides": (
+        lambda: (b := _sorted_block(35_000, seed=61), _rewritten(b)), 4, [], 3,
+    ),
+    "aligned_sidecar_views": (
+        lambda: (
+            _as_sidecar_view(b := _sorted_block(35_000, seed=61)),
+            _as_sidecar_view(_rewritten(b)),
+        ),
+        4, [], 3,
+    ),
+    "uniform_churn": (
+        lambda: (b := _sorted_block(35_000, seed=62), _churned(b, 0.05, 8)), 4, [], 0,
+    ),
+    # 800 rows gone inside chunk 1: more than a window holds at any offset,
+    # less than a step of the bucket grid (the new side keeps its body a view)
+    "hole_inside_a_chunk": (
+        lambda: (b := _sorted_block(35_000, seed=63), _without(b, 12_000, 12_800)),
+        4, [1], 2,
+    ),
+    # the hole ends chunk 0 and starts chunk 1: no tile has it inside its span
+    "hole_across_a_boundary": (
+        lambda: (b := _sorted_block(35_000, seed=64), _without(b, _CHUNK - 400, _CHUNK + 400)),
+        4, [], 1,
+    ),
+    # 1,500 rows gone: the new side of chunk 1 is shorter than the bucket's
+    # body and is copied whole
+    "hole_wider_than_a_grid_step": (
+        lambda: (b := _sorted_block(35_000, seed=65), _without(b, 12_000, 13_500)),
+        4, [1], 2,
+    ),
+    # 25,000 keys inserted between two old keys: chunks 1 and 2 have no old rows
+    "bulk_insert_empty_chunks": (_bulk_insert_case, 4, [], 0),
+    "empty_old_side": (
+        lambda: (_sorted_block(0, seed=67), _sorted_block(25_000, seed=67)), 3, [], 0,
+    ),
+    "one_chunk": (
+        lambda: (b := _sorted_block(5_000, seed=68), _churned(b, 0.05, 9)), 1, [], 0,
+    ),
+    # blocks that arrive padded to their bucket: the last chunk's tails are
+    # the blocks' own padding, in place
+    "padded_blocks": (
+        lambda: (_padded(b := _sorted_block(35_000, seed=69)), _padded(_churned(b, 0.05, 10))),
+        4, [], 1,
+    ),
+}
+
+
+@pytest.mark.parametrize("route", ["sort", "window"])
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_device_classify_chunks_match_reference(case, route, monkeypatch):
+    """The device route cut into key-range chunks (a small chunk size, the
+    production code) equals the numpy reference whatever the commit does to
+    the key set, never by way of the host fallback; each chunk has its own
+    spans and its own tile census (a numpy recount of that chunk's rows); a
+    tile overflow sends its chunk, and no other, to the sort-join; a call of
+    one chunk is the four spans it always was."""
+    from kart_tpu import runtime
+    from kart_tpu import telemetry as tm
+    from kart_tpu.ops import diff_kernel
+    from kart_tpu.ops.diff_kernel import classify_chunk_plan, join_census_reference
+
+    build, n_chunks, sorted_chunks, view_chunks = CHUNK_CASES[case]
+    old, new = build()
+    ref_old, ref_new = classify_blocks_reference(old, new)
+    monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    if route == "window":
+        monkeypatch.setattr(runtime, "default_backend", lambda: "tpu")
+    plan = classify_chunk_plan(old, new)
+    assert len(plan) == n_chunks
+    tm.reset()
+    tm.enable(metrics=True, trace=True)
+    try:
+        with tm.span("diff.classify"):
+            old_class, new_class, counts = classify_blocks(old, new)
+        counters = {k[0]: v for k, v in tm.counters_snapshot().items()}
+        events = tm.drain_events()
+    finally:
+        tm.reset()
+    assert "diff.device.fallbacks" not in counters
+    np.testing.assert_array_equal(old_class, ref_old)
+    np.testing.assert_array_equal(new_class, ref_new)
+    assert counts == {
+        "inserts": int(np.sum(ref_new == INSERT)),
+        "updates": int(np.sum(ref_old == UPDATE)),
+        "deletes": int(np.sum(ref_old == DELETE)),
+    }
+    assert counters["diff.device.chunks"] == n_chunks
+    assert counters.get("diff.device.view_chunks", 0) == view_chunks
+    (classify,) = [e["args"] for e in events if e["name"] == "diff.classify"]
+    assert (classify["chunks"], classify["view_chunks"]) == (n_chunks, view_chunks)
+
+    device = [e for e in events if e["name"].startswith("diff.device.")]
+    if n_chunks == 1:
+        assert [e["name"] for e in device] == _DEVICE_SPANS
+        assert not any("chunk" in e["args"] for e in device)
+    else:
+        by_name = {}
+        for e in device:
+            by_name.setdefault(e["name"], []).append(e)
+        assert set(by_name) == set(_DEVICE_SPANS) | {"diff.device.enqueue"}
+        for name, spans in by_name.items():
+            assert [e["args"]["chunk"] for e in spans] == list(range(n_chunks)), name
+        # three deep: chunk c's inputs are waited for once chunk c+1 is on
+        # its way, and chunk c is drained once chunk c+1 has landed
+        starts = {name: [e["ts"] for e in spans] for name, spans in by_name.items()}
+        for c in range(n_chunks - 1):
+            assert starts["diff.device.enqueue"][c + 1] < starts["diff.device.transfer"][c]
+            if c < n_chunks - 2:
+                assert starts["diff.device.transfer"][c + 1] < starts["diff.device.kernel"][c]
+        for c in range(n_chunks):
+            assert starts["diff.device.transfer"][c] < starts["diff.device.kernel"][c]
+            assert starts["diff.device.kernel"][c] < starts["diff.device.fetch"][c]
+        assert [e["args"]["bytes"] for e in by_name["diff.device.enqueue"]] == [
+            e["args"]["bytes"] for e in by_name["diff.device.transfer"]
+        ] == [28 * sum(sizes) for *_, sizes in plan]
+    kernels = [e["args"] for e in device if e["name"] == "diff.device.kernel"]
+    packs = [e["args"] for e in device if e["name"] == "diff.device.pack"]
+    for c, ((old_rows, new_rows, sizes), kernel, pack) in enumerate(zip(plan, kernels, packs)):
+        assert kernel["program"] == "mergesort" and kernel["bucket"] == max(sizes)
+        assert pack["rows"] == old_rows[1] - old_rows[0] + new_rows[1] - new_rows[0]
+        if route == "sort":
+            assert "join" not in kernel
+            continue
+        sides = [
+            _block(b.keys[lo:hi], b.oids[lo:hi])
+            for b, (lo, hi) in ((old, old_rows), (new, new_rows))
+        ]
+        assert (kernel["dense_tiles"], kernel["overflow_tiles"]) == (
+            join_census_reference(*sides, sizes)
+        ), c
+        assert kernel["join"] == ("sort" if c in sorted_chunks else "window"), c
+        assert (kernel["overflow_tiles"] > 0) == (c in sorted_chunks)
+        assert kernel.get("window_ran", False) == (c in sorted_chunks)
+    assert counters.get("diff.device.join_overflows", 0) == (
+        len(sorted_chunks) if route == "window" else 0
+    )
+    if case == "hole_wider_than_a_grid_step":
+        # chunk 1: the old side two views, the new side four fresh arrays
+        assert packs[1]["bytes"] == 28 * plan[1][2][1]
+    if case == "hole_inside_a_chunk":
+        # chunk 1: the new side's body a view, one fresh tail a column
+        assert packs[1]["bytes"] == 28 * (bucket_size(_CHUNK) - bucket_body(bucket_size(_CHUNK)))
+    if case == "bulk_insert_empty_chunks":
+        assert [rows[0][1] - rows[0][0] for rows in plan] == [6_000, 0, 0, 6_000]
+
+
+def test_full_chunk_goes_over_as_views():
+    """A chunk that fills its bucket on both sides hands the device eight
+    arrays that own no data: the caller's pages (a sidecar's mapping,
+    unaligned and read-only), the first byte to the last."""
+    from kart_tpu.ops.diff_kernel import _split_columns, classify_chunk_plan
+
+    old = _as_sidecar_view(_sorted_block(35_000, seed=61))
+    new = _as_sidecar_view(_rewritten(old))
+    plan = classify_chunk_plan(old, new, _CHUNK)
+    assert [sizes for *_, sizes in plan] == [(_CHUNK, _CHUNK)] * 3 + [(4608, 4608)]
+    for old_rows, new_rows, sizes in plan[:-1]:
+        for block, (lo, hi), size in ((old, old_rows, sizes[0]), (new, new_rows, sizes[1])):
+            arrays = _split_columns(block, lo, hi, size)
+            assert not any(a.flags.owndata for a in arrays)
+            keys_body, keys_tail, oids_body, oids_tail = arrays
+            assert np.shares_memory(keys_body, block.keys)
+            assert np.shares_memory(oids_tail, block.oids)
+            np.testing.assert_array_equal(
+                np.concatenate([keys_body, keys_tail]), block.keys[lo:hi]
+            )
+            np.testing.assert_array_equal(
+                np.concatenate([oids_body, oids_tail]), block.oids[lo:hi]
+            )
+    # the ragged last chunk: body views, one fresh tail a column
+    (lo, hi), _, (size, _) = plan[-1]
+    arrays = _split_columns(old, lo, hi, size)
+    assert [a.flags.owndata for a in arrays] == [False, True, False, True]
+    assert arrays[1][hi - lo - bucket_body(size)] == PAD_KEY
+
+
+@pytest.mark.parametrize("n,lo,hi,size", [
+    (30_000, 10_240, 20_480, 10_240),  # full: views
+    (30_000, 10_240, 20_000, 10_240),  # short of the bucket: fresh tail
+    (30_000, 10_240, 19_000, 10_240),  # short of the body: all fresh
+    (30_000, 10_240, 10_240, 10_240),  # empty
+    (30_000, 29_000, 30_000, 1_024),  # bodyless bucket
+])
+def test_split_columns_of_a_row_range(n, lo, hi, size):
+    """``_split_columns`` over a row range spells the padded chunk row for
+    row, never reads past ``hi``, and copies only what comes short."""
+    from kart_tpu.ops.diff_kernel import _split_columns
+
+    block = _sorted_block(n, seed=6)
+    keys_body, keys_tail, oids_body, oids_tail = _split_columns(block, lo, hi, size)
+    body = bucket_body(size)
+    assert keys_body.shape == (body,) and keys_tail.shape == (size - body,)
+    assert oids_body.shape == (body, 5) and oids_tail.shape == (size - body, 5)
+    keys = np.concatenate([keys_body, keys_tail])
+    oids = np.concatenate([oids_body, oids_tail])
+    np.testing.assert_array_equal(keys[: hi - lo], block.keys[lo:hi])
+    np.testing.assert_array_equal(oids[: hi - lo], block.oids[lo:hi])
+    assert np.all(keys[hi - lo :] == PAD_KEY) and not np.any(oids[hi - lo :])
+    copied = sum(
+        a.nbytes for a in (keys_body, keys_tail, oids_body, oids_tail) if a.flags.owndata
+    )
+    rows = hi - lo
+    assert copied == 28 * (0 if rows == size else size - body if rows >= body else size)

@@ -29,6 +29,7 @@ import pytest
 
 from kart_tpu.diff.device_batch import DEVICE_BATCH_ROWS
 from kart_tpu.ops.blocks import bucket_size
+from kart_tpu.ops.diff_kernel import CLASSIFY_CHUNK_ROWS
 from kart_tpu.parallel.mesh import FEATURES_AXIS
 
 
@@ -125,8 +126,8 @@ def test_bbox_pallas_compiles(one_chip, n_envelopes):
     "bucket",
     [
         1024,
-        # what a 10M-row diff pads to, and the streamed path's 8M-row chunk
-        pytest.param(bucket_size(10_000_000), marks=pytest.mark.slow),
+        # a full chunk of the one-device route, and the merge's 8M-row chunk
+        pytest.param(bucket_size(CLASSIFY_CHUNK_ROWS), marks=pytest.mark.slow),
         pytest.param(bucket_size(8_000_000), marks=pytest.mark.slow),
     ],
 )
@@ -147,12 +148,13 @@ def test_classify_mergesort_compiles(one_chip, bucket):
     [
         1024,  # the minimum bucket: an empty body, the whole of it the tail
         1152,  # the smallest bucket with a body (1024 rows + a 128-row step)
-        pytest.param(bucket_size(10_000_000), marks=pytest.mark.slow),
-        pytest.param(bucket_size(8_000_000), marks=pytest.mark.slow),
+        # a full chunk of the device route: what an overflowing chunk is
+        # re-joined at on a TPU (the sort compiles for a minute)
+        pytest.param(bucket_size(CLASSIFY_CHUNK_ROWS), marks=pytest.mark.slow),
     ],
 )
 def test_classify_split_entry_compiles(one_chip, bucket):
-    """The monolithic route's jitted entry — each column a body and a tail,
+    """The sort-join's jitted entry — each column a body and a tail,
     joined on the device — under the name the benchmark's kernel metrics
     look for, and within the chip's memory with its inputs kept alive."""
     import jax
@@ -178,21 +180,31 @@ def test_classify_split_entry_compiles(one_chip, bucket):
     assert _device_bytes(lowered.compile()) < 16e9
 
 
+def _last_chunk_bucket(rows):
+    """The bucket of the last key-range chunk of a ``rows``-row side."""
+    return bucket_size(rows % CLASSIFY_CHUNK_ROWS)
+
+
 @pytest.mark.parametrize(
-    "bucket",
+    "bucket,new_bucket",
     [
-        1024,
-        1152,  # nine 128-row lines: not a whole tile, not a whole grid step
+        (1024, 1024),
+        (1152, 1152),  # nine 128-row lines: not a whole tile, not a whole grid step
         # the production shapes are not marked slow: without the sort the
-        # program compiles in ~6 s at any bucket
-        bucket_size(10_000_000),
-        bucket_size(8_000_000),
-        # the filtered cell's survivors: 2.94M of 10M rows (PR 35)
-        bucket_size(2_942_000),
+        # program compiles in ~6 s at any bucket. A full chunk of the device
+        # route (every chunk but a call's last, PR 36):
+        (bucket_size(CLASSIFY_CHUNK_ROWS), bucket_size(CLASSIFY_CHUNK_ROWS)),
+        # the last chunk of a 10M-row call, both sides alike
+        (_last_chunk_bucket(10_000_000), _last_chunk_bucket(10_000_000)),
+        # ... and after 0.5% of the keys were deleted all over the old range
+        # and as many appended (the churn cell): the sides' buckets differ
+        (_last_chunk_bucket(10_000_000), _last_chunk_bucket(10_050_000)),
+        # the last chunk of the filtered cell's 2.94M survivors (PR 35)
+        (_last_chunk_bucket(2_942_000), _last_chunk_bucket(2_942_000)),
     ],
 )
-def test_classify_window_entry_compiles(one_chip, bucket, monkeypatch):
-    """The windowed join — what the monolithic route runs on an
+def test_classify_window_entry_compiles(one_chip, bucket, new_bucket, monkeypatch):
+    """The windowed join — what the device route runs a chunk on an
     accelerator: Mosaic takes the Pallas kernel at the real widths (a slab
     block at a dynamic 8-line offset, unaligned sublane loads, lane
     rotations and gathers), the program sorts nothing, keeps the name
@@ -208,19 +220,21 @@ def test_classify_window_entry_compiles(one_chip, bucket, monkeypatch):
     # for the interpreter: here it is lowered for the described chip
     monkeypatch.setattr(diff_kernel, "_join_interpreted", lambda: False)
 
-    body = bucket_body(bucket)
-    columns = [
-        _shape(shape, dtype, one_chip)
-        for shape, dtype in (
-            ((body,), np.int64),
-            ((bucket - body,), np.int64),
-            ((body, 5), np.uint32),
-            ((bucket - body, 5), np.uint32),
-        )
-    ]
+    def columns(size):
+        body = bucket_body(size)
+        return [
+            _shape(shape, dtype, one_chip)
+            for shape, dtype in (
+                ((body,), np.int64),
+                ((size - body,), np.int64),
+                ((body, 5), np.uint32),
+                ((size - body, 5), np.uint32),
+            )
+        ]
+
     count = _shape((), np.int64, one_chip)
     lowered = jax.jit(_classify_window_split.__wrapped__).lower(
-        *columns, *columns, count, count
+        *columns(bucket), *columns(new_bucket), count, count
     )
     text = lowered.as_text()
     assert "@jit__classify_mergesort_core_window_split" in text
