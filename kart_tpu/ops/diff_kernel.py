@@ -648,6 +648,41 @@ def note_device_fallback(what, e, to):
     )
 
 
+def _clock_probe():
+    """The clock ping's program: no argument to copy, one scalar out (0.24
+    µs of device time; of four shapes tried on a v5e it is the one the host
+    brackets most tightly, 0.52 ms dispatch to notice under the profiler
+    where a host scalar argument makes it 0.73 ms; my chip run, PR 37)."""
+    import jax.numpy as jnp
+
+    return jnp.zeros((), jnp.int32)
+
+
+# jit__clock_probe in the device trace: not a jit__classify_* program
+_clock_probe = lazy_jit(_clock_probe)
+
+
+def clock_ping(at):
+    """One ``diff.device.clock`` span (``at`` = ``start`` | ``end``) around
+    one run of ``jit__clock_probe``, dispatched and waited for inside it,
+    while the device has nothing else to do. The program's spans and a jax
+    profiler's device trace are on two clocks, and the pipeline below leaves
+    no other span that holds a program tightly (a chunk's program starts
+    when its inputs land, a chunk's copy before the span that waits for it
+    opens). This one does: the program starts a launch latency after the
+    span and the span ends a completion notice after the program, so over
+    the pings of a session the offset between the clocks is known to within
+    the shortest launch plus the shortest notice: 0.0003-0.0006 s over the
+    ten pings of a traced benchmark run, where the old anchor, the
+    ``diff.device.kernel`` span, was out by 0.09-0.15 s (my chip runs, PR
+    37; ``benchmarks/span_tree.py clock_offset``; docs/OBSERVABILITY.md
+    §2-§3). A ping takes 0.7 ms of the command."""
+    import jax
+
+    with tm.span("diff.device.clock", at=at):
+        jax.block_until_ready(_clock_probe())
+
+
 # -- the device route: a pipeline of key-range chunks -------------------------
 #
 # Rows a side of one chunk. A call is cut at common key values
@@ -758,6 +793,17 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     ``diff.device.chunks`` and ``diff.device.view_chunks`` (chunks that
     copied nothing); the open ``diff.classify`` span gets both too.
 
+    What the copy hid is counted where it happens (spans on): before the
+    host waits for a chunk's inputs, and later for its answer, it asks once
+    whether they are there already (``is_ready``, no wait) —
+    ``diff.device.transfer`` and ``diff.device.kernel`` carry ``ready`` = 1
+    | 0, ``diff.classify`` gets ``landed_ahead`` and ``hidden_programs``
+    (chunks whose copy, whose program had ended before the host came to
+    wait), counter ``diff.device.hidden_programs``. While span *events* are
+    recorded (``kart --trace``) the call is bracketed by two clock pings
+    (:func:`clock_ping`): before the first chunk is put and after the last
+    drain, the device idle both times.
+
     Raises what the device raises; nothing is published before the last
     chunk has drained. Bit-identical to the numpy reference (tested);
     counts are the sum of the chunks' count vectors."""
@@ -769,14 +815,19 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     from kart_tpu.runtime import default_backend
 
     # the windowed join's kernel is Mosaic's
-    entry = _classify_window_split if default_backend() == "tpu" else _classify_split
+    if default_backend() == "tpu":
+        entry, entry_name = _classify_window_split, "window_join"
+    else:
+        entry, entry_name = _classify_split, "sort_join"
     plan = classify_chunk_plan(old_block, new_block, chunk_rows)
     ahead = len(plan) > 1
+    pings = tm.tracing_enabled()
+    counting = tm.spans_enabled()
     old_class = np.empty(old_block.count, dtype=np.int8)
     new_class = np.empty(new_block.count, dtype=np.int8)
     totals = np.zeros(3, dtype=np.int64)
     in_flight = deque()  # enqueued and not drained, oldest first: two at most
-    view_chunks = 0
+    view_chunks = landed_ahead = hidden_programs = 0
 
     def put(chunk):
         # asynchronous: queued here, copied when the transfer engine is free
@@ -797,22 +848,36 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
         chunk.out = out
 
     def landed(chunk):
-        with tm.span("diff.device.transfer", **chunk.label, bytes=chunk.put_bytes):
+        nonlocal landed_ahead
+        with tm.span(
+            "diff.device.transfer", **chunk.label, bytes=chunk.put_bytes, ready=0
+        ) as sp:
             if chunk.dev is None:
                 put(chunk)
+            elif counting and all(a.is_ready() for a in reversed(chunk.dev)):
+                # the last put first: while the copy is under way that one
+                # call says so
+                landed_ahead += 1
+                sp.set(ready=1)
             jax.block_until_ready(chunk.dev)
         chunk.host = None  # landed: the views are done with
 
     def drain():
+        nonlocal hidden_programs
         chunk = in_flight.popleft()
         if chunk.host is not None:
             landed(chunk)
         bucket = max(chunk.sizes)
         with tm.span(
-            "diff.device.kernel", **chunk.label, program="mergesort", bucket=bucket
+            "diff.device.kernel", **chunk.label, program=entry_name, bucket=bucket,
+            ready=0,
         ) as sp:
             if chunk.out is None:
                 call(chunk, entry)
+            elif counting and chunk.out[0].is_ready():
+                # one program's outputs are there together: one is asked
+                hidden_programs += 1
+                sp.set(ready=1)
             old_part, new_part, counts, census = jax.block_until_ready(chunk.out)
             if census is not None:
                 dense_tiles, overflow_tiles = (int(t) for t in np.asarray(census))
@@ -830,7 +895,7 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
                     # this is no fallback rung — but it is counted, and the
                     # span says that both programs ran under it
                     tm.incr("diff.device.join_overflows")
-                    sp.set(window_ran=True)
+                    sp.set(window_ran=True, program="sort_join")
                     call(chunk, _classify_split)
                     old_part, new_part, counts, _ = jax.block_until_ready(chunk.out)
         (old_lo, old_hi), (new_lo, new_hi) = chunk.rows
@@ -842,6 +907,8 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
             new_class[new_lo:new_hi] = new_part[: new_hi - new_lo]
             totals[:] += counts
 
+    if pings:
+        clock_ping("start")
     for c, (*rows, sizes) in enumerate(plan):
         label = {"chunk": c} if ahead else {}
         with tm.span(
@@ -871,9 +938,15 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
         in_flight.append(chunk)
     while in_flight:
         drain()
+    if pings:
+        clock_ping("end")
     tm.incr("diff.device.chunks", len(plan))
     tm.incr("diff.device.view_chunks", view_chunks)
-    tm.annotate_span("diff.classify", chunks=len(plan), view_chunks=view_chunks)
+    tm.incr("diff.device.hidden_programs", hidden_programs)
+    tm.annotate_span(
+        "diff.classify", chunks=len(plan), view_chunks=view_chunks,
+        landed_ahead=landed_ahead, hidden_programs=hidden_programs,
+    )
     return (
         old_class,
         new_class,

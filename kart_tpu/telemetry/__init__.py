@@ -36,6 +36,7 @@ from kart_tpu.telemetry.core import (  # noqa: F401
     observe,
     snapshot,
     span,
+    spans_enabled,
     trace_path,
     tracing_enabled,
 )

@@ -20,12 +20,16 @@ observability they aren't using. The enablement ladder:
 Disabled, ``incr()``/``span()`` are one module-global bool test; a tier-1
 test bounds the calls a 1M-row diff issues times that cost under 2% of the
 diff (a CPU figure). What the instrumentation costs a command on the chip
-is measured by the benchmark's tracing-off comparison of the PR that put
-the stage spans inside ``diff.classify`` (PERF.md §6, PR 26: ``diff_wall_s``
-1.6486 s at the parent, 1.5944 s at the change in ``points10m.diff_count``;
-2.9456 s and 2.9566 s in ``polygons10m.diff_jsonl`` — medians of six
-runs, inside both cells' spread; the driver's own comparison is in
-``PERF_LEDGER.jsonl`` under PR 26). Instrumented code calls through the
+(TPU v5e, ``points10m.diff_count``: a 10M-row ``kart diff -o
+feature-count``, ten chunks, ~55 spans; my chip runs, PR 37, one process,
+16 commands a state; PERF.md §6): 0.10733 s a command with metrics and
+span aggregation on (the benchmark's untraced window), 0.10888 s with span
+events recorded as well (+0.0016 s), 0.11014 s with the device classify's
+two clock pings besides (``diff.device.clock``: +0.0013 s, two runs of a
+one-scalar program at 0.69 ms each, dispatched only while events are
+recorded); 0.12547 → 0.12662 s for the pings in the churn cell. Tracing
+off against the parent commit is the benchmark's own comparison
+(``PERF_LEDGER.jsonl``, PR 37). Instrumented code calls through the
 package attributes (``telemetry.span`` / ``telemetry.incr``), so tests and
 the overhead bench can swap in counting stubs without touching call sites.
 
@@ -113,6 +117,12 @@ _tls = threading.local()  # .stack: [child-duration accumulators]
 
 def metrics_enabled():
     return _METRICS_ON
+
+
+def spans_enabled():
+    """Are spans being aggregated (any layer on)? What a caller asks before
+    it gathers an attribute that costs a call of its own."""
+    return _SPANS_ON
 
 
 def tracing_enabled():

@@ -948,7 +948,8 @@ def test_device_classify_split_matches_reference(case, route, monkeypatch):
             join_census_reference,
         )
 
-        assert attrs["program"] == "mergesort"
+        # the entry that answered: the sort-join where a tile overflowed
+        assert attrs["program"] == ("sort_join" if overflows else "window_join")
         assert attrs["join"] == ("sort" if overflows else "window")
         # the tiles of the grid: whole steps of 32 over the bucket's tiles
         assert attrs["tiles"] % (2 * JOIN_STEP_TILES) == 0
@@ -964,7 +965,7 @@ def test_device_classify_split_matches_reference(case, route, monkeypatch):
             attrs["dense_tiles"]
         )
     else:
-        assert attrs["program"] == "mergesort" and "join" not in attrs
+        assert attrs["program"] == "sort_join" and "join" not in attrs
         assert overflowed == 0
 
 
@@ -1080,7 +1081,7 @@ def test_device_classify_pack_span_counts_only_the_tails(monkeypatch):
     assert events["diff.device.pack"]["bucket"] == 5120
     assert events["diff.device.pack"]["bytes"] == 2 * step * 28
     assert events["diff.device.transfer"]["bytes"] == 2 * 5120 * 28
-    assert events["diff.device.kernel"]["program"] == "mergesort"
+    assert events["diff.device.kernel"]["program"] == "sort_join"
 
 
 # -- the device route is a pipeline of key-range chunks (ISSUE 36) ----------
@@ -1193,7 +1194,11 @@ def test_device_classify_chunks_match_reference(case, route, monkeypatch):
     (classify,) = [e["args"] for e in events if e["name"] == "diff.classify"]
     assert (classify["chunks"], classify["view_chunks"]) == (n_chunks, view_chunks)
 
-    device = [e for e in events if e["name"].startswith("diff.device.")]
+    clock = [e for e in events if e["name"] == "diff.device.clock"]
+    device = [
+        e for e in events if e["name"].startswith("diff.device.") and e not in clock
+    ]
+    assert len(clock) == 2  # span events are on: the two pings, tested below
     if n_chunks == 1:
         assert [e["name"] for e in device] == _DEVICE_SPANS
         assert not any("chunk" in e["args"] for e in device)
@@ -1220,7 +1225,10 @@ def test_device_classify_chunks_match_reference(case, route, monkeypatch):
     kernels = [e["args"] for e in device if e["name"] == "diff.device.kernel"]
     packs = [e["args"] for e in device if e["name"] == "diff.device.pack"]
     for c, ((old_rows, new_rows, sizes), kernel, pack) in enumerate(zip(plan, kernels, packs)):
-        assert kernel["program"] == "mergesort" and kernel["bucket"] == max(sizes)
+        assert kernel["program"] == (
+            "window_join" if route == "window" and c not in sorted_chunks else "sort_join"
+        )
+        assert kernel["bucket"] == max(sizes)
         assert pack["rows"] == old_rows[1] - old_rows[0] + new_rows[1] - new_rows[0]
         if route == "sort":
             assert "join" not in kernel
@@ -1246,6 +1254,164 @@ def test_device_classify_chunks_match_reference(case, route, monkeypatch):
         assert packs[1]["bytes"] == 28 * (bucket_size(_CHUNK) - bucket_body(bucket_size(_CHUNK)))
     if case == "bulk_insert_empty_chunks":
         assert [rows[0][1] - rows[0][0] for rows in plan] == [6_000, 0, 0, 6_000]
+
+
+# -- the clock pings and what the copy hid (ISSUE 37) -------------------------
+
+def _counted_probe(monkeypatch):
+    """``_clock_probe`` counted: -> the list its dispatches are noted in."""
+    from kart_tpu.ops import diff_kernel
+
+    dispatched, real = [], diff_kernel._clock_probe
+
+    def counting():
+        dispatched.append(1)
+        return real()
+
+    monkeypatch.setattr(diff_kernel, "_clock_probe", counting)
+    return dispatched
+
+
+def _streamed(old, new, **layers):
+    """classify_blocks on the device route under a ``diff.classify`` span
+    with the telemetry ``layers`` on -> (classes and counts, events,
+    counters by name, names of the spans aggregated)."""
+    from kart_tpu import telemetry as tm
+
+    tm.reset()
+    if layers:
+        tm.enable(**layers)
+    try:
+        with tm.span("diff.classify"):
+            answer = classify_blocks(old, new)
+        counters = {k[0]: v for k, v in tm.counters_snapshot().items()}
+        names = {name for name, _, _ in tm.snapshot()["histograms"]}
+        return answer, tm.drain_events(), counters, names
+    finally:
+        tm.reset()
+
+
+@pytest.mark.parametrize(
+    "layers", [{}, {"spans": True}, {"metrics": True}], ids=["off", "spans", "metrics"]
+)
+@pytest.mark.parametrize("rows", [5_000, 35_000], ids=["one_chunk", "four_chunks"])
+def test_no_clock_ping_unless_span_events_are_recorded(rows, layers, monkeypatch):
+    """With tracing off — telemetry off, or only the aggregation the
+    benchmark's untraced window leaves on (``enable(metrics=True)``) — the
+    device route dispatches no probe and records no ``diff.device.clock``:
+    the pings cost a bool test."""
+    from kart_tpu.ops import diff_kernel
+
+    monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    dispatched = _counted_probe(monkeypatch)
+    old = _sorted_block(rows, seed=71)
+    new = _churned(old, 0.05, 11)
+    (old_class, new_class, _), events, _, names = _streamed(old, new, **layers)
+    assert dispatched == [] and events == []
+    assert "diff.device.clock" not in names
+    assert ("diff.device.kernel" in names) == bool(layers)
+    ref_old, ref_new = classify_blocks_reference(old, new)
+    np.testing.assert_array_equal(old_class, ref_old)
+    np.testing.assert_array_equal(new_class, ref_new)
+
+
+@pytest.mark.parametrize("route", ["sort", "window"])
+@pytest.mark.parametrize("rows", [5_000, 35_000], ids=["one_chunk", "four_chunks"])
+def test_a_traced_call_is_bracketed_by_two_clock_pings(rows, route, monkeypatch):
+    """While span events are recorded a streamed call, of one chunk or of
+    several, runs the probe twice: once before anything is put and once
+    after the last drain, each dispatched and waited for inside its own
+    ``diff.device.clock`` span, a child of ``diff.classify`` on the thread
+    that called. The classes are bit for bit those of an untraced call."""
+    import threading
+
+    from kart_tpu import runtime
+    from kart_tpu.ops import diff_kernel
+
+    monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    if route == "window":
+        monkeypatch.setattr(runtime, "default_backend", lambda: "tpu")
+    dispatched = _counted_probe(monkeypatch)
+    old = _sorted_block(rows, seed=72)
+    new = _churned(old, 0.05, 12)
+    untraced, no_events, _, _ = _streamed(old, new)
+    assert dispatched == [] and no_events == []
+    traced, events, _, _ = _streamed(old, new, trace=True)
+    assert len(dispatched) == 2
+    np.testing.assert_array_equal(traced[0], untraced[0])
+    np.testing.assert_array_equal(traced[1], untraced[1])
+    assert traced[2] == untraced[2]
+
+    clock = [e for e in events if e["name"] == "diff.device.clock"]
+    assert [e["args"]["at"] for e in clock] == ["start", "end"]
+    (classify,) = [e for e in events if e["name"] == "diff.classify"]
+    for e in clock:
+        assert e["args"]["parent"] == "diff.classify"
+        assert e["tid"] == classify["tid"] == threading.get_ident()
+        assert classify["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= classify["ts"] + classify["dur"]
+    others = [e for e in events if e["name"].startswith("diff.device.") and e not in clock]
+    assert clock[0]["ts"] + clock[0]["dur"] <= min(e["ts"] for e in others)
+    assert max(e["ts"] + e["dur"] for e in others) <= clock[1]["ts"]
+
+
+def test_the_probe_program_is_not_a_classify_program():
+    """The device trace names a program after its function: the clock
+    readers look for ``jit__clock_probe*`` and the old ``idle.*`` pair K
+    kernel spans with K ``jit__classify_*`` programs, so the probe may not
+    be one of those."""
+    import jax
+
+    from kart_tpu.ops.diff_kernel import _clock_probe
+
+    assert _clock_probe.__wrapped__.__name__ == "_clock_probe"
+    text = jax.jit(_clock_probe.__wrapped__).lower().as_text()
+    assert "jit__clock_probe" in text and "jit__classify_" not in text
+    assert _clock_probe().shape == ()  # one scalar, nothing copied to make it
+
+
+@pytest.mark.parametrize("route", ["sort", "window"])
+@pytest.mark.parametrize("case", ["aligned_sides", "hole_inside_a_chunk", "one_chunk"])
+@pytest.mark.parametrize("layers", [{"trace": True}, {"metrics": True}], ids=["trace", "metrics"])
+def test_what_the_copy_hid_is_counted_a_chunk_at_a_time(case, route, layers, monkeypatch):
+    """Every ``diff.device.transfer`` and ``diff.device.kernel`` span says
+    whether what it waits for was already there (``ready`` = 1 | 0, asked
+    without waiting); ``diff.classify`` gets the sums beside ``chunks`` and
+    the counter ``diff.device.hidden_programs`` adds the programs up. A call
+    of one chunk puts and calls under those spans: nothing can be ready."""
+    from kart_tpu import runtime
+    from kart_tpu.ops import diff_kernel
+
+    build, n_chunks, _, _ = CHUNK_CASES[case]
+    old, new = build()
+    monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1")
+    if route == "window":
+        monkeypatch.setattr(runtime, "default_backend", lambda: "tpu")
+    (old_class, new_class, _), events, counters, _ = _streamed(old, new, **layers)
+    ref_old, ref_new = classify_blocks_reference(old, new)
+    np.testing.assert_array_equal(old_class, ref_old)
+    np.testing.assert_array_equal(new_class, ref_new)
+    most = n_chunks if n_chunks > 1 else 0
+    if "metrics" in layers:
+        assert events == []  # aggregation only: the counter is what is left
+        assert 0 <= counters["diff.device.hidden_programs"] <= most
+        assert counters["diff.device.chunks"] == n_chunks
+        return
+    (classify,) = [e["args"] for e in events if e["name"] == "diff.classify"]
+    assert classify["chunks"] == n_chunks
+    ready = {
+        name: [e["args"]["ready"] for e in events if e["name"] == name]
+        for name in ("diff.device.transfer", "diff.device.kernel")
+    }
+    for name, flags in ready.items():
+        assert len(flags) == n_chunks and set(flags) <= {0, 1}, name
+    assert classify["landed_ahead"] == sum(ready["diff.device.transfer"])
+    assert classify["hidden_programs"] == sum(ready["diff.device.kernel"])
+    assert 0 <= classify["hidden_programs"] <= most
+    assert 0 <= classify["landed_ahead"] <= most
 
 
 def test_full_chunk_goes_over_as_views():

@@ -444,10 +444,16 @@ def test_device_classify_names_its_four_stages(rows, padded, monkeypatch):
     assert classify["args"]["backend"] == "device_jax"
     assert classify["args"]["counts_only"] is False
     children = [e for e in events if e["args"].get("parent") == "diff.classify"]
-    assert [e["name"] for e in children] == DEVICE_STAGES + ["diff.changed_indices"]
-    assert [e["name"] for e in events if e["name"].startswith("diff.device.")] == (
-        DEVICE_STAGES
+    # span events are on, so the call is bracketed by its two clock pings
+    assert [e["name"] for e in children] == (
+        ["diff.device.clock"] + DEVICE_STAGES
+        + ["diff.device.clock", "diff.changed_indices"]
     )
+    assert [e["name"] for e in events if e["name"].startswith("diff.device.")] == (
+        ["diff.device.clock"] + DEVICE_STAGES + ["diff.device.clock"]
+    )
+    assert [children[i]["args"]["at"] for i in (0, 5)] == ["start", "end"]
+    children = [e for e in children if e["name"] != "diff.device.clock"]
     pack, transfer, kernel, fetch, select = children
     bucket = bucket_size(rows)
     side = bucket * 8 + bucket * 5 * 4  # int64 keys + (n, 5) uint32 oids
@@ -457,8 +463,11 @@ def test_device_classify_names_its_four_stages(rows, padded, monkeypatch):
     tail = bucket - bucket_body(bucket)
     assert pack["args"]["bytes"] == (0 if padded else 2 * tail * (8 + 5 * 4))
     assert transfer["args"]["bytes"] == 2 * side
+    # one chunk: put and called under its own spans, nothing to hide under
+    assert transfer["args"]["ready"] == 0
     assert kernel["args"] == {
-        "program": "mergesort", "bucket": bucket, "parent": "diff.classify"
+        "program": "sort_join", "bucket": bucket, "ready": 0,
+        "parent": "diff.classify",
     }
     assert fetch["args"]["bytes"] == 2 * bucket + 3 * 8  # int8 classes + counts
     assert select["args"]["rows"] == 2 * rows

@@ -244,6 +244,20 @@ def test_classify_window_entry_compiles(one_chip, bucket, new_bucket, monkeypatc
     assert _device_bytes(compiled) < 16e9
 
 
+def test_clock_probe_compiles_under_its_own_name(one_chip):
+    """The clock ping's program (``diff.device.clock``): the chip's compiler
+    takes it, and the module is named so that no ``jit__classify_*`` reader
+    counts it and the clock readers find it."""
+    import jax
+
+    from kart_tpu.ops.diff_kernel import _clock_probe
+
+    lowered = jax.jit(_clock_probe.__wrapped__, out_shardings=one_chip).lower()
+    assert "jit__clock_probe" in lowered.as_text()
+    assert "jit__classify_" not in lowered.as_text()
+    lowered.compile()
+
+
 @pytest.mark.parametrize("bucket", [1024, bucket_size(4_000_000)])
 def test_merge_classify_compiles(one_chip, bucket):
     """The 3-way classify at a small bucket and at the 4M-row merge the
