@@ -1472,16 +1472,24 @@ def multichip_worker():
 
     old_block, new_block = _multichip_slice(lo, hi)
     if mode == "mono":
-        from kart_tpu.ops.diff_kernel import _classify_split, _split_columns
+        from kart_tpu.ops.blocks import PAD_KEY, bucket_size
+        from kart_tpu.ops.diff_kernel import _classify_padded
+
+        def padded(block):
+            size = bucket_size(max(block.count, 1))
+            keys = np.full(size, PAD_KEY, dtype=np.int64)
+            keys[: block.count] = block.keys
+            oids = np.zeros((size, 5), dtype=np.uint32)
+            oids[: block.count] = block.oids
+            return keys, oids
+
+        sides = padded(old_block) + padded(new_block)
 
         # compile + first-touch at full shape (jit specialises per padded
         # bucket size, so a tiny warm pair would not pre-pay this compile)
         def run():
-            oc, ncl, _, cnt = _classify_split(
-                *_split_columns(old_block),
-                *_split_columns(new_block),
-                old_block.count,
-                new_block.count,
+            oc, ncl, _, cnt = _classify_padded(
+                *sides, old_block.count, new_block.count
             )
             cnt = np.asarray(cnt)
             # worker-protocol counts (same shape as the classify counts

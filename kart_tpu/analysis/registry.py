@@ -281,6 +281,23 @@ CACHES = {
             "not fire there anyway"
         ),
     },
+    "diff.device.resident": {
+        "module": "kart_tpu/ops/resident.py",
+        "cls": "PageStore",
+        "registry_global": None,  # one store a process: resident.PAGES
+        "key_fn": "page_key",
+        "key_tokens": ("tree_oid",),
+        "ref_drop": None,
+        "ref_drop_rationale": (
+            "page keys pin the feature tree's oid — the sidecar's own "
+            "content address — with the column, the page number and the "
+            "page's rows, and a tree oid never changes meaning, so a ref "
+            "move cannot stale a page (the rationale tiles.source gives "
+            "for its commit-keyed blocks); blocks that name no tree are "
+            "never kept; the byte budget alone reclaims device memory "
+            "(docs/DEVICE.md §5)"
+        ),
+    },
 }
 
 #: where every ref update funnels; the declared ``ref_drop`` hooks above
@@ -332,6 +349,7 @@ DEVICE_MODULES = frozenset(
         "kart_tpu/ops/bbox.py",
         "kart_tpu/ops/diff_kernel.py",
         "kart_tpu/ops/merge_kernel.py",
+        "kart_tpu/ops/resident.py",
         "kart_tpu/parallel/__init__.py",
         "kart_tpu/parallel/mesh.py",
         "kart_tpu/parallel/sharded_merge.py",

@@ -27,7 +27,10 @@ Every device backend degrades to ``host_native`` on failure mid-call
 fault): the CLI must always complete, and a failed device attempt publishes
 nothing. Every such rung bumps ``diff.device.fallbacks{what=…}``
 (:func:`kart_tpu.ops.diff_kernel.note_device_fallback`) — a measurement
-asserts it stayed 0.
+asserts it stayed 0. Before it degrades, a rung the device refused an
+allocation is made once more with the classify's resident pages let go
+(:func:`kart_tpu.ops.resident.with_pages_let_go`): they are a cache, and a
+cache must not cost a program the device.
 """
 
 import functools
@@ -37,6 +40,7 @@ import numpy as np
 
 from kart_tpu import routing
 from kart_tpu import telemetry as tm
+from kart_tpu.ops.resident import with_pages_let_go
 
 BACKENDS = {}
 
@@ -175,7 +179,9 @@ class ShardedJaxBackend(DiffBackend):
         from kart_tpu.diff.device_batch import classify_blocks_batched
 
         try:
-            result = classify_blocks_batched(old_block, new_block)
+            result = with_pages_let_go(
+                lambda: classify_blocks_batched(old_block, new_block)
+            )
         except Exception as e:
             # device OOM / runtime error / injected transfer fault: nothing
             # was published, so the host engine starts from clean state
@@ -192,8 +198,10 @@ class ShardedJaxBackend(DiffBackend):
         from kart_tpu.diff.device_batch import classify_blocks_batched
 
         try:
-            _, _, counts = classify_blocks_batched(
-                old_block, new_block, counts_only=True
+            _, _, counts = with_pages_let_go(
+                lambda: classify_blocks_batched(
+                    old_block, new_block, counts_only=True
+                )
             )
         except Exception as e:
             return self._fall_back(e, "counts").counts(old_block, new_block)
@@ -204,7 +212,9 @@ class ShardedJaxBackend(DiffBackend):
 
     def sampled_counts(self, old_sub, new_sub):
         try:
-            counts = sampled_counts_pmapped(old_sub, new_sub)
+            counts = with_pages_let_go(
+                lambda: sampled_counts_pmapped(old_sub, new_sub)
+            )
         except Exception as e:
             return self._fall_back(e, "sampled_counts").counts(old_sub, new_sub)
         from kart_tpu.parallel.sharded_diff import STATS
@@ -221,7 +231,9 @@ class ShardedJaxBackend(DiffBackend):
         ):
             return super().envelope_hits(block, query)
         try:
-            return sharded_envelope_hits(block.envelopes, block.count, q)
+            return with_pages_let_go(
+                lambda: sharded_envelope_hits(block.envelopes, block.count, q)
+            )
         except Exception as e:
             return self._fall_back(e, "envelope_hits").envelope_hits(block, query)
 
@@ -230,13 +242,15 @@ class ShardedJaxBackend(DiffBackend):
         if not routing.runtime_ready(len(e), routing.DEVICE_MIN_ENVELOPES):
             return super().merc_envelopes(e)
         try:
-            return sharded_merc_envelopes(e)
+            return with_pages_let_go(lambda: sharded_merc_envelopes(e))
         except Exception as exc:
             return self._fall_back(exc, "merc_envelopes").merc_envelopes(e)
 
     def join_counts(self, build_env, probe_env):
         try:
-            return sharded_join_counts(build_env, probe_env)
+            return with_pages_let_go(
+                lambda: sharded_join_counts(build_env, probe_env)
+            )
         except Exception as e:
             # device OOM / runtime error mid-batch: nothing was published
             # (the query layer accumulates only returned batches), so the
@@ -245,7 +259,9 @@ class ShardedJaxBackend(DiffBackend):
 
     def refine_pairs(self, col_a, ia, col_b, ib):
         try:
-            return sharded_refine_pairs(col_a, ia, col_b, ib)
+            return with_pages_let_go(
+                lambda: sharded_refine_pairs(col_a, ia, col_b, ib)
+            )
         except Exception as e:
             # nothing published mid-batch (the refine stage only applies
             # returned verdict arrays), so the host twin restarts clean
@@ -326,7 +342,11 @@ def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
 
         try:
             return (
-                sharded_merge_classify(ancestor_block, ours_block, theirs_block),
+                with_pages_let_go(
+                    lambda: sharded_merge_classify(
+                        ancestor_block, ours_block, theirs_block
+                    )
+                ),
                 "sharded_jax",
             )
         except Exception as e:
@@ -340,8 +360,10 @@ def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
             # upload instead of one monolithic 3-block transfer
             try:
                 return (
-                    merge_classify_streamed(
-                        ancestor_block, ours_block, theirs_block
+                    with_pages_let_go(
+                        lambda: merge_classify_streamed(
+                            ancestor_block, ours_block, theirs_block
+                        )
                     ),
                     "device_jax",
                 )
@@ -379,11 +401,13 @@ def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
     union_padded[:u] = union
 
     try:
-        decision, presence, n_conf, n_theirs = _merge_classify_padded(
-            ancestor_block.keys, ancestor_block.oids, ancestor_block.count,
-            ours_block.keys, ours_block.oids, ours_block.count,
-            theirs_block.keys, theirs_block.oids, theirs_block.count,
-            union_padded, u,
+        decision, presence, n_conf, n_theirs = with_pages_let_go(
+            lambda: _merge_classify_padded(
+                ancestor_block.keys, ancestor_block.oids, ancestor_block.count,
+                ours_block.keys, ours_block.oids, ours_block.count,
+                theirs_block.keys, theirs_block.oids, theirs_block.count,
+                union_padded, u,
+            )
         )
     except Exception as e:
         # device OOM / runtime failure mid-call: the merge must still

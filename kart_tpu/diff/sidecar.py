@@ -267,7 +267,12 @@ def load_block(repo, dataset, pad=True):
         tm.incr("sidecar.load_misses")
         return None
     with tm.span("sidecar.load"):
-        return _load_block_from_mmap(mm, dataset, pad)
+        block = _load_block_from_mmap(mm, dataset, pad)
+    if block is not None:
+        # the sidecar's own key: content-addressed, so it names these
+        # columns for good
+        block.tree_oid = feature_tree.oid
+    return block
 
 
 def _load_block_from_mmap(mm, dataset, pad):

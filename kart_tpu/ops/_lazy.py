@@ -8,10 +8,11 @@ module import; the real ``jax.jit`` happens on the first *call*.
 
 
 class _LazyJit:
-    __slots__ = ("_fn", "_jitted")
+    __slots__ = ("_fn", "_options", "_jitted")
 
-    def __init__(self, fn):
+    def __init__(self, fn, options):
         self._fn = fn
+        self._options = options
         self._jitted = None
 
     @property
@@ -26,10 +27,11 @@ class _LazyJit:
             # keys and the PAD_KEY sentinel corrupt silently under x32, and
             # an inherited JAX_ENABLE_X64=0 must not defeat that
             jax.config.update("jax_enable_x64", True)
-            self._jitted = jax.jit(self._fn)
+            self._jitted = jax.jit(self._fn, **self._options)
         return self._jitted(*args, **kwargs)
 
 
-def lazy_jit(fn):
-    """jax.jit that defers the jax import to the first call."""
-    return _LazyJit(fn)
+def lazy_jit(fn, **options):
+    """jax.jit that defers the jax import to the first call; ``options``
+    are jax.jit's own (``static_argnames``)."""
+    return _LazyJit(fn, options)

@@ -27,6 +27,7 @@ import threading
 import numpy as np
 
 from kart_tpu.ops._lazy import lazy_jit
+from kart_tpu.ops.resident import with_pages_let_go
 
 
 def _range_len_np(w, e):
@@ -265,16 +266,21 @@ def bbox_intersects(envelopes, query, *, cache_key=None):
     if not routing.runtime_ready(n, routing.DEVICE_MIN_ENVELOPES):
         return _bbox_host(envelopes, query)
     backend = default_backend()
-    if cache_key is not None:
-        w, s, e, nn, count = _resident_columns(cache_key, envelopes)
-    else:
-        w, s, e, nn, count = pad_envelopes(np.asarray(envelopes))
-    q = np.asarray(query, dtype=np.float32)
-    if backend == "tpu":
-        mask = bbox_intersects_pallas(w, s, e, nn, q)
-    else:
-        mask = bbox_intersects_jnp(w, s, e, nn, q)
-    return np.asarray(mask)[:count]
+
+    def on_device():
+        if cache_key is not None:
+            w, s, e, nn, count = _resident_columns(cache_key, envelopes)
+        else:
+            w, s, e, nn, count = pad_envelopes(np.asarray(envelopes))
+        q = np.asarray(query, dtype=np.float32)
+        if backend == "tpu":
+            mask = bbox_intersects_pallas(w, s, e, nn, q)
+        else:
+            mask = bbox_intersects_jnp(w, s, e, nn, q)
+        return np.asarray(mask)[:count]
+
+    # the classify's resident pages give way to this scan's columns
+    return with_pages_let_go(on_device)
 
 
 def _bbox_host(envelopes, query):

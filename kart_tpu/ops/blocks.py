@@ -167,10 +167,10 @@ class FeatureBlock:
     (kept host-side for value materialisation of changed rows only)."""
 
     __slots__ = ("keys", "oids", "paths", "count", "envelopes", "env_blocks",
-                 "geom_raw", "_vertices")
+                 "geom_raw", "_vertices", "tree_oid")
 
     def __init__(self, keys, oids, paths, count, envelopes=None,
-                 env_blocks=None, geom_raw=None, vertices=None):
+                 env_blocks=None, geom_raw=None, vertices=None, tree_oid=None):
         self.keys = keys
         self.oids = oids
         self.paths = paths  # list[str], in the same (sorted) order, len == count
@@ -187,6 +187,11 @@ class FeatureBlock:
         # diff loads must not pay the decode they never use
         self.geom_raw = geom_raw
         self._vertices = vertices
+        # the feature tree's oid where the columns are that tree's sidecar,
+        # row for row (sidecar.load_block stamps it): the identity under
+        # which the device keeps their pages between calls
+        # (ops/resident.py). A block made any other way has none
+        self.tree_oid = tree_oid
 
     def vertex_column(self):
         """Lazily decoded :class:`kart_tpu.geom.VertexColumn` for the

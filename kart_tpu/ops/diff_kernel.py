@@ -48,15 +48,18 @@ monotonic partner gather).
 There is one device route (:func:`classify_blocks` →
 :func:`classify_blocks_streamed`), at every size: the call is cut at common
 key values into chunks of at most ``CLASSIFY_CHUNK_ROWS`` rows a side, every
-chunk's arrays and program are enqueued ahead and the chunks are drained in
-order, so the host→device copy of chunk c+1 runs under the program of chunk
-c; a tile overflow sends its chunk, not the call, to the sort-join. A call
-of at most one chunk's rows is one put, one program, one fetch. No chunk is
-copied to pad it: each column reaches the device as a body and a tail
-(:func:`_split_columns`), joined on the device by the ``*_split`` entries —
-both views of the caller's own pages where the chunk fills its bucket (every
-chunk but the last of a commit that keeps the key set), else the body a view
-and the tail freshly padded.
+chunk's program is enqueued ahead and the chunks are drained in order, so
+the host→device copy of chunk c+1 runs under the program of chunk c; a tile
+overflow sends its chunk, not the call, to the sort-join. A call of at most
+one chunk's rows is one put, one program, one fetch. The programs read
+**pages**: rows ``[p * R, (p + 1) * R)`` of one column of one revision, put
+as views of the caller's own pages (a revision's last, partial page alone
+costs a copy: one step of the bucket grid) and cut to the chunk on the
+device by the ``*_split`` entries. A page does not depend on the revision it
+is joined with, so where the block names its feature tree
+(``FeatureBlock.tree_oid``) its pages stay on the device between calls
+(:mod:`kart_tpu.ops.resident`) and a call ships only the pages the device
+does not hold.
 
 Classes: 0 = unchanged, 1 = insert, 2 = update, 3 = delete.
 """
@@ -183,26 +186,49 @@ _classify_padded = lazy_jit(_classify_mergesort_core)
 
 
 def _split_entry(core):
-    """The jitted entry of the device route: ``core`` with each of its
-    four columns arriving as (body, tail) — the sidecar's own pages and one
-    step of the bucket grid (:func:`_split_columns`) — joined on the
-    device. Shapes depend on the bucket alone, so this compiles once per
-    bucket as the six-argument core does. The program is named after the
-    core (``jit__classify_mergesort_core_split``): the benchmark's kernel
+    """The jitted entry of the device route: ``core`` over a chunk cut out
+    of pages on the device. A side's column arrives as the page its first
+    row lies in and the page after it (the shared padding page where the
+    chunk does not reach that far, or the revision ends); ``rows`` is one
+    int32 vector — the old and the new side's first row's place in its
+    first page, then their row counts: one small put a call, where four
+    scalars are four (each costs what a page's does to enqueue) — and
+    ``sizes`` (static) the padded rows of each side. Rows from the side's
+    count on — the next chunk's, in a
+    page — are made padding (``PAD_KEY``, zero oids), so ``core`` sees what
+    it would of a chunk padded on the host. Shapes depend on the page
+    geometry and ``sizes`` alone: one program whether a page was put for
+    this call or was there before it. The program is named after the core
+    (``jit__classify_mergesort_core_split``): the benchmark's kernel
     metrics find it by that prefix in the device trace."""
 
     def entry(
-        old_keys, old_keys_tail, old_oids, old_oids_tail,
-        new_keys, new_keys_tail, new_oids, new_oids_tail,
-        old_count, new_count,
+        old_keys, old_keys_next, old_oids, old_oids_next,
+        new_keys, new_keys_next, new_oids, new_oids_next,
+        rows, *, sizes,
     ):
+        import jax
         import jax.numpy as jnp
 
+        from kart_tpu.ops.blocks import PAD_KEY
+
+        old_lo, new_lo, old_count, new_count = rows
+
+        def cut(page, following, lo, count, size, fill):
+            pages = [page, following]
+            short = size - page.shape[0]
+            if short > 0:
+                # a revision of one page, smaller than its partner's chunk
+                pages.append(jnp.full((short,) + page.shape[1:], fill, page.dtype))
+            part = jax.lax.dynamic_slice_in_dim(jnp.concatenate(pages), lo, size)
+            valid = (jnp.arange(size) < count).reshape((size,) + (1,) * (part.ndim - 1))
+            return jnp.where(valid, part, jnp.asarray(fill, page.dtype))
+
         return core(
-            jnp.concatenate([old_keys, old_keys_tail]),
-            jnp.concatenate([old_oids, old_oids_tail]),
-            jnp.concatenate([new_keys, new_keys_tail]),
-            jnp.concatenate([new_oids, new_oids_tail]),
+            cut(old_keys, old_keys_next, old_lo, old_count, sizes[0], PAD_KEY),
+            cut(old_oids, old_oids_next, old_lo, old_count, sizes[0], 0),
+            cut(new_keys, new_keys_next, new_lo, new_count, sizes[1], PAD_KEY),
+            cut(new_oids, new_oids_next, new_lo, new_count, sizes[1], 0),
             old_count,
             new_count,
         )
@@ -211,7 +237,30 @@ def _split_entry(core):
     return entry
 
 
-_classify_split = lazy_jit(_split_entry(_classify_mergesort_core))
+_classify_split = lazy_jit(
+    _split_entry(_classify_mergesort_core), static_argnames="sizes"
+)
+
+
+def _resident_page(body, tail, *, rows):
+    """A revision's last page, made whole on the device: its body (a view
+    of the caller's pages) and its freshly padded tail (:func:`_page_parts`)
+    joined, then padding up to the ``rows`` every page of the revision has.
+    Runs when such a page is put and never on a hit. Not a
+    ``jit__classify_*`` program: the benchmark pairs those with the kernel
+    spans."""
+    import jax.numpy as jnp
+
+    from kart_tpu.ops.blocks import PAD_KEY
+
+    fill = PAD_KEY if body.dtype == jnp.int64 else 0
+    short = rows - body.shape[0] - tail.shape[0]
+    return jnp.concatenate(
+        [body, tail, jnp.full((short,) + body.shape[1:], fill, body.dtype)]
+    )
+
+
+_resident_page = lazy_jit(_resident_page, static_argnames="rows")
 
 
 # -- the windowed join: the device route's program on a TPU ------------------
@@ -634,7 +683,9 @@ def _classify_mergesort_core_window(
 
 
 # jit__classify_mergesort_core_window_split in the device trace
-_classify_window_split = lazy_jit(_split_entry(_classify_mergesort_core_window))
+_classify_window_split = lazy_jit(
+    _split_entry(_classify_mergesort_core_window), static_argnames="sizes"
+)
 
 
 def note_device_fallback(what, e, to):
@@ -707,15 +758,21 @@ def classify_blocks(old_block, new_block):
     blocks, CPU backends and wedged accelerators; a device gets
     :func:`classify_blocks_streamed` — the one device route, whatever the
     size — with the host engine beneath it should the device fail mid-call.
+    Where the device refuses an allocation while pages of earlier calls are
+    resident, they are let go and the call is made once more first, as on
+    every device rung (:func:`kart_tpu.ops.resident.with_pages_let_go`).
     Bit-identical results on every route."""
     from kart_tpu import routing
+    from kart_tpu.ops.resident import with_pages_let_go
 
     if not routing.device_open(max(old_block.count, new_block.count)):
         # the host merge-join reads count-sliced views directly — callers
         # may pass unpadded (mmap-backed) blocks with no copy at all
         return classify_blocks_host(old_block, new_block)
     try:
-        return classify_blocks_streamed(old_block, new_block)
+        return with_pages_let_go(
+            lambda: classify_blocks_streamed(old_block, new_block)
+        )
     except Exception as e:
         # device OOM / runtime failure mid-call: the CLI must still complete
         # (north-star scale can exceed a single chip's HBM). Nothing was
@@ -760,38 +817,68 @@ def classify_chunk_plan(old_block, new_block, chunk_rows=None):
     return plan
 
 
+def page_rows(count, chunk_rows=None):
+    """Rows of every page of a revision of ``count`` rows: a full chunk's
+    bucket, or the revision's own bucket where that is smaller (one page,
+    as a call of one chunk is one bucket). A function of the revision alone,
+    so a page put for one diff serves the next one."""
+    from kart_tpu.ops.blocks import bucket_size
+
+    full = bucket_size(max(int(chunk_rows or CLASSIFY_CHUNK_ROWS), 1))
+    return full if count > full else bucket_size(max(count, 1))
+
+
 def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     """The device classify: a pipeline of key-range chunks
-    (:func:`classify_chunk_plan`), three deep. A chunk's eight arrays — a
-    body and a tail a column, views of the caller's pages wherever the
-    chunk fills its bucket (:func:`_split_columns`) — are handed to
-    ``jax.device_put`` and its program is dispatched, neither waited for.
-    Then the host waits for the chunk *before* to have landed, which paces
-    the copies — the runtime copies whatever it has been handed all at once,
-    so ten chunks enqueued together all land at the end (0.127 s a 10M-row
-    churn command against 0.116 s paced; my chip runs, PR 36) — and drains
-    the chunk before that, whose program has had a chunk's copy of time to
-    run. So chunk c+1's bytes land while chunk c's program runs and chunk
-    c-1's classes come home, and the device holds three chunks at most
-    whatever the call's size.
+    (:func:`classify_chunk_plan`), three deep, over pages on the device.
+
+    A chunk of ``size`` rows from row ``lo`` of a side lies in at most two
+    of the side's pages (:func:`page_rows` rows each); the jitted entry
+    cuts it out of them (:func:`_split_entry`). Each page is looked for on
+    the device first (``kart_tpu.ops.resident.PAGES``, where the block names
+    its feature tree) and else put: a view of the caller's own pages, or
+    for a revision's last page a body view and one padded step of the
+    bucket grid (:func:`_page_parts`), made whole on the device. So a page
+    is shipped at most once a call, in chunk order, and not at all where an
+    earlier call left it; a page past a revision's end, or past the chunk's,
+    is one shared page of padding. Pages of a block with a tree oid stay
+    (within the store's byte budget, pinned while this call runs); any
+    other page lives as long as the chunks that read it.
+
+    A chunk's missing pages are handed to ``jax.device_put`` and its program
+    is dispatched, neither waited for. Then the host waits for the chunk
+    *before* to have landed, which paces the copies — the runtime copies
+    whatever it has been handed all at once, so ten chunks enqueued together
+    all land at the end (0.127 s a 10M-row churn command against 0.116 s
+    paced; my chip runs, PR 36) — and drains the chunk before that, whose
+    program has had a chunk's copy of time to run. So chunk c+1's bytes land
+    while chunk c's program runs and chunk c-1's classes come home, and of
+    pages that are not kept the device holds three chunks' at most whatever
+    the call's size. Hit or miss, the chunks run the same programs.
 
     The program is the windowed join on a TPU, the sort-join on any other
     backend. A chunk whose windowed join reports an overflowing tile is
-    answered by the sort-join from that chunk's arrays, still on the device
+    answered by the sort-join from the same pages
     (``diff.device.join_overflows`` counts such chunks): a bulk insert or
     delete costs its chunk, not the call.
 
-    Spans, one set a chunk (``chunk=c``): ``diff.device.pack`` (the views
-    and whatever had to be copied: ``bytes``), ``diff.device.enqueue`` (the
-    puts and the jitted call, no wait), ``diff.device.transfer`` (the wait
-    for the chunk's inputs, once the next chunk is on its way: what of the
-    copy nothing hid) and, at the drain, ``diff.device.kernel`` (the wait
-    for its program, the census read and, on overflow, the sort-join) and
+    Spans, one set a chunk (``chunk=c``): ``diff.device.pack`` (the pages
+    looked up, the views and whatever had to be copied: ``bytes``),
+    ``diff.device.enqueue`` (the puts and the jitted call, no wait;
+    ``bytes`` put), ``diff.device.transfer`` (the wait for what the chunk
+    put, once the next chunk is on its way: what of the copy nothing hid;
+    ``bytes`` put, 0 where every page was found; ``resident`` = pages
+    found) and, at the drain, ``diff.device.kernel`` (the wait for its
+    program, the census read and, on overflow, the sort-join) and
     ``diff.device.fetch``. A call of one chunk has nothing to overlap: it
     puts under ``diff.device.transfer`` and calls under
     ``diff.device.kernel``, with no ``chunk`` and no enqueue span. Counters
-    ``diff.device.chunks`` and ``diff.device.view_chunks`` (chunks that
-    copied nothing); the open ``diff.classify`` span gets both too.
+    ``diff.device.chunks``, ``diff.device.view_chunks`` (chunks that copied
+    nothing), ``diff.device.resident_hit_bytes`` / ``_put_bytes`` (bytes of
+    the revisions' rows found in the store; bytes put that it kept); the
+    open ``diff.classify`` span gets ``chunks``, ``view_chunks`` and
+    ``input_bytes`` / ``resident_bytes`` (the rows' bytes in the pages the
+    call read; what of them was on the device before it).
 
     What the copy hid is counted where it happens (spans on): before the
     host waits for a chunk's inputs, and later for its answer, it asks once
@@ -799,19 +886,23 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     ``diff.device.transfer`` and ``diff.device.kernel`` carry ``ready`` = 1
     | 0, ``diff.classify`` gets ``landed_ahead`` and ``hidden_programs``
     (chunks whose copy, whose program had ended before the host came to
-    wait), counter ``diff.device.hidden_programs``. While span *events* are
-    recorded (``kart --trace``) the call is bracketed by two clock pings
+    wait; a chunk that put nothing has landed), counter
+    ``diff.device.hidden_programs``. While span *events* are recorded
+    (``kart --trace``) the call is bracketed by two clock pings
     (:func:`clock_ping`): before the first chunk is put and after the last
     drain, the device idle both times.
 
     Raises what the device raises; nothing is published before the last
-    chunk has drained. Bit-identical to the numpy reference (tested);
-    counts are the sum of the chunks' count vectors."""
+    chunk has drained, and the pages a failed call put are forgotten.
+    Bit-identical to the numpy reference (tested); counts are the sum of
+    the chunks' count vectors."""
     import jax
 
     from collections import deque
     from types import SimpleNamespace
 
+    from kart_tpu.ops.blocks import PAD_KEY
+    from kart_tpu.ops.resident import PAGES, page_key
     from kart_tpu.runtime import default_backend
 
     # the windowed join's kernel is Mosaic's
@@ -828,17 +919,113 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     totals = np.zeros(3, dtype=np.int64)
     in_flight = deque()  # enqueued and not drained, oldest first: two at most
     view_chunks = landed_ahead = hidden_programs = 0
+    # a side's pages as the last chunk left them: ``recent`` the (column,
+    # page) slots it read — the next chunk starts in one of them — and
+    # ``pad`` the slots of the padding page
+    sides = [
+        SimpleNamespace(
+            block=block, rows=page_rows(block.count, chunk_rows), recent={}, pad={}
+        )
+        for block in (old_block, new_block)
+    ]
+    pinned, kept_keys = [], []  # store keys: to unpin; put by this call
+    input_bytes = resident_bytes = kept_bytes = 0
+
+    def page_slot(side, column, page, made):
+        """Where one page of one column is: on the device already
+        (``array``) or on the host, as the arrays to put (``parts``)."""
+        nonlocal input_bytes, resident_bytes
+        if page is None:  # padding stands in
+            if column not in side.pad:
+                side.pad[column] = SimpleNamespace(
+                    column=column, rows=side.rows, key=None, array=None, parts=None
+                )
+            return side.pad[column]
+        slot = side.recent.get((column, page))
+        if slot is not None:
+            return slot
+        block, rows = side.block, side.rows
+        values = getattr(block, column)
+        key = array = parts = None
+        # (a block made around __init__ — a benchmark's, a test's — has no
+        # such attribute at all)
+        tree_oid = getattr(block, "tree_oid", None)
+        if tree_oid is not None:
+            key = page_key(tree_oid, column, page, rows)
+            array = PAGES.pin(key)
+        real = min(block.count - page * rows, rows) * values[:1].nbytes
+        input_bytes += real
+        if array is not None:
+            pinned.append(key)
+            resident_bytes += real
+        else:
+            parts = _page_parts(values, block.count, page * rows, rows)
+        slot = SimpleNamespace(
+            column=column, rows=rows, key=key, array=array, parts=parts
+        )
+        made.append(slot)
+        return slot
+
+    def chunk_slots(side, lo, hi, made):
+        """The four pages a side's rows ``lo:hi`` are cut from (keys, keys
+        of the page after, oids, oids after) and ``lo``'s place in the
+        first. Padding stands in for a page no row of the chunk lies in."""
+        first = lo // side.rows
+        pages = (
+            first if hi > lo else None,
+            first + 1 if hi > (first + 1) * side.rows else None,
+        )
+        slots, recent = [], {}
+        for column in ("keys", "oids"):
+            for page in pages:
+                slot = page_slot(side, column, page, made)
+                slots.append(slot)
+                if page is not None:
+                    recent[column, page] = slot
+        if recent:  # an empty chunk reads nothing: the next starts where this did
+            side.recent = recent
+        return slots, (lo - first * side.rows if hi > lo else 0)
+
+    def padding(column, rows):
+        if column == "keys":
+            return jax.device_put(np.full(rows, PAD_KEY, dtype=np.int64))
+        return jax.device_put(np.zeros((rows, 5), dtype=np.uint32))
 
     def put(chunk):
         # asynchronous: queued here, copied when the transfer engine is free
-        chunk.dev = [jax.device_put(a) for a in chunk.host]
+        nonlocal kept_bytes
+        chunk.fresh = []
+        for slot in chunk.made:
+            if slot.parts is None:
+                continue  # found on the device
+            arrays = [jax.device_put(a) for a in slot.parts]
+            array = arrays[0]
+            if len(arrays) > 1:
+                array = _resident_page(*arrays, rows=slot.rows)
+            if slot.key is not None:
+                array, kept = PAGES.keep(slot.key, array)
+                if kept:
+                    pinned.append(slot.key)
+                    kept_keys.append(slot.key)
+                    kept_bytes += sum(a.nbytes for a in slot.parts)
+            slot.array = array
+            chunk.fresh.append(array)
+        for slot in chunk.slots:
+            if slot.array is None:
+                slot.array = PAGES.pad_page(slot.column, slot.rows, padding)
 
     def call(chunk, program):
         # asynchronous too: the program runs on the device once its
         # arguments are there, and its answer starts for the host when it
         # ends, not when the host asks (an np.asarray that has to ask costs
         # a round trip an array: 2 ms a chunk)
-        out = program(*chunk.dev, *(hi - lo for lo, hi in chunk.rows))
+        out = program(
+            *(slot.array for slot in chunk.slots),
+            np.array(
+                [*chunk.los, *(hi - lo for lo, hi in chunk.rows)], dtype=np.int32
+            ),
+            sizes=chunk.sizes,
+        )
         if program is _classify_split:
             old_part, new_part, _, counts = out
             out = (old_part, new_part, counts, None)  # no census
@@ -850,22 +1037,25 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     def landed(chunk):
         nonlocal landed_ahead
         with tm.span(
-            "diff.device.transfer", **chunk.label, bytes=chunk.put_bytes, ready=0
+            "diff.device.transfer", **chunk.label, bytes=chunk.put_bytes,
+            resident=chunk.found, ready=0,
         ) as sp:
-            if chunk.dev is None:
+            if chunk.fresh is None:
                 put(chunk)
-            elif counting and all(a.is_ready() for a in reversed(chunk.dev)):
+            elif counting and all(a.is_ready() for a in reversed(chunk.fresh)):
                 # the last put first: while the copy is under way that one
                 # call says so
                 landed_ahead += 1
                 sp.set(ready=1)
-            jax.block_until_ready(chunk.dev)
-        chunk.host = None  # landed: the views are done with
+            jax.block_until_ready(chunk.fresh)
+        for slot in chunk.made:
+            slot.parts = None  # landed: the views are done with
+        chunk.landed = True
 
     def drain():
         nonlocal hidden_programs
         chunk = in_flight.popleft()
-        if chunk.host is not None:
+        if not chunk.landed:
             landed(chunk)
         bucket = max(chunk.sizes)
         with tm.span(
@@ -890,7 +1080,7 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
                 )
                 if overflow_tiles:
                     # some tile's partners did not fit its window: the
-                    # sort-join answers from the chunk's arrays, which are
+                    # sort-join answers from the chunk's pages, which are
                     # still on the device. The device still answered, so
                     # this is no fallback rung — but it is counted, and the
                     # span says that both programs ran under it
@@ -907,45 +1097,58 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
             new_class[new_lo:new_hi] = new_part[: new_hi - new_lo]
             totals[:] += counts
 
-    if pings:
-        clock_ping("start")
-    for c, (*rows, sizes) in enumerate(plan):
-        label = {"chunk": c} if ahead else {}
-        with tm.span(
-            "diff.device.pack", **label, rows=sum(hi - lo for lo, hi in rows)
-        ) as sp:
-            host = [
-                a
-                for block, (lo, hi), size in zip((old_block, new_block), rows, sizes)
-                for a in _split_columns(block, lo, hi, size)
-            ]
-            # bytes the host copied: the tails it made; a view owns nothing
-            copied = sum(a.nbytes for a in host if a.flags.owndata)
-            sp.set(bucket=max(sizes), bytes=copied)
-        view_chunks += not copied
-        chunk = SimpleNamespace(
-            label=label, rows=rows, sizes=sizes, host=host,
-            put_bytes=sum(a.nbytes for a in host), dev=None, out=None,
-        )
-        if ahead:
-            with tm.span("diff.device.enqueue", **label, bytes=chunk.put_bytes):
-                put(chunk)
-                call(chunk, entry)
-            if in_flight:
-                landed(in_flight[-1])
-            if len(in_flight) > 1:
-                drain()
-        in_flight.append(chunk)
-    while in_flight:
-        drain()
-    if pings:
-        clock_ping("end")
+    try:
+        if pings:
+            clock_ping("start")
+        for c, (*rows, sizes) in enumerate(plan):
+            label = {"chunk": c} if ahead else {}
+            with tm.span(
+                "diff.device.pack", **label, rows=sum(hi - lo for lo, hi in rows)
+            ) as sp:
+                made, slots, los = [], [], []
+                for side, (lo, hi) in zip(sides, rows):
+                    side_slots, lo_in_page = chunk_slots(side, lo, hi, made)
+                    slots += side_slots
+                    los.append(lo_in_page)
+                host = [a for slot in made for a in slot.parts or ()]
+                # bytes the host copied: the tails it made; a view owns nothing
+                copied = sum(a.nbytes for a in host if a.flags.owndata)
+                sp.set(bucket=max(sizes), bytes=copied)
+            view_chunks += not copied
+            chunk = SimpleNamespace(
+                label=label, rows=rows, sizes=sizes, slots=slots, los=los,
+                made=made, put_bytes=sum(a.nbytes for a in host),
+                found=sum(slot.parts is None for slot in made),
+                fresh=None, landed=False, out=None,
+            )
+            if ahead:
+                with tm.span("diff.device.enqueue", **label, bytes=chunk.put_bytes):
+                    put(chunk)
+                    call(chunk, entry)
+                if in_flight:
+                    landed(in_flight[-1])
+                if len(in_flight) > 1:
+                    drain()
+            in_flight.append(chunk)
+        while in_flight:
+            drain()
+        if pings:
+            clock_ping("end")
+    except BaseException:
+        # their copies may never have landed
+        PAGES.discard(kept_keys)
+        raise
+    finally:
+        PAGES.unpin(pinned)
     tm.incr("diff.device.chunks", len(plan))
     tm.incr("diff.device.view_chunks", view_chunks)
     tm.incr("diff.device.hidden_programs", hidden_programs)
+    tm.incr("diff.device.resident_hit_bytes", resident_bytes)
+    tm.incr("diff.device.resident_put_bytes", kept_bytes)
     tm.annotate_span(
         "diff.classify", chunks=len(plan), view_chunks=view_chunks,
         landed_ahead=landed_ahead, hidden_programs=hidden_programs,
+        input_bytes=input_bytes, resident_bytes=resident_bytes,
     )
     return (
         old_class,
@@ -958,48 +1161,29 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     )
 
 
-def _split_columns(block, lo=0, hi=None, size=None):
-    """(keys body, keys tail, oids body, oids tail): rows ``lo:hi`` of the
-    block's two columns (the whole block by default) as the device programs
-    take them, ``size`` padded rows in all (the rows' own bucket by
-    default). The body is the first ``bucket_body(size)`` rows, the tail
-    the rest. Both are views of the caller's arrays (the sidecar's mmap'd
-    pages, read-only and unaligned as they come) where the block has all of
-    ``size`` rows there: a chunk that fills its bucket, or the end of a
-    block that arrives padded. A side that comes short of the bucket gets a
-    freshly made tail — its last rows, then ``PAD_KEY`` / zero oids — and
-    one that comes short of the body (a hole in the key range wider than a
-    step of the bucket grid, or a side that has run out) a fresh body too.
-    What was copied is what owns its data."""
+def _page_parts(values, count, lo, rows):
+    """Rows ``lo : lo + rows`` of a block's column (``count`` real rows), as
+    the host arrays that are put for that page. One view of the caller's
+    array (the sidecar's mmap'd pages, read-only and unaligned as they
+    come) where it has all of them: a full page, or the end of a block that
+    arrives padded. Else — a revision's last page — two: its rows at their
+    own bucket, the first ``bucket_body`` of them a view and the rest, one
+    step of the bucket grid, freshly made and padded (``PAD_KEY`` / zero
+    oids); the device joins them and pads the page out
+    (:func:`_resident_page`). What was copied is what owns its data."""
     from kart_tpu.ops.blocks import PAD_KEY, bucket_body, bucket_size
 
-    if hi is None:
-        hi = block.count
-    if size is None:
-        size = bucket_size(max(hi - lo, 1))
+    if len(values) - lo >= rows:
+        return (values[lo : lo + rows],)
+    size = bucket_size(max(count - lo, 1))
     body = bucket_body(size)
-    keys, oids = block.keys, block.oids
-    cut = lo + body
-    # rows past ``hi`` are the next chunk's; past the block's count they are
-    # the block's own padding
-    have = len(keys) - lo if hi >= block.count else hi - lo
-    if have >= size:
-        return keys[lo:cut], keys[cut : lo + size], oids[lo:cut], oids[cut : lo + size]
-
-    def fresh(start, rows):
-        # rows ``start:hi``, then padding
-        n = max(hi - start, 0)
-        keys_part = np.full(rows, PAD_KEY, dtype=np.int64)
-        keys_part[:n] = keys[start : start + n]
-        oids_part = np.zeros((rows, 5), dtype=np.uint32)
-        oids_part[:n] = oids[start : start + n]
-        return keys_part, oids_part
-
-    keys_tail, oids_tail = fresh(cut, size - body)
-    if hi >= cut:
-        return keys[lo:cut], keys_tail, oids[lo:cut], oids_tail
-    keys_body, oids_body = fresh(lo, body)
-    return keys_body, keys_tail, oids_body, oids_tail
+    tail = np.full(
+        (size - body,) + values.shape[1:],
+        PAD_KEY if values.ndim == 1 else 0,
+        dtype=values.dtype,
+    )
+    tail[: count - lo - body] = values[lo + body : count]
+    return values[lo : lo + body], tail
 
 
 def classify_blocks_host(old_block, new_block):

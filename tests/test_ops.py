@@ -1030,35 +1030,33 @@ def test_window_join_random_runs_match_reference(seed, monkeypatch):
 
 @pytest.mark.parametrize("n", [700, _BODY_5120 + 1, 5000, 5120, 9000])
 @pytest.mark.parametrize("shape", [None, _padded, _as_sidecar_view])
-def test_split_columns_copies_one_tail_and_views_the_rest(n, shape):
-    """``_split_columns``: body + tail spell the padded block row for row,
-    the body is the caller's own memory, shapes depend on the bucket alone,
-    and what is copied is at most one grid step of 28-byte rows."""
-    from kart_tpu.ops.diff_kernel import _split_columns
+def test_page_parts_copy_one_tail_and_view_the_rest(n, shape):
+    """``_page_parts`` of a revision of one page: the parts spell the padded
+    block row for row; a block that fills its bucket, padded or by its own
+    rows, is one view; any other is a body that is the caller's own memory
+    and one freshly padded grid step of the column's rows."""
+    from kart_tpu.ops.diff_kernel import _page_parts, page_rows
 
     block = _sorted_block(n, seed=5)
     if shape is not None:
         block = shape(block)
     bucket = bucket_size(n)
+    assert page_rows(n) == bucket  # far below a chunk: its own bucket
     body = bucket_body(bucket)
-    keys_body, keys_tail, oids_body, oids_tail = _split_columns(block)
-    assert keys_body.shape == (body,) and keys_tail.shape == (bucket - body,)
-    assert oids_body.shape == (body, 5) and oids_tail.shape == (bucket - body, 5)
     want = _padded(block)
-    np.testing.assert_array_equal(np.concatenate([keys_body, keys_tail]), want.keys)
-    np.testing.assert_array_equal(np.concatenate([oids_body, oids_tail]), want.oids)
-    if body:
-        assert np.shares_memory(keys_body, block.keys)
-        assert np.shares_memory(oids_body, block.oids)
-    copied = sum(
-        a.nbytes for a in (keys_body, keys_tail, oids_body, oids_tail)
-        if a.flags.owndata
-    )
-    # a block that fills its bucket, padded or by its own rows, has its
-    # tail in place too
     whole = len(block.keys) >= bucket
+    copied = 0
+    for values, padded, row_bytes in ((block.keys, want.keys, 8), (block.oids, want.oids, 20)):
+        parts = _page_parts(values, block.count, 0, bucket)
+        np.testing.assert_array_equal(np.concatenate(parts), padded)
+        copied += sum(a.nbytes for a in parts if a.flags.owndata)
+        if whole:
+            assert len(parts) == 1 and np.shares_memory(parts[0], values)
+        else:
+            assert [len(a) for a in parts] == [body, bucket - body]
+            assert parts[1].flags.owndata and parts[1].nbytes == (bucket - body) * row_bytes
+            assert not body or np.shares_memory(parts[0], values)
     assert copied == (0 if whole else (bucket - body) * 28)
-    assert np.shares_memory(keys_tail, block.keys) == whole
 
 
 def test_device_classify_pack_span_counts_only_the_tails(monkeypatch):
@@ -1099,54 +1097,78 @@ def _bulk_insert_case():
 
 
 #: name -> (builder of (old, new), chunks, chunks the sort-join answers on
-#: the windowed route, chunks that copy nothing)
+#: the windowed route)
 CHUNK_CASES = {
     # three full chunks and a ragged last one
     "aligned_sides": (
-        lambda: (b := _sorted_block(35_000, seed=61), _rewritten(b)), 4, [], 3,
+        lambda: (b := _sorted_block(35_000, seed=61), _rewritten(b)), 4, [],
     ),
     "aligned_sidecar_views": (
         lambda: (
             _as_sidecar_view(b := _sorted_block(35_000, seed=61)),
             _as_sidecar_view(_rewritten(b)),
         ),
-        4, [], 3,
+        4, [],
     ),
     "uniform_churn": (
-        lambda: (b := _sorted_block(35_000, seed=62), _churned(b, 0.05, 8)), 4, [], 0,
+        lambda: (b := _sorted_block(35_000, seed=62), _churned(b, 0.05, 8)), 4, [],
     ),
-    # 800 rows gone inside chunk 1: more than a window holds at any offset,
-    # less than a step of the bucket grid (the new side keeps its body a view)
+    # 800 rows gone inside chunk 1: more than a window holds at any offset
     "hole_inside_a_chunk": (
         lambda: (b := _sorted_block(35_000, seed=63), _without(b, 12_000, 12_800)),
-        4, [1], 2,
+        4, [1],
     ),
     # the hole ends chunk 0 and starts chunk 1: no tile has it inside its span
     "hole_across_a_boundary": (
         lambda: (b := _sorted_block(35_000, seed=64), _without(b, _CHUNK - 400, _CHUNK + 400)),
-        4, [], 1,
+        4, [],
     ),
     # 1,500 rows gone: the new side of chunk 1 is shorter than the bucket's
-    # body and is copied whole
+    # body (copied whole before the chunks were cut from pages)
     "hole_wider_than_a_grid_step": (
         lambda: (b := _sorted_block(35_000, seed=65), _without(b, 12_000, 13_500)),
-        4, [1], 2,
+        4, [1],
     ),
     # 25,000 keys inserted between two old keys: chunks 1 and 2 have no old rows
-    "bulk_insert_empty_chunks": (_bulk_insert_case, 4, [], 0),
+    "bulk_insert_empty_chunks": (_bulk_insert_case, 4, []),
     "empty_old_side": (
-        lambda: (_sorted_block(0, seed=67), _sorted_block(25_000, seed=67)), 3, [], 0,
+        lambda: (_sorted_block(0, seed=67), _sorted_block(25_000, seed=67)), 3, [],
     ),
     "one_chunk": (
-        lambda: (b := _sorted_block(5_000, seed=68), _churned(b, 0.05, 9)), 1, [], 0,
+        lambda: (b := _sorted_block(5_000, seed=68), _churned(b, 0.05, 9)), 1, [],
     ),
-    # blocks that arrive padded to their bucket: the last chunk's tails are
-    # the blocks' own padding, in place
+    # blocks that arrive padded to their bucket
     "padded_blocks": (
         lambda: (_padded(b := _sorted_block(35_000, seed=69)), _padded(_churned(b, 0.05, 10))),
-        4, [], 1,
+        4, [],
     ),
 }
+
+
+def _page_traffic(plan, blocks, chunk_rows):
+    """What each chunk of ``plan`` costs in bytes by the rule alone ->
+    [(copied on the host, put)]: a chunk puts every page its rows lie in
+    that no earlier chunk has put; a full page is a view; a revision's last
+    page is put at its own bucket, one grid step of it copied."""
+    from kart_tpu.ops.diff_kernel import page_rows
+
+    seen, costs = set(), []
+    for *sides, _ in plan:
+        copied = put = 0
+        for s, (block, (lo, hi)) in enumerate(zip(blocks, sides)):
+            rows = page_rows(block.count, chunk_rows)
+            for page in range(lo // rows, -(-hi // rows) if hi > lo else 0):
+                if (s, page) in seen:
+                    continue
+                seen.add((s, page))
+                if len(block.keys) - page * rows >= rows:
+                    put += 28 * rows
+                    continue
+                size = bucket_size(block.count - page * rows)
+                put += 28 * size
+                copied += 28 * (size - bucket_body(size))
+        costs.append((copied, put))
+    return costs
 
 
 @pytest.mark.parametrize("route", ["sort", "window"])
@@ -1163,7 +1185,7 @@ def test_device_classify_chunks_match_reference(case, route, monkeypatch):
     from kart_tpu.ops import diff_kernel
     from kart_tpu.ops.diff_kernel import classify_chunk_plan, join_census_reference
 
-    build, n_chunks, sorted_chunks, view_chunks = CHUNK_CASES[case]
+    build, n_chunks, sorted_chunks = CHUNK_CASES[case]
     old, new = build()
     ref_old, ref_new = classify_blocks_reference(old, new)
     monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
@@ -1172,6 +1194,8 @@ def test_device_classify_chunks_match_reference(case, route, monkeypatch):
         monkeypatch.setattr(runtime, "default_backend", lambda: "tpu")
     plan = classify_chunk_plan(old, new)
     assert len(plan) == n_chunks
+    traffic = _page_traffic(plan, (old, new), _CHUNK)
+    view_chunks = sum(not copied for copied, _ in traffic)
     tm.reset()
     tm.enable(metrics=True, trace=True)
     try:
@@ -1219,11 +1243,16 @@ def test_device_classify_chunks_match_reference(case, route, monkeypatch):
         for c in range(n_chunks):
             assert starts["diff.device.transfer"][c] < starts["diff.device.kernel"][c]
             assert starts["diff.device.kernel"][c] < starts["diff.device.fetch"][c]
+        # each page is shipped once, by the first chunk that reads it
         assert [e["args"]["bytes"] for e in by_name["diff.device.enqueue"]] == [
             e["args"]["bytes"] for e in by_name["diff.device.transfer"]
-        ] == [28 * sum(sizes) for *_, sizes in plan]
+        ] == [put for _, put in traffic]
+        assert not any(e["args"]["resident"] for e in by_name["diff.device.transfer"])
     kernels = [e["args"] for e in device if e["name"] == "diff.device.kernel"]
     packs = [e["args"] for e in device if e["name"] == "diff.device.pack"]
+    # only a revision's last page costs a host copy: one grid step a column
+    assert [pack["bytes"] for pack in packs] == [copied for copied, _ in traffic]
+    assert sum(copied for copied, _ in traffic) <= 2 * 28 * _CHUNK // 8
     for c, ((old_rows, new_rows, sizes), kernel, pack) in enumerate(zip(plan, kernels, packs)):
         assert kernel["program"] == (
             "window_join" if route == "window" and c not in sorted_chunks else "sort_join"
@@ -1246,12 +1275,6 @@ def test_device_classify_chunks_match_reference(case, route, monkeypatch):
     assert counters.get("diff.device.join_overflows", 0) == (
         len(sorted_chunks) if route == "window" else 0
     )
-    if case == "hole_wider_than_a_grid_step":
-        # chunk 1: the old side two views, the new side four fresh arrays
-        assert packs[1]["bytes"] == 28 * plan[1][2][1]
-    if case == "hole_inside_a_chunk":
-        # chunk 1: the new side's body a view, one fresh tail a column
-        assert packs[1]["bytes"] == 28 * (bucket_size(_CHUNK) - bucket_body(bucket_size(_CHUNK)))
     if case == "bulk_insert_empty_chunks":
         assert [rows[0][1] - rows[0][0] for rows in plan] == [6_000, 0, 0, 6_000]
 
@@ -1384,7 +1407,7 @@ def test_what_the_copy_hid_is_counted_a_chunk_at_a_time(case, route, layers, mon
     from kart_tpu import runtime
     from kart_tpu.ops import diff_kernel
 
-    build, n_chunks, _, _ = CHUNK_CASES[case]
+    build, n_chunks, _ = CHUNK_CASES[case]
     old, new = build()
     monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
     monkeypatch.setenv("KART_DIFF_DEVICE", "1")
@@ -1414,60 +1437,56 @@ def test_what_the_copy_hid_is_counted_a_chunk_at_a_time(case, route, layers, mon
     assert 0 <= classify["landed_ahead"] <= most
 
 
-def test_full_chunk_goes_over_as_views():
-    """A chunk that fills its bucket on both sides hands the device eight
-    arrays that own no data: the caller's pages (a sidecar's mapping,
-    unaligned and read-only), the first byte to the last."""
-    from kart_tpu.ops.diff_kernel import _split_columns, classify_chunk_plan
+def test_full_pages_go_over_as_views():
+    """Every page of a revision but its last is handed to the device as one
+    array a column that owns no data: the caller's pages (a sidecar's
+    mapping, unaligned and read-only), the first byte to the last. The last
+    page is a body view and one fresh tail a column."""
+    from kart_tpu.ops.diff_kernel import _page_parts, page_rows
 
-    old = _as_sidecar_view(_sorted_block(35_000, seed=61))
-    new = _as_sidecar_view(_rewritten(old))
-    plan = classify_chunk_plan(old, new, _CHUNK)
-    assert [sizes for *_, sizes in plan] == [(_CHUNK, _CHUNK)] * 3 + [(4608, 4608)]
-    for old_rows, new_rows, sizes in plan[:-1]:
-        for block, (lo, hi), size in ((old, old_rows, sizes[0]), (new, new_rows, sizes[1])):
-            arrays = _split_columns(block, lo, hi, size)
-            assert not any(a.flags.owndata for a in arrays)
-            keys_body, keys_tail, oids_body, oids_tail = arrays
-            assert np.shares_memory(keys_body, block.keys)
-            assert np.shares_memory(oids_tail, block.oids)
-            np.testing.assert_array_equal(
-                np.concatenate([keys_body, keys_tail]), block.keys[lo:hi]
-            )
-            np.testing.assert_array_equal(
-                np.concatenate([oids_body, oids_tail]), block.oids[lo:hi]
-            )
-    # the ragged last chunk: body views, one fresh tail a column
-    (lo, hi), _, (size, _) = plan[-1]
-    arrays = _split_columns(old, lo, hi, size)
-    assert [a.flags.owndata for a in arrays] == [False, True, False, True]
-    assert arrays[1][hi - lo - bucket_body(size)] == PAD_KEY
+    block = _as_sidecar_view(_sorted_block(35_000, seed=61))
+    rows = page_rows(block.count, _CHUNK)
+    assert rows == _CHUNK
+    for page in range(3):
+        lo = page * rows
+        for values in (block.keys, block.oids):
+            (view,) = _page_parts(values, block.count, lo, rows)
+            assert not view.flags.owndata and not view.flags.writeable
+            assert np.shares_memory(view, values)
+            np.testing.assert_array_equal(view, values[lo : lo + rows])
+    lo, size = 3 * rows, bucket_size(35_000 - 3 * rows)
+    keys_body, keys_tail = _page_parts(block.keys, block.count, lo, rows)
+    oids_body, oids_tail = _page_parts(block.oids, block.count, lo, rows)
+    assert [a.flags.owndata for a in (keys_body, keys_tail, oids_body, oids_tail)] == [
+        False, True, False, True,
+    ]
+    assert len(keys_body) == len(oids_body) == bucket_body(size)
+    assert len(keys_tail) == len(oids_tail) == size - bucket_body(size)
+    assert keys_tail[35_000 - lo - bucket_body(size)] == PAD_KEY
 
 
-@pytest.mark.parametrize("n,lo,hi,size", [
-    (30_000, 10_240, 20_480, 10_240),  # full: views
-    (30_000, 10_240, 20_000, 10_240),  # short of the bucket: fresh tail
-    (30_000, 10_240, 19_000, 10_240),  # short of the body: all fresh
-    (30_000, 10_240, 10_240, 10_240),  # empty
-    (30_000, 29_000, 30_000, 1_024),  # bodyless bucket
+@pytest.mark.parametrize("n,lo,rows", [
+    (30_000, 10_240, 10_240),  # a full page: one view
+    (30_000, 20_480, 10_240),  # the last page, nearly full: a body and a tail
+    (25_000, 20_480, 10_240),  # the last page at a smaller bucket
+    (20_481, 20_480, 10_240),  # one row: a bodyless bucket
+    (31_000, 30_720, 10_240),  # under the minimum bucket
 ])
-def test_split_columns_of_a_row_range(n, lo, hi, size):
-    """``_split_columns`` over a row range spells the padded chunk row for
-    row, never reads past ``hi``, and copies only what comes short."""
-    from kart_tpu.ops.diff_kernel import _split_columns
+def test_page_parts_of_a_page(n, lo, rows):
+    """``_page_parts`` of one page of a longer revision spells the page's
+    rows at their own bucket, padded from the revision's count on, reads
+    nothing past it, and copies only one step of the bucket grid."""
+    from kart_tpu.ops.diff_kernel import _page_parts
 
     block = _sorted_block(n, seed=6)
-    keys_body, keys_tail, oids_body, oids_tail = _split_columns(block, lo, hi, size)
-    body = bucket_body(size)
-    assert keys_body.shape == (body,) and keys_tail.shape == (size - body,)
-    assert oids_body.shape == (body, 5) and oids_tail.shape == (size - body, 5)
-    keys = np.concatenate([keys_body, keys_tail])
-    oids = np.concatenate([oids_body, oids_tail])
-    np.testing.assert_array_equal(keys[: hi - lo], block.keys[lo:hi])
-    np.testing.assert_array_equal(oids[: hi - lo], block.oids[lo:hi])
-    assert np.all(keys[hi - lo :] == PAD_KEY) and not np.any(oids[hi - lo :])
-    copied = sum(
-        a.nbytes for a in (keys_body, keys_tail, oids_body, oids_tail) if a.flags.owndata
-    )
-    rows = hi - lo
-    assert copied == 28 * (0 if rows == size else size - body if rows >= body else size)
+    have = min(n - lo, rows)
+    size = rows if have == rows else bucket_size(have)
+    for values, fill in ((block.keys, PAD_KEY), (block.oids, 0)):
+        parts = _page_parts(values, block.count, lo, rows)
+        page = np.concatenate(parts)
+        assert len(page) == size <= rows
+        np.testing.assert_array_equal(page[:have], values[lo : lo + have])
+        assert np.all(page[have:] == fill)
+        copied = sum(a.nbytes for a in parts if a.flags.owndata)
+        row_bytes = values[:1].nbytes
+        assert copied == (0 if have == rows else (size - bucket_body(size)) * row_bytes)

@@ -143,38 +143,39 @@ def test_classify_mergesort_compiles(one_chip, bucket):
     assert _device_bytes(compiled) < 16e9
 
 
+def _page_columns(rows, one_chip):
+    """The four arrays a side of a chunk is cut from: its keys' page and
+    the page after, its oids' page and the page after, ``rows`` rows each."""
+    return [
+        _shape(shape, dtype, one_chip)
+        for shape, dtype in (((rows,), np.int64), ((rows, 5), np.uint32))
+        for _ in range(2)
+    ]
+
+
 @pytest.mark.parametrize(
     "bucket",
     [
-        1024,  # the minimum bucket: an empty body, the whole of it the tail
-        1152,  # the smallest bucket with a body (1024 rows + a 128-row step)
+        1024,  # the minimum bucket: a revision of one page, the chunk all of it
+        1152,  # the smallest bucket above it
         # a full chunk of the device route: what an overflowing chunk is
         # re-joined at on a TPU (the sort compiles for a minute)
         pytest.param(bucket_size(CLASSIFY_CHUNK_ROWS), marks=pytest.mark.slow),
     ],
 )
 def test_classify_split_entry_compiles(one_chip, bucket):
-    """The sort-join's jitted entry — each column a body and a tail,
-    joined on the device — under the name the benchmark's kernel metrics
-    look for, and within the chip's memory with its inputs kept alive."""
+    """The sort-join's jitted entry — each side's chunk cut out of two
+    pages a column on the device — under the name the benchmark's kernel
+    metrics look for, and within the chip's memory with its inputs kept
+    alive."""
     import jax
 
-    from kart_tpu.ops.blocks import bucket_body
     from kart_tpu.ops.diff_kernel import _classify_split
 
-    body = bucket_body(bucket)
-    columns = [
-        _shape(shape, dtype, one_chip)
-        for shape, dtype in (
-            ((body,), np.int64),
-            ((bucket - body,), np.int64),
-            ((body, 5), np.uint32),
-            ((bucket - body, 5), np.uint32),
-        )
-    ]
-    count = _shape((), np.int32, one_chip)
-    lowered = jax.jit(_classify_split.__wrapped__).lower(
-        *columns, *columns, count, count
+    columns = _page_columns(bucket, one_chip)
+    rows = _shape((4,), np.int32, one_chip)
+    lowered = jax.jit(_classify_split.__wrapped__, static_argnames="sizes").lower(
+        *columns, *columns, rows, sizes=(bucket, bucket)
     )
     assert "@jit__classify_mergesort_core_split" in lowered.as_text()
     assert _device_bytes(lowered.compile()) < 16e9
@@ -185,56 +186,52 @@ def _last_chunk_bucket(rows):
     return bucket_size(rows % CLASSIFY_CHUNK_ROWS)
 
 
+_PAGE = bucket_size(CLASSIFY_CHUNK_ROWS)  # rows of a page of a long revision
+
+
 @pytest.mark.parametrize(
-    "bucket,new_bucket",
+    "pages,sizes",
     [
-        (1024, 1024),
-        (1152, 1152),  # nine 128-row lines: not a whole tile, not a whole grid step
+        # revisions of one page: the chunk is the page
+        ((1024, 1024), (1024, 1024)),
+        ((1152, 1152), (1152, 1152)),  # nine 128-row lines: not a whole tile, not a whole grid step
         # the production shapes are not marked slow: without the sort the
         # program compiles in ~6 s at any bucket. A full chunk of the device
-        # route (every chunk but a call's last, PR 36):
-        (bucket_size(CLASSIFY_CHUNK_ROWS), bucket_size(CLASSIFY_CHUNK_ROWS)),
+        # route (every chunk but a call's last, PR 36) out of full pages:
+        ((_PAGE, _PAGE), (_PAGE, _PAGE)),
         # the last chunk of a 10M-row call, both sides alike
-        (_last_chunk_bucket(10_000_000), _last_chunk_bucket(10_000_000)),
+        ((_PAGE, _PAGE), (_last_chunk_bucket(10_000_000),) * 2),
         # ... and after 0.5% of the keys were deleted all over the old range
         # and as many appended (the churn cell): the sides' buckets differ
-        (_last_chunk_bucket(10_000_000), _last_chunk_bucket(10_050_000)),
+        ((_PAGE, _PAGE), (_last_chunk_bucket(10_000_000), _last_chunk_bucket(10_050_000))),
         # the last chunk of the filtered cell's 2.94M survivors (PR 35)
-        (_last_chunk_bucket(2_942_000), _last_chunk_bucket(2_942_000)),
+        ((_PAGE, _PAGE), (_last_chunk_bucket(2_942_000),) * 2),
+        # a revision of one small page against a long one: the chunk is
+        # longer than the page it is cut from
+        ((4608, _PAGE), (_PAGE, _PAGE)),
     ],
 )
-def test_classify_window_entry_compiles(one_chip, bucket, new_bucket, monkeypatch):
+def test_classify_window_entry_compiles(one_chip, pages, sizes, monkeypatch):
     """The windowed join — what the device route runs a chunk on an
-    accelerator: Mosaic takes the Pallas kernel at the real widths (a slab
-    block at a dynamic 8-line offset, unaligned sublane loads, lane
-    rotations and gathers), the program sorts nothing, keeps the name
-    prefix the benchmark's kernel metrics look for, and fits the chip with
-    its eight inputs kept alive (the overflow branch reuses them)."""
+    accelerator — over a chunk cut out of pages at a dynamic row: Mosaic
+    takes the Pallas kernel at the real widths (a slab block at a dynamic
+    8-line offset, unaligned sublane loads, lane rotations and gathers),
+    the program sorts nothing, keeps the name prefix the benchmark's kernel
+    metrics look for, and fits the chip with its sixteen inputs kept alive
+    (the overflow branch reuses them; resident pages outlive the call)."""
     import jax
 
     from kart_tpu.ops import diff_kernel
-    from kart_tpu.ops.blocks import bucket_body
     from kart_tpu.ops.diff_kernel import _classify_window_split
 
     # the process's backend is the CPU, where the kernel would be traced
     # for the interpreter: here it is lowered for the described chip
     monkeypatch.setattr(diff_kernel, "_join_interpreted", lambda: False)
-
-    def columns(size):
-        body = bucket_body(size)
-        return [
-            _shape(shape, dtype, one_chip)
-            for shape, dtype in (
-                ((body,), np.int64),
-                ((size - body,), np.int64),
-                ((body, 5), np.uint32),
-                ((size - body, 5), np.uint32),
-            )
-        ]
-
-    count = _shape((), np.int64, one_chip)
-    lowered = jax.jit(_classify_window_split.__wrapped__).lower(
-        *columns(bucket), *columns(new_bucket), count, count
+    lowered = jax.jit(
+        _classify_window_split.__wrapped__, static_argnames="sizes"
+    ).lower(
+        *_page_columns(pages[0], one_chip), *_page_columns(pages[1], one_chip),
+        _shape((4,), np.int32, one_chip), sizes=sizes,
     )
     text = lowered.as_text()
     assert "@jit__classify_mergesort_core_window_split" in text
@@ -242,6 +239,30 @@ def test_classify_window_entry_compiles(one_chip, bucket, new_bucket, monkeypatc
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not the interpreter
     assert _device_bytes(compiled) < 16e9
+
+
+@pytest.mark.parametrize("column", [((), np.int64), ((5,), np.uint32)], ids=["keys", "oids"])
+def test_resident_page_compiles_under_its_own_name(one_chip, column):
+    """What makes a revision's last page whole on the device (the 10M-row
+    cells': 562,816 rows put as a body view and a 32,768-row tail, padded
+    out to a page): the chip's compiler takes it, and no
+    ``jit__classify_*`` reader counts it."""
+    import jax
+
+    from kart_tpu.ops.blocks import bucket_body
+    from kart_tpu.ops.diff_kernel import _resident_page
+
+    width, dtype = column
+    size = _last_chunk_bucket(10_000_000)
+    body = bucket_body(size)
+    lowered = jax.jit(_resident_page.__wrapped__, static_argnames="rows").lower(
+        _shape((body,) + width, dtype, one_chip),
+        _shape((size - body,) + width, dtype, one_chip),
+        rows=_PAGE,
+    )
+    assert "jit__resident_page" in lowered.as_text()
+    assert "jit__classify_" not in lowered.as_text()
+    assert lowered.compile().output_shardings is not None
 
 
 def test_clock_probe_compiles_under_its_own_name(one_chip):
