@@ -228,10 +228,12 @@ def test_the_stages_and_the_self_time_add_up_to_the_root():
 
 
 def test_every_new_metric_is_listed_for_the_mesh_cell_alone():
-    assert sorted(MESH_METRICS) == sorted(SPAN_METRICS + TRACE_METRICS)
+    """A later PR may list more metrics for the mesh cell alone; the one-chip
+    kernel's two never list it (the mesh runs another program)."""
+    assert set(SPAN_METRICS + TRACE_METRICS) <= set(MESH_METRICS)
     for name in ("kernel.classify_s", "kernel.classify_roofline"):
         (entry,) = [m for m in MANIFEST["per_layer"] if m["name"] == name]
-        assert entry["workloads"] == ["points10m.diff_count", "polygons10m.diff_jsonl"]
+        assert CELL not in entry["workloads"]
 
 
 @pytest.mark.parametrize("name", SPAN_METRICS + TRACE_METRICS)

@@ -28,6 +28,7 @@ with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
 
 CELLS = ["points10m.diff_count", "polygons10m.diff_jsonl", "nodes10m.diff_count.filtered"]
+MESH = "points10m.diff_count.mesh4"
 PROBE = "jit__clock_probe(7)"
 WINDOW = "jit__classify_mergesort_core_window_split(11)"
 CLOCK = {"anchor_span": "diff.device.clock", "module_prefix": "jit__clock_probe"}
@@ -234,13 +235,16 @@ def test_a_new_metric_is_silent_on_the_parents_trace(name):
 
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_each_new_metric_is_a_file_over_a_reader_and_lists_the_three_cells(name):
+    """``CELLS`` are the cells the metrics came with; a later PR may list a
+    metric for more one-chip cells, never for the mesh cell (its root has no
+    clock pings)."""
     spec = metric_spec(name)
     assert os.path.exists(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
     assert len(spec["what"]) > 40
     # one new reader; every other metric is a file over code that was there
     assert (spec["reader"] == "align_width") == (name == "trace.clock_width_s")
     (entry,) = [m for m in MANIFEST["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == CELLS
+    assert set(CELLS) <= set(entry["workloads"]) and MESH not in entry["workloads"]
     assert entry["moves"] == "diff_wall_s" and "bound" not in entry
     overlaid = name.startswith(("pipeline.idle_", "trace."))
     assert entry["source"] == ("device_trace" if overlaid else "program_span")
@@ -253,9 +257,11 @@ def test_each_new_metric_is_a_file_over_a_reader_and_lists_the_three_cells(name)
         assert CLOCK["module_prefix"] == "jit_" + _clock_probe.__wrapped__.__name__
 
 
-def test_the_new_metrics_are_the_last_entries_and_nothing_else_lists_them():
+def test_the_new_metrics_are_listed_once_each_and_not_for_the_mesh_cell():
+    """By name, not by place: where an entry stands in ``per_layer`` is the
+    driver's to hold (it compares entries by place, so a PR appends), and a
+    test that held the tail would refuse the next appended metric."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-len(NEW_METRICS):] == NEW_METRICS
-    mesh = [m["name"] for m in MANIFEST["per_layer"]
-            if "points10m.diff_count.mesh4" in m.get("workloads", ["points10m.diff_count.mesh4"])]
+    assert [names.count(name) for name in NEW_METRICS] == [1] * len(NEW_METRICS)
+    mesh = [m["name"] for m in MANIFEST["per_layer"] if MESH in m.get("workloads", [MESH])]
     assert not set(mesh) & set(NEW_METRICS)
