@@ -76,7 +76,7 @@ def parse_args(argv=None):
     p.add_argument("--seed", type=int, default=0, help="seed of all data")
     p.add_argument("--rows", type=int, default=10_000_000,
                    help="rows of the diffed point layer (1%% edited)")
-    p.add_argument("--cli-merge-rows", type=int, default=2_100_000,
+    p.add_argument("--cli-merge-rows", type=int, default=4_000_000,
                    help="rows of the repo `kart merge` runs on")
     p.add_argument("--cli-merge-conflicts", type=int, default=1_000)
     p.add_argument("--merge-rows", type=int, default=4_000_000,
@@ -447,11 +447,15 @@ def _merge_blocks(n, conflicts, seed):
 
 
 def phase_merge(smoke):
-    """A 3-way `kart merge` of two conflicting branches whose key union
-    crosses the device gate, and merge_classify on blocks of BASELINE
-    config 5's size — the CLI merge walks three feature trees in Python
-    (most of a minute per 2.1M-row merge), so the million-conflict merge is
-    run at the kernel's own entry point."""
+    """A 3-way `kart merge` of two conflicting branches at BASELINE config
+    5's row count, and merge_classify on blocks with its million conflicts.
+    Since PR 40 the CLI merge reads its three revisions from their sidecars
+    (no feature tree is walked), so the layer is the full size; its
+    conflicts stay few here because this phase writes them feature by
+    feature (`commit_feature_edits`) — the million-conflict repository is
+    the benchmark cell's (`merge4m.conflicts1m`, built in columns by
+    benchmarks/layers/int_pk_merge_layer.py) — and the million-conflict
+    classify is run at the kernel's own entry point."""
     from kart_tpu import telemetry as tm
     from kart_tpu.diff.backend import merge_classify
     from kart_tpu.synth import commit_feature_edits, synth_repo

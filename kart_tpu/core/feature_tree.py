@@ -70,7 +70,8 @@ def plan_int_feature_tree(pks, encoder=None):
     # sort by (leaf, name-bytes): git tree order; zero-padding the key
     # reproduces "a name that is a prefix of another sorts first"
     name_key = b64_mat.copy()
-    name_key[np.arange(b64w)[None, :] >= b64_len[:, None]] = 0
+    if n and int(b64_len.min()) < b64w:
+        name_key[np.arange(b64w)[None, :] >= b64_len[:, None]] = 0
     pad_to = (-b64w) % 8
     if pad_to:
         name_key = np.concatenate(
@@ -115,13 +116,17 @@ def plan_int_feature_tree(pks, encoder=None):
     plan.entry_matrix = out
     plan.fixed_width = uniform
 
-    plan.uniq_leaves, plan.first_idx, plan.counts = np.unique(
-        leaf_ids, return_index=True, return_counts=True
+    # leaf_ids is sorted (the sort's first key): a leaf starts where it changes
+    plan.first_idx = np.concatenate(
+        [np.zeros(min(n, 1), dtype=np.int64),
+         np.flatnonzero(leaf_ids[1:] != leaf_ids[:-1]) + 1]
     )
+    plan.uniq_leaves = leaf_ids[plan.first_idx]
+    plan.counts = np.diff(np.append(plan.first_idx, n))
     plan.byte_offsets = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(entry_lens, out=plan.byte_offsets[1:])
     # sorted-row -> leaf slot (for mapping edited rows to touched leaves)
-    plan.row_of_leaf = np.searchsorted(plan.first_idx, rows, side="right") - 1
+    plan.row_of_leaf = np.repeat(np.arange(len(plan.counts)), plan.counts)
     return plan
 
 
@@ -271,6 +276,91 @@ def build_int_feature_tree(odb, pks, oids_u8, encoder=None):
         return odb.write_tree([])
     oid, _ = emit_feature_tree(odb, plan, oids_u8)
     return oid
+
+
+class _TreeNamer:
+    """Stands in for the object store where a tree's name alone is wanted:
+    every tree object is hashed, none is kept."""
+
+    _bulk_writer = None
+
+    @staticmethod
+    def write_raw(obj_type, content):
+        from kart_tpu.core.objects import hash_object
+
+        return hash_object(obj_type, content)
+
+
+#: rows a batch of :func:`write_int_feature_tree`'s leaf stream: its arrays
+#: (11 MB of payloads, as much again framed) stay under the allocator's
+#: 32 MB mmap ceiling, so every batch after the first reuses the heap the
+#: one before freed. A whole 4M-row column at once asks the kernel for
+#: ~1.5 GB of fresh pages a call, which on a shared host is both most of the
+#: time and most of its run-to-run spread (PERF.md §6, PR 40)
+LEAF_STREAM_ROWS = 262_144
+
+
+def _stream_leaf_trees(batches, encoder):
+    """The leaf trees of (pk, oid) columns that come in key order, made and
+    named batch by batch in the native IO core
+    (:class:`StreamingLeafEmitter`, ``native.pack_records_base``): -> (the
+    emitter, [framed record batches]) where a batch's first member is its
+    leaves' oids, or None where the stream does not apply (no native core,
+    pks not strictly ascending or out of the encoder's range) and the plan
+    has to do it."""
+    from kart_tpu import native
+    from kart_tpu.core.packs import TYPE_CODES
+
+    stream = StreamingLeafEmitter(encoder)
+    framed = []
+
+    def frame(batch):
+        if batch is not None:
+            buf, offsets, _ = batch
+            framed.append(
+                native.pack_records_base("tree", TYPE_CODES["tree"], buf, offsets, 0)
+            )
+
+    for pks, oids_u8 in batches:
+        if not (stream.ok and stream._native):
+            return None
+        for lo in range(0, len(pks), LEAF_STREAM_ROWS):
+            frame(stream.feed(pks[lo : lo + LEAF_STREAM_ROWS], oids_u8[lo : lo + LEAF_STREAM_ROWS]))
+    frame(stream.finish())
+    if not (stream.ok and stream._native) or any(r is None for r in framed):
+        return None
+    return stream, framed
+
+
+def write_int_feature_tree(odb, batches, encoder=None):
+    """:func:`build_int_feature_tree` for a tree that may be there already
+    (a merge tried again, a dry run repeated): the tree objects are named
+    first, in memory, and written — one pack, stored — only where ``odb``
+    does not hold the root. A store holds a tree with all beneath it (a pack
+    appears whole, its root written last), so the root answers for the
+    rest. ``batches()`` -> the columns as (pks int64, oids (n, 20) uint8)
+    pairs, together not empty. Batches in key order take the native leaf
+    stream as they come (:func:`_stream_leaf_trees`: nothing of the layer's
+    size is held but the trees); any other columns the plan, whole.
+    -> feature tree hex oid."""
+    streamed = _stream_leaf_trees(batches(), encoder)
+    if streamed is None:
+        pks, oids_u8 = (np.concatenate(column) for column in zip(*batches()))
+        plan = plan_int_feature_tree(pks, encoder)
+        root, _ = emit_feature_tree(_TreeNamer, plan, oids_u8)
+        if not odb.contains(root):
+            with odb.bulk_pack(level=0):
+                emit_feature_tree(odb, plan, oids_u8)
+        return root
+    stream, framed = streamed
+    leaf_oids = [records[0] for records in framed]
+    root = stream.build_root(_TreeNamer, leaf_oids)
+    if not odb.contains(root):
+        with odb.bulk_pack(level=0) as writer:
+            for records in framed:
+                writer.append_framed(records)
+            stream.build_root(odb, leaf_oids)
+    return root
 
 
 class StreamingLeafEmitter:

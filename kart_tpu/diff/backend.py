@@ -304,19 +304,23 @@ def _mesh_or_host(n_rows, allow_device):
 
 def merge_classify(ancestor_block, ours_block, theirs_block):
     """FeatureBlock x3 -> (union_keys (U,) int64 np, decision (U,) int8 np,
-    presence (U,) int8 np with bits a=1/o=2/t=4, stats dict).
-
-    Union keys are computed host-side (cheap, sorted inputs) and padded to a
-    bucket so jit shapes are reused. The ``diff.merge_classify`` span names
-    the engine that answered (``backend=`` — the merge twin of the
-    ``diff.classify`` span's attribute).
-    """
-    n_max = max(ancestor_block.count, ours_block.count, theirs_block.count)
-    with tm.span("diff.merge_classify", rows=n_max) as span:
-        result, backend = _merge_classify_routed(
-            ancestor_block, ours_block, theirs_block, n_max
-        )
-        span.set(backend=backend)
+    presence (U,) int8 np with bits a=1/o=2/t=4, stats dict), the same on
+    every engine. The ``diff.merge_classify`` span names the engine that
+    answered (``backend=`` — the merge twin of the ``diff.classify`` span's
+    attribute) and carries the call's census: ``rows_ancestor``,
+    ``rows_ours``, ``rows_theirs``, ``union``, ``conflicts``,
+    ``take_theirs``."""
+    blocks = (ancestor_block, ours_block, theirs_block)
+    n_max = max(b.count for b in blocks)
+    with tm.span(
+        "diff.merge_classify",
+        rows=n_max,
+        rows_ancestor=ancestor_block.count,
+        rows_ours=ours_block.count,
+        rows_theirs=theirs_block.count,
+    ) as span:
+        result, backend = _merge_classify_routed(*blocks, n_max)
+        span.set(backend=backend, union=len(result[0]), **result[3])
     return result
 
 
@@ -324,15 +328,11 @@ def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
     """-> (merge_classify's result, the name of the backend that produced
     it): mesh when it exists and pays, one device when profitable, the host
     engine otherwise and beneath every device rung."""
-    from kart_tpu.ops.blocks import PAD_KEY, bucket_size
     from kart_tpu.ops.diff_kernel import note_device_fallback
     from kart_tpu.ops.merge_kernel import (
-        CONFLICT,
-        MERGE_STREAMED_MIN_ROWS,
-        TAKE_THEIRS,
         _merge_classify_np,
-        _merge_classify_padded,
-        merge_classify_streamed,
+        decision_stats,
+        merge_classify_two_diffs,
     )
 
     if routing.mesh_open(n_max):
@@ -352,74 +352,35 @@ def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
         except Exception as e:
             note_device_fallback("merge_sharded", e, "single-chip path")
 
-    if n_max >= MERGE_STREAMED_MIN_ROWS and routing.device_open(n_max):
-        from kart_tpu.runtime import default_backend
-
-        if default_backend() != "cpu":
-            # accelerator at north-star scale: chunked double-buffered
-            # upload instead of one monolithic 3-block transfer
-            try:
-                return (
-                    with_pages_let_go(
-                        lambda: merge_classify_streamed(
-                            ancestor_block, ours_block, theirs_block
-                        )
-                    ),
-                    "device_jax",
-                )
-            except Exception as e:
-                note_device_fallback("merge_streamed", e, "monolithic path")
-
-    a_real = ancestor_block.keys[: ancestor_block.count]
-    o_real = ours_block.keys[: ours_block.count]
-    t_real = theirs_block.keys[: theirs_block.count]
-    union = np.union1d(np.union1d(a_real, o_real), t_real).astype(np.int64)
-    u = len(union)
-
-    def on_host():
-        decision, presence = _merge_classify_np(
-            ancestor_block, ours_block, theirs_block, union
-        )
-        return (
-            union,
-            decision,
-            presence,
-            {
-                "conflicts": int(np.sum(decision == CONFLICT)),
-                "take_theirs": int(np.sum(decision == TAKE_THEIRS)),
-            },
-        ), "host_native"
-
     # same cost model as classify_blocks: small merges never pay backend
     # init / compile, and XLA-CPU backends route to the host path (where the
     # native/numpy engines win at every size)
-    if not routing.device_open(u):
-        return on_host()
-
-    size = bucket_size(max(u, 1))
-    union_padded = np.full(size, PAD_KEY, dtype=np.int64)
-    union_padded[:u] = union
-
-    try:
-        decision, presence, n_conf, n_theirs = with_pages_let_go(
-            lambda: _merge_classify_padded(
-                ancestor_block.keys, ancestor_block.oids, ancestor_block.count,
-                ours_block.keys, ours_block.oids, ours_block.count,
-                theirs_block.keys, theirs_block.oids, theirs_block.count,
-                union_padded, u,
+    if routing.device_open(n_max):
+        try:
+            return (
+                with_pages_let_go(
+                    lambda: merge_classify_two_diffs(
+                        ancestor_block, ours_block, theirs_block
+                    )
+                ),
+                "device_jax",
             )
-        )
-    except Exception as e:
-        # device OOM / runtime failure mid-call: the merge must still
-        # complete (same guarantee classify_blocks gives the diff path)
-        note_device_fallback("merge_device", e, "host path")
-        return on_host()
-    return (
-        union,
-        np.asarray(decision)[:u],
-        np.asarray(presence)[:u],
-        {"conflicts": int(n_conf), "take_theirs": int(n_theirs)},
-    ), "device_jax"
+        except Exception as e:
+            # device OOM / runtime failure mid-call: the merge must still
+            # complete (same guarantee classify_blocks gives the diff path)
+            note_device_fallback("merge_device", e, "host path")
+
+    union = np.union1d(
+        np.union1d(
+            ancestor_block.keys[: ancestor_block.count],
+            ours_block.keys[: ours_block.count],
+        ),
+        theirs_block.keys[: theirs_block.count],
+    ).astype(np.int64)
+    decision, presence = _merge_classify_np(
+        ancestor_block, ours_block, theirs_block, union
+    )
+    return (union, decision, presence, decision_stats(decision)), "host_native"
 
 
 # --- sharded bbox prefilter kernel ------------------------------------------

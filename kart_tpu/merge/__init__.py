@@ -18,6 +18,7 @@ import logging
 
 import numpy as np
 
+from kart_tpu import telemetry as tm
 from kart_tpu.core.repo import (
     MERGE_BRANCH,
     MERGE_HEAD,
@@ -38,7 +39,7 @@ from kart_tpu.merge.index import (
     PkLabels,
     RowPaths,
 )
-from kart_tpu.ops.blocks import FeatureBlock, unpack_oid_hex
+from kart_tpu.ops.blocks import FeatureBlock, oid_rows_u8, unpack_oid_hex
 from kart_tpu.ops.merge_kernel import CONFLICT, KEEP_OURS, TAKE_THEIRS
 
 
@@ -71,21 +72,44 @@ class MergeResult:
         return self.merge_index is not None and bool(self.merge_index.conflicts)
 
 
-def _dataset_blocks(structures, ds_path):
-    """Per-version FeatureBlock for ds_path (absent dataset -> empty block)."""
+def _dataset_blocks(repo, structures, ds_path):
+    """Per-version FeatureBlock for ds_path (absent dataset -> empty block),
+    read as ``kart diff`` reads a revision: the feature tree's KCOL sidecar,
+    keys and oids mapped and not copied, the block stamped with its tree's
+    oid (so the device classify keeps and finds its pages,
+    ops/resident.py). A tree with no sidecar yet is walked once and its
+    sidecar saved (:func:`kart_tpu.diff.sidecar.ensure_block`); counter
+    ``merge.tree_walk_rows`` says how many rows such walks read."""
+    from kart_tpu.diff import sidecar
+
     blocks = []
     datasets = []
-    for structure in structures:
-        ds = structure.datasets.get(ds_path) if structure.tree is not None else None
-        datasets.append(ds)
-        if ds is None:
-            blocks.append(
-                FeatureBlock.from_arrays(
+    walked = 0
+    with tm.span("merge.load_blocks") as span:
+        for structure in structures:
+            ds = structure.datasets.get(ds_path) if structure.tree is not None else None
+            datasets.append(ds)
+            block = None
+            if ds is not None and ds.feature_tree is not None:
+                walks = not sidecar.has_sidecar(repo, ds)
+                block = sidecar.ensure_block(repo, ds, pad=False)
+                if block is None:
+                    # no sidecar could be written or read back
+                    block = FeatureBlock.from_dataset(ds)
+                if walks:
+                    walked += block.count
+            if block is None:
+                block = FeatureBlock.from_arrays(
                     np.zeros(0, dtype=np.int64), np.zeros((0, 5), np.uint32), []
                 )
-            )
-        else:
-            blocks.append(FeatureBlock.from_dataset(ds))
+            blocks.append(block)
+        span.set(
+            rows_ancestor=blocks[0].count,
+            rows_ours=blocks[1].count,
+            rows_theirs=blocks[2].count,
+            source="tree_walk" if walked else "sidecar",
+        )
+    tm.incr("merge.tree_walk_rows", walked)
     return blocks, datasets
 
 
@@ -118,11 +142,126 @@ def _feature_label(ds_path, datasets, rel_paths):
     return f"{ds_path}:feature:{rel}"
 
 
-def _merge_dataset_features(ds_path, structures, tree_builder):
+#: An int-pk layer's merged feature tree is made whole from the merged
+#: (pk, oid) columns where theirs' clean changes number at least one in this
+#: many of ours' rows; below it they go into the tree builder path by path.
+#: Whole, the tree costs a fixed time a row of the layer whatever changed
+#: (names, tree order, one hash a leaf: the native leaf stream of
+#: core/feature_tree.py) and is written as one pack, or not at all where the
+#: store holds it; by path every touched leaf is parsed and written again
+#: as a loose object at the flush. Measured at 4M rows with the native leaf
+#: stream, on two hosts (PERF.md section 5, PR 40): the two cross at one row
+#: in 900-1,100 where a loose object costs 0.3 ms to write, and at one in
+#: 1,800 (objects there) to above one in 4,050 (objects written) where it
+#: costs 3 ms, as on the chip's host. Set at the share measured on both
+#: where they disagree least: the whole tree costs the first host 0.5-0.7 s
+#: too much there and saves the second 2.1 s. The by-path side has no cell
+#: in the benchmark (PERF.md section 7).
+REBUILD_MIN_SHARE = 4096
+
+
+def _int_encoder(ds):
+    encoder = getattr(ds, "path_encoder", None)
+    return encoder if getattr(encoder, "scheme", None) == "int" else None
+
+
+def _feature_paths(prefix, block, ds, rows):
+    """Repository paths of ``rows`` of one version, as one column: encoded in
+    a batch from the pks where the path is a function of the pk."""
+    encoder = _int_encoder(ds)
+    if encoder is not None:
+        return EncodedPkPaths(prefix, encoder, block.keys[rows]).batch()
+    return RowPaths(prefix, block.paths, rows).batch()
+
+
+def _merged_batches(o_block, t_block, rewritten, rewriting, removed, added):
+    """The merged (pk, oid) columns of an int-pk layer in key order, a batch
+    of ours' rows at a time: -> (pks int64, oids uint8 (n, 20)) per batch.
+    ``rewritten`` are ours' rows theirs rewrote and ``rewriting`` theirs'
+    rows that did, ``removed`` ours' rows theirs deleted, ``added`` theirs'
+    rows ours lacks — all ascending. A batch at a time, so that nothing of
+    the layer's size is copied whole (core/feature_tree.py
+    ``LEAF_STREAM_ROWS`` says why that matters)."""
+    from kart_tpu.core.feature_tree import LEAF_STREAM_ROWS
+
+    n = o_block.count
+    o_keys = o_block.keys[:n]
+    new_oids = oid_rows_u8(t_block.oids[rewriting])
+    added_keys, added_oids = t_block.keys[added], oid_rows_u8(t_block.oids[added])
+    taken = 0  # of the added keys
+    for lo in range(0, n, LEAF_STREAM_ROWS):
+        hi = min(lo + LEAF_STREAM_ROWS, n)
+        pks, oids = o_keys[lo:hi], oid_rows_u8(o_block.oids[lo:hi])  # oids: a copy
+        first, last = np.searchsorted(rewritten, (lo, hi))
+        oids[rewritten[first:last] - lo] = new_oids[first:last]
+        first, last = np.searchsorted(removed, (lo, hi))
+        if last > first:
+            keep = np.ones(hi - lo, dtype=bool)
+            keep[removed[first:last] - lo] = False
+            pks, oids = pks[keep], oids[keep]
+        # theirs' new keys that sort before ours' next batch
+        upto = len(added_keys) if hi == n else int(np.searchsorted(added_keys, o_keys[hi]))
+        if upto > taken:
+            at = np.searchsorted(pks, added_keys[taken:upto])
+            pks = np.insert(pks, at, added_keys[taken:upto])
+            oids = np.insert(oids, at, added_oids[taken:upto], axis=0)
+            taken = upto
+        if len(pks):
+            yield pks, oids
+    if taken < len(added_keys):  # ours is empty
+        yield added_keys[taken:], added_oids[taken:]
+
+
+def _apply_take_theirs(inner, blocks, datasets, take_keys, tree_builder):
+    """Theirs' clean changes, applied to ours' tree in ``tree_builder``:
+    blobs theirs wrote are inserted, paths theirs deleted are removed."""
+    _, o_block, t_block = blocks
+    _, o_ds, t_ds = datasets
+    prefix = f"{inner}/feature/"
+    with tm.span("merge.apply", take_theirs=len(take_keys)) as span:
+        t_rows = _keys_to_block_rows(t_block, take_keys)
+        o_rows = _keys_to_block_rows(o_block, take_keys)
+        present = t_rows >= 0
+        inserted = t_rows[present]
+        removed = o_rows[~present & (o_rows >= 0)]
+        span.set(inserted=len(inserted), removed=len(removed), trees_written=0)
+        encoder = _int_encoder(o_ds)
+        rewrites = o_rows[present] >= 0  # of what theirs wrote: ours has the key
+        if (
+            encoder is not None
+            and (len(inserted) == 0 or _int_encoder(t_ds) == encoder)
+            and len(take_keys) * REBUILD_MIN_SHARE >= o_block.count
+            and o_block.count - len(removed) + int(np.count_nonzero(~rewrites)) > 0
+        ):
+            # the merged columns: ours' rows, theirs' oid where theirs
+            # rewrote one, less what theirs deleted, with what theirs added
+            from kart_tpu.core.feature_tree import write_int_feature_tree
+            from kart_tpu.core.objects import MODE_TREE
+
+            feature_tree = write_int_feature_tree(
+                tree_builder.odb,
+                lambda: _merged_batches(
+                    o_block, t_block, o_rows[present][rewrites],
+                    inserted[rewrites], removed, inserted[~rewrites],
+                ),
+                encoder,
+            )
+            tree_builder.insert(f"{inner}/feature", feature_tree, mode=MODE_TREE)
+            span.set(trees_written=1)
+            return
+        if len(inserted):
+            tree_builder.insert_many(
+                _feature_paths(prefix, t_block, t_ds, inserted),
+                unpack_oid_hex(t_block.oids[inserted]),
+            )
+        for path in _feature_paths(prefix, o_block, o_ds, removed):
+            tree_builder.remove(path)
+
+
+def _merge_dataset_features(repo, ds_path, structures, tree_builder):
     """Vectorized per-feature 3-way for one dataset. Mutates tree_builder with
     clean theirs-changes; -> (conflicts dict, stats)."""
-    blocks, datasets = _dataset_blocks(structures, ds_path)
-    a_block, o_block, t_block = blocks
+    blocks, datasets = _dataset_blocks(repo, structures, ds_path)
 
     if any(b.has_key_collisions() for b in blocks):
         # hash-keyed identity collided (~1e-4 probability at 1e8 features):
@@ -131,10 +270,9 @@ def _merge_dataset_features(ds_path, structures, tree_builder):
 
     from kart_tpu.diff.backend import merge_classify
 
-    union, decision, presence, stats = merge_classify(a_block, o_block, t_block)
-
-    take_idx = np.nonzero(decision == TAKE_THEIRS)[0]
-    conflict_idx = np.nonzero(decision == CONFLICT)[0]
+    union, decision, presence, stats = merge_classify(*blocks)
+    tm.incr("merge.conflicts", stats["conflicts"])
+    tm.incr("merge.take_theirs", stats["take_theirs"])
 
     inner = None
     for ds in datasets:
@@ -144,24 +282,15 @@ def _merge_dataset_features(ds_path, structures, tree_builder):
     if inner is None:
         return {}, stats
 
-    # apply clean theirs-changes in batch: one searchsorted per side, then a
-    # straight zip over the changed rows only
-    take_keys = union[take_idx]
-    t_rows = _keys_to_block_rows(t_block, take_keys)
-    o_rows = _keys_to_block_rows(o_block, take_keys)
-    present = t_rows >= 0
-    if np.any(present):
-        rows = t_rows[present]
-        oid_hexes = unpack_oid_hex(t_block.oids[rows])
-        for row, oid in zip(rows, oid_hexes):
-            tree_builder.insert(f"{inner}/feature/{t_block.paths[row]}", oid)
-    for row in o_rows[~present]:
-        if row >= 0:
-            tree_builder.remove(f"{inner}/feature/{o_block.paths[row]}")
+    take_idx = np.nonzero(decision == TAKE_THEIRS)[0]
+    if len(take_idx):
+        _apply_take_theirs(inner, blocks, datasets, union[take_idx], tree_builder)
 
-    conflicts = materialise_conflicts(
-        ds_path, blocks, datasets, inner, union, conflict_idx
-    )
+    conflict_idx = np.nonzero(decision == CONFLICT)[0]
+    with tm.span("merge.conflicts", conflicts=len(conflict_idx)):
+        conflicts = materialise_conflicts(
+            ds_path, blocks, datasets, inner, union, conflict_idx
+        )
     return conflicts, stats
 
 
@@ -402,13 +531,15 @@ def merge_trees_vectorized(repo, ancestor_struct, ours_struct, theirs_struct):
         if structure.tree is not None:
             ds_paths.update(structure.datasets.paths())
     for ds_path in sorted(ds_paths):
-        conflicts, stats = _merge_dataset_features(ds_path, structures, tb)
+        conflicts, stats = _merge_dataset_features(repo, ds_path, structures, tb)
         all_conflicts.add(conflicts)
         for k in total_stats:
             total_stats[k] += stats.get(k, 0)
 
-    all_conflicts.add(_merge_non_features(structures, tb))
-    merged_tree = tb.flush() if tb else ours_struct.tree_oid
+    with tm.span("merge.non_features"):
+        all_conflicts.add(_merge_non_features(structures, tb))
+    with tm.span("merge.write_tree", changes=tb.change_count):
+        merged_tree = tb.flush() if tb else ours_struct.tree_oid
     return merged_tree, all_conflicts, total_stats
 
 

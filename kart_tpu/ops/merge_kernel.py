@@ -4,10 +4,14 @@ behind `kart/merge.py:99-100` + per-feature conflict semantics of
 
 Kart gets per-feature merge "for free" because one feature == one blob at a
 PK-determined path, and libgit2 merges trees path-by-path. Here the same
-semantics run as one jitted kernel over the *union* key array of the
-(ancestor, ours, theirs) FeatureBlocks: three searchsorted joins produce
-per-key (present, oid) triples, then the classic 3-way rule classifies every
-key at once — no per-feature Python, no data-dependent control flow.
+semantics run over whole key columns of the (ancestor, ours, theirs)
+FeatureBlocks — no per-feature Python, no data-dependent control flow — in
+three forms with one answer: on one device as two runs of the diff's
+classify and the three-way rule over the keys they report changed
+(:func:`merge_classify_two_diffs`); on the mesh as three searchsorted joins
+over each shard's union key array (:func:`_merge_classify_padded_core`, the
+shard body of ``parallel/sharded_merge.py``); on the host as the numpy twin
+of those joins (:func:`_merge_classify_np`).
 
 Per-key decision for versions a/o/t (absent = not present):
     o == t           -> KEEP_OURS   (same change both sides, incl. both absent)
@@ -17,15 +21,13 @@ Per-key decision for versions a/o/t (absent = not present):
 
 Codes: 0 = KEEP_OURS, 1 = TAKE_THEIRS, 2 = CONFLICT.
 
-This module holds the one-device kernels and their numpy twin; the router
-that picks between them and the mesh (``parallel/sharded_merge.py``) is
+The router that picks between them is
 :func:`kart_tpu.diff.backend.merge_classify`.
 """
 
 import numpy as np
 
-from kart_tpu.ops._lazy import lazy_jit
-from kart_tpu.ops.blocks import PAD_KEY, bucket_size
+from kart_tpu import telemetry as tm
 
 KEEP_OURS = 0
 TAKE_THEIRS = 1
@@ -86,158 +88,131 @@ def _merge_classify_padded_core(
     return decision, presence, n_conflicts, n_take_theirs
 
 
-_merge_classify_padded = lazy_jit(_merge_classify_padded_core)
+def _classify_side(side, ancestor_block, block, chunk_rows):
+    """One of the merge's two diffs, ancestor -> ``side``, on the device
+    route of ``kart diff`` itself: -> (ancestor classes, the side's
+    classes). Its own ``diff.classify`` span, so the diff's children and
+    census attributes (``diff.device.*``, ``input_bytes`` /
+    ``resident_bytes``) say of a merge what they say of a diff."""
+    from kart_tpu.ops.diff_kernel import classify_blocks_streamed
+
+    rows = max(ancestor_block.count, block.count)
+    with tm.span("diff.classify", side=side, backend="device_jax", rows=rows):
+        if not rows:  # the dataset is in neither revision
+            return np.zeros(0, dtype=np.int8), np.zeros(0, dtype=np.int8)
+        return classify_blocks_streamed(ancestor_block, block, chunk_rows)[:2]
 
 
-# from MERGE_STREAMED_MIN_ROWS rows the accelerator merge streams its three
-# blocks chunk-wise (kart_tpu/diff/backend.py), so that the host->HBM
-# transfer of chunk i+1 overlaps the joins of chunk i instead of one
-# monolithic upload. The module's own choice from the size it observes, not a
-# routing decision (kart_tpu/routing.py). The diff's device route is chunked
-# at every size and has constants of its own (ops/diff_kernel.py)
-MERGE_STREAMED_MIN_ROWS = 16_000_000
-MERGE_CHUNK_ROWS = 8_000_000
+def _updated_rows(side_class, ancestor_rows, ancestor_class):
+    """The rows of a side that rewrite the ancestor's rows ``ancestor_rows``
+    (all ``UPDATE`` there): both list a key's update in key order, so the
+    k-th updated row of the ancestor is the k-th updated row of the side."""
+    from kart_tpu.ops.diff_kernel import UPDATE
+
+    return np.flatnonzero(side_class == UPDATE)[
+        np.cumsum(ancestor_class == UPDATE)[ancestor_rows] - 1
+    ]
 
 
-def stream_chunk_splits(key_arrays, chunk_rows):
-    """Key-space chunking for the streamed device paths: sorted key arrays
-    (one per block side) -> (per-side split-point arrays, n_chunks), where
-    chunk c of side s is rows ``splits[s][c]:splits[s][c+1]``. A key falls
-    in the same chunk on every side, so merge-joins stay chunk-local.
-
-    Boundaries balance the *combined* population: quantiles of one side
-    alone collapse under key-range skew (e.g. a renumbered-PK revision
-    whose new keys all exceed the old range would pile every new row into
-    one chunk). Candidate keys are fine-grained quantiles of each side;
-    each target combined-rank picks the nearest candidate."""
-    chunk_rows = max(int(chunk_rows), 1)
-    n_chunks = max(1, -(-max(len(k) for k in key_arrays) // chunk_rows))
-    total = sum(len(k) for k in key_arrays)
-
-    def _quantile_keys(keys, m):
-        if not len(keys) or m <= 0:
-            return keys[:0]
-        return keys[(np.arange(1, m) * len(keys)) // m]
-
-    cand = np.unique(
-        np.concatenate([_quantile_keys(k, 4 * n_chunks) for k in key_arrays])
-    )
-    if len(cand):
-        ranks = sum(np.searchsorted(k, cand) for k in key_arrays)
-        targets = (np.arange(1, n_chunks) * total) // n_chunks
-        picks = np.searchsorted(ranks, targets)
-        bounds = np.unique(cand[np.minimum(picks, len(cand) - 1)])
-    else:
-        bounds = cand
-    splits = tuple(
-        np.concatenate(([0], np.searchsorted(k, bounds), [len(k)]))
-        for k in key_arrays
-    )
-    return splits, len(bounds) + 1
+def decision_stats(decision):
+    """The two counts every engine hands back beside its decisions."""
+    return {
+        "conflicts": int(np.count_nonzero(decision == CONFLICT)),
+        "take_theirs": int(np.count_nonzero(decision == TAKE_THEIRS)),
+    }
 
 
-def merge_classify_streamed(
+def merge_classify_two_diffs(
     ancestor_block, ours_block, theirs_block, chunk_rows=None
 ):
-    """Double-buffered chunked device merge classify — the merge analog of
-    ``diff_kernel.classify_blocks_streamed`` (SURVEY §2.3 pipelined
-    streaming): north-star-scale merges must not ship three whole blocks to
-    HBM as one upload. Key-space chunks keep every 3-way decision
-    chunk-local; per-chunk unions concatenate (in order) into the exact
-    global sorted union, so output is identical to ``merge_classify``
-    (tested). With two chunks in flight, chunk i+1's host->HBM copy
-    overlaps chunk i's joins."""
-    import jax
+    """The one-device merge classify: ``kart diff``'s classify twice —
+    ancestor -> ours, ancestor -> theirs, the chunked windowed join over
+    resident pages (:func:`kart_tpu.ops.diff_kernel.classify_blocks_streamed`;
+    the ancestor's pages are read by both, and are the pages a diff of
+    either branch reads) — then the three-way rule on the host over the
+    changed keys alone (span ``merge.combine``):
 
-    from collections import deque
+    * a key neither side changed keeps ours, one only theirs changed takes
+      theirs, one only ours changed keeps ours;
+    * a key both changed keeps ours where they left the same value (both
+      deleted it, or both wrote the same oid) and is a conflict otherwise;
+    * absence is a value: a key the ancestor lacks is an insert of one side
+      (taken or kept) or of both (the same oid, or a conflict).
 
-    if chunk_rows is None:
-        chunk_rows = MERGE_CHUNK_ROWS
-    blocks = (ancestor_block, ours_block, theirs_block)
-    reals = tuple(
-        (b.keys[: b.count], b.oids[: b.count]) for b in blocks
-    )
-    splits, n_chunks = stream_chunk_splits(
-        tuple(keys for keys, _ in reals), chunk_rows
-    )
-    # per-chunk unions first: all chunks share one union bucket (one
-    # compiled shape), and their ordered concatenation IS the global union
-    unions = []
-    for c in range(n_chunks):
-        parts = [
-            reals[s][0][splits[s][c] : splits[s][c + 1]] for s in range(3)
-        ]
-        unions.append(
-            np.union1d(np.union1d(parts[0], parts[1]), parts[2]).astype(
-                np.int64
-            )
+    -> ``merge_classify``'s contract, bit-identical to
+    :func:`_merge_classify_np` over ``np.union1d`` of the three key columns
+    (tested): (union (U,) int64, decision (U,) int8, presence (U,) int8,
+    stats). Raises what the device raises. The union is the ancestor's keys
+    with both sides' inserted keys merged in; nothing of its size is sorted
+    or searched."""
+    from kart_tpu.ops.diff_kernel import DELETE, INSERT, UNCHANGED, UPDATE
+
+    a_keys = ancestor_block.keys[: ancestor_block.count]
+    a_o, o_class = _classify_side("ours", ancestor_block, ours_block, chunk_rows)
+    a_t, t_class = _classify_side("theirs", ancestor_block, theirs_block, chunk_rows)
+
+    with tm.span("merge.combine") as span:
+        # -- keys the ancestor holds: a class a side, per ancestor row
+        changed_o, changed_t = a_o != UNCHANGED, a_t != UNCHANGED
+        decision_a = np.where(changed_t & ~changed_o, TAKE_THEIRS, KEEP_OURS).astype(
+            np.int8
         )
-    side_max = max(
-        (
-            int(np.max(np.diff(splits[s])))
-            for s in range(3)
-            if len(splits[s]) > 1
-        ),
-        default=1,
-    )
-    b_bucket = bucket_size(max(side_max, 1))
-    u_bucket = bucket_size(max(max((len(u) for u in unions), default=1), 1))
+        both = np.flatnonzero(changed_o & changed_t)
+        # both changed it: the same value only where both deleted it or both
+        # wrote the same blob
+        differ = a_o[both] != a_t[both]
+        rewrote = np.flatnonzero((a_o[both] == UPDATE) & (a_t[both] == UPDATE))
+        if len(rewrote):
+            o_rows = _updated_rows(o_class, both[rewrote], a_o)
+            t_rows = _updated_rows(t_class, both[rewrote], a_t)
+            differ[rewrote] = np.any(
+                ours_block.oids[o_rows] != theirs_block.oids[t_rows], axis=1
+            )
+        decision_a[both[differ]] = CONFLICT
+        presence_a = (
+            np.int8(7)
+            - np.int8(2) * (a_o == DELETE).astype(np.int8)
+            - np.int8(4) * (a_t == DELETE).astype(np.int8)
+        )
 
-    def _padded(keys, oids, lo, hi):
-        k = np.full(b_bucket, PAD_KEY, dtype=np.int64)
-        o = np.zeros((b_bucket, 5), dtype=np.uint32)
-        k[: hi - lo] = keys[lo:hi]
-        o[: hi - lo] = oids[lo:hi]
-        return k, o
+        # -- keys it lacks: one side's inserts, or both sides'
+        o_new = np.flatnonzero(o_class == INSERT)
+        t_new = np.flatnonzero(t_class == INSERT)
+        o_new_keys = ours_block.keys[o_new]
+        t_new_keys = theirs_block.keys[t_new]
+        new_keys = np.union1d(o_new_keys, t_new_keys).astype(np.int64)
+        in_o = np.isin(new_keys, o_new_keys, assume_unique=True)
+        in_t = np.isin(new_keys, t_new_keys, assume_unique=True)
+        decision_new = np.where(in_t & ~in_o, TAKE_THEIRS, KEEP_OURS).astype(np.int8)
+        shared = in_o & in_t
+        if shared.any():
+            decision_new[shared] = np.where(
+                np.any(
+                    ours_block.oids[o_new[in_t[in_o]]]
+                    != theirs_block.oids[t_new[in_o[in_t]]],
+                    axis=1,
+                ),
+                CONFLICT,
+                KEEP_OURS,
+            )
+        presence_new = np.int8(2) * in_o.astype(np.int8) + np.int8(4) * in_t.astype(
+            np.int8
+        )
 
-    out_decision = []
-    out_presence = []
-    totals = np.zeros(2, dtype=np.int64)
-    in_flight = deque()
-
-    def _drain():
-        out, u_count = in_flight.popleft()
-        decision, presence, n_conf, n_theirs = out
-        out_decision.append(np.asarray(decision)[:u_count])
-        out_presence.append(np.asarray(presence)[:u_count])
-        totals[0] += int(n_conf)
-        totals[1] += int(n_theirs)
-
-    for c in range(n_chunks):
-        args = []
-        for s in range(3):
-            lo, hi = int(splits[s][c]), int(splits[s][c + 1])
-            k, o = _padded(reals[s][0], reals[s][1], lo, hi)
-            args.extend((jax.device_put(k), jax.device_put(o), hi - lo))
-        u = unions[c]
-        u_padded = np.full(u_bucket, PAD_KEY, dtype=np.int64)
-        u_padded[: len(u)] = u
-        args.extend((jax.device_put(u_padded), len(u)))
-        out = _merge_classify_padded(*args)
-        in_flight.append((out, len(u)))
-        if len(in_flight) >= 2:
-            _drain()
-    while in_flight:
-        _drain()
-    union = (
-        np.concatenate(unions) if unions else np.zeros(0, dtype=np.int64)
-    )
-    decision = (
-        np.concatenate(out_decision)
-        if out_decision
-        else np.zeros(0, dtype=np.int8)
-    )
-    presence = (
-        np.concatenate(out_presence)
-        if out_presence
-        else np.zeros(0, dtype=np.int8)
-    )
-    return (
-        union,
-        decision,
-        presence,
-        {"conflicts": int(totals[0]), "take_theirs": int(totals[1])},
-    )
+        # -- the union, in key order: the new keys slipped in among the old
+        if len(new_keys):
+            at = np.searchsorted(a_keys, new_keys)
+            union = np.insert(a_keys, at, new_keys)
+            decision = np.insert(decision_a, at, decision_new)
+            presence = np.insert(presence_a, at, presence_new)
+        else:
+            union, decision, presence = np.asarray(a_keys), decision_a, presence_a
+        span.set(
+            changed_ours=int(np.count_nonzero(changed_o)) + len(o_new),
+            changed_theirs=int(np.count_nonzero(changed_t)) + len(t_new),
+            both=len(both) + int(np.count_nonzero(shared)),
+        )
+    return union, decision, presence, decision_stats(decision)
 
 
 def _join_np(block, union_keys):

@@ -61,6 +61,7 @@ SUBSYSTEMS = frozenset(
     {
         "cli",       # command lifecycle
         "diff",      # diff engine (classify / prefilter / tree walk)
+        "merge",     # 3-way merge stages (blocks / combine / apply / conflicts)
         "sidecar",   # columnar sidecar load/save/build
         "odb",       # object db reads/writes
         "packs",     # packfile machinery

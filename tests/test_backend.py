@@ -481,7 +481,7 @@ def _merge_triple(seed=3, n=3000):
 @pytest.mark.parametrize(
     "what, sharded, broken",
     [
-        ("merge_device", "0", "kart_tpu.ops.merge_kernel._merge_classify_padded"),
+        ("merge_device", "0", "kart_tpu.ops.merge_kernel.merge_classify_two_diffs"),
         (
             "merge_sharded",
             "1",

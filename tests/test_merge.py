@@ -541,63 +541,3 @@ def test_encode_paths_joined_bytes_matches_batch():
     expected = "\x00".join("pre/" + p for p in enc.encode_paths_batch(pks)).encode()
     assert joined == expected
     assert enc.encode_paths_joined_bytes(np.zeros(0, dtype=np.int64)) == b""
-
-
-class TestStreamedMergeClassify:
-    def test_matches_monolithic(self, monkeypatch):
-        """Chunked double-buffered merge classify must reproduce
-        merge_classify exactly for every chunk size (boundaries never split
-        a key's 3-way decision)."""
-        import numpy as np
-
-        from kart_tpu.diff.backend import merge_classify
-        from kart_tpu.ops.merge_kernel import merge_classify_streamed
-        from kart_tpu.parallel.sharded_diff import synthetic_block
-
-        monkeypatch.setenv("KART_DIFF_SHARDED", "0")
-        n = 4000
-        anc = synthetic_block(n, seed=11)
-        ours = synthetic_block(n, seed=11)
-        ours.oids = ours.oids.copy()
-        theirs = synthetic_block(n, seed=11)
-        theirs.oids = theirs.oids.copy()
-        rng = np.random.default_rng(12)
-        both = rng.choice(n, size=300, replace=False)
-        ours.oids[both, 0] ^= 1
-        theirs.oids[both, 0] ^= 2
-        ours.oids[rng.choice(n, 200, replace=False), 1] ^= 3
-        theirs.oids[rng.choice(n, 250, replace=False), 2] ^= 4
-
-        want = merge_classify(anc, ours, theirs)
-        for chunk_rows in (257, 1024, 10_000):
-            got = merge_classify_streamed(
-                anc, ours, theirs, chunk_rows=chunk_rows
-            )
-            for a, b in zip(got[:3], want[:3]):
-                np.testing.assert_array_equal(a, b)
-            assert got[3] == want[3]
-            assert got[3]["conflicts"] >= 300
-
-    def test_disjoint_sides(self, monkeypatch):
-        """Renumbered shape: ours adds a whole new key range."""
-        import numpy as np
-
-        from kart_tpu.ops.blocks import FeatureBlock
-        from kart_tpu.diff.backend import merge_classify
-        from kart_tpu.ops.merge_kernel import merge_classify_streamed
-
-        monkeypatch.setenv("KART_DIFF_SHARDED", "0")
-
-        def block(lo, hi):
-            keys = np.arange(lo, hi, dtype=np.int64)
-            oids = np.ones((len(keys), 5), dtype=np.uint32)
-            return FeatureBlock.from_arrays(keys, oids, [str(k) for k in keys])
-
-        anc = block(0, 2000)
-        ours = block(1000, 4000)  # dropped 0..999, added 2000..3999
-        theirs = block(0, 2000)
-        want = merge_classify(anc, ours, theirs)
-        got = merge_classify_streamed(anc, ours, theirs, chunk_rows=333)
-        for a, b in zip(got[:3], want[:3]):
-            np.testing.assert_array_equal(a, b)
-        assert got[3] == want[3]

@@ -425,7 +425,7 @@ def _bbox_rung(monkeypatch):
 
 RUNGS = {
     "merge_device": lambda mp: _merge_rung(
-        mp, "kart_tpu.ops.merge_kernel._merge_classify_padded", "1", "0"
+        mp, "kart_tpu.ops.merge_kernel.merge_classify_two_diffs", "1", "0"
     ),
     "merge_sharded": lambda mp: _merge_rung(
         mp, "kart_tpu.parallel.sharded_merge.sharded_merge_classify", "0", "1"

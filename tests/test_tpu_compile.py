@@ -206,6 +206,11 @@ _PAGE = bucket_size(CLASSIFY_CHUNK_ROWS)  # rows of a page of a long revision
         ((_PAGE, _PAGE), (_last_chunk_bucket(10_000_000), _last_chunk_bucket(10_050_000))),
         # the last chunk of the filtered cell's 2.94M survivors (PR 35)
         ((_PAGE, _PAGE), (_last_chunk_bucket(2_942_000),) * 2),
+        # the last chunks of the merge cell's two diffs (PR 40): the 4M-row
+        # ancestor against ours (50,000 appended), and against theirs, whose
+        # 175,000 appended rows fill the chunk's bucket on that side
+        ((_PAGE, _PAGE), (_last_chunk_bucket(4_000_000),) * 2),
+        ((_PAGE, _PAGE), (_last_chunk_bucket(4_000_000), _PAGE)),
         # a revision of one small page against a long one: the chunk is
         # longer than the page it is cut from
         ((4608, _PAGE), (_PAGE, _PAGE)),
