@@ -10,9 +10,14 @@ generator (kart_tpu/synth.py). Reference analog: the N x git fast-import
 tree build (kart/fast_import.py:286-399).
 """
 
+import contextlib
+import itertools
+
 import numpy as np
 
+from kart_tpu import telemetry as tm
 from kart_tpu.models.paths import PathEncoder
+from kart_tpu.utils import map_in_order, pool_workers
 
 _TREE_BATCH = 65536
 
@@ -293,43 +298,135 @@ class _TreeNamer:
 
 #: rows a batch of :func:`write_int_feature_tree`'s leaf stream: its arrays
 #: (11 MB of payloads, as much again framed) stay under the allocator's
-#: 32 MB mmap ceiling, so every batch after the first reuses the heap the
-#: one before freed. A whole 4M-row column at once asks the kernel for
+#: 32 MB mmap ceiling, so a batch reuses the heap an earlier one freed (a
+#: few batches are in flight at a time, one a pool thread and one more: not
+#: the layer). A whole 4M-row column at once asks the kernel for
 #: ~1.5 GB of fresh pages a call, which on a shared host is both most of the
 #: time and most of its run-to-run spread (PERF.md §6, PR 40)
 LEAF_STREAM_ROWS = 262_144
 
 
-def _stream_leaf_trees(batches, encoder):
-    """The leaf trees of (pk, oid) columns that come in key order, made and
-    named batch by batch in the native IO core
-    (:class:`StreamingLeafEmitter`, ``native.pack_records_base``): -> (the
-    emitter, [framed record batches]) where a batch's first member is its
-    leaves' oids, or None where the stream does not apply (no native core,
-    pks not strictly ascending or out of the encoder's range) and the plan
-    has to do it."""
+def _ascending_in_range(pks, pk_limit):
+    """Are the (not empty) ``pks`` strictly ascending within [0, pk_limit)?
+    What the native leaf build needs of a batch (no leaf-id wraparound)."""
+    return bool(pks[0] >= 0 and pks[-1] < pk_limit and (pks[1:] > pks[:-1]).all())
+
+
+def _root_over_leaves(odb, leaf_id_chunks, leaf_oid_chunks, encoder):
+    """The spine over leaf trees made batch by batch; -> feature-root hex
+    oid. ``leaf_id_chunks``: ascending int64 leaf slots, an array a batch;
+    ``leaf_oid_chunks``: their (n, 20) uint8 oids, batch for batch."""
+    child_ids = np.concatenate(leaf_id_chunks)
+    hexes = b"".join(c.tobytes() for c in leaf_oid_chunks).hex()
+    child_oids = [hexes[i : i + 40] for i in range(0, len(hexes), 40)]
+    assert len(child_oids) == len(child_ids)
+    return build_upper_levels(odb, child_ids, child_oids, encoder)
+
+
+def _cut_on_leaves(batches, branches):
+    """(pks, oids) batches in key order -> the same rows in batches of at
+    most ``LEAF_STREAM_ROWS`` rows, none of which ends inside a leaf: each
+    can be made into leaf trees on its own, by whichever thread is free. A
+    batch's tail is held until the next batch is seen. Where the producer
+    cut on a leaf boundary itself (``merge._merged_batches``) every batch
+    goes on as it came, a view; where the next batch goes on with the held
+    leaf, that leaf's rows move over to it, which copies the next batch.
+    Rows out of order are cut somewhere all the same: whoever makes the
+    leaves finds them."""
+    held = None  # the last piece seen: its last leaf may go on
+    for pks, oids_u8 in batches:
+        pks = np.asarray(pks, dtype=np.int64)
+        oids_u8 = np.asarray(oids_u8, dtype=np.uint8).reshape(-1, 20)
+        if not len(pks):
+            continue
+        if held is not None:
+            h_pks, h_oids = held
+            if h_pks[-1] // branches == pks[0] // branches:
+                cut = int(np.searchsorted(h_pks, h_pks[-1] // branches * branches))
+                pks = np.concatenate([h_pks[cut:], pks])
+                oids_u8 = np.concatenate([h_oids[cut:], oids_u8])
+                h_pks, h_oids = h_pks[:cut], h_oids[:cut]
+            if len(h_pks):
+                yield h_pks, h_oids
+        lo = 0
+        while len(pks) - lo > LEAF_STREAM_ROWS:
+            hi = lo + LEAF_STREAM_ROWS
+            cut = lo + int(np.searchsorted(pks[lo:hi], pks[hi] // branches * branches))
+            if cut == lo:  # no leaf is that long: the rows are out of order
+                cut = hi
+            yield pks[lo:cut], oids_u8[lo:cut]
+            lo = cut
+        held = pks[lo:], oids_u8[lo:]
+    if held is not None:
+        yield held
+
+
+def _leaf_batch(batch, encoder, pk_limit):
+    """The leaf trees of one batch of :func:`_cut_on_leaves`, made, hashed
+    and framed in two GIL-free native calls: -> (leaf ids, framed records
+    ``(oids, crcs, records, offsets)``), or None where the stream does not
+    apply. Runs on a pool thread, which has no open span: the span names
+    its parent itself."""
     from kart_tpu import native
     from kart_tpu.core.packs import TYPE_CODES
 
-    stream = StreamingLeafEmitter(encoder)
-    framed = []
-
-    def frame(batch):
-        if batch is not None:
-            buf, offsets, _ = batch
-            framed.append(
-                native.pack_records_base("tree", TYPE_CODES["tree"], buf, offsets, 0)
-            )
-
-    for pks, oids_u8 in batches:
-        if not (stream.ok and stream._native):
+    pks, oids_u8 = batch
+    with tm.span("merge.leaf_batch", rows=len(pks), parent="merge.apply") as span:
+        if not _ascending_in_range(pks, pk_limit):
             return None
-        for lo in range(0, len(pks), LEAF_STREAM_ROWS):
-            frame(stream.feed(pks[lo : lo + LEAF_STREAM_ROWS], oids_u8[lo : lo + LEAF_STREAM_ROWS]))
-    frame(stream.finish())
-    if not (stream.ok and stream._native) or any(r is None for r in framed):
+        made = native.leaf_payloads(pks, oids_u8, encoder.branches, pk_limit)
+        if made is None:
+            return None
+        buf, offsets, leaf_ids = made
+        framed = native.pack_records_base("tree", TYPE_CODES["tree"], buf, offsets, 0)
+        if framed is None:
+            return None
+        span.set(leaves=len(leaf_ids), bytes=len(buf))
+    return leaf_ids, framed
+
+
+def _stream_leaf_trees(batches, encoder):
+    """The leaf trees of (pk, oid) columns that come in key order, made and
+    named batch by batch in the native IO core, the batches cut on leaf
+    boundaries (:func:`_cut_on_leaves`) and handed to a pool of threads
+    (:func:`_leaf_batch`; as many as ``pool_workers()`` reads off the host,
+    results taken in batch order, at most one more than that in flight): ->
+    ([a batch's leaf ids], [a batch's framed records, the first member of
+    which is its leaves' oids]), or None where the stream does not apply (no
+    native core, pks not strictly ascending or out of the encoder's range,
+    in any batch) and the plan has to do it. One worker, or a stream of one
+    batch, stays on the calling thread."""
+    from kart_tpu import native
+
+    if encoder.scheme != "int" or native.load_io() is None:
         return None
-    return stream, framed
+    pk_limit = encoder.branches ** (encoder.levels + 1)
+    pieces = _cut_on_leaves(batches, encoder.branches)
+    head = list(itertools.islice(pieces, 2))
+    pieces = itertools.chain(head, pieces)
+    workers = pool_workers() if len(head) == 2 else 1
+
+    def make(batch):
+        return _leaf_batch(batch, encoder, pk_limit)
+
+    if workers == 1:
+        results = (make(batch) for batch in pieces)
+    else:
+        results = map_in_order(make, pieces, workers, "kart-leaf")
+    leaf_id_chunks, framed = [], []
+    last_leaf = -1
+    with contextlib.closing(results):
+        for result in results:
+            # leaf ids ascending from batch to batch: the batches came in
+            # key order and none shares a leaf with the one before it
+            if result is None or result[0][0] <= last_leaf:
+                return None
+            leaf_id_chunks.append(result[0])
+            framed.append(result[1])
+            last_leaf = int(result[0][-1])
+    tm.incr("merge.leaf_batches", len(framed))
+    tm.annotate_span("merge.apply", leaf_batches=len(framed), workers=workers)
+    return leaf_id_chunks, framed
 
 
 def write_int_feature_tree(odb, batches, encoder=None):
@@ -341,8 +438,10 @@ def write_int_feature_tree(odb, batches, encoder=None):
     rest. ``batches()`` -> the columns as (pks int64, oids (n, 20) uint8)
     pairs, together not empty. Batches in key order take the native leaf
     stream as they come (:func:`_stream_leaf_trees`: nothing of the layer's
-    size is held but the trees); any other columns the plan, whole.
+    size is held but the trees; a producer that ends its batches where a
+    leaf ends saves it a copy); any other columns the plan, whole.
     -> feature tree hex oid."""
+    encoder = encoder or PathEncoder.INT_PK_ENCODER
     streamed = _stream_leaf_trees(batches(), encoder)
     if streamed is None:
         pks, oids_u8 = (np.concatenate(column) for column in zip(*batches()))
@@ -352,14 +451,14 @@ def write_int_feature_tree(odb, batches, encoder=None):
             with odb.bulk_pack(level=0):
                 emit_feature_tree(odb, plan, oids_u8)
         return root
-    stream, framed = streamed
+    leaf_id_chunks, framed = streamed
     leaf_oids = [records[0] for records in framed]
-    root = stream.build_root(_TreeNamer, leaf_oids)
+    root = _root_over_leaves(_TreeNamer, leaf_id_chunks, leaf_oids, encoder)
     if not odb.contains(root):
         with odb.bulk_pack(level=0) as writer:
             for records in framed:
                 writer.append_framed(records)
-            stream.build_root(odb, leaf_oids)
+            _root_over_leaves(odb, leaf_id_chunks, leaf_oids, encoder)
     return root
 
 
@@ -395,11 +494,9 @@ class StreamingLeafEmitter:
         self.leaf_id_chunks = []
 
     def _check(self, pks):
-        if pks[0] < 0 or pks[-1] >= self._pk_limit:
-            return False
         if self._last_pk is not None and pks[0] <= self._last_pk:
             return False
-        return bool((pks[1:] > pks[:-1]).all())
+        return _ascending_in_range(pks, self._pk_limit)
 
     def _payloads(self, pks, oids_u8):
         """Complete-leaf payloads for sorted ``pks`` -> (buf uint8,
@@ -487,12 +584,8 @@ class StreamingLeafEmitter:
         """Upper spine over the streamed leaves; -> feature-root hex oid.
         ``leaf_oids_u8_chunks``: (n,20) uint8 arrays, one per emitted
         payload batch, in emission order."""
-        child_ids = np.concatenate(self.leaf_id_chunks)
-        hexes = b"".join(
-            c.tobytes() for c in leaf_oids_u8_chunks
-        ).hex()
-        child_oids = [hexes[i : i + 40] for i in range(0, len(hexes), 40)]
-        assert len(child_oids) == len(child_ids)
-        return build_upper_levels(odb, child_ids, child_oids, self.encoder)
+        return _root_over_leaves(
+            odb, self.leaf_id_chunks, leaf_oids_u8_chunks, self.encoder
+        )
 
 

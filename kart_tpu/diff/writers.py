@@ -33,6 +33,7 @@ from kart_tpu.diff.output import (
 from kart_tpu.diff.structs import RepoDiff
 from kart_tpu.models.dataset import FeatureOidPromise
 from kart_tpu.models.schema import Schema
+from kart_tpu.utils import map_in_order, pool_workers
 
 _NULL = object()
 
@@ -608,30 +609,6 @@ class JsonDiffWriter(BaseDiffWriter):
         return out
 
 
-def _map_in_order(fn, items, workers):
-    """``fn(item)`` for each item on a pool of ``workers`` threads, the
-    results yielded in item order. The next item is submitted when the
-    consumer comes back for more, so at most ``workers + 1`` results exist
-    at a time (the one being consumed among them) however far the pool
-    could run ahead. An exception of ``fn`` is raised where its result
-    would have been yielded."""
-    from collections import deque
-    from concurrent.futures import ThreadPoolExecutor
-
-    items = iter(items)
-    pool = ThreadPoolExecutor(workers, thread_name_prefix="kart-jsonl")
-    try:
-        in_flight = deque(
-            pool.submit(fn, item) for item in itertools.islice(items, workers + 1)
-        )
-        while in_flight:
-            yield in_flight.popleft().result()
-            for item in itertools.islice(items, 1):
-                in_flight.append(pool.submit(fn, item))
-    finally:
-        pool.shutdown(wait=True, cancel_futures=True)
-
-
 def _ascii_sink(fp):
     """``write(buffer)`` for pure-ASCII bytes bound for the text file
     ``fp``: straight to the binary file under it where it has one whose
@@ -769,8 +746,6 @@ class JsonLinesDiffWriter(BaseDiffWriter):
         value or geometry outside its fast paths, invalid UTF-8):
         :meth:`python_line` makes exactly those rows, spliced in place —
         and every row where ``libkart_io`` is unavailable."""
-        import os
-
         import numpy as np
 
         from kart_tpu import native
@@ -863,9 +838,8 @@ class JsonLinesDiffWriter(BaseDiffWriter):
             return pieces, n_bytes
 
         write = _ascii_sink(self.fp)
-        workers = max(1, min(os.cpu_count() or 1, 4))
-        for pieces, n_bytes in _map_in_order(
-            materialise_chunk, range(0, m, chunk_rows), workers
+        for pieces, n_bytes in map_in_order(
+            materialise_chunk, range(0, m, chunk_rows), pool_workers(), "kart-jsonl"
         ):
             with tm.span("serialise.write", bytes=n_bytes):
                 for piece in pieces:

@@ -174,14 +174,17 @@ def _feature_paths(prefix, block, ds, rows):
     return RowPaths(prefix, block.paths, rows).batch()
 
 
-def _merged_batches(o_block, t_block, rewritten, rewriting, removed, added):
+def _merged_batches(o_block, t_block, rewritten, rewriting, removed, added, branches):
     """The merged (pk, oid) columns of an int-pk layer in key order, a batch
     of ours' rows at a time: -> (pks int64, oids uint8 (n, 20)) per batch.
     ``rewritten`` are ours' rows theirs rewrote and ``rewriting`` theirs'
     rows that did, ``removed`` ours' rows theirs deleted, ``added`` theirs'
     rows ours lacks — all ascending. A batch at a time, so that nothing of
     the layer's size is copied whole (core/feature_tree.py
-    ``LEAF_STREAM_ROWS`` says why that matters)."""
+    ``LEAF_STREAM_ROWS`` says why that matters), and every batch cut where a
+    leaf of the feature tree ends (``branches`` keys a leaf): all keys below
+    the first key of the leaf ours' next row lies in, so that the leaf
+    stream can hand each batch to another thread as it comes."""
     from kart_tpu.core.feature_tree import LEAF_STREAM_ROWS
 
     n = o_block.count
@@ -189,8 +192,16 @@ def _merged_batches(o_block, t_block, rewritten, rewriting, removed, added):
     new_oids = oid_rows_u8(t_block.oids[rewriting])
     added_keys, added_oids = t_block.keys[added], oid_rows_u8(t_block.oids[added])
     taken = 0  # of the added keys
-    for lo in range(0, n, LEAF_STREAM_ROWS):
+    lo = 0
+    while lo < n:
         hi = min(lo + LEAF_STREAM_ROWS, n)
+        if hi < n:
+            bound = o_keys[hi] // branches * branches  # that leaf's first key
+            cut = lo + int(np.searchsorted(o_keys[lo:hi], bound))
+            if cut > lo:
+                hi = cut
+            else:  # a leaf longer than a batch
+                bound = o_keys[hi]
         pks, oids = o_keys[lo:hi], oid_rows_u8(o_block.oids[lo:hi])  # oids: a copy
         first, last = np.searchsorted(rewritten, (lo, hi))
         oids[rewritten[first:last] - lo] = new_oids[first:last]
@@ -199,8 +210,8 @@ def _merged_batches(o_block, t_block, rewritten, rewriting, removed, added):
             keep = np.ones(hi - lo, dtype=bool)
             keep[removed[first:last] - lo] = False
             pks, oids = pks[keep], oids[keep]
-        # theirs' new keys that sort before ours' next batch
-        upto = len(added_keys) if hi == n else int(np.searchsorted(added_keys, o_keys[hi]))
+        # theirs' new keys that belong before ours' next batch
+        upto = len(added_keys) if hi == n else int(np.searchsorted(added_keys, bound))
         if upto > taken:
             at = np.searchsorted(pks, added_keys[taken:upto])
             pks = np.insert(pks, at, added_keys[taken:upto])
@@ -208,6 +219,7 @@ def _merged_batches(o_block, t_block, rewritten, rewriting, removed, added):
             taken = upto
         if len(pks):
             yield pks, oids
+        lo = hi
     if taken < len(added_keys):  # ours is empty
         yield added_keys[taken:], added_oids[taken:]
 
@@ -243,6 +255,7 @@ def _apply_take_theirs(inner, blocks, datasets, take_keys, tree_builder):
                 lambda: _merged_batches(
                     o_block, t_block, o_rows[present][rewrites],
                     inserted[rewrites], removed, inserted[~rewrites],
+                    encoder.branches,
                 ),
                 encoder,
             )

@@ -3,6 +3,7 @@
 import contextlib
 import functools
 import itertools
+import os
 
 
 def chunked(iterable, size):
@@ -71,3 +72,34 @@ def paused_gc():
     finally:
         if was_enabled:
             gc.enable()
+
+
+def pool_workers():
+    """Threads for a pool of GIL-free native calls: as many as the host has
+    cores, four at most. Read off the machine: no setting, no argument."""
+    return max(1, min(os.cpu_count() or 1, 4))
+
+
+def map_in_order(fn, items, workers, thread_name_prefix):
+    """``fn(item)`` for each item on a pool of ``workers`` threads, the
+    results yielded in item order. The next item is submitted when the
+    consumer comes back for more, so at most ``workers + 1`` results exist
+    at a time (the one being consumed among them) however far the pool
+    could run ahead. An exception of ``fn`` is raised where its result
+    would have been yielded; a consumer that stops early (``close()``)
+    cancels what has not started."""
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    items = iter(items)
+    pool = ThreadPoolExecutor(workers, thread_name_prefix=thread_name_prefix)
+    try:
+        in_flight = deque(
+            pool.submit(fn, item) for item in itertools.islice(items, workers + 1)
+        )
+        while in_flight:
+            yield in_flight.popleft().result()
+            for item in itertools.islice(items, 1):
+                in_flight.append(pool.submit(fn, item))
+    finally:
+        pool.shutdown(wait=True, cancel_futures=True)

@@ -142,11 +142,11 @@ def test_chunk_workers_byte_identical(tmp_path, monkeypatch, workers):
 
 @pytest.mark.parametrize("workers", [1, 2, 4])
 def test_map_in_order_bounds_results_in_flight(workers):
-    """_map_in_order: results in item order, at most workers + 1 made and
+    """map_in_order: results in item order, at most workers + 1 made and
     not yet given back, an fn error raised at its item's turn."""
     import threading
 
-    from kart_tpu.diff.writers import _map_in_order
+    from kart_tpu.utils import map_in_order
 
     lock = threading.Lock()
     state = {"made": 0, "taken": 0, "most": 0}
@@ -161,7 +161,7 @@ def test_map_in_order_bounds_results_in_flight(workers):
 
     got = []
     with pytest.raises(ValueError, match="item 40"):
-        for result in _map_in_order(fn, range(100), workers):
+        for result in map_in_order(fn, range(100), workers, "kart-test"):
             got.append(result)
             with lock:
                 state["taken"] += 1

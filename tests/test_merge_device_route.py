@@ -247,6 +247,7 @@ def test_the_merged_tree_is_the_plans_tree_and_is_written_once(n, first, tmp_pat
     from kart_tpu import native
     from kart_tpu.core import feature_tree
     from kart_tpu.core.repo import KartRepo
+    from kart_tpu.models.paths import PathEncoder
 
     rng = np.random.default_rng(n)
     pks = first + np.sort(rng.choice(n * 3, n, replace=False)).astype(np.int64)
@@ -261,8 +262,8 @@ def test_the_merged_tree_is_the_plans_tree_and_is_written_once(n, first, tmp_pat
             yield pks[lo : lo + 1_700], oids[lo : lo + 1_700]
 
     if native.load_io() is not None:
-        stream, framed = feature_tree._stream_leaf_trees(whole(), None)
-        assert len(framed) >= max(1, n // 1_000)  # a batch a feed, and the last leaf
+        _, framed = feature_tree._stream_leaf_trees(whole(), PathEncoder.INT_PK_ENCODER)
+        assert len(framed) >= max(1, n // 1_000)  # a whole column is cut into batches
     odb = KartRepo.init_repository(str(tmp_path / "merged")).odb
     want = feature_tree.build_int_feature_tree(
         KartRepo.init_repository(str(tmp_path / "plan")).odb, pks, oids
@@ -278,25 +279,9 @@ def test_the_merged_tree_is_the_plans_tree_and_is_written_once(n, first, tmp_pat
     assert sorted(os.listdir(os.path.join(odb.objects_dir, "pack"))) == packs
 
 
-@pytest.mark.parametrize("n_ours", [0, 1, 999, 1000, 1001, 4321], ids=lambda n: f"ours_{n}")
-def test_the_merged_columns_come_in_key_order_batch_by_batch(n_ours, monkeypatch):
-    """``_merged_batches`` against a dict per key: rewrites, deletes and
-    theirs' new keys — below, between and above ours' — land in the batch
-    their key belongs to, whatever the batch boundaries cut."""
-    from kart_tpu.core import feature_tree
-    from kart_tpu.merge import _merged_batches
-
-    monkeypatch.setattr(feature_tree, "LEAF_STREAM_ROWS", 1000)
-    rng = np.random.default_rng(n_ours)
-    ours = {int(k): 1 for k in rng.choice(20_000, n_ours, replace=False) + 100}
-    theirs = dict(ours)
-    keys = np.array(sorted(ours), dtype=np.int64)
-    for k in rng.choice(keys, n_ours // 5, replace=False).tolist():
-        theirs[k] = 2
-    for k in rng.choice(keys, n_ours // 7, replace=False).tolist():
-        theirs.pop(k, None)
-    for k in rng.choice(25_000, 300, replace=False).tolist():
-        theirs.setdefault(int(k), 3)
+def _merge_inputs(ours, theirs):
+    """{key: first oid word} of ours and of theirs (a clean change wherever
+    they differ) -> ``_merged_batches``' arguments less the leaf width."""
     o_block, t_block = _revision(ours), _revision(theirs)
     changed = np.array(
         sorted(k for k in set(ours) | set(theirs) if ours.get(k) != theirs.get(k)),
@@ -312,15 +297,262 @@ def test_the_merged_columns_come_in_key_order_batch_by_batch(n_ours, monkeypatch
     o_rows, t_rows = rows(o_block, changed), rows(t_block, changed)
     present = t_rows >= 0
     rewrites = o_rows[present] >= 0
-    batches = list(_merged_batches(
+    return (
         o_block, t_block, o_rows[present][rewrites], t_rows[present][rewrites],
         o_rows[~present], t_rows[present][~rewrites],
-    ))
+    )
+
+
+@pytest.mark.parametrize("n_ours", [0, 1, 999, 1000, 1001, 4321], ids=lambda n: f"ours_{n}")
+def test_the_merged_columns_come_in_key_order_batch_by_batch(n_ours, monkeypatch):
+    """``_merged_batches`` against a dict per key: rewrites, deletes and
+    theirs' new keys — below, between and above ours' — land in the batch
+    their key belongs to, whatever the batch boundaries cut, and no leaf of
+    the feature tree lies in two batches."""
+    from kart_tpu.core import feature_tree
+    from kart_tpu.merge import _merged_batches
+
+    monkeypatch.setattr(feature_tree, "LEAF_STREAM_ROWS", 1000)
+    rng = np.random.default_rng(n_ours)
+    ours = {int(k): 1 for k in rng.choice(20_000, n_ours, replace=False) + 100}
+    theirs = dict(ours)
+    keys = np.array(sorted(ours), dtype=np.int64)
+    for k in rng.choice(keys, n_ours // 5, replace=False).tolist():
+        theirs[k] = 2
+    for k in rng.choice(keys, n_ours // 7, replace=False).tolist():
+        theirs.pop(k, None)
+    for k in rng.choice(25_000, 300, replace=False).tolist():
+        theirs.setdefault(int(k), 3)
+    batches = list(_merged_batches(*_merge_inputs(ours, theirs), 64))
     pks = np.concatenate([b[0] for b in batches])
     oids = np.concatenate([b[1] for b in batches])
     assert pks.tolist() == sorted(theirs) and oids.shape == (len(theirs), 20)
     assert oids[:, 0].tolist() == [theirs[k] for k in sorted(theirs)]
     assert all(len(b[0]) for b in batches) and len(batches) >= n_ours // 1000
+    assert all(a[0][-1] // 64 < b[0][0] // 64 for a, b in zip(batches, batches[1:]))
+
+
+# -- the leaf stream on a pool of threads (ISSUE 41) ---------------------------
+
+def _merge_shape(shape):
+    """-> (ours, theirs) as {key: first oid word}: 5,000 of ours' rows and
+    theirs' clean changes, cut by ``LEAF_STREAM_ROWS`` = 1,000."""
+    if shape == "sparse":  # a leaf a row
+        ours = {100 + 64 * i: 1 for i in range(5_000)}
+    else:  # dense from 7 on: ours' row 1,000 is the 48th of its leaf
+        ours = {7 + i: 1 for i in range(5_000)}
+    keys = sorted(ours)
+    theirs = dict(ours)
+    for k in keys[::9]:
+        theirs[k] = 2
+    if shape == "removed_at_a_batchs_end":  # ours' rows 930..1,069 and the last 80
+        for k in keys[930:1_070] + keys[-80:]:
+            del theirs[k]
+    if shape == "added_past_the_last_key":
+        for k in range(keys[-1] + 1, keys[-1] + 1_500):
+            theirs[k] = 3
+        theirs[keys[-1] + 10_000] = 3
+    return ours, theirs
+
+
+@pytest.fixture
+def quick_switches():
+    """Threads give way every microsecond: what holds only by luck of the
+    scheduler fails here."""
+    import sys
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    yield
+    sys.setswitchinterval(interval)
+
+
+def _pack_files(odb):
+    pack_dir = os.path.join(odb.objects_dir, "pack")
+    out = {}
+    for name in sorted(os.listdir(pack_dir)):
+        with open(os.path.join(pack_dir, name), "rb") as f:
+            out[name] = f.read()
+    return out
+
+
+@pytest.mark.parametrize("workers", [1, 2, 4])
+@pytest.mark.parametrize(
+    "shape", ["dense", "sparse", "removed_at_a_batchs_end", "added_past_the_last_key"]
+)
+def test_the_leaf_stream_on_a_pool_names_and_writes_the_plans_tree(
+    shape, workers, tmp_path, monkeypatch, quick_switches
+):
+    """``write_int_feature_tree`` over ``_merged_batches`` with the host's
+    cores given as 1, 2 and 4: the root is ``build_int_feature_tree``'s, the
+    pack written on a miss is byte for byte the one a single worker writes
+    (the same objects in the same order), a second call writes nothing."""
+    from kart_tpu import native
+    from kart_tpu.core import feature_tree
+    from kart_tpu.core.repo import KartRepo
+    from kart_tpu.merge import _merged_batches
+
+    if native.load_io() is None:
+        pytest.skip("no native IO core: the plan answers, which other tests hold")
+    monkeypatch.setattr(feature_tree, "LEAF_STREAM_ROWS", 1_000)
+    ours, theirs = _merge_shape(shape)
+    inputs = _merge_inputs(ours, theirs)
+    assert inputs[0].keys[1_000] % 64 != 0 or shape == "sparse"  # a cut inside a leaf
+
+    def batches():
+        return _merged_batches(*inputs, 64)
+
+    pks = np.array(sorted(theirs), dtype=np.int64)
+    oids = np.concatenate([b[1] for b in batches()])
+    want = feature_tree.build_int_feature_tree(
+        KartRepo.init_repository(str(tmp_path / "plan")).odb, pks, oids
+    )
+
+    tm.reset()
+    tm.enable(metrics=True, trace=True)
+    try:
+        monkeypatch.setattr(os, "cpu_count", lambda: workers)
+        odb = KartRepo.init_repository(str(tmp_path / "pool")).odb
+        with tm.span("merge.apply"):
+            assert feature_tree.write_int_feature_tree(odb, batches) == want
+        events = tm.drain_events()
+        written = _pack_files(odb)
+        assert feature_tree.write_int_feature_tree(odb, batches) == want
+        assert _pack_files(odb) == written and len(written) == 2  # a pack and its index
+
+        monkeypatch.setattr(os, "cpu_count", lambda: 1)
+        alone = KartRepo.init_repository(str(tmp_path / "alone")).odb
+        assert feature_tree.write_int_feature_tree(alone, batches) == want
+        assert _pack_files(alone) == written
+    finally:
+        tm.reset()
+    assert sum(1 for _ in odb.tree(want).walk_blobs()) == len(theirs)
+
+    leaf_spans = [e for e in events if e["name"] == "merge.leaf_batch"]
+    (applied,) = [e for e in events if e["name"] == "merge.apply"]
+    assert applied["args"]["workers"] == workers
+    assert applied["args"]["leaf_batches"] == len(leaf_spans) >= 5
+    assert sum(e["args"]["rows"] for e in leaf_spans) == len(theirs)
+    assert sum(e["args"]["leaves"] for e in leaf_spans) == len(np.unique(pks // 64))
+    assert all(e["args"]["parent"] == "merge.apply" for e in leaf_spans)
+    names = {e["tname"] for e in leaf_spans}
+    if workers == 1:
+        assert names == {applied["tname"]}
+    else:
+        assert all(name.startswith("kart-leaf") for name in names)
+
+
+def test_a_stream_of_one_batch_stays_on_the_calling_thread(tmp_path, monkeypatch):
+    from kart_tpu import native
+    from kart_tpu.core import feature_tree
+    from kart_tpu.core.repo import KartRepo
+
+    if native.load_io() is None:
+        pytest.skip("no native IO core")
+    monkeypatch.setattr(os, "cpu_count", lambda: 4)
+    pks = np.arange(900, dtype=np.int64)
+    oids = np.full((900, 20), 9, dtype=np.uint8)
+    tm.reset()
+    tm.enable(metrics=True, trace=True)
+    try:
+        with tm.span("merge.apply"):
+            feature_tree.write_int_feature_tree(
+                KartRepo.init_repository(str(tmp_path / "one")).odb,
+                lambda: iter([(pks, oids)]),
+            )
+        events = tm.drain_events()
+        counters = tm.counters_snapshot()
+    finally:
+        tm.reset()
+    (leaf,) = [e for e in events if e["name"] == "merge.leaf_batch"]
+    (applied,) = [e for e in events if e["name"] == "merge.apply"]
+    assert leaf["tname"] == applied["tname"] and leaf["args"]["parent"] == "merge.apply"
+    assert (applied["args"]["leaf_batches"], applied["args"]["workers"]) == (1, 1)
+    assert (leaf["args"]["rows"], leaf["args"]["leaves"]) == (900, 15)
+    assert counters[("merge.leaf_batches", ())] == 1
+
+
+@pytest.mark.parametrize("why", ["no_native_library", "a_later_batch_out_of_order",
+                                 "a_later_batch_out_of_range"])
+def test_a_stream_that_does_not_qualify_is_answered_by_the_plan(
+    why, tmp_path, monkeypatch, quick_switches
+):
+    """The two declines: without the native core no batch is made; a batch
+    whose pks are out of order (or past the encoder's range) is met by a
+    later worker, after earlier batches' results exist. Either way the plan
+    names the same tree, whole, and no pool thread is left behind."""
+    import threading
+
+    from kart_tpu import native
+    from kart_tpu.core import feature_tree
+    from kart_tpu.core.repo import KartRepo
+
+    monkeypatch.setattr(feature_tree, "LEAF_STREAM_ROWS", 1_000)
+    monkeypatch.setattr(os, "cpu_count", lambda: 2)
+    rng = np.random.default_rng(41)
+    pks = np.arange(3, 8_003, dtype=np.int64)
+    oids = rng.integers(0, 256, (len(pks), 20), dtype=np.uint8)
+    if why == "no_native_library":
+        monkeypatch.setattr(native, "load_io", lambda: None)
+    elif native.load_io() is None:
+        pytest.skip("no native IO core: nothing to decline")
+    elif why == "a_later_batch_out_of_order":
+        pks[[6_500, 6_501]] = pks[[6_501, 6_500]]
+    else:
+        pks[-1] = 64**6
+    want = feature_tree.build_int_feature_tree(
+        KartRepo.init_repository(str(tmp_path / "plan")).odb, pks, oids
+    )
+
+    def batches():
+        for lo in range(0, len(pks), 1_024):  # 16 leaves a batch
+            yield pks[lo : lo + 1_024], oids[lo : lo + 1_024]
+
+    tm.reset()
+    tm.enable(metrics=True, trace=True)
+    try:
+        odb = KartRepo.init_repository(str(tmp_path / "merged")).odb
+        assert feature_tree._stream_leaf_trees(
+            batches(), feature_tree.PathEncoder.INT_PK_ENCODER
+        ) is None
+        made = [e["args"] for e in tm.drain_events() if e["name"] == "merge.leaf_batch"]
+        assert feature_tree.write_int_feature_tree(odb, batches) == want
+    finally:
+        tm.reset()
+    if why == "no_native_library":
+        assert made == []
+    else:  # the batches before the bad one were made, whole, before it was met
+        assert sum("leaves" in args for args in made) >= 6
+        assert sum("leaves" not in args for args in made) == 1
+    assert sum(1 for _ in odb.tree(want).walk_blobs()) == len(pks)
+    assert not [t for t in threading.enumerate() if t.name.startswith("kart-leaf")]
+
+
+def test_batches_that_end_inside_a_leaf_are_cut_again_and_lose_no_row(monkeypatch):
+    """``_cut_on_leaves``: batches as a caller may hand them over — a whole
+    column, pieces that end inside a leaf, a piece of one row, an empty one
+    — come out in key order, at most ``LEAF_STREAM_ROWS`` rows each, no leaf
+    in two of them; batches that end where a leaf ends are passed on as
+    views of what came in."""
+    from kart_tpu.core import feature_tree
+
+    monkeypatch.setattr(feature_tree, "LEAF_STREAM_ROWS", 1_000)
+    rng = np.random.default_rng(7)
+    pks = np.sort(rng.choice(30_000, 9_000, replace=False)).astype(np.int64)
+    oids = rng.integers(0, 256, (len(pks), 20), dtype=np.uint8)
+    for edges in ([0, 9_000], [0, 1, 1, 700, 701, 5_000, 9_000], list(range(0, 9_001, 450))):
+        got = list(feature_tree._cut_on_leaves(
+            ((pks[a:b], oids[a:b]) for a, b in zip(edges, edges[1:])), 64
+        ))
+        np.testing.assert_array_equal(np.concatenate([g[0] for g in got]), pks)
+        np.testing.assert_array_equal(np.concatenate([g[1] for g in got]), oids)
+        assert all(0 < len(g[0]) <= 1_000 for g in got)
+        assert all(a[0][-1] // 64 < b[0][0] // 64 for a, b in zip(got, got[1:]))
+    aligned = [int(np.searchsorted(pks, leaf * 64)) for leaf in (0, 5, 11, 17, 40, 469)]
+    got = list(feature_tree._cut_on_leaves(
+        ((pks[a:b], oids[a:b]) for a, b in zip(aligned, aligned[1:])), 64
+    ))
+    assert all(np.shares_memory(g[0], pks) and np.shares_memory(g[1], oids) for g in got)
 
 
 # -- the CLI merge on every route ---------------------------------------------
@@ -456,6 +688,41 @@ def test_a_dry_run_names_the_conflicts_and_changes_nothing(merge_layer, tmp_path
     assert reference._is_normal(repo_path, info)
     code, twin_stdout, _, _, _ = _merge(repo_path, env=HOST, dry_run=True)
     assert code == 0 and twin_stdout == stdout
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+def test_a_commands_leaf_batches_are_its_own_spans(workers, merge_layer, tmp_path, monkeypatch):
+    """``kart merge --dry-run`` with spans recorded: every ``merge.leaf_batch``
+    names ``merge.apply`` as its parent and carries the command's trace id,
+    on whichever thread it ran; their rows and leaves add up to the merged
+    layer's; ``merge.apply`` says how many batches and workers there were.
+    With one worker the spans are emitted all the same."""
+    from kart_tpu import native
+    from kart_tpu.core import feature_tree
+
+    if native.load_io() is None:
+        pytest.skip("no native IO core: the plan makes the tree, no batch is made")
+    builder, base = merge_layer
+    repo_path, info = builder.add_edit_commit(base, str(tmp_path), PARAMS, 2147484041)
+    monkeypatch.setattr(feature_tree, "LEAF_STREAM_ROWS", 500)
+    monkeypatch.setattr(os, "cpu_count", lambda: workers)
+    code, _, _, events, counters = _merge(repo_path, env=HOST, dry_run=True)
+    assert code == 0
+    (command,) = [e for e in events if e["name"] == "cli.command"]
+    (applied,) = [e for e in events if e["name"] == "merge.apply"]
+    batches = [e for e in events if e["name"] == "merge.leaf_batch"]
+    assert len(batches) >= 6
+    assert (applied["args"]["leaf_batches"], applied["args"]["workers"]) == (len(batches), workers)
+    assert counters[("merge.leaf_batches", ())] == len(batches)
+    for e in batches:
+        assert e["args"]["parent"] == "merge.apply"
+        assert e["args"]["trace_id"] == command["args"]["trace_id"]
+        assert applied["ts"] <= e["ts"] and e["ts"] + e["dur"] <= applied["ts"] + applied["dur"]
+        assert (e["tid"] == applied["tid"]) is (workers == 1)
+    merged_pks = np.asarray(info["merged_pks"])
+    assert sum(e["args"]["rows"] for e in batches) == len(merged_pks)
+    assert sum(e["args"]["leaves"] for e in batches) == len(np.unique(merged_pks // 64))
+    assert sum(e["args"]["bytes"] for e in batches) >= 30 * len(merged_pks)  # an entry a row
 
 
 def test_a_tree_without_a_sidecar_is_walked_once_and_its_sidecar_kept(merge_layer, tmp_path):
