@@ -1,13 +1,15 @@
-"""Vectorized Merkle feature-tree construction for int-pk datasets.
+"""Vectorized Merkle feature-tree construction.
 
 Builds the Datasets-V3 feature tree from (pk, blob-oid) columns as numpy
 matrix operations — filenames from the PathEncoder's batch matrix, per-leaf
 payloads sliced from one entries buffer, tree objects hashed+deflated
-through the native batch IO. Bit-identical to per-path TreeBuilder
-construction (tested in tests/test_synth.py) at a fraction of the Python
-cost; used by the bulk importer's int-pk fast path and the synthetic-repo
-generator (kart_tpu/synth.py). Reference analog: the N x git fast-import
-tree build (kart/fast_import.py:286-399).
+through the native batch IO, the upper levels a level at a time as one
+fixed-width entry matrix. Bit-identical to per-path TreeBuilder
+construction (tested in tests/test_synth.py and tests/test_hash_keyed.py)
+at a fraction of the Python cost; used by the bulk importer (int-pk and
+msgpack/hash layouts), the merge's apply and the synthetic-repo generator
+(kart_tpu/synth.py). Reference analog: the N x git fast-import tree build
+(kart/fast_import.py:286-399).
 """
 
 import contextlib
@@ -52,7 +54,6 @@ def plan_int_feature_tree(pks, encoder=None):
     pks must be unique int64 (any order)."""
     from kart_tpu.models.paths import _b64_batch, _msgpack_single_int_batch
 
-    HOLE = 0xFF
     encoder = encoder or PathEncoder.INT_PK_ENCODER
     assert encoder.group_length == 1, "upper-level builder assumes 1-char tree names"
     plan = TreePlan()
@@ -65,12 +66,21 @@ def plan_int_feature_tree(pks, encoder=None):
     else:
         srt = np.argsort(pks, kind="stable")
     pks = np.ascontiguousarray(pks[srt])
-    n = plan.n = len(pks)
 
     fn_bytes, fn_len = _msgpack_single_int_batch(pks)
     b64_mat, b64_len = _b64_batch(fn_bytes, fn_len)
-    b64w = b64_mat.shape[1]
     leaf_ids = (pks // encoder.branches) % encoder.max_trees
+    return _plan_named(plan, b64_mat, b64_len, leaf_ids, srt)
+
+
+def _plan_named(plan, b64_mat, b64_len, leaf_ids, srt, last_wins=False):
+    """The layout of rows named ``b64_mat`` (row ``i`` its first
+    ``b64_len[i]`` bytes) in the leaves ``leaf_ids``; ``srt`` maps the rows
+    to the caller's. ``last_wins``: of rows with one leaf and one name, the
+    last alone is kept (a tree builder's insert over an insert)."""
+    HOLE = 0xFF
+    n = len(leaf_ids)
+    b64w = b64_mat.shape[1]
 
     # sort by (leaf, name-bytes): git tree order; zero-padding the key
     # reproduces "a name that is a prefix of another sorts first"
@@ -87,6 +97,13 @@ def plan_int_feature_tree(pks, encoder=None):
         tuple(words[:, i] for i in range(words.shape[1] - 1, -1, -1))
         + (leaf_ids,)
     )
+    if last_wins and n > 1:
+        # the sort is stable: of equal rows the last sorts last
+        w, lf = words[order], leaf_ids[order]
+        same_next = (lf[1:] == lf[:-1]) & (w[1:] == w[:-1]).all(axis=1)
+        order = order[~np.append(same_next, False)]
+        n = len(order)
+    plan.n = n
     plan.order = srt[order]  # original-row -> sorted-row permutation
     b64_mat = b64_mat[order]
     b64_len = b64_len[order]
@@ -143,6 +160,22 @@ def _stamp_oids(plan, oids_u8):
     else:
         rows = np.arange(plan.n)
         plan.entry_matrix[rows[:, None], plan.oid_cols] = oids_sorted
+
+
+def _payload_buffer(plan):
+    """Every leaf payload of a stamped plan as one buffer (the entry matrix,
+    holes left out) and offsets: leaf ``k`` is ``buf[offsets[k]:offsets[k +
+    1]]``."""
+    offsets = np.empty(len(plan.uniq_leaves) + 1, dtype=np.int64)
+    if plan.fixed_width:
+        buf = plan.entry_matrix.reshape(-1)
+        offsets[0] = 0
+        np.cumsum(plan.counts * plan.entry_matrix.shape[1], out=offsets[1:])
+    else:
+        buf = plan.entry_matrix[~plan.hole_mask]
+        offsets[:-1] = plan.byte_offsets[plan.first_idx]
+        offsets[-1] = plan.byte_offsets[plan.n]
+    return buf, offsets
 
 
 def _leaf_payloads(plan, touched):
@@ -240,32 +273,90 @@ def build_upper_levels(odb, child_ids, child_oids, encoder):
     slots (``pk // branches`` space, ascending); ``child_oids``: their hex
     oids. Shared by :func:`emit_feature_tree` and the import pipeline's
     streamed leaf build (identical grouping -> identical tree objects)."""
-    # upper levels: group child trees by parent prefix, entries
-    # "40000 <char>\0" + oid, children sorted by raw char byte
-    alpha = encoder.alphabet
     child_ids = np.asarray(child_ids, dtype=np.int64)
-    for _level in range(encoder.levels - 1, -1, -1):
-        parents = {}
-        for cid, coid in zip(child_ids.tolist(), child_oids):
-            digit = cid % encoder.branches
-            parents.setdefault(cid // encoder.branches, []).append(
-                (alpha[digit], coid)
+    oids_u8 = np.frombuffer(bytes.fromhex("".join(child_oids)), dtype=np.uint8)
+    return _spine(odb, child_ids, oids_u8.reshape(-1, 20), None, encoder)
+
+
+#: tree objects a native framing call makes (a batch of the payload column)
+_PAYLOAD_BATCH = 262_144
+_SUBTREE_ENTRY = 28  # "40000 " + one-character name + NUL + 20-byte oid
+
+
+def _write_payloads(odb, buf, offsets):
+    """Write the tree objects ``buf[offsets[i]:offsets[i + 1]]``; -> their
+    (n, 20) uint8 oids. Into a bulk pack they go framed by the native IO
+    core a batch at a time, with no bytes object a tree; anywhere else (a
+    loose store, :class:`_TreeNamer`) through ``write_raw``."""
+    from kart_tpu import native
+    from kart_tpu.core.packs import TYPE_CODES
+
+    offsets = np.asarray(offsets, dtype=np.int64)
+    n = len(offsets) - 1
+    out = np.empty((n, 20), dtype=np.uint8)
+    writer = getattr(odb, "_bulk_writer", None)
+    for i in range(0, n, _PAYLOAD_BATCH):
+        j = min(n, i + _PAYLOAD_BATCH)
+        lo, hi = int(offsets[i]), int(offsets[j])
+        framed = None
+        if writer is not None:
+            framed = native.pack_records_base(
+                "tree", TYPE_CODES["tree"], buf[lo:hi], offsets[i : j + 1] - lo,
+                writer.level,
             )
-        parent_ids = np.fromiter(parents.keys(), dtype=np.int64, count=len(parents))
-        parent_ids.sort()
-        payloads = []
-        for pid in parent_ids.tolist():
-            entries = sorted(parents[pid], key=lambda t: t[0].encode())
-            payloads.append(
-                b"".join(
-                    b"40000 %s\x00" % ch.encode() + bytes.fromhex(oid)
-                    for ch, oid in entries
-                )
-            )
-        child_oids = _write_level(odb, payloads)
-        child_ids = parent_ids
-    assert len(child_oids) == 1
-    return child_oids[0]
+        if framed is not None:
+            out[i:j] = writer.append_framed(framed)
+            continue
+        chunk = bytes(buf[lo:hi])
+        bounds = (offsets[i : j + 1] - lo).tolist()
+        hexes = _write_level(odb, [chunk[a:b] for a, b in zip(bounds[:-1], bounds[1:])])
+        out[i:j] = np.frombuffer(bytes.fromhex("".join(hexes)), dtype=np.uint8).reshape(
+            -1, 20
+        )
+    return out
+
+
+def _spine(odb, ids, oids_u8, present, encoder, old=None, written=None):
+    """The upper-level trees over changed nodes of the deepest level; ->
+    feature-root hex oid. ``ids``: the nodes (int64 slots, ascending,
+    unique), ``oids_u8`` (n, 20) their new oids, ``present``: which of them
+    still exist (None: all). ``old``, for a tree that was there before:
+    ``old[d]`` = (slots, oids) of the children the depth-``d`` ancestors of
+    ``ids`` had, which stay unless ``ids`` name them. A level at a time:
+    each parent's entries ``"40000 <char>\\0" + oid`` sorted by the raw
+    character byte, as one fixed-width matrix. ``written``: a list the
+    number of trees each level wrote is appended to."""
+    branches, alpha = encoder.branches, encoder._alpha_u8
+    if present is None:
+        present = np.ones(len(ids), dtype=bool)
+    for depth in range(encoder.levels - 1, -1, -1):
+        parents = np.unique(ids // branches)
+        c_ids, c_oids = ids[present], oids_u8[present]
+        if old is not None:
+            o_ids, o_oids = old[depth]
+            stay = ~np.isin(o_ids, ids)
+            c_ids = np.concatenate([o_ids[stay], c_ids])
+            c_oids = np.concatenate([o_oids[stay], c_oids])
+        chars = alpha[c_ids % branches]
+        order = np.lexsort((chars, c_ids // branches))
+        entries = np.empty((len(order), _SUBTREE_ENTRY), dtype=np.uint8)
+        entries[:, :6] = np.frombuffer(b"40000 ", dtype=np.uint8)
+        entries[:, 6] = chars[order]
+        entries[:, 7] = 0
+        entries[:, 8:] = c_oids[order]
+        par = c_ids[order] // branches
+        first = np.flatnonzero(np.append(True, par[1:] != par[:-1])) if len(par) else par
+        offsets = np.append(first, len(par)) * _SUBTREE_ENTRY
+        made = _write_payloads(odb, entries.reshape(-1), offsets)
+        if written is not None:
+            written.append(len(made))
+        present = np.isin(parents, par[first])
+        oids_u8 = np.zeros((len(parents), 20), dtype=np.uint8)
+        oids_u8[present] = made
+        ids = parents
+    if not present[0]:
+        return odb.write_tree([])
+    return oids_u8[0].tobytes().hex()
 
 
 def build_int_feature_tree(odb, pks, oids_u8, encoder=None):
@@ -317,10 +408,11 @@ def _root_over_leaves(odb, leaf_id_chunks, leaf_oid_chunks, encoder):
     oid. ``leaf_id_chunks``: ascending int64 leaf slots, an array a batch;
     ``leaf_oid_chunks``: their (n, 20) uint8 oids, batch for batch."""
     child_ids = np.concatenate(leaf_id_chunks)
-    hexes = b"".join(c.tobytes() for c in leaf_oid_chunks).hex()
-    child_oids = [hexes[i : i + 40] for i in range(0, len(hexes), 40)]
+    child_oids = np.concatenate(
+        [np.asarray(c, dtype=np.uint8).reshape(-1, 20) for c in leaf_oid_chunks]
+    )
     assert len(child_oids) == len(child_ids)
-    return build_upper_levels(odb, child_ids, child_oids, encoder)
+    return _spine(odb, child_ids.astype(np.int64), child_oids, None, encoder)
 
 
 def _cut_on_leaves(batches, branches):
@@ -462,6 +554,143 @@ def write_int_feature_tree(odb, batches, encoder=None):
     return root
 
 
+class _NotLaidOut(Exception):
+    """A tree that is not the encoder's layout (another encoder wrote it,
+    or a hand-made commit): the per-path builder must do it."""
+
+
+def _parse_subtrees(contents, node_ids, encoder):
+    """Raw contents of upper-level trees -> (child slots int64, (m, 20)
+    uint8 oids), sorted by slot. Every entry must be a subtree with a
+    one-character name of the encoder's alphabet."""
+    lens = np.fromiter((len(c) for c in contents), dtype=np.int64, count=len(contents))
+    if len(lens) and bool((lens % _SUBTREE_ENTRY).any()):
+        raise _NotLaidOut()
+    rows = np.frombuffer(b"".join(contents), dtype=np.uint8).reshape(-1, _SUBTREE_ENTRY)
+    digit = encoder._alpha_inv[rows[:, 6]]
+    if not (
+        (rows[:, :6] == np.frombuffer(b"40000 ", dtype=np.uint8)).all()
+        and (rows[:, 7] == 0).all()
+        and (digit >= 0).all()
+    ):
+        raise _NotLaidOut()
+    ids = np.repeat(np.asarray(node_ids, dtype=np.int64), lens // _SUBTREE_ENTRY)
+    ids = ids * encoder.branches + digit
+    order = np.argsort(ids, kind="stable")
+    return ids[order], np.ascontiguousarray(rows[order, 8:])
+
+
+def _parse_leaves(contents, leaf_ids):
+    """Raw contents of leaf trees -> [(leaf slot, name bytes, oid bytes)]."""
+    out = []
+    for leaf, content in zip(leaf_ids, contents):
+        i = 0
+        while i < len(content):
+            sp = content.index(b" ", i)
+            nul = content.index(b"\x00", sp)
+            if content[i:sp] != b"100644":
+                raise _NotLaidOut()
+            out.append((leaf, content[sp + 1 : nul], content[nul + 1 : nul + 21]))
+            i = nul + 21
+    return out
+
+
+def _read_touched(odb, root_oid, leaf_ids, encoder):
+    """The parts of the tree ``root_oid`` that a change of the leaves
+    ``leaf_ids`` (ascending, unique) rewrites, read top down: -> (``old``
+    for :func:`_spine`, the entries those leaves hold now)."""
+    old = []
+    node_ids, node_oids = np.zeros(1, dtype=np.int64), [root_oid]
+    for depth in range(encoder.levels + 1):
+        contents = []
+        for oid in node_oids:
+            kind, content = odb.read_raw(oid)
+            if kind != "tree":
+                raise _NotLaidOut()
+            contents.append(content)
+        if depth == encoder.levels:
+            return old, _parse_leaves(contents, node_ids.tolist())
+        c_ids, c_oids = _parse_subtrees(contents, node_ids, encoder)
+        old.append((c_ids, c_oids))
+        want = np.unique(leaf_ids // encoder.branches ** (encoder.levels - 1 - depth))
+        pos = np.minimum(np.searchsorted(c_ids, want), max(len(c_ids) - 1, 0))
+        found = c_ids[pos] == want if len(c_ids) else np.zeros(len(want), dtype=bool)
+        node_ids = want[found]
+        hexes = c_oids[pos[found]].tobytes().hex()
+        node_oids = [hexes[i : i + 40] for i in range(0, len(hexes), 40)]
+
+
+def write_hash_feature_tree(odb, rows, oids_u8, encoder, *, prev=None, removed=None):
+    """The feature tree of a msgpack/hash dataset, written column-wise; ->
+    its hex oid, or None where ``prev`` is not laid out by ``encoder`` (the
+    caller's per-path builder does it then).
+
+    ``rows``: :class:`kart_tpu.models.paths.HashRows` of the features to
+    write, ``oids_u8`` their (n, 20) blob oids; of two rows with one pk the
+    last is kept. With ``prev`` (a feature tree's hex oid) the tree is
+    ``prev`` less the features ``removed`` (HashRows; pks it does not hold
+    are passed over) plus ``rows``: only the leaves those touch, and their
+    ancestors, are read and written. Bit-identical to ``TreeBuilder``'s
+    ``remove`` of every removed path then ``insert_many`` of the rows
+    (tested). Leaves are made as the int layout's are (:func:`_plan_named`:
+    git's entry order, one matrix), written through the native framing in
+    batches, then the upper levels a level at a time (:func:`_spine`)."""
+    written = []
+    with tm.span("feature_tree.write", scheme=encoder.scheme, rows=len(rows)) as sp:
+        oids_u8 = np.asarray(oids_u8, dtype=np.uint8).reshape(-1, 20)
+        old = None
+        if prev is None:
+            if not len(rows):
+                return odb.write_tree([])
+            names, lens, leaf_ids = rows.names, rows.name_lens, rows.leaf_ids
+        else:
+            from kart_tpu.models.paths import ByteRows
+
+            gone = removed if removed is not None else rows.take(slice(0, 0))
+            touched = np.unique(np.concatenate([gone.leaf_ids, rows.leaf_ids]))
+            if not len(touched):
+                return prev
+            try:
+                old, entries = _read_touched(odb, prev, touched, encoder)
+            except _NotLaidOut:
+                return None
+            drop = set(gone.name_rows().tolist()) | set(rows.name_rows().tolist())
+            kept = [e for e in entries if e[1] not in drop]
+            mat, lens = ByteRows.from_list(
+                [e[1] for e in kept] + rows.name_rows().tolist()
+            ).matrix()
+            names, leaf_ids = mat, np.concatenate(
+                [np.fromiter((e[0] for e in kept), dtype=np.int64, count=len(kept)),
+                 rows.leaf_ids]
+            )
+            oids_u8 = np.concatenate([
+                np.frombuffer(b"".join(e[2] for e in kept), dtype=np.uint8).reshape(-1, 20),
+                oids_u8,
+            ])
+        plan = _plan_named(
+            TreePlan(), names, lens, leaf_ids, np.arange(len(leaf_ids)), last_wins=True
+        )
+        plan.encoder = encoder
+        if plan.n:
+            _stamp_oids(plan, oids_u8)
+            buf, offsets = _payload_buffer(plan)
+            leaf_oids = _write_payloads(odb, buf, offsets)
+        else:
+            leaf_oids = np.zeros((0, 20), dtype=np.uint8)
+        written.append(len(leaf_oids))
+        if old is None:
+            ids, present = plan.uniq_leaves, None
+        else:
+            ids = touched
+            present = np.isin(touched, plan.uniq_leaves)
+            new_oids = np.zeros((len(touched), 20), dtype=np.uint8)
+            new_oids[present] = leaf_oids
+            leaf_oids = new_oids
+        root = _spine(odb, ids, leaf_oids, present, encoder, old, written)
+        sp.set(trees=sum(written))
+    return root
+
+
 class StreamingLeafEmitter:
     """Incremental leaf-tree construction from the import pipeline's sorted
     (pk, blob-oid) stream: :meth:`feed` buffers the trailing partial leaf
@@ -528,18 +757,7 @@ class StreamingLeafEmitter:
             self._native = False  # lib lost mid-run: stay on the plan path
         plan = plan_int_feature_tree(pks, self.encoder)
         _stamp_oids(plan, oids_u8)
-        n_leaves = len(plan.uniq_leaves)
-        offsets = np.empty(n_leaves + 1, dtype=np.int64)
-        if plan.fixed_width:
-            buf = plan.entry_matrix.reshape(-1)
-            offsets[0] = 0
-            np.cumsum(
-                plan.counts * plan.entry_matrix.shape[1], out=offsets[1:]
-            )
-        else:
-            buf = plan.entry_matrix[~plan.hole_mask]
-            offsets[:-1] = plan.byte_offsets[plan.first_idx]
-            offsets[-1] = plan.byte_offsets[plan.n]
+        buf, offsets = _payload_buffer(plan)
         self.leaf_id_chunks.append(plan.uniq_leaves)
         return buf, offsets, plan.uniq_leaves
 

@@ -151,6 +151,77 @@ def _pks_for_index(block, ds, i):
     return ds.decode_path_to_pks(block.path_for_index(i))
 
 
+def _hash_keyed(ds):
+    """Is ``ds`` keyed by the hash of its feature filenames (not an int pk)?"""
+    encoder = getattr(ds, "path_encoder", None)
+    return encoder is not None and encoder.scheme != "int"
+
+
+def key_guard(old_block, new_block):
+    """Why two hash-keyed blocks cannot be joined by key, or None: two rows
+    of one side share a key (``within``, as the sidecar recorded it when it
+    was written), or a real key is the padding key the kernels take for no
+    row (``pad_key``). Counted under ``diff.hash_guard.fallbacks{why}``."""
+    for block in (old_block, new_block):
+        why = (
+            "within" if block.has_key_collisions()
+            else "pad_key" if block.has_pad_key() else None
+        )
+        if why is not None:
+            tm.incr("diff.hash_guard.fallbacks", why=why)
+            return why
+    return None
+
+
+def _path_rows(paths, rows):
+    """The feature paths of block rows ``rows``, as ByteRows."""
+    from kart_tpu.models.paths import ByteRows
+
+    if isinstance(paths, ByteRows):
+        return paths.take(rows)
+    return ByteRows.from_list([paths[int(i)].encode("utf8") for i in rows])
+
+
+def _rows_differ(a, b):
+    """bool per row: do ByteRows ``a`` and ``b`` hold other bytes?"""
+    differ = a.lengths() != b.lengths()
+    same = np.flatnonzero(~differ)
+    if len(same):
+        a, b = a.take(same), b.take(same)
+        neq = np.asarray(a.data) != np.asarray(b.data)
+        if neq.any():
+            differ[same] = np.logical_or.reduceat(neq, a.offs[:-1])
+    return differ
+
+
+def hash_guard(old_block, new_block, old_class, new_class):
+    """The cross-version collision guard of a hash-keyed join: a deleted
+    feature and an inserted one can share a 63-bit key, which the join reads
+    as an update. Both sides are sorted by key and keys are unique on each,
+    so the k-th UPDATE row of one side pairs with the k-th of the other;
+    every pair must name one feature path (the path is a function of the
+    filename, so this is the filename compared) — gathered from both
+    sides' path columns and compared as one array. -> True when they all
+    do; else the fallback is counted (``why=across``) and the caller takes
+    the exact path."""
+    from kart_tpu.ops.diff_kernel import UPDATE
+
+    with tm.span("diff.hash_guard") as sp:
+        old_upd = np.flatnonzero(old_class == UPDATE)
+        new_upd = np.flatnonzero(new_class == UPDATE)
+        if len(old_upd) != len(new_upd):
+            collisions = abs(len(old_upd) - len(new_upd))
+        else:
+            collisions = int(np.count_nonzero(_rows_differ(
+                _path_rows(old_block.paths, old_upd),
+                _path_rows(new_block.paths, new_upd),
+            )))
+        sp.set(pairs=min(len(old_upd), len(new_upd)), collisions=collisions)
+    if collisions:
+        tm.incr("diff.hash_guard.fallbacks", why="across")
+    return not collisions
+
+
 def get_feature_diff_columnar(base_ds, target_ds, ds_filter=None, *, blocks=None):
     """Bulk columnar variant of get_feature_diff: both versions' (pk, oid)
     arrays are classified in one jitted device join, and only changed rows
@@ -181,9 +252,13 @@ def get_feature_diff_columnar(base_ds, target_ds, ds_filter=None, *, blocks=None
         new_block = FeatureBlock.from_dataset(target_ds) if target_ds is not None else None
     old_block = old_block if old_block is not None else empty_block()
     new_block = new_block if new_block is not None else empty_block()
-    if old_block.has_key_collisions() or new_block.has_key_collisions():
-        # 63-bit hash identity collided (hash-encoded dataset): fall back to
-        # the exact tree-diff path
+    hash_keyed = _hash_keyed(base_ds or target_ds)
+    if (
+        key_guard(old_block, new_block) if hash_keyed
+        else old_block.has_key_collisions() or new_block.has_key_collisions()
+    ):
+        # 63-bit hash identity collided (hash-encoded dataset), or a key
+        # the kernels take for padding: the exact tree-diff path
         return get_feature_diff(base_ds, target_ds, ds_filter)
 
     from kart_tpu.diff.backend import select_backend
@@ -197,24 +272,8 @@ def get_feature_diff_columnar(base_ds, target_ds, ds_filter=None, *, blocks=None
     ):
         old_class, new_class, _ = backend.classify(old_block, new_block)
         old_idx, new_idx = changed_indices(old_class, new_class)
-
-    # Cross-version collision guard (hash-encoded datasets): a deleted pk X
-    # and an inserted pk Y can share a 63-bit key, which the join would
-    # misread as an update of X. Every matched-but-changed (UPDATE) pair must
-    # refer to the same blob filename on both sides; otherwise fall back.
-    hash_keyed = getattr(base_ds or target_ds, "path_encoder", None) is not None and (
-        (base_ds or target_ds).path_encoder.scheme != "int"
-    )
-    if hash_keyed:
-        new_changed_filenames = {
-            new_block.path_for_index(int(i)).rsplit("/", 1)[-1]
-            for i in new_idx
-        }
-        for i in old_idx:
-            if old_class[i] == UPDATE:
-                fn = old_block.path_for_index(int(i)).rsplit("/", 1)[-1]
-                if fn not in new_changed_filenames:
-                    return get_feature_diff(base_ds, target_ds, ds_filter)
+    if hash_keyed and not hash_guard(old_block, new_block, old_class, new_class):
+        return get_feature_diff(base_ds, target_ds, ds_filter)
 
     # values resolve by oid straight from the sidecar columns — no
     # per-feature path->tree walk at materialisation time (measured ~500us
@@ -603,9 +662,16 @@ def get_dataset_feature_count_fast(
     blob reads for the residue; NULL and empty geometries match, a blob
     that is promised matches).
 
+    A hash-keyed dataset (msgpack/hash paths) is counted from the class
+    arrays after two guards: no side may hold a key twice or the padding
+    key (:func:`key_guard`), and every UPDATE pair must name one path on
+    both sides (:func:`hash_guard`). Either failing, the delta path answers,
+    exactly.
+
     -> int, or None when the count can't be taken from the columnar route
-    with delta-path parity (dataset added/removed, hash-keyed identities,
-    missing sidecars, or the engine forced to the tree walk)."""
+    with delta-path parity (dataset added/removed, a hash-keyed collision,
+    a spatially filtered hash-keyed dataset, missing sidecars, or the
+    engine forced to the tree walk)."""
     import os
 
     from kart_tpu.diff import sidecar
@@ -622,16 +688,17 @@ def get_dataset_feature_count_fast(
         target_tree.oid if target_tree is not None else None
     ):
         return 0
-    for ds in (base_ds, target_ds):
-        enc = getattr(ds, "path_encoder", None)
-        if enc is None or enc.scheme != "int":
-            return None  # hash-keyed: collision guards need the delta path
+    if any(getattr(ds, "path_encoder", None) is None for ds in (base_ds, target_ds)):
+        return None
+    hash_keyed = _hash_keyed(base_ds) or _hash_keyed(target_ds)
     repo = base_ds.repo or target_ds.repo
     if repo is None:
         return None
     if not (sidecar.has_sidecar(repo, base_ds) and sidecar.has_sidecar(repo, target_ds)):
         return None
     rect = _prefilter_rect(spatial_filter_spec)
+    if hash_keyed and rect is not None:
+        return None  # the filtered count's refine reads pks: int-pk only
     # no padded copies: the host engine and the sharded device path
     # consume count-sliced mmap views, and the one-device route pads chunk
     # by chunk inside classify_blocks (at 100M the two padded copies were
@@ -670,13 +737,27 @@ def get_dataset_feature_count_fast(
         )
 
     backend = select_backend(max(old_block.count, new_block.count))
-    with tm.span(
-        "diff.classify",
-        rows=max(old_block.count, new_block.count),
-        backend=backend.name,
-        counts_only=True,
-    ):
-        counts = backend.counts(old_block, new_block)
+    if hash_keyed:
+        # the classes are wanted for the guard: no count-only route
+        if key_guard(old_block, new_block):
+            return None
+        with tm.span(
+            "diff.classify",
+            rows=max(old_block.count, new_block.count),
+            backend=backend.name,
+            counts_only=False,
+        ):
+            old_class, new_class, counts = backend.classify(old_block, new_block)
+        if not hash_guard(old_block, new_block, old_class, new_class):
+            return None
+    else:
+        with tm.span(
+            "diff.classify",
+            rows=max(old_block.count, new_block.count),
+            backend=backend.name,
+            counts_only=True,
+        ):
+            counts = backend.counts(old_block, new_block)
     return counts["inserts"] + counts["updates"] + counts["deletes"]
 
 

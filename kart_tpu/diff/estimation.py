@@ -167,12 +167,7 @@ def estimate_counts_from_blocks(old_block, new_block, accuracy):
         keys_p[:n] = sub_keys
         oids_p = np.zeros((size, 5), dtype=np.uint32)
         oids_p[:n] = sub_oids
-        sub = FeatureBlock.__new__(FeatureBlock)
-        sub.keys = keys_p
-        sub.oids = oids_p
-        sub.paths = None
-        sub.count = n
-        return sub
+        return FeatureBlock(keys_p, oids_p, None, n)
 
     old_sub = subsample(old_block)
     new_sub = subsample(new_block)

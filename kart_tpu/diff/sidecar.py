@@ -10,10 +10,13 @@ resets and clones — a tree oid never changes meaning. Files:
     magic   b"KCOL1\\n"
     header  one json line: {"count": N, "keys_are_pks": bool,
                             "paths_bytes": M, "envelope_bytes": E,
-                            "agg_block_rows": B}   (B only with aggregates)
+                            "agg_block_rows": B,   (B only with aggregates)
+                            "key_collisions": bool,
+                            "path_offset_bytes": 8}  (only when M > 2^32 - 1)
     arrays  keys   int64[N]    (little-endian; pk, or filename-hash key)
             oids   uint8[N,20]
-            offs   uint32[N+1]  (only when paths stored)
+            offs   uint32[N+1]  (only when paths stored; uint64 where the
+                                 header says 8 offset bytes)
             paths  utf8 bytes   (blob-relative paths, concatenated)
             envs   float32[N,4] (only when envelope_bytes > 0: per-feature
                                  wsen EPSG:4326 envelopes — feeds the
@@ -47,6 +50,10 @@ trailing section gated by a new header key ("geom_bytes"), so old readers
 skip it and new readers of old files fall back to blob-read extraction
 (docs/FORMAT.md §3.4).
 
+"key_collisions" is what the writer found in the sorted key column (two rows
+with one key: a hash-keyed identity collided), so no reader scans for it;
+a file without it is scanned as before (docs/FORMAT.md §3).
+
 A small LRU (by mtime) bounds the cache directory size.
 """
 
@@ -56,10 +63,14 @@ import os
 import numpy as np
 
 from kart_tpu import telemetry as tm
+from kart_tpu.models.paths import ByteRows
 from kart_tpu.ops.blocks import FeatureBlock, bucket_size, PAD_KEY, hash_keys_for_paths
 
 MAGIC = b"KCOL1\n"
 MAX_CACHED_FILES = 64
+#: path bytes past which the path offsets are written as uint64: a uint32
+#: offset wraps at 4 GiB of paths (~70M rows of a UUID-keyed layer)
+PATH_OFFSETS_U32_MAX = 0xFFFFFFFF
 
 #: rows per envelope-aggregate block: small enough that boundary blocks'
 #: fine scans stay cheap (64KB of envelope data), large enough that the
@@ -122,18 +133,11 @@ def sidecar_file(repo, feature_tree_oid):
     return os.path.join(_cache_dir(repo), feature_tree_oid + ".kcol")
 
 
-class LazyPaths:
+class LazyPaths(ByteRows):
     """List-like view over (offsets, bytes) without materialising N python
     strings — changed rows only are ever looked up."""
 
-    __slots__ = ("offs", "data")
-
-    def __init__(self, offs, data):
-        self.offs = offs
-        self.data = data
-
-    def __len__(self):
-        return len(self.offs) - 1
+    __slots__ = ()
 
     def __getitem__(self, i):
         return bytes(self.data[self.offs[i] : self.offs[i + 1]]).decode("utf8")
@@ -160,8 +164,8 @@ class IntKeyPaths:
 def save_sidecar(repo, feature_tree_oid, keys, oids_u8, paths=None, envelopes=None,
                  vertices=None):
     """Persist a sidecar. ``keys`` int64 (N,), ``oids_u8`` uint8 (N, 20) —
-    *not necessarily sorted*; ``paths`` list[str] aligned with keys, or None
-    for int-pk datasets; ``envelopes`` (N, 4) float wsen per feature, or
+    *not necessarily sorted*; ``paths`` list[str] or ``ByteRows`` aligned
+    with keys, or None for int-pk datasets; ``envelopes`` (N, 4) float wsen per feature, or
     None; ``vertices`` a kart_tpu.geom.VertexColumn aligned with keys, or
     None. Atomic (tmp + rename)."""
     with tm.span("sidecar.save", rows=int(len(keys))):
@@ -181,12 +185,12 @@ def _save_sidecar(repo, feature_tree_oid, keys, oids_u8, paths, envelopes,
     path_blob = b""
     offs = None
     if paths is not None:
-        encoded = [paths[i].encode("utf8") for i in order]
-        offs = np.zeros(len(encoded) + 1, dtype="<u4")
-        offs[1:] = np.cumsum(
-            np.fromiter((len(e) for e in encoded), dtype=np.int64, count=len(encoded))
-        )
-        path_blob = b"".join(encoded)
+        if not isinstance(paths, ByteRows):
+            paths = ByteRows.from_list([p.encode("utf8") for p in paths])
+        paths = paths.take(order)
+        path_blob = paths.data
+        wide = len(path_blob) > PATH_OFFSETS_U32_MAX
+        offs = paths.offs.astype("<u8" if wide else "<u4")
     env_arr = None
     agg = flags = None
     if envelopes is not None:
@@ -206,7 +210,10 @@ def _save_sidecar(repo, feature_tree_oid, keys, oids_u8, paths, envelopes,
         "keys_are_pks": paths is None,
         "paths_bytes": len(path_blob),
         "envelope_bytes": int(env_arr.nbytes) if env_arr is not None else 0,
+        "key_collisions": bool(np.any(keys[1:] == keys[:-1])),
     }
+    if offs is not None and offs.dtype.itemsize == 8:
+        header_fields["path_offset_bytes"] = 8
     if agg is not None:
         header_fields["agg_block_rows"] = AGG_BLOCK_ROWS
     if geom_blob:
@@ -222,7 +229,7 @@ def _save_sidecar(repo, feature_tree_oid, keys, oids_u8, paths, envelopes,
         f.write(oids_u8.tobytes())
         if offs is not None:
             f.write(offs.tobytes())
-            f.write(path_blob)
+            f.write(memoryview(path_blob))
         if env_arr is not None:
             f.write(env_arr.tobytes())
         if agg is not None:
@@ -292,8 +299,9 @@ def _load_block_from_mmap(mm, dataset, pad):
         if header["keys_are_pks"]:
             paths = IntKeyPaths(keys, dataset.path_encoder, n)
         else:
-            offs = np.frombuffer(mm, dtype="<u4", count=n + 1, offset=pos)
-            pos += 4 * (n + 1)
+            width = header.get("path_offset_bytes", 4)
+            offs = np.frombuffer(mm, dtype=f"<u{width}", count=n + 1, offset=pos)
+            pos += width * (n + 1)
             data = mm[pos : pos + header["paths_bytes"]]
             paths = LazyPaths(offs, data)
             pos += header["paths_bytes"]
@@ -326,6 +334,7 @@ def _load_block_from_mmap(mm, dataset, pad):
     except (IndexError, KeyError, ValueError):
         return None
 
+    collisions = header.get("key_collisions")
     if not pad:
         oid_rows = (
             oids_u8.reshape(n, 5, 4).view(np.uint32).reshape(n, 5)
@@ -334,7 +343,7 @@ def _load_block_from_mmap(mm, dataset, pad):
         )
         return FeatureBlock(
             keys, oid_rows, paths, n, envelopes=envelopes, env_blocks=env_blocks,
-            geom_raw=geom_raw,
+            geom_raw=geom_raw, key_collisions=collisions,
         )
     # pad (copy — the kernel wants aligned padded arrays; the mmap'd
     # originals stay untouched for the path views)
@@ -346,7 +355,7 @@ def _load_block_from_mmap(mm, dataset, pad):
         oids_p[:n] = oids_u8.reshape(n, 5, 4).view(np.uint32).reshape(n, 5)
     return FeatureBlock(
         keys_p, oids_p, paths, n, envelopes=envelopes, env_blocks=env_blocks,
-        geom_raw=geom_raw,
+        geom_raw=geom_raw, key_collisions=collisions,
     )
 
 
@@ -376,21 +385,27 @@ def ensure_block(repo, dataset, pad=True):
 
 def update_sidecar_for_commit(repo, old_ds, new_feature_tree_oid, feature_diff):
     """Derive the new feature tree's sidecar from the old one + the commit's
-    feature deltas — O(changed) instead of an O(N) tree walk. Int-pk datasets
-    only (hash-keyed ones would need path bookkeeping per delta); silently a
-    no-op when the old sidecar is missing (it's a cache)."""
+    feature deltas — O(changed) instead of an O(N) tree walk. An int-pk
+    dataset's rows are named by pk, a hash-keyed one's by feature path;
+    silently a no-op when the old sidecar is missing (it's a cache)."""
     if old_ds is None or old_ds.feature_tree is None:
-        return None
-    if old_ds.path_encoder.scheme != "int":
         return None
     target = sidecar_file(repo, new_feature_tree_oid)
     if os.path.exists(target):
         return target
-    block = load_block(repo, old_ds)
+    block = load_block(repo, old_ds, pad=False)
     if block is None:
         return None
 
     from kart_tpu.core.objects import hash_object
+
+    encoder = old_ds.path_encoder
+    hashed = encoder.scheme != "int"
+
+    def row_name(pk_values):
+        if hashed:
+            return encoder.encode_pks_to_path(tuple(pk_values))
+        return int(pk_values[0])
 
     schema = old_ds.schema
     geom_col = next(
@@ -402,13 +417,14 @@ def update_sidecar_for_commit(repo, old_ds, new_feature_tree_oid, feature_diff):
     added_geoms = {} if block.vertex_column() is not None else None
     for delta in feature_diff.values():
         if delta.old is not None:
-            removed.add(int(delta.old_key))
+            key = delta.old_key
+            removed.add(row_name(key if isinstance(key, tuple) else (key,)))
         if delta.new is not None:
             pk_values, blob = schema.encode_feature_blob(delta.new_value)
-            pk = int(pk_values[0])
-            added[pk] = hash_object("blob", blob)
+            name = row_name(pk_values)
+            added[name] = hash_object("blob", blob)
             if added_envs is not None:
-                added_envs[pk] = _feature_envelope_wsen(
+                added_envs[name] = _feature_envelope_wsen(
                     delta.new_value, geom_col
                 )
             if added_geoms is not None:
@@ -417,7 +433,7 @@ def update_sidecar_for_commit(repo, old_ds, new_feature_tree_oid, feature_diff):
                     if geom_col is not None and hasattr(delta.new_value, "get")
                     else None
                 )
-                added_geoms[pk] = bytes(value) if value else None
+                added_geoms[name] = bytes(value) if value else None
     return derive_sidecar(
         repo, block, new_feature_tree_oid, removed, added, added_envs,
         added_geoms,
@@ -446,61 +462,93 @@ def _feature_envelope_wsen(feature, geom_col):
     return (x0, y0, x1, y1)
 
 
+def _rows_named(block, names, hashed):
+    """Row numbers of ``block`` that hold the rows ``names`` (pks, or feature
+    paths of a hash-keyed block), names it does not hold left out; None
+    where a hash key is not enough to tell (two rows share it, or the row
+    found under a path's key holds another path)."""
+    if not names:
+        return np.zeros(0, dtype=np.int64)
+    keys = np.asarray(block.keys[: block.count])
+    if hashed:
+        if block.has_key_collisions():
+            return None
+        want = hash_keys_for_paths(names)
+    else:
+        want = np.fromiter(names, dtype=np.int64, count=len(names))
+    pos = np.minimum(np.searchsorted(keys, want), max(len(keys) - 1, 0))
+    hit = keys[pos] == want if len(keys) else np.zeros(len(want), dtype=bool)
+    rows = pos[hit]
+    if hashed and len(rows):
+        held = block.paths.take(rows).tolist()
+        if held != [n.encode("utf8") for n, h in zip(names, hit) if h]:
+            return None
+    return rows
+
+
 def derive_sidecar(repo, old_block, new_feature_tree_oid, removed, added,
                    added_envs=None, added_geoms=None):
-    """New sidecar from an old int-pk block + the change set — O(changed)
-    array ops, no tree walk. removed: iterable of pks; added: {pk: oid hex}
-    (an added pk overrides a removal); added_envs: {pk: wsen} carried into
-    the envelope column when the old block has one (a derived sidecar must
-    not silently lose the spatial prefilter for later revisions);
-    added_geoms: {pk: GPKG blob or None} carried into the vertex column the
-    same way — kept rows are row-sliced (O(changed) gathers, no re-extract),
-    only added rows pay WKB extraction."""
-    keys = old_block.keys[: old_block.count]
+    """New sidecar from an old block + the change set — O(changed) lookups
+    and row gathers, no tree walk. The rows are named by pk (int-pk block)
+    or by feature path (hash-keyed block, whose paths ride along): removed:
+    an iterable of names; added: {name: oid hex} (an added name overrides a
+    removal); added_envs: {name: wsen} carried into the envelope column
+    when the old block has one (a derived sidecar must not silently lose
+    the spatial prefilter for later revisions); added_geoms: {name: GPKG
+    blob or None} carried into the vertex column the same way — kept rows
+    are row-sliced (O(changed) gathers, no re-extract), only added rows pay
+    WKB extraction. None where a hash-keyed block cannot tell its rows
+    apart by key (the next diff rebuilds the sidecar from the tree)."""
+    hashed = isinstance(old_block.paths, ByteRows)
+    n = old_block.count
+    drop = _rows_named(old_block, list(set(removed) | set(added)), hashed)
+    if drop is None:
+        return None
+    keep = np.ones(n, dtype=bool)
+    keep[drop] = False
+    kept = np.flatnonzero(keep) if len(drop) else slice(None)
+    keys = np.asarray(old_block.keys[:n])[kept]
     oids_u8 = (
-        np.ascontiguousarray(old_block.oids[: old_block.count])
-        .view(np.uint8)
-        .reshape(-1, 20)
+        np.ascontiguousarray(old_block.oids[:n][kept]).view(np.uint8).reshape(-1, 20)
     )
     envs = (
-        np.asarray(old_block.envelopes)
+        np.asarray(old_block.envelopes)[kept]
         if old_block.envelopes is not None and added_envs is not None
         else None
     )
-    verts = (
-        old_block.vertex_column() if added_geoms is not None else None
-    )
-    drop = set(removed) | set(added)
-    if drop:
-        drop_arr = np.fromiter(drop, dtype=np.int64, count=len(drop))
-        mask = ~np.isin(keys, drop_arr)
-        keys = keys[mask]
-        oids_u8 = oids_u8[mask]
-        if envs is not None:
-            envs = envs[mask]
-        if verts is not None:
-            verts = verts.take(np.flatnonzero(mask))
+    verts = old_block.vertex_column() if added_geoms is not None else None
+    if verts is not None and len(drop):
+        verts = verts.take(kept)
+    paths = None
+    if hashed:
+        paths = old_block.paths.take(np.arange(n)[kept])
     if added:
-        add_keys = np.fromiter(added.keys(), dtype=np.int64, count=len(added))
+        names = list(added)
+        add_keys = (
+            hash_keys_for_paths(names) if hashed
+            else np.fromiter(names, dtype=np.int64, count=len(names))
+        )
         add_oids = np.frombuffer(
-            bytes.fromhex("".join(added.values())), dtype=np.uint8
+            bytes.fromhex("".join(added[k] for k in names)), dtype=np.uint8
         ).reshape(-1, 20)
         keys = np.concatenate([keys, add_keys])
         oids_u8 = np.concatenate([oids_u8, add_oids])
+        if paths is not None:
+            paths = ByteRows.concat(
+                [paths, ByteRows.from_list([k.encode("utf8") for k in names])]
+            )
         if envs is not None:
             add_env = np.array(
-                [added_envs[int(pk)] for pk in add_keys], dtype=np.float32
+                [added_envs[k] for k in names], dtype=np.float32
             ).reshape(-1, 4)
             envs = np.concatenate([envs, add_env])
         if verts is not None:
             from kart_tpu.geom import VertexColumn, vertex_column_from_blobs
 
-            add_verts = vertex_column_from_blobs(
-                added_geoms.get(int(pk)) for pk in add_keys
-            )
+            add_verts = vertex_column_from_blobs(added_geoms.get(k) for k in names)
             verts = VertexColumn.concat([verts, add_verts])
     return save_sidecar(
-        repo, new_feature_tree_oid, keys, oids_u8, envelopes=envs,
+        repo, new_feature_tree_oid, keys, oids_u8, paths=paths, envelopes=envs,
         vertices=verts,
     )
 
@@ -511,8 +559,8 @@ class SidecarCapture:
 
     def __init__(self):
         self._pk_chunks = []  # int64 arrays
-        self._path_chunks = []  # list[str] chunks
         self._oid_chunks = []  # raw 20-byte-per-oid bytes chunks
+        self._hashed = None  # (keys, oids (n, 20), path ByteRows), whole
         self.count = 0
 
     def add_int_batch(self, pks, oid_hexes):
@@ -527,16 +575,18 @@ class SidecarCapture:
         self._oid_chunks.append(oid_bytes)
         self.count += len(pks)
 
-    def add_path_batch(self, rel_paths, oid_hexes):
-        self._path_chunks.append(list(rel_paths))
-        self._oid_chunks.append(bytes.fromhex("".join(oid_hexes)))
-        self.count += len(rel_paths)
+    def set_hashed_columns(self, keys, oids_u8, paths):
+        """A hash-keyed dataset's whole columns, as its tree was written
+        (``core.feature_tree.write_hash_feature_tree``): keys, oids and
+        path ByteRows, row for row."""
+        self._hashed = (keys, oids_u8, paths)
+        self.count = len(keys)
 
     def int_columns(self):
         """(pks int64 (n,), oids (n, 20) uint8) for an int-pk capture, or
         None — the importer's vectorized tree build reads the columns
         straight from here instead of accumulating a second copy."""
-        if not self._pk_chunks or self._path_chunks:
+        if not self._pk_chunks:
             return None
         pks = np.concatenate(self._pk_chunks)
         oids_u8 = np.frombuffer(b"".join(self._oid_chunks), dtype=np.uint8).reshape(
@@ -549,14 +599,12 @@ class SidecarCapture:
         restarted import stream (the pipelined importer's native-reader
         fallback) can :meth:`rewind` the partial feed instead of
         double-counting features."""
-        return (len(self._pk_chunks), len(self._path_chunks),
-                len(self._oid_chunks), self.count)
+        return len(self._pk_chunks), len(self._oid_chunks), self.count
 
     def rewind(self, mark):
         """Drop everything captured since ``mark``."""
-        n_pk, n_path, n_oid, count = mark
+        n_pk, n_oid, count = mark
         del self._pk_chunks[n_pk:]
-        del self._path_chunks[n_path:]
         del self._oid_chunks[n_oid:]
         self.count = count
 
@@ -571,17 +619,13 @@ class SidecarCapture:
     def save(self, repo, feature_tree_oid):
         if not self.count:
             return None
+        if self._hashed is not None:
+            keys, oids_u8, paths = self._hashed
+            return save_sidecar(repo, feature_tree_oid, keys, oids_u8, paths=paths)
         oids_u8 = np.frombuffer(
             b"".join(self._oid_chunks), dtype=np.uint8
         ).reshape(-1, 20)
-        if self._pk_chunks and not self._path_chunks:
-            keys = np.concatenate(self._pk_chunks)
-            return save_sidecar(repo, feature_tree_oid, keys, oids_u8)
-        if self._path_chunks and not self._pk_chunks:
-            paths = [p for chunk in self._path_chunks for p in chunk]
-            keys = hash_keys_for_paths(paths)
-            return save_sidecar(repo, feature_tree_oid, keys, oids_u8, paths=paths)
-        return None  # mixed capture: shouldn't happen; skip rather than lie
+        return save_sidecar(repo, feature_tree_oid, np.concatenate(self._pk_chunks), oids_u8)
 
 
 def has_sidecar(repo, dataset):

@@ -21,6 +21,7 @@ from kart_tpu import telemetry as tm
 from kart_tpu.core.structure import RepoStructure
 from kart_tpu.core.tree_builder import TreeBuilder
 from kart_tpu.models.dataset import Dataset3
+from kart_tpu.core.serialise import msg_pack
 from kart_tpu.models.paths import encoder_for_schema
 from kart_tpu.utils import chunked, paused_gc
 
@@ -250,6 +251,44 @@ def _check_replace_ids_compatible(existing_ds, schema, encoder):
         )
 
 
+class HashedColumns:
+    """A msgpack/hash dataset's rows as the import streams them: the
+    msgpack of each pk and its blob oid. The feature tree is written from
+    them in one columnar pass when the stream ends
+    (:func:`kart_tpu.core.feature_tree.write_hash_feature_tree`), as the
+    int-pk branch writes its own, instead of a tree-builder insert a path."""
+
+    def __init__(self):
+        self.packed = []
+        self.oid_chunks = []
+
+    def add(self, packed, oids_u8):
+        self.packed.extend(packed)
+        self.oid_chunks.append(np.asarray(oids_u8, dtype=np.uint8).reshape(-1, 20))
+
+    def clear(self):
+        self.packed.clear()
+        self.oid_chunks.clear()
+
+    def write_tree(self, odb, encoder, capture):
+        """Write the feature tree (a pk given twice: the last row wins, as a
+        tree builder's insert over an insert) and hand its columns to a
+        SidecarCapture; -> the tree's hex oid."""
+        from kart_tpu.core.feature_tree import write_hash_feature_tree
+        from kart_tpu.diff.sidecar import SidecarCapture
+        from kart_tpu.models.paths import ByteRows, hash_feature_rows
+
+        rows = hash_feature_rows(ByteRows.from_list(self.packed), encoder)
+        oids_u8 = np.concatenate(self.oid_chunks)
+        keep = rows.last_wins()
+        if len(keep) < len(rows):
+            rows, oids_u8 = rows.take(keep), oids_u8[keep]
+        root = write_hash_feature_tree(odb, rows, oids_u8, encoder)
+        if isinstance(capture, SidecarCapture):
+            capture.set_hashed_columns(rows.keys, oids_u8, rows.paths(encoder))
+        return root
+
+
 class ReplaceIdsCapture:
     """What a --replace-ids import changed, for the O(changed) sidecar
     derivation (the incremental-import workflow must not lose the columnar
@@ -369,6 +408,7 @@ def _import_single_source(
 
     count = 0
     use_batch_paths = encoder.scheme == "int"
+    hashed = None if use_batch_paths else HashedColumns()
     # int-pk fast path: (pks, oid bytes) -> vectorized tree build. When a
     # SidecarCapture is running it already holds these columns; only
     # accumulate separately without one (a 100M import must not hold two
@@ -401,6 +441,7 @@ def _import_single_source(
                 pk_chunks=pk_chunks,
                 oid_chunks=oid_chunks,
                 use_batch_paths=use_batch_paths,
+                hashed=hashed,
                 log=log,
                 ds_path=ds_path,
             )
@@ -452,21 +493,26 @@ def _import_single_source(
                         pk_chunks.append(pks)
                         oid_chunks.append(bytes.fromhex("".join(oids)))
                 else:
-                    rel_paths = [
-                        encoder.encode_pks_to_path(pk_values)
-                        for pk_values, _ in encoded
-                    ]
-                    tb.insert_many((prefix + rel for rel in rel_paths), oids)
-                if capture is not None:
-                    if use_batch_paths:
-                        capture.add_int_batch(pks, oids)
-                    else:
-                        capture.add_path_batch(rel_paths, oids)
+                    hashed.add(
+                        [msg_pack(pk_values) for pk_values, _ in encoded],
+                        np.frombuffer(bytes.fromhex("".join(oids)), dtype=np.uint8),
+                    )
+                if capture is not None and use_batch_paths:
+                    capture.add_int_batch(pks, oids)
                 count += len(batch)
                 if log and count % 100000 == 0:
                     log(f"  {ds_path}: {count} features...")
 
-    if use_batch_paths and count and stream_root is not None:
+    if hashed is not None and count:
+        from kart_tpu.core.objects import MODE_TREE
+
+        with phases.span("tree_build"):
+            tb.insert(
+                f"{ds_path}/{Dataset3.DATASET_DIRNAME}/feature",
+                hashed.write_tree(repo.odb, encoder, capture),
+                mode=MODE_TREE,
+            )
+    elif use_batch_paths and count and stream_root is not None:
         # the pipeline already built (and wrote) the feature tree from the
         # sorted stream — the strictly-increasing pk guarantee it enforces
         # also rules out duplicate pks, so no last-wins resolution needed
@@ -534,7 +580,7 @@ def _import_single_source(
 def _run_import_pipeline(
     repo, tb, source, schema, encoder, prefix, *,
     capture, collect_local, pk_chunks, oid_chunks, use_batch_paths,
-    log, ds_path,
+    log, ds_path, hashed=None,
 ):
     """Stream one source through the bounded 4-stage pipeline
     (:mod:`kart_tpu.importer.pipeline`): fused read+encode (ONE native
@@ -587,7 +633,6 @@ def _run_import_pipeline(
         from kart_tpu.models.dataset import compiled_blob_encoder
 
         blob_enc = compiled_blob_encoder(schema)
-        enc_path = encoder.encode_pks_to_path
 
         def _generic_producer():
             for batch in chunked(source.features(), BATCH_SIZE):
@@ -598,7 +643,7 @@ def _run_import_pipeline(
                         pk_values, blob = blob_enc(feature)
                         keys.append(
                             pk_values[0] if use_batch_paths
-                            else enc_path(pk_values)
+                            else msg_pack(pk_values)
                         )
                         blobs.append(blob)
                 yield ("py", keys, blobs)
@@ -708,11 +753,7 @@ def _run_import_pipeline(
                     buf, offs, leaf_ids = out
                     inject(("tree", buf, offs, leaf_ids))
         else:
-            hexes = oids_u8.tobytes().hex()
-            oid_list = [hexes[i : i + 40] for i in range(0, len(hexes), 40)]
-            tb.insert_many((prefix + rel for rel in keys), oid_list)
-            if capture is not None:
-                capture.add_path_batch(keys, oid_list)
+            hashed.add(keys, oids_u8)
         count += len(keys)
         if log and count % 100000 < len(keys):
             log(f"  {ds_path}: {count} features...")
@@ -743,6 +784,8 @@ def _run_import_pipeline(
         gc_batch = 0
         tree_busy = 0.0
         tree_oid_chunks.clear()
+        if hashed is not None:
+            hashed.clear()
         del pk_chunks[base_pk_chunks:]
         del oid_chunks[base_oid_chunks:]
         if capture is not None:

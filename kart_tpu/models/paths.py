@@ -264,6 +264,229 @@ class MsgpackHashPathEncoder(PathEncoder):
 
 
 # ---------------------------------------------------------------------------
+# Columns of byte strings, and the msgpack/hash layout of a column of pks
+# ---------------------------------------------------------------------------
+
+
+class ByteRows:
+    """``n`` byte strings as one buffer: row ``i`` is
+    ``data[offs[i]:offs[i + 1]]``. ``data`` may be a read-only map (a
+    sidecar's path section)."""
+
+    __slots__ = ("offs", "data")
+
+    def __init__(self, offs, data):
+        self.offs = offs
+        self.data = data
+
+    @classmethod
+    def from_list(cls, items):
+        lens = np.fromiter((len(b) for b in items), dtype=np.int64, count=len(items))
+        offs = np.zeros(len(items) + 1, dtype=np.int64)
+        np.cumsum(lens, out=offs[1:])
+        return cls(offs, np.frombuffer(b"".join(items), dtype=np.uint8))
+
+    @classmethod
+    def from_matrix(cls, mat, lens):
+        """Row ``i``: the first ``lens[i]`` bytes of ``mat[i]``."""
+        n, w = mat.shape
+        lens = np.asarray(lens, dtype=np.int64)
+        offs = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum(lens, out=offs[1:])
+        if n and bool((lens == w).all()):
+            return cls(offs, np.ascontiguousarray(mat).reshape(-1))
+        return cls(offs, mat[np.arange(w)[None, :] < lens[:, None]])
+
+    @classmethod
+    def concat(cls, parts):
+        offs = [np.zeros(1, dtype=np.int64)]
+        base = 0
+        for p in parts:
+            o = np.asarray(p.offs, dtype=np.int64)
+            offs.append(o[1:] - o[0] + base)
+            base += int(o[-1] - o[0])
+        return cls(
+            np.concatenate(offs),
+            np.concatenate(
+                [np.asarray(p.data[int(p.offs[0]):int(p.offs[-1])]) for p in parts]
+                or [np.zeros(0, dtype=np.uint8)]
+            ),
+        )
+
+    def __len__(self):
+        return len(self.offs) - 1
+
+    def lengths(self):
+        return np.diff(np.asarray(self.offs, dtype=np.int64))
+
+    def width(self):
+        """The one length every row has, or None."""
+        lens = self.lengths()
+        return int(lens[0]) if len(lens) and bool((lens == lens[0]).all()) else None
+
+    def tolist(self):
+        lo = int(self.offs[0])
+        buf = bytes(self.data[lo : int(self.offs[-1])])
+        bounds = (np.asarray(self.offs, dtype=np.int64) - lo).tolist()
+        return [buf[a:b] for a, b in zip(bounds[:-1], bounds[1:])]
+
+    def take(self, rows, chunk=1 << 18):
+        """The rows ``rows`` (an index array), in that order. Where each of
+        them starts at its row number times one width (rows of one width,
+        as UUID paths are) one fancy index of a matrix view; else a gather
+        of the bytes, a chunk of rows at a time so the index stays small.
+        Either way only the offsets of ``rows`` are read."""
+        rows = np.asarray(rows, dtype=np.int64)
+        starts = np.asarray(self.offs[rows], dtype=np.int64)
+        lens = np.asarray(self.offs[rows + 1], dtype=np.int64) - starts
+        out_offs = np.zeros(len(rows) + 1, dtype=np.int64)
+        np.cumsum(lens, out=out_offs[1:])
+        out = np.empty(int(out_offs[-1]), dtype=np.uint8)
+        data = np.asarray(self.data)
+        w = int(lens[0]) if len(rows) else 0
+        if w and bool((lens == w).all()) and bool((starts == rows * w).all()):
+            out.reshape(-1, w)[:] = data[: len(data) // w * w].reshape(-1, w)[rows]
+            return ByteRows(out_offs, out)
+        for lo in range(0, len(rows), chunk):
+            hi = min(lo + chunk, len(rows))
+            a, b = int(out_offs[lo]), int(out_offs[hi])
+            idx = np.repeat(starts[lo:hi] - out_offs[lo:hi], lens[lo:hi])
+            idx += np.arange(a, b, dtype=np.int64)
+            out[a:b] = data[idx]
+        return ByteRows(out_offs, out)
+
+    def matrix(self):
+        """-> (uint8 (n, longest row) zero-filled past each row, lengths)."""
+        lens = self.lengths()
+        n = len(lens)
+        w = int(lens.max()) if n else 0
+        lo = int(self.offs[0])
+        data = np.asarray(self.data[lo : int(self.offs[-1])])
+        if n and bool((lens == w).all()):
+            return data.reshape(n, w).copy(), lens
+        mat = np.zeros((n, w), dtype=np.uint8)
+        mat[np.arange(w)[None, :] < lens[:, None]] = data
+        return mat, lens
+
+
+def sha256_prefixes(rows):
+    """ByteRows -> uint64 (n,): the first eight bytes of each row's sha256,
+    big-endian. One hashlib call a row, nothing else made per row."""
+    import hashlib
+
+    sha = hashlib.sha256
+    n = len(rows)
+    lo = int(rows.offs[0]) if n else 0
+    buf = bytes(rows.data[lo : int(rows.offs[-1])]) if n else b""
+    w = rows.width()
+    if w is not None:
+        digests = [sha(buf[i : i + w]).digest()[:8] for i in range(0, n * w, w)]
+    else:
+        bounds = (np.asarray(rows.offs, dtype=np.int64) - lo).tolist()
+        digests = [sha(buf[a:b]).digest()[:8] for a, b in zip(bounds[:-1], bounds[1:])]
+    return np.frombuffer(b"".join(digests), dtype=">u8").astype(np.uint64)
+
+
+def msgpack_pk_rows(pk_values):
+    """The msgpack of each row's pk tuple, as ``PathEncoder.encode_filename``
+    packs it -> ByteRows. ``pk_values``: a sequence of pk tuples, or a numpy
+    ``S`` array of one text pk column. The ASCII column whose values all
+    fill their width (a UUID column) is packed as one matrix; anything else
+    a row at a time."""
+    arr = pk_values if isinstance(pk_values, np.ndarray) else None
+    if arr is not None and arr.dtype.kind == "S" and arr.ndim == 1 and len(arr):
+        w = arr.dtype.itemsize
+        mat = np.ascontiguousarray(arr).view(np.uint8).reshape(len(arr), w)
+        if w < 1 << 16 and bool((mat != 0).all()) and int(mat.max()) < 0x80:
+            head = (
+                [0x91, 0xA0 | w] if w < 32
+                else [0x91, 0xD9, w] if w < 256
+                else [0x91, 0xDA, w >> 8, w & 0xFF]
+            )
+            out = np.empty((len(arr), len(head) + w), dtype=np.uint8)
+            out[:, : len(head)] = head
+            out[:, len(head) :] = mat
+            return ByteRows.from_matrix(out, np.full(len(arr), out.shape[1]))
+    if arr is not None:
+        pk_values = [
+            (v.decode("utf8") if isinstance(v, bytes) else v,) for v in arr.tolist()
+        ]
+    return ByteRows.from_list([msg_pack(tuple(v)) for v in pk_values])
+
+
+class HashRows:
+    """A column of features laid out by a msgpack/hash encoder: per row its
+    leaf tree (``leaf_ids``: the first ``levels`` characters of
+    ``b64(sha256(msgpack(pk)))`` as digits), its filename
+    (``urlsafe_b64(msgpack(pk))``: ``names`` zero-filled past ``name_lens``)
+    and its sidecar identity key (``keys``: ``ops.blocks.hash_keys`` of the
+    filename)."""
+
+    __slots__ = ("leaf_ids", "names", "name_lens", "keys")
+
+    def __init__(self, leaf_ids, names, name_lens, keys):
+        self.leaf_ids = leaf_ids
+        self.names = names
+        self.name_lens = name_lens
+        self.keys = keys
+
+    def __len__(self):
+        return len(self.leaf_ids)
+
+    def take(self, rows):
+        return HashRows(
+            self.leaf_ids[rows], self.names[rows], self.name_lens[rows], self.keys[rows]
+        )
+
+    def name_rows(self):
+        return ByteRows.from_matrix(self.names, self.name_lens)
+
+    def last_wins(self):
+        """Row numbers to keep, ascending: of rows with one filename (one
+        pk) the last, as a tree builder's insert over an insert."""
+        order = np.argsort(self.keys, kind="stable")
+        same = self.keys[order[1:]] == self.keys[order[:-1]]
+        at = np.flatnonzero(same)
+        if len(at):  # one key: the same pk, or two pks whose hashes collide
+            a, b = order[at], order[at + 1]
+            same[at] = (self.name_lens[a] == self.name_lens[b]) & (
+                self.names[a] == self.names[b]
+            ).all(axis=1)
+        return np.sort(order[~np.append(same, False)])
+
+    def paths(self, encoder):
+        """The feature paths (``A/B/C/D/<filename>``), as ByteRows."""
+        n, w = self.names.shape
+        lv = encoder.levels
+        mat = np.empty((n, 2 * lv + w), dtype=np.uint8)
+        for level in range(lv):
+            digit = (self.leaf_ids >> (6 * (lv - 1 - level))) & 63
+            mat[:, 2 * level] = encoder._alpha_u8[digit]
+            mat[:, 2 * level + 1] = ord("/")
+        mat[:, 2 * lv :] = self.names
+        return ByteRows.from_matrix(mat, self.name_lens + 2 * lv)
+
+
+def hash_feature_rows(packed, encoder):
+    """ByteRows of msgpack'd pks -> :class:`HashRows` under ``encoder`` (one
+    base64 character a level: the V3 ``GENERAL_ENCODER``): every path part
+    of every row, batch by batch of numpy, two sha256 a row."""
+    assert encoder.encoding == "base64" and encoder.group_length == 1, encoder.to_dict()
+    mat, lens = packed.matrix()
+    leaf_ids = (sha256_prefixes(packed) >> np.uint64(64 - 6 * encoder.levels)).astype(
+        np.int64
+    )
+    names, name_lens = _b64_batch(mat, lens)
+    if len(names) and int(name_lens.min()) < names.shape[1]:
+        names[np.arange(names.shape[1])[None, :] >= name_lens[:, None]] = 0
+    from kart_tpu.ops import blocks
+
+    rows = HashRows(leaf_ids, names, name_lens, None)
+    rows.keys = blocks.hash_keys(rows.name_rows())
+    return rows
+
+
+# ---------------------------------------------------------------------------
 # Vectorized msgpack + base64 helpers
 # ---------------------------------------------------------------------------
 

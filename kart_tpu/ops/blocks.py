@@ -148,18 +148,28 @@ def unpack_oid_bytes(oid_rows):
     return [b[i : i + 20] for i in range(0, len(b), 20)]
 
 
+#: bits of a hash-keyed identity (sha256 of the filename, sign-cleared);
+#: the collision tests take it down to force what 63 bits make rare
+KEY_BITS = 63
+
+
+def hash_keys(names):
+    """Blob filenames (ByteRows) -> int64 identity keys: the first
+    ``KEY_BITS`` bits of each one's sha256, big-endian. One hashlib call a
+    name (``models.paths.sha256_prefixes``)."""
+    from kart_tpu.models.paths import sha256_prefixes
+
+    return (sha256_prefixes(names) >> np.uint64(64 - KEY_BITS)).astype(np.int64)
+
+
 def hash_keys_for_paths(paths):
     """Feature paths (hash-encoded datasets) -> int64 identity keys: the first
     8 bytes (big-endian, sign-cleared) of sha256 of the blob *filename*.
     Uniform over [0, 2^63): collision probability at 100M keys ~ 5e-4; the
     caller must check `has_key_collisions` and disambiguate via paths."""
-    n = len(paths)
-    out = np.empty(n, dtype=np.int64)
-    for i, p in enumerate(paths):
-        name = p.rsplit("/", 1)[-1]
-        digest = hashlib.sha256(name.encode()).digest()
-        out[i] = int.from_bytes(digest[:8], "big") >> 1
-    return out
+    from kart_tpu.models.paths import ByteRows
+
+    return hash_keys(ByteRows.from_list([p.rpartition("/")[2].encode() for p in paths]))
 
 
 class FeatureBlock:
@@ -167,10 +177,11 @@ class FeatureBlock:
     (kept host-side for value materialisation of changed rows only)."""
 
     __slots__ = ("keys", "oids", "paths", "count", "envelopes", "env_blocks",
-                 "geom_raw", "_vertices", "tree_oid")
+                 "geom_raw", "_vertices", "tree_oid", "key_collisions")
 
     def __init__(self, keys, oids, paths, count, envelopes=None,
-                 env_blocks=None, geom_raw=None, vertices=None, tree_oid=None):
+                 env_blocks=None, geom_raw=None, vertices=None, tree_oid=None,
+                 key_collisions=None):
         self.keys = keys
         self.oids = oids
         self.paths = paths  # list[str], in the same (sorted) order, len == count
@@ -192,6 +203,10 @@ class FeatureBlock:
         # which the device keeps their pages between calls
         # (ops/resident.py). A block made any other way has none
         self.tree_oid = tree_oid
+        # whether two rows share a key, as the sidecar's writer found when
+        # it wrote the columns; None where nobody recorded it (a block not
+        # from a sidecar, or a sidecar from before the header said)
+        self.key_collisions = key_collisions
 
     def vertex_column(self):
         """Lazily decoded :class:`kart_tpu.geom.VertexColumn` for the
@@ -261,8 +276,17 @@ class FeatureBlock:
         return len(self.keys)
 
     def has_key_collisions(self):
+        """Do two rows share a key? What the sidecar recorded, else a scan
+        of the key column."""
+        if self.key_collisions is not None:
+            return self.key_collisions
         real = self.keys[: self.count]
         return bool(np.any(real[1:] == real[:-1])) if self.count > 1 else False
+
+    def has_pad_key(self):
+        """Does a real row hold the padding key, which the kernels take for
+        no row? The keys are sorted: the last real one says."""
+        return bool(self.count) and int(self.keys[self.count - 1]) == int(PAD_KEY)
 
     def path_for_index(self, i):
         return self.paths[i]

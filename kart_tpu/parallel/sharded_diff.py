@@ -23,14 +23,8 @@ def synthetic_block(n, seed=0, change_none=False):
     rng = np.random.default_rng(seed)
     keys = np.arange(n, dtype=np.int64)
     oids = rng.integers(0, 2**32, size=(n, 5), dtype=np.uint32)
-    paths = None  # benchmarks never materialise values
-    block = FeatureBlock.__new__(FeatureBlock)
     size = bucket_size(max(n, 1))
     if size > n:
         keys = np.concatenate([keys, np.full(size - n, PAD_KEY, dtype=np.int64)])
         oids = np.concatenate([oids, np.zeros((size - n, 5), dtype=np.uint32)])
-    block.keys = keys
-    block.oids = oids
-    block.paths = paths
-    block.count = n
-    return block
+    return FeatureBlock(keys, oids, None, n)  # benchmarks never materialise values

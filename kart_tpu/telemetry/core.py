@@ -63,6 +63,7 @@ SUBSYSTEMS = frozenset(
         "diff",      # diff engine (classify / prefilter / tree walk)
         "merge",     # 3-way merge stages (blocks / combine / apply / conflicts)
         "sidecar",   # columnar sidecar load/save/build
+        "feature_tree",  # column-wise feature-tree writers
         "odb",       # object db reads/writes
         "packs",     # packfile machinery
         "serialise", # output materialisation/serialisation
