@@ -68,6 +68,18 @@ class DiffBackend:
         classes host-side."""
         return self.classify(old_block, new_block)[2]
 
+    def guarded_counts(self, old_block, new_block):
+        """The hash-keyed count's classify and its cross-version collision
+        guard -> (counts, ``engine.GuardVerdict``), under the spans
+        ``diff.classify`` then ``diff.hash_guard``. Base: the classes come
+        home and :func:`kart_tpu.diff.engine.host_guard` compares the UPDATE
+        pairs' paths."""
+        from kart_tpu.diff.engine import host_guard
+
+        with classify_span(self, old_block, new_block):
+            old_class, new_class, counts = self.classify(old_block, new_block)
+        return counts, host_guard(old_block, new_block, old_class, new_class)
+
     def sampled_counts(self, old_sub, new_sub):
         """Counts of an estimation subsample (small blocks, called once)."""
         return self.counts(old_sub, new_sub)
@@ -163,6 +175,37 @@ class DeviceJaxBackend(DiffBackend):
         from kart_tpu.ops.diff_kernel import classify_blocks
 
         return classify_blocks(old_block, new_block)
+
+    def guarded_counts(self, old_block, new_block):
+        """Where both path columns have one stride the guard runs on the
+        device beside the classify (``classify_blocks_guarded``): the class
+        arrays never come home, and the ``diff.hash_guard`` span is the wait
+        for the summed verdict and its one read. Else the base's host guard
+        over the classes the classify fetched. A device that fails at that
+        read is a fallback rung (``what=hash_guard``): the host classifies
+        again and its guard answers."""
+        from kart_tpu.diff.engine import guard_verdict, host_guard
+        from kart_tpu.ops.diff_kernel import (
+            classify_blocks_guarded,
+            classify_blocks_host,
+            note_device_fallback,
+        )
+
+        with classify_span(self, old_block, new_block) as sp:
+            old_class, new_class, counts, verdict = classify_blocks_guarded(
+                old_block, new_block
+            )
+            sp.set(counts_only=verdict is not None)
+        if verdict is None:
+            return counts, host_guard(old_block, new_block, old_class, new_class)
+        try:
+            return counts, guard_verdict(
+                "device", lambda: tuple(int(v) for v in np.asarray(verdict))
+            )
+        except Exception as e:
+            note_device_fallback("hash_guard", e, "host path")
+            old_class, new_class, _ = classify_blocks_host(old_block, new_block)
+            return counts, host_guard(old_block, new_block, old_class, new_class)
 
 
 @_register
@@ -268,6 +311,16 @@ class ShardedJaxBackend(DiffBackend):
             return self._fall_back(e, "refine").refine_pairs(
                 col_a, ia, col_b, ib
             )
+
+
+def classify_span(backend, old_block, new_block, counts_only=False):
+    """The ``diff.classify`` span a diff's classify runs under."""
+    return tm.span(
+        "diff.classify",
+        rows=max(old_block.count, new_block.count),
+        backend=backend.name,
+        counts_only=counts_only,
+    )
 
 
 def select_backend(n_rows):

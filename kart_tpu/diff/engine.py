@@ -12,6 +12,7 @@ Two layers:
 """
 
 import threading
+from collections import namedtuple
 
 import numpy as np
 
@@ -194,19 +195,36 @@ def _rows_differ(a, b):
     return differ
 
 
-def hash_guard(old_block, new_block, old_class, new_class):
-    """The cross-version collision guard of a hash-keyed join: a deleted
-    feature and an inserted one can share a 63-bit key, which the join reads
-    as an update. Both sides are sorted by key and keys are unique on each,
-    so the k-th UPDATE row of one side pairs with the k-th of the other;
-    every pair must name one feature path (the path is a function of the
-    filename, so this is the filename compared) — gathered from both
-    sides' path columns and compared as one array. -> True when they all
-    do; else the fallback is counted (``why=across``) and the caller takes
-    the exact path."""
+#: the collision guard's answer for one pair of revisions: UPDATE pairs
+#: compared, and how many of them name two paths (or by how many rows the
+#: sides' UPDATE counts differ)
+GuardVerdict = namedtuple("GuardVerdict", "pairs collisions")
+
+
+def guard_verdict(where, compute):
+    """``compute()`` -> (pairs, collisions) under the span ``diff.hash_guard``
+    (``where`` = ``host`` | ``device``: on the device route the span is the
+    wait for the device's verdict and its read), counted under
+    ``diff.hash_guard.route{where}`` -> GuardVerdict."""
+    with tm.span("diff.hash_guard", where=where) as sp:
+        pairs, collisions = compute()
+        sp.set(pairs=pairs, collisions=collisions)
+    tm.incr("diff.hash_guard.route", where=where)
+    return GuardVerdict(pairs, collisions)
+
+
+def host_guard(old_block, new_block, old_class, new_class):
+    """The cross-version collision guard of a hash-keyed join, on the host:
+    a deleted feature and an inserted one can share a 63-bit key, which the
+    join reads as an update. Both sides are sorted by key and keys are
+    unique on each, so the k-th UPDATE row of one side pairs with the k-th
+    of the other; every pair must name one feature path (the path is a
+    function of the filename, so this is the filename compared) — gathered
+    from both sides' path columns and compared as one array. -> GuardVerdict
+    (:func:`guard_verdict`, ``where=host``)."""
     from kart_tpu.ops.diff_kernel import UPDATE
 
-    with tm.span("diff.hash_guard") as sp:
+    def compute():
         old_upd = np.flatnonzero(old_class == UPDATE)
         new_upd = np.flatnonzero(new_class == UPDATE)
         if len(old_upd) != len(new_upd):
@@ -216,10 +234,24 @@ def hash_guard(old_block, new_block, old_class, new_class):
                 _path_rows(old_block.paths, old_upd),
                 _path_rows(new_block.paths, new_upd),
             )))
-        sp.set(pairs=min(len(old_upd), len(new_upd)), collisions=collisions)
-    if collisions:
+        return min(len(old_upd), len(new_upd)), collisions
+
+    return guard_verdict("host", compute)
+
+
+def guard_passed(verdict):
+    """Did every UPDATE pair name one path? Else the fallback is counted
+    (``diff.hash_guard.fallbacks{why=across}``) and the caller takes the
+    exact path."""
+    if verdict.collisions:
         tm.incr("diff.hash_guard.fallbacks", why="across")
-    return not collisions
+    return not verdict.collisions
+
+
+def hash_guard(old_block, new_block, old_class, new_class):
+    """:func:`host_guard` -> True when every UPDATE pair names one path;
+    else the fallback is counted and the caller takes the exact path."""
+    return guard_passed(host_guard(old_block, new_block, old_class, new_class))
 
 
 def get_feature_diff_columnar(base_ds, target_ds, ds_filter=None, *, blocks=None):
@@ -662,10 +694,12 @@ def get_dataset_feature_count_fast(
     blob reads for the residue; NULL and empty geometries match, a blob
     that is promised matches).
 
-    A hash-keyed dataset (msgpack/hash paths) is counted from the class
-    arrays after two guards: no side may hold a key twice or the padding
-    key (:func:`key_guard`), and every UPDATE pair must name one path on
-    both sides (:func:`hash_guard`). Either failing, the delta path answers,
+    A hash-keyed dataset (msgpack/hash paths) is counted from the classify
+    after two guards: no side may hold a key twice or the padding key
+    (:func:`key_guard`), and every UPDATE pair must name one path on both
+    sides (the backend's ``guarded_counts``: on the one-device route the
+    pairs are compared on the device, beside the classify; else
+    :func:`host_guard` over the classes). Either failing, the delta path answers,
     exactly.
 
     -> int, or None when the count can't be taken from the columnar route
@@ -738,17 +772,12 @@ def get_dataset_feature_count_fast(
 
     backend = select_backend(max(old_block.count, new_block.count))
     if hash_keyed:
-        # the classes are wanted for the guard: no count-only route
+        # the counts and the cross-version guard: on the device beside the
+        # classify where the backend can, else the classes come home
         if key_guard(old_block, new_block):
             return None
-        with tm.span(
-            "diff.classify",
-            rows=max(old_block.count, new_block.count),
-            backend=backend.name,
-            counts_only=False,
-        ):
-            old_class, new_class, counts = backend.classify(old_block, new_block)
-        if not hash_guard(old_block, new_block, old_class, new_class):
+        counts, verdict = backend.guarded_counts(old_block, new_block)
+        if not guard_passed(verdict):
             return None
     else:
         with tm.span(

@@ -688,6 +688,171 @@ _classify_window_split = lazy_jit(
 )
 
 
+# -- the hash guard on the device ----------------------------------------------
+#
+# A hash-keyed revision's sidecar holds each row's feature path; where every
+# path has one width (a UUID-keyed layer's are 60 B) the path section is a
+# matrix, and its pages go to the device like the key and oid pages: rows
+# ``[p * R, (p + 1) * R)`` of the word view, flat in lines of 128 words (a
+# row's words follow each other, so a page costs its rows' bytes: a matrix of
+# ``words`` columns would be padded to 128 lanes). After a chunk's classify the
+# guard program pairs its UPDATE rows by rank and compares their path words;
+# only the call's summed (pairs, collisions) come home.
+
+#: the fewest pairs a guard program is compiled for: chunks with fewer
+#: UPDATE rows share it
+GUARD_MIN_CAP = 1024
+#: the widest path (in 32-bit words) the device guard compares: a row then
+#: lies in two lines of 128 words (512 B: a msgpack/hash name of a pk of
+#: some 370 bytes); wider paths take the host guard
+GUARD_MAX_WORDS = 128
+
+
+def guard_cap(updates):
+    """Pairs the guard program of a chunk with ``updates`` UPDATE rows a side
+    is compiled for: the power of two at or above them, at least
+    ``GUARD_MIN_CAP`` — a handful of shapes, where the chunk's bucket would
+    make the program gather a million rows for a hundred."""
+    return max(GUARD_MIN_CAP, 1 << max(int(updates) - 1, 0).bit_length())
+
+
+def _update_rows(classes, cap):
+    """The rows of a chunk's first ``cap`` UPDATE rows, in row order, and how
+    many UPDATE rows the chunk has. No sort and no scatter: the rows are
+    counted a 128-row line at a time; the line holding the k-th UPDATE row
+    is found by counting the groups of 128 lines whose running count ends
+    below k, then the lines of the next group that do; its lane by a
+    running count over that line alone. Past the count the rows are any
+    row of the chunk."""
+    import jax.numpy as jnp
+
+    lines = (classes == UPDATE).astype(jnp.int32).reshape(-1, JOIN_LANES)
+    n_lines = lines.shape[0]
+    ends = jnp.cumsum(jnp.sum(lines, axis=1))  # UPDATE rows to each line's end
+    k = jnp.arange(1, cap + 1, dtype=jnp.int32)
+    groups = jnp.pad(
+        ends, (0, -n_lines % JOIN_LANES), constant_values=jnp.iinfo(jnp.int32).max
+    ).reshape(-1, JOIN_LANES)
+    group = jnp.minimum(
+        jnp.sum(groups[:, -1][None, :] < k[:, None], axis=1, dtype=jnp.int32),
+        groups.shape[0] - 1,
+    )
+    line = jnp.minimum(
+        group * JOIN_LANES
+        + jnp.sum(groups[group] < k[:, None], axis=1, dtype=jnp.int32),
+        n_lines - 1,
+    )
+    before = jnp.where(line > 0, ends[jnp.maximum(line - 1, 0)], 0)
+    within = jnp.cumsum(lines[line], axis=1)
+    lane = jnp.sum(within < (k - before)[:, None], axis=1, dtype=jnp.int32)
+    return line * JOIN_LANES + lane, ends[-1]
+
+
+def _path_words(page, following, lo, rows, words):
+    """(len(rows), words) uint32: the path words of rows ``lo + rows`` of a
+    side whose path pages are ``page`` and the one after it (lines of 128
+    words, ``words`` <= ``GUARD_MAX_WORDS`` a row, so a row lies in two
+    lines). Each of the two lines from a row's first is gathered whole from
+    whichever page holds it — a gather of single lines is one the chip's
+    compiler emits as such, where a two-line window becomes a loop over the
+    rows — then the pair is shifted left by the row's first lane, one bit
+    of it at a time."""
+    import jax.numpy as jnp
+
+    start = (lo + rows) * words
+    line, lane = start >> 7, start & (JOIN_LANES - 1)
+    n_lines = page.shape[0]
+    held = jnp.concatenate(
+        [
+            jnp.where(
+                (line + t < n_lines)[:, None],
+                page[jnp.minimum(line + t, n_lines - 1)],
+                following[jnp.clip(line + t - n_lines, 0, n_lines - 1)],
+            )
+            for t in range(2)
+        ],
+        axis=1,
+    )
+    for bit in range(7):
+        shift = 1 << bit
+        moved = jnp.concatenate([held[:, shift:], held[:, :shift]], axis=1)
+        held = jnp.where((((lane >> bit) & 1) == 1)[:, None], moved, held)
+    return held[:, :words]
+
+
+def _hash_guard(
+    verdict, old_class, new_class, old_paths, old_paths_next, new_paths,
+    new_paths_next, rows, *, cap, words,
+):
+    """One chunk's hash guard: ``verdict`` (int32 [pairs, collisions]) plus
+    this chunk's. The chunk's classes (as its classify program left them on
+    the device) give each side's UPDATE rows; a key lies in one chunk on both
+    sides and is unique on each, so the k-th UPDATE row of the old side
+    pairs with the k-th of the new. Each pair's path words are gathered from
+    the side's two path pages (``rows``: the sides' first rows' places in
+    their first pages, as the classify's entry takes them) and compared
+    whole; a pair that differs in any word is a collision, and so is every
+    row by which the sides' UPDATE counts differ. Named ``jit__hash_guard``
+    in the device trace: no ``jit__classify_*`` reader counts it."""
+    import jax.numpy as jnp
+
+    old_rows, n_old = _update_rows(old_class, cap)
+    new_rows, n_new = _update_rows(new_class, cap)
+    old_words = _path_words(old_paths, old_paths_next, rows[0], old_rows, words)
+    new_words = _path_words(new_paths, new_paths_next, rows[1], new_rows, words)
+    pairs = jnp.minimum(n_old, n_new)
+    differ = jnp.sum(
+        (jnp.arange(cap) < pairs) & jnp.any(old_words != new_words, axis=1),
+        dtype=jnp.int32,
+    )
+    collisions = jnp.where(n_old == n_new, differ, jnp.abs(n_old - n_new))
+    return verdict + jnp.stack([pairs, collisions]).astype(jnp.int32)
+
+
+_hash_guard = lazy_jit(_hash_guard, static_argnames=("cap", "words"))
+
+
+def path_word_view(block):
+    """A hash-keyed block's path column as a zero-copy ``(count, words)``
+    uint32 view, where every path has one width, a multiple of four bytes
+    (``offs[i] == i * w``: a UUID-keyed layer's, and msgpack/hash names, are
+    base64 with its padding) and no wider than ``GUARD_MAX_WORDS``; else
+    None. Whether the stride is single is measured over the offsets once a
+    tree oid (:meth:`kart_tpu.ops.resident.PageStore.path_stride`): the
+    sidecar is content-addressed, so the answer never changes."""
+    from kart_tpu.models.paths import ByteRows
+    from kart_tpu.ops.resident import PAGES
+
+    paths, n = block.paths, block.count
+    if not isinstance(paths, ByteRows) or n == 0:
+        return None
+    tree_oid = getattr(block, "tree_oid", None)
+    if tree_oid is None:
+        width = _path_stride(paths.offs, n)
+    else:
+        width = PAGES.path_stride(tree_oid, lambda: _path_stride(paths.offs, n))
+    if not width or width % 4 or width // 4 > GUARD_MAX_WORDS:
+        return None
+    words = width // 4
+    return np.frombuffer(paths.data, dtype="<u4", count=n * words).reshape(n, words)
+
+
+def _path_stride(offs, n, step=1 << 20):
+    """The width every one of ``n`` rows has (``offs[i] == i * w``), else 0:
+    one pass over the offsets, a million at a time."""
+    width = int(offs[1]) - int(offs[0])
+    if int(offs[0]) != 0 or width <= 0:
+        return 0
+    for lo in range(0, n + 1, step):
+        hi = min(lo + step, n + 1)
+        if not np.array_equal(
+            np.asarray(offs[lo:hi], dtype=np.int64),
+            np.arange(lo, hi, dtype=np.int64) * width,
+        ):
+            return 0
+    return width
+
+
 def note_device_fallback(what, e, to):
     """One device→host rung taken: the CLI still completes, but never
     silently — ``diff.device.fallbacks{what=…}`` counts it (a measurement
@@ -779,6 +944,36 @@ def classify_blocks(old_block, new_block):
         # published: the classes are handed back only when every chunk is in
         note_device_fallback("device_classify", e, "host path")
         return classify_blocks_host(old_block, new_block)
+
+
+def classify_blocks_guarded(old_block, new_block):
+    """:func:`classify_blocks` for a hash-keyed count, which needs the
+    collision guard beside the counts -> (old_class, new_class, counts,
+    verdict). Where the device route takes the call and both revisions' path
+    columns have one stride (:func:`path_word_view`) the guard runs on the
+    device beside the classify (:func:`classify_blocks_streamed`'s
+    ``paths``): the classes stay there (None) and ``verdict`` is the
+    device's int32 [pairs, collisions], its copy home under way. Anywhere
+    else ``verdict`` is None and the classes are home for the host guard."""
+    from kart_tpu import routing
+    from kart_tpu.ops.resident import with_pages_let_go
+
+    views = routing.device_open(max(old_block.count, new_block.count)) and [
+        path_word_view(block) for block in (old_block, new_block)
+    ]
+    if (
+        not views
+        or any(view is None for view in views)
+        or views[0].shape[1] != views[1].shape[1]
+    ):
+        return (*classify_blocks(old_block, new_block), None)
+    try:
+        return with_pages_let_go(
+            lambda: _stream_chunks(old_block, new_block, None, views)
+        )
+    except Exception as e:
+        note_device_fallback("device_classify", e, "host path")
+        return (*classify_blocks_host(old_block, new_block), None)
 
 
 def classify_chunk_plan(old_block, new_block, chunk_rows=None):
@@ -892,10 +1087,27 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     (:func:`clock_ping`): before the first chunk is put and after the last
     drain, the device idle both times.
 
+    **The hash guard** (:func:`classify_blocks_guarded`, through the same
+    pipeline): the two revisions' path columns, as ``(count, words)`` word
+    views, make each side's path pages a third column, cut and kept like the
+    other two (store key ``(tree oid, "paths", p, R)``), and a chunk's drain
+    then dispatches
+    :func:`_hash_guard` over the classes its program left on the device, at
+    the ``guard_cap`` of its UPDATE count (span ``diff.device.guard``:
+    ``cap``, ``updates``). The classes are neither copied nor fetched; the
+    call's summed [pairs, collisions] is asked home once, after the last.
+
     Raises what the device raises; nothing is published before the last
     chunk has drained, and the pages a failed call put are forgotten.
     Bit-identical to the numpy reference (tested); counts are the sum of
     the chunks' count vectors."""
+    return _stream_chunks(old_block, new_block, chunk_rows, None)[:3]
+
+
+def _stream_chunks(old_block, new_block, chunk_rows, paths):
+    """:func:`classify_blocks_streamed` -> (old_class, new_class, counts,
+    verdict): with ``paths`` the classes are None and ``verdict`` the
+    guard's device array; without, ``verdict`` is None."""
     import jax
 
     from collections import deque
@@ -914,22 +1126,38 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     ahead = len(plan) > 1
     pings = tm.tracing_enabled()
     counting = tm.spans_enabled()
-    old_class = np.empty(old_block.count, dtype=np.int8)
-    new_class = np.empty(new_block.count, dtype=np.int8)
+    guarded = paths is not None
+    if guarded:
+        words = paths[0].shape[1]
+        old_class = new_class = None
+        verdict = np.zeros(2, dtype=np.int32)
+    else:
+        old_class = np.empty(old_block.count, dtype=np.int8)
+        new_class = np.empty(new_block.count, dtype=np.int8)
+        verdict = None
     totals = np.zeros(3, dtype=np.int64)
     in_flight = deque()  # enqueued and not drained, oldest first: two at most
     view_chunks = landed_ahead = hidden_programs = 0
     # a side's pages as the last chunk left them: ``recent`` the (column,
     # page) slots it read — the next chunk starts in one of them — and
-    # ``pad`` the slots of the padding page
+    # ``pad`` the slots of the padding page; ``columns`` what its pages are
+    # cut from
     sides = [
         SimpleNamespace(
-            block=block, rows=page_rows(block.count, chunk_rows), recent={}, pad={}
+            block=block, rows=page_rows(block.count, chunk_rows), recent={}, pad={},
+            columns={"keys": block.keys, "oids": block.oids},
         )
         for block in (old_block, new_block)
     ]
+    if guarded:
+        for side, view in zip(sides, paths):
+            side.columns["paths"] = view
     pinned, kept_keys = [], []  # store keys: to unpin; put by this call
     input_bytes = resident_bytes = kept_bytes = 0
+
+    def geometry(side, column):
+        # a page's first dimension: rows, or for paths its lines of words
+        return side.rows * words // JOIN_LANES if column == "paths" else side.rows
 
     def page_slot(side, column, page, made):
         """Where one page of one column is: on the device already
@@ -938,14 +1166,15 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
         if page is None:  # padding stands in
             if column not in side.pad:
                 side.pad[column] = SimpleNamespace(
-                    column=column, rows=side.rows, key=None, array=None, parts=None
+                    column=column, rows=geometry(side, column), key=None,
+                    array=None, parts=None,
                 )
             return side.pad[column]
         slot = side.recent.get((column, page))
         if slot is not None:
             return slot
         block, rows = side.block, side.rows
-        values = getattr(block, column)
+        values = side.columns[column]
         key = array = parts = None
         # (a block made around __init__ — a benchmark's, a test's — has no
         # such attribute at all)
@@ -961,22 +1190,24 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
         else:
             parts = _page_parts(values, block.count, page * rows, rows)
         slot = SimpleNamespace(
-            column=column, rows=rows, key=key, array=array, parts=parts
+            column=column, rows=geometry(side, column), key=key, array=array,
+            parts=parts,
         )
         made.append(slot)
         return slot
 
     def chunk_slots(side, lo, hi, made):
         """The four pages a side's rows ``lo:hi`` are cut from (keys, keys
-        of the page after, oids, oids after) and ``lo``'s place in the
-        first. Padding stands in for a page no row of the chunk lies in."""
+        of the page after, oids, oids after; then the two of the paths, if
+        guarded) and ``lo``'s place in the first. Padding stands in for a
+        page no row of the chunk lies in."""
         first = lo // side.rows
         pages = (
             first if hi > lo else None,
             first + 1 if hi > (first + 1) * side.rows else None,
         )
         slots, recent = [], {}
-        for column in ("keys", "oids"):
+        for column in side.columns:
             for page in pages:
                 slot = page_slot(side, column, page, made)
                 slots.append(slot)
@@ -989,6 +1220,8 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
     def padding(column, rows):
         if column == "keys":
             return jax.device_put(np.full(rows, PAD_KEY, dtype=np.int64))
+        if column == "paths":
+            return jax.device_put(np.zeros((rows, JOIN_LANES), dtype=np.uint32))
         return jax.device_put(np.zeros((rows, 5), dtype=np.uint32))
 
     def put(chunk):
@@ -998,7 +1231,14 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
         for slot in chunk.made:
             if slot.parts is None:
                 continue  # found on the device
-            arrays = [jax.device_put(a) for a in slot.parts]
+            arrays = [
+                # a path page goes as lines of words (its rows are whole
+                # grid steps, so a view of the rows is one of the lines)
+                jax.device_put(
+                    a.reshape(-1, JOIN_LANES) if slot.column == "paths" else a
+                )
+                for a in slot.parts
+            ]
             array = arrays[0]
             if len(arrays) > 1:
                 array = _resident_page(*arrays, rows=slot.rows)
@@ -1010,9 +1250,11 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
                     kept_bytes += sum(a.nbytes for a in slot.parts)
             slot.array = array
             chunk.fresh.append(array)
-        for slot in chunk.slots:
+        for slot in chunk.slots + chunk.path_slots:
             if slot.array is None:
                 slot.array = PAGES.pad_page(slot.column, slot.rows, padding)
+        if guarded:  # two programs read it: one put
+            chunk.place = jax.device_put(chunk.place)
 
     def call(chunk, program):
         # asynchronous too: the program runs on the device once its
@@ -1020,19 +1262,28 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
         # ends, not when the host asks (an np.asarray that has to ask costs
         # a round trip an array: 2 ms a chunk)
         out = program(
-            *(slot.array for slot in chunk.slots),
-            np.array(
-                [*chunk.los, *(hi - lo for lo, hi in chunk.rows)], dtype=np.int32
-            ),
-            sizes=chunk.sizes,
+            *(slot.array for slot in chunk.slots), chunk.place, sizes=chunk.sizes
         )
         if program is _classify_split:
             old_part, new_part, _, counts = out
             out = (old_part, new_part, counts, None)  # no census
-        for a in out:
+        # guarded, the classes stay on the device for the guard program
+        for a in out[2:] if guarded else out:
             if a is not None:
                 a.copy_to_host_async()
         chunk.out = out
+
+    def guard(chunk, old_part, new_part, updates):
+        # the chunk's guard, dispatched and not waited for: its verdict is
+        # added to the call's on the device
+        nonlocal verdict
+        cap = guard_cap(updates)
+        with tm.span("diff.device.guard", **chunk.label, cap=cap, updates=updates):
+            verdict = _hash_guard(
+                verdict, old_part, new_part,
+                *(slot.array for slot in chunk.path_slots), chunk.place,
+                cap=cap, words=words,
+            )
 
     def landed(chunk):
         nonlocal landed_ahead
@@ -1089,6 +1340,13 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
                     call(chunk, _classify_split)
                     old_part, new_part, counts, _ = jax.block_until_ready(chunk.out)
         (old_lo, old_hi), (new_lo, new_hi) = chunk.rows
+        if guarded:
+            with tm.span("diff.device.fetch", **chunk.label) as sp:
+                counts = np.asarray(counts)
+                sp.set(bytes=counts.nbytes)
+                totals[:] += counts
+            guard(chunk, old_part, new_part, int(counts[1]))
+            return
         with tm.span("diff.device.fetch", **chunk.label) as sp:
             old_part, new_part = np.asarray(old_part), np.asarray(new_part)
             counts = np.asarray(counts)
@@ -1105,10 +1363,11 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
             with tm.span(
                 "diff.device.pack", **label, rows=sum(hi - lo for lo, hi in rows)
             ) as sp:
-                made, slots, los = [], [], []
+                made, slots, path_slots, los = [], [], [], []
                 for side, (lo, hi) in zip(sides, rows):
                     side_slots, lo_in_page = chunk_slots(side, lo, hi, made)
-                    slots += side_slots
+                    slots += side_slots[:4]
+                    path_slots += side_slots[4:]
                     los.append(lo_in_page)
                 host = [a for slot in made for a in slot.parts or ()]
                 # bytes the host copied: the tails it made; a view owns nothing
@@ -1116,8 +1375,14 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
                 sp.set(bucket=max(sizes), bytes=copied)
             view_chunks += not copied
             chunk = SimpleNamespace(
-                label=label, rows=rows, sizes=sizes, slots=slots, los=los,
-                made=made, put_bytes=sum(a.nbytes for a in host),
+                label=label, rows=rows, sizes=sizes, slots=slots,
+                path_slots=path_slots, made=made,
+                # both sides' first row's place in its first page, then
+                # their row counts: the entry's one small int32 argument
+                place=np.array(
+                    [*los, *(hi - lo for lo, hi in rows)], dtype=np.int32
+                ),
+                put_bytes=sum(a.nbytes for a in host),
                 found=sum(slot.parts is None for slot in made),
                 fresh=None, landed=False, out=None,
             )
@@ -1132,6 +1397,8 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
             in_flight.append(chunk)
         while in_flight:
             drain()
+        if guarded:
+            verdict.copy_to_host_async()  # read once, by the caller
         if pings:
             clock_ping("end")
     except BaseException:
@@ -1158,6 +1425,7 @@ def classify_blocks_streamed(old_block, new_block, chunk_rows=None):
             "updates": int(totals[1]),
             "deletes": int(totals[2]),
         },
+        verdict,
     )
 
 

@@ -1,5 +1,6 @@
-"""Pages of a revision's key and oid columns, kept on the device between
-calls (docs/DEVICE.md §5).
+"""Pages of a revision's key and oid columns — and, for the hash-keyed
+count's guard, of its path column — kept on the device between calls
+(docs/DEVICE.md §5).
 
 A sidecar is one file per *feature tree oid*, content-addressed: a tree oid
 never changes meaning (diff/sidecar.py). So rows ``[p * R, (p + 1) * R)`` of
@@ -42,7 +43,8 @@ BUDGET_WITHOUT_LIMIT = 1 << 28
 
 def page_key(tree_oid, column, page, rows):
     """What a resident page is known by: the feature tree's oid (the
-    sidecar's own content address), the column (``keys`` | ``oids``), the
+    sidecar's own content address), the column (``keys`` | ``oids`` |
+    ``paths``), the
     page number and the rows of a page (the geometry it was cut at: a page
     of another size is another page)."""
     return (tree_oid, column, page, rows)
@@ -61,6 +63,7 @@ class PageStore:
         self._lock = threading.Lock()
         self._pages = OrderedDict()  # key -> [array, pins]
         self._pads = {}  # (column, rows) -> the shared all-padding page
+        self._strides = {}  # tree oid -> its path rows' one width, or 0
         self._bytes = 0
         self._budget = budget_bytes
 
@@ -160,6 +163,20 @@ class PageStore:
             with self._lock:
                 page = self._pads.setdefault((column, rows), page)
         return page
+
+    def path_stride(self, tree_oid, measure):
+        """The one width of every path row of the tree ``tree_oid`` (0 where
+        they differ): ``measure()`` the first time a process asks, kept
+        beside the pages after that — the sidecar is content-addressed, so
+        the answer never changes, and it is not device memory: emptying the
+        store keeps it."""
+        with self._lock:
+            width = self._strides.get(tree_oid)
+        if width is None:
+            width = measure()
+            with self._lock:
+                self._strides[tree_oid] = width
+        return width
 
     def resident_bytes(self):
         with self._lock:

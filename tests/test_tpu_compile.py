@@ -246,24 +246,29 @@ def test_classify_window_entry_compiles(one_chip, pages, sizes, monkeypatch):
     assert _device_bytes(compiled) < 16e9
 
 
-@pytest.mark.parametrize("column", [((), np.int64), ((5,), np.uint32)], ids=["keys", "oids"])
+@pytest.mark.parametrize(
+    "column",
+    [((), np.int64, 1), ((5,), np.uint32, 1), ((128,), np.uint32, 15 / 128)],
+    ids=["keys", "oids", "paths"],
+)
 def test_resident_page_compiles_under_its_own_name(one_chip, column):
     """What makes a revision's last page whole on the device (the 10M-row
     cells': 562,816 rows put as a body view and a 32,768-row tail, padded
-    out to a page): the chip's compiler takes it, and no
+    out to a page; a path page, the hash guard's, as lines of 128 words of
+    60-byte rows): the chip's compiler takes it, and no
     ``jit__classify_*`` reader counts it."""
     import jax
 
     from kart_tpu.ops.blocks import bucket_body
     from kart_tpu.ops.diff_kernel import _resident_page
 
-    width, dtype = column
+    width, dtype, per_row = column
     size = _last_chunk_bucket(10_000_000)
     body = bucket_body(size)
     lowered = jax.jit(_resident_page.__wrapped__, static_argnames="rows").lower(
-        _shape((body,) + width, dtype, one_chip),
-        _shape((size - body,) + width, dtype, one_chip),
-        rows=_PAGE,
+        _shape((int(body * per_row),) + width, dtype, one_chip),
+        _shape((int((size - body) * per_row),) + width, dtype, one_chip),
+        rows=int(_PAGE * per_row),
     )
     assert "jit__resident_page" in lowered.as_text()
     assert "jit__classify_" not in lowered.as_text()
@@ -282,6 +287,45 @@ def test_clock_probe_compiles_under_its_own_name(one_chip):
     assert "jit__clock_probe" in lowered.as_text()
     assert "jit__classify_" not in lowered.as_text()
     lowered.compile()
+
+
+@pytest.mark.parametrize(
+    "page,size,updates",
+    [
+        # a full chunk of the UUID cell's 10M-row republish (1% updates)
+        (_PAGE, _PAGE, 10_500),
+        # its last chunk
+        (_PAGE, _last_chunk_bucket(10_000_000), 5_700),
+        # a revision of one small page
+        (1024, 1024, 3),
+    ],
+)
+def test_hash_guard_compiles_under_its_own_name(one_chip, page, size, updates):
+    """The hash-keyed count's guard program at a chunk's shapes, over path
+    pages of 60-byte rows (15 words) held as lines of 128 words: the chip's
+    compiler takes it, no ``jit__classify_*`` reader counts it, it gathers
+    at its cap, not at the chunk's bucket — what it holds beyond its
+    arguments is some rows of 128 words a pair, not a page's — and every
+    gather is one the compiler emits whole (a two-line window was lowered
+    as a loop over the rows)."""
+    import jax
+
+    from kart_tpu.ops.diff_kernel import _hash_guard, guard_cap
+
+    words = 15
+    cap = guard_cap(updates)
+    lowered = jax.jit(_hash_guard.__wrapped__, static_argnames=("cap", "words")).lower(
+        _shape((2,), np.int32, one_chip),
+        *[_shape((size,), np.int8, one_chip)] * 2,
+        *[_shape((page * words // 128, 128), np.uint32, one_chip)] * 4,
+        _shape((4,), np.int32, one_chip),
+        cap=cap, words=words,
+    )
+    assert "jit__hash_guard" in lowered.as_text()
+    assert "jit__classify_" not in lowered.as_text()
+    compiled = lowered.compile()
+    assert " while(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * (cap * 128 * 4 + size)
 
 
 @pytest.mark.parametrize("bucket", [1024, bucket_size(4_000_000)])
