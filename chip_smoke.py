@@ -87,8 +87,8 @@ def parse_args(argv=None):
     p.add_argument("--jsonl-rows", type=int, default=250_000,
                    help="changed rows of the json-lines materialise check")
     p.add_argument("--filtered-rows", type=int, default=8_000_000,
-                   help="rows of the layer the filtered count runs on (29%% of "
-                   "them survive the prefilter: enough for the device route)")
+                   help="rows of the layer the filtered count runs on (enough "
+                   "for the device route, which classifies the whole pair)")
     return p.parse_args(argv)
 
 
@@ -745,13 +745,13 @@ def _bench_module(kind, name):
     return module
 
 
-#: the attributes the filtered count's spans carry (docs/OBSERVABILITY.md)
+#: the attributes the filtered count's spans carry on one chip, where the
+#: census picks the changed-rows route (docs/OBSERVABILITY.md)
 FILTERED_SPANS = {
     "diff.prefilter": ("rows",),
-    "diff.prefilter.scan": ("rows", "blocks", "blocks_scanned", "hits_old", "hits_new"),
-    "diff.prefilter.propagate": ("probed",),
-    "diff.prefilter.compact": ("rows", "survivors", "bytes", "runs"),
-    "diff.classify": ("rows", "backend", "counts_only"),
+    "diff.prefilter.census": ("blocks", "bound_share"),
+    "diff.prefilter.changed": ("rows", "survivors"),
+    "diff.classify": ("rows", "backend", "counts_only", "input_bytes", "resident_bytes"),
     "diff.refine": ("candidates", "inside", "outside", "residue", "blobs_read"),
 }
 

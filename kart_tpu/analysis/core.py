@@ -14,6 +14,7 @@ the tree where reviewers read it.
 """
 
 import ast
+import gc
 import io
 import os
 import re
@@ -102,7 +103,7 @@ class FileContext:
     def nodes(self):
         """Flat node list — one tree walk shared by every rule."""
         if self._nodes is None:
-            self._nodes = list(ast.walk(self.tree))
+            self._nodes = subtree(self.tree)
         return self._nodes
 
     @property
@@ -111,7 +112,7 @@ class FileContext:
         if self._parents is None:
             self._parents = {}
             for parent in self.nodes:
-                for child in ast.iter_child_nodes(parent):
+                for child in children(parent):
                     self._parents[child] = parent
         return self._parents
 
@@ -291,6 +292,19 @@ def run_lint(paths=None, root=None):
     set (kart_tpu/ + bench.py) including the cross-file ``finalize`` checks;
     explicit paths (pre-commit single-file mode) run per-file checks only.
     """
+    # the run builds and walks millions of short-lived AST objects and
+    # helper containers; cyclic collection passes over them cost more than
+    # the rules themselves, so collection waits until the run is done
+    gc_was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        return _run_lint(paths, root)
+    finally:
+        if gc_was_enabled:
+            gc.enable()
+
+
+def _run_lint(paths, root):
     root = root or repo_root()
     full = paths is None
     targets = default_targets(root) if full else _expand(paths, root)
@@ -414,6 +428,32 @@ def changed_targets(root=None, ref="HEAD"):
 
 
 # -- shared AST helpers used by the rules -----------------------------------
+
+
+def children(node):
+    """``ast.iter_child_nodes(node)`` as a tuple, cached on the node. The
+    rules walk the same (never mutated) trees many times over, and the
+    field scan is most of what each walk costs."""
+    try:
+        return node._lint_children
+    except AttributeError:
+        kids = node._lint_children = tuple(ast.iter_child_nodes(node))
+        return kids
+
+
+def subtree(node):
+    """``ast.walk(node)`` (same breadth-first order) as a tuple, cached on
+    the node like :func:`children`."""
+    try:
+        return node._lint_subtree
+    except AttributeError:
+        out = [node]
+        i = 0
+        while i < len(out):
+            out.extend(children(out[i]))
+            i += 1
+        nodes = node._lint_subtree = tuple(out)
+        return nodes
 
 
 def dotted_name(node):

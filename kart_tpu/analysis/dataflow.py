@@ -45,7 +45,13 @@ import os
 import re
 
 from kart_tpu.analysis import interproc, registry
-from kart_tpu.analysis.core import dotted_name, enclosing, unparse
+from kart_tpu.analysis.core import (
+    children,
+    dotted_name,
+    enclosing,
+    subtree,
+    unparse,
+)
 
 #: run-wide counter (reset per lint run by KTL030's constructor); bench.py
 #: records it as ``lint_taint_functions_analyzed``.
@@ -251,7 +257,7 @@ def _side_names(expr):
             for kw in node.keywords:
                 walk(kw.value, in_len)
             return
-        for child in ast.iter_child_nodes(node):
+        for child in children(node):
             walk(child, in_len)
 
     walk(expr)
@@ -261,7 +267,7 @@ def _side_names(expr):
 def _pure_arith(expr):
     """True when ``expr`` is built only from names, constants, and
     arithmetic — an invertible-enough derivation for pin propagation."""
-    for node in ast.walk(expr):
+    for node in subtree(expr):
         if not isinstance(node, (ast.BinOp, ast.UnaryOp, ast.Constant,
                                  ast.Name, ast.operator, ast.unaryop,
                                  ast.expr_context)):
@@ -755,7 +761,7 @@ class _FnPass:
         return out
 
     def _validator_effects(self, expr):
-        for node in ast.walk(expr):
+        for node in subtree(expr):
             if isinstance(node, ast.Call):
                 dn = dotted_name(node.func)
                 if dn and dn.rsplit(".", 1)[-1] in self.eng.validators:
@@ -770,7 +776,7 @@ class _FnPass:
         return t if (t is not None and not t.checked) else None
 
     def _check_sinks(self, expr):
-        for node in ast.walk(expr):
+        for node in subtree(expr):
             if isinstance(node, ast.Call):
                 self._sink_call(node)
             elif isinstance(node, ast.BinOp):
@@ -977,7 +983,7 @@ class _FnPass:
                     and loop.target.id == e.id
                     and any(
                         isinstance(n, ast.Name) and n.id == sub.value.id
-                        for n in ast.walk(loop.iter)
+                        for n in subtree(loop.iter)
                     )
                 ):
                     return
@@ -1150,13 +1156,13 @@ def project_taint(project):
 def consume_exact_ok(ctx, fn_node):
     """KTL033: does the decoder contain a consumed-vs-declared mismatch
     raise (`if consumed != expected: raise ...`) on some path?"""
-    for node in ast.walk(fn_node):
+    for node in subtree(fn_node):
         if not isinstance(node, ast.Raise):
             continue
         guard = enclosing(ctx, node, ast.If)
         if guard is None:
             continue
-        for sub in ast.walk(guard.test):
+        for sub in subtree(guard.test):
             if isinstance(sub, ast.Compare) and any(
                 isinstance(op, ast.NotEq) for op in sub.ops
             ):

@@ -38,7 +38,7 @@ negative for a near-zero false-positive rate — the rules built on top
 import ast
 import re
 
-from kart_tpu.analysis.core import dotted_name, unparse
+from kart_tpu.analysis.core import children, dotted_name, subtree, unparse
 
 #: resolve a bare-name method call only when the project defines that
 #: method name in at most this many places (keeps common verbs inert)
@@ -156,7 +156,7 @@ class FileSummary:
                     self.imports[local] = ("name", node.module, alias.name)
 
     def _collect_defs(self, tree, prefix, cls):
-        for node in ast.iter_child_nodes(tree):
+        for node in children(tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 qual = f"{self.rel}::{prefix}{node.name}"
                 info = FunctionInfo(self.ctx, qual, node.name, cls, node)
@@ -200,7 +200,7 @@ class FileSummary:
         for fn in self.functions:
             if fn.cls is None:
                 continue
-            for node in ast.walk(fn.node):
+            for node in subtree(fn.node):
                 if not isinstance(node, ast.Assign):
                     continue
                 kind = self._lock_kind(node.value)
@@ -646,7 +646,7 @@ def lock_summary(model, fn_info, blocking_reason):
         else:
             stack = [
                 c
-                for c in ast.iter_child_nodes(node)
+                for c in children(node)
                 if not isinstance(c, ast.stmt)
             ]
         while stack:
@@ -662,7 +662,7 @@ def lock_summary(model, fn_info, blocking_reason):
                     out.blocking.append((reason, sub, held_ids))
             elif isinstance(sub, (ast.Yield, ast.YieldFrom)):
                 out.yields.append((sub, held_ids))
-            stack.extend(ast.iter_child_nodes(sub))
+            stack.extend(children(sub))
 
     walk(fn_info.node.body, [])
     model._lock_summaries[fn_info.qual] = out

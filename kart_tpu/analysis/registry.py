@@ -410,6 +410,7 @@ DEVICE_SEAMS = {
             "classify_env_blocks_np",
             "BLOCK_ALL_IN",
             "BLOCK_ALL_OUT",
+            "BLOCK_BOUNDARY",
         }
     ),
     "kart_tpu/ops/diff_kernel.py": frozenset(

@@ -12,10 +12,12 @@ import re
 from kart_tpu.analysis import interproc, registry
 from kart_tpu.analysis.core import (
     Rule,
+    children,
     dotted_name,
     enclosing,
     register,
     str_const,
+    subtree,
     unparse,
 )
 
@@ -455,7 +457,7 @@ class ResourceLifecycle(Rule):
             yield node
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
-            stack.extend(ast.iter_child_nodes(node))
+            stack.extend(children(node))
 
     def _name_uses(self, scope):
         """name -> {"close", "with", "return", "yield", "arg", "attr"}:
@@ -666,7 +668,7 @@ def _own_scope_walk(fn):
         node = stack.pop()
         yield node
         if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            stack.extend(ast.iter_child_nodes(node))
+            stack.extend(children(node))
 
 
 # the thread-entry / mutation / lock-ish notions are shared with the
@@ -807,7 +809,7 @@ class ThreadForkSafety(Rule):
             scope = enclosing(
                 ctx, node, (ast.FunctionDef, ast.AsyncFunctionDef)
             )
-            guard_nodes = ast.walk(scope) if scope is not None else ctx.nodes
+            guard_nodes = subtree(scope) if scope is not None else ctx.nodes
             # a real reference to threading.active_count (not a string
             # merely mentioning it) counts as the guard
             if any(
@@ -986,7 +988,7 @@ class BenchKeySchemaDrift(Rule):
             # only literals in the guard's NEW_KEYS list assignments pin a
             # key — an incidentally quoted word elsewhere in the test file
             # must not count as schema coverage
-            for node in ast.walk(guard_tree):
+            for node in subtree(guard_tree):
                 target = None
                 if isinstance(node, ast.Assign) and node.targets:
                     target = node.targets[0]
@@ -1020,16 +1022,16 @@ class BenchKeySchemaDrift(Rule):
         dicts, dicts bound to a returned name, and ``record = {...}``.
         (Dicts built for other purposes — synthetic feature JSON, config
         blocks — never reach a Return or the record assignment.)"""
-        for fn in ast.walk(tree):
+        for fn in subtree(tree):
             if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 continue
             returned_names = set()
-            for node in ast.walk(fn):
+            for node in subtree(fn):
                 if isinstance(node, ast.Return) and isinstance(
                     node.value, ast.Name
                 ):
                     returned_names.add(node.value.id)
-            for node in ast.walk(fn):
+            for node in subtree(fn):
                 if isinstance(node, ast.Return) and isinstance(
                     node.value, ast.Dict
                 ):

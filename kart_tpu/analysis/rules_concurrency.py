@@ -29,6 +29,7 @@ from kart_tpu.analysis.core import (
     dotted_name,
     register,
     str_const,
+    subtree,
     unparse,
 )
 
@@ -604,7 +605,7 @@ class FillTokenLifecycle(Rule):
         findings = []
         summary = interproc.file_summary(ctx)
         for f in summary.functions:
-            for node in ast.walk(f.node):
+            for node in subtree(f.node):
                 if (
                     isinstance(node, ast.Assign)
                     and isinstance(node.value, ast.Call)
@@ -649,7 +650,7 @@ class FillTokenLifecycle(Rule):
                         return False
                     if isinstance(t, ast.Attribute):
                         return True  # stored on an owner object
-            for node in ast.walk(stmt):
+            for node in subtree(stmt):
                 if isinstance(node, ast.Call):
                     fn = node.func
                     if (
@@ -667,7 +668,7 @@ class FillTokenLifecycle(Rule):
             return False
 
         def risky(stmt):
-            for node in ast.walk(stmt):
+            for node in subtree(stmt):
                 if isinstance(node, ast.Raise):
                     return True
                 if isinstance(node, ast.Call):
@@ -685,7 +686,7 @@ class FillTokenLifecycle(Rule):
 
         def abandons(stmts):
             for s in stmts:
-                for node in ast.walk(s):
+                for node in subtree(s):
                     if (
                         isinstance(node, ast.Call)
                         and isinstance(node.func, ast.Attribute)
@@ -843,7 +844,7 @@ class FillTokenLifecycle(Rule):
         publish = base.methods.get("_publish")
         ok = False
         if publish is not None:
-            for node in ast.walk(publish.node):
+            for node in subtree(publish.node):
                 if isinstance(node, ast.Try):
                     for h in node.handlers:
                         if any(
@@ -851,7 +852,7 @@ class FillTokenLifecycle(Rule):
                             and isinstance(c.func, ast.Attribute)
                             and c.func.attr == "_abandon"
                             for b in h.body
-                            for c in ast.walk(b)
+                            for c in subtree(b)
                         ):
                             ok = True
         if not ok:
@@ -997,7 +998,7 @@ class CacheInvalidationCoverage(Rule):
                     isinstance(n, ast.Call)
                     and (dotted_name(n.func) or "").rsplit(".", 1)[-1]
                     == emit_hook
-                    for n in ast.walk(hook_fn.node)
+                    for n in subtree(hook_fn.node)
                 )
                 if not called:
                     findings.append(
@@ -1089,14 +1090,14 @@ class CacheInvalidationCoverage(Rule):
         else:
             idents = {
                 n.id
-                for n in ast.walk(key_fn.node)
+                for n in subtree(key_fn.node)
                 if isinstance(n, ast.Name)
             } | {
-                n.arg for n in ast.walk(key_fn.node)
+                n.arg for n in subtree(key_fn.node)
                 if isinstance(n, ast.arg)
             } | {
                 n.attr
-                for n in ast.walk(key_fn.node)
+                for n in subtree(key_fn.node)
                 if isinstance(n, ast.Attribute)
             }
             for token in entry.get("key_tokens", ()):
@@ -1128,7 +1129,7 @@ class CacheInvalidationCoverage(Rule):
             called = any(
                 isinstance(n, ast.Call)
                 and (dotted_name(n.func) or "").rsplit(".", 1)[-1] == drop
-                for n in ast.walk(hook_fn.node)
+                for n in subtree(hook_fn.node)
             )
             if not called:
                 findings.append(

@@ -23,6 +23,7 @@ from kart_tpu.analysis.core import (
     Rule,
     dotted_name,
     register,
+    subtree,
 )
 from kart_tpu.analysis.rules import _env_read_name
 
@@ -90,14 +91,14 @@ class DeviceTracePurity(Rule):
         }
         # local name -> candidate defs (e.g. `core = A if k else B`)
         name_binds = {}
-        for node in ast.walk(fn_info.node):
+        for node in subtree(fn_info.node):
             if isinstance(node, ast.Assign) and len(node.targets) == 1:
                 t = node.targets[0]
                 if isinstance(t, ast.Name):
                     for cand in self._name_candidates(node.value):
                         if cand in local_defs:
                             name_binds.setdefault(t.id, set()).add(cand)
-        for node in ast.walk(fn_info.node):
+        for node in subtree(fn_info.node):
             issue = self._impurity(node, params)
             if issue is not None:
                 findings.append(
@@ -167,7 +168,7 @@ class DeviceTracePurity(Rule):
                 )
         elif isinstance(node, (ast.If, ast.While, ast.Assert)):
             test = node.test
-            for sub in ast.walk(test):
+            for sub in subtree(test):
                 if isinstance(sub, ast.Name) and sub.id in params:
                     kind = type(node).__name__.lower()
                     return (

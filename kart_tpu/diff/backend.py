@@ -57,6 +57,13 @@ class DiffBackend:
 
     name = None
 
+    def keeps_pages(self, n_rows):
+        """Does a classify of ``n_rows`` through this backend read pages the
+        device keeps between calls (``ops/resident.py``), so that a
+        revision it has seen costs no copy? Base: no — the host engine reads
+        the sidecar, the mesh streams its record batches every call."""
+        return False
+
     def classify(self, old_block, new_block):
         """-> (old_class int8 (n_old,), new_class (n_new,), counts dict),
         block-row order."""
@@ -170,6 +177,11 @@ class DeviceJaxBackend(DiffBackend):
     routing (the chunked device route or the host) and host fallback."""
 
     name = "device_jax"
+
+    def keeps_pages(self, n_rows):
+        """Where the one-device route takes the call (the ladder's answer
+        :func:`classify_blocks` asks too): its pages stay resident."""
+        return routing.device_open(n_rows)
 
     def classify(self, old_block, new_block):
         from kart_tpu.ops.diff_kernel import classify_blocks

@@ -435,7 +435,16 @@ def spatial_prefilter_blocks(old_block, new_block, rect_wsen):
     The survivors are gathered once, into arrays of their own size: the
     device classify pads a block's tail itself. The sub-blocks' oid columns
     are memory this module keeps (:func:`_take_rows`): they hold until the
-    next call on this thread, which every caller is done with them by."""
+    next call on this thread, which every caller is done with them by.
+
+    This is the *rows route*: the rule runs before the classify, over every
+    row, and the classify reads sub-blocks that name no tree, so none of
+    their pages stays on a device. The json-lines filtered route always
+    takes it; the filtered count takes it where it shrinks the work — the
+    host engine, the mesh, or a census that bounds the rectangle's keep
+    share below :data:`CHANGED_ROUTE_MIN_SHARE` — and elsewhere applies the
+    same rule behind the classify of the whole pair, to its changed rows
+    alone (:func:`changed_rows_in_rect`)."""
     if old_block.envelopes is None or new_block.envelopes is None:
         return None
     from kart_tpu.diff.backend import select_backend
@@ -536,6 +545,137 @@ def _prefilter_rect(spatial_filter_spec):
     )
 
 
+#: The filtered count's changed-rows route (the rectangle test behind the
+#: classify of the whole pair, :func:`changed_rows_in_rect`) is taken, where
+#: the backend keeps its pages, when the census bounds the rectangle's keep
+#: share at or above this. The rows route costs the scan of the blocks the
+#: rectangle meets plus, per unit of keep share, the propagate, the
+#: compaction and the survivors' classify (on the host below 2M survivors,
+#: copied to the device above); the changed-rows route costs the classify of
+#: the whole resident pair and its classes' fetch, whatever the rectangle.
+#: Measured warm on one TPU v5e, 10M rows a side, 1% of them edited, under
+#: rectangles of known keep share (the census bound beside it), rows route
+#: against changed-rows route, median seconds a command (PERF.md §5):
+#: 1.5% (0.022) 0.030-0.032 against 0.050; 3% (0.043) 0.038 against 0.051;
+#: 6% (0.076) 0.049-0.051 against 0.053-0.054; 12% (0.144) 0.072-0.074
+#: against 0.061-0.062; the filtered cell's polygon, 29.4% (0.338),
+#: 0.130-0.133 against 0.069-0.070. The routes tie near a bound of 0.09.
+CHANGED_ROUTE_MIN_SHARE = 0.09
+
+
+def block_census(block, query):
+    """The sidecar's block census of ``block`` against ``query`` -> (an
+    upper bound on the share of its rows whose envelope meets ``query``,
+    the BLOCK_* class of each aggregate block or None without aggregates).
+    The bound is the rows of the blocks not called all-out
+    (:func:`kart_tpu.ops.bbox.classify_env_blocks_np`, the block-pruned
+    scan's own classes) over the rows, 1.0 without aggregates; O(aggregate
+    blocks)."""
+    from kart_tpu.ops.bbox import BLOCK_ALL_OUT, classify_env_blocks_np
+
+    n = block.count
+    if block.env_blocks is None:
+        return (1.0 if n else 0.0), None
+    agg, flags, block_rows = block.env_blocks
+    cls = classify_env_blocks_np(agg, flags, query)
+    if n == 0:
+        return 0.0, cls
+    met = np.flatnonzero(cls != BLOCK_ALL_OUT)
+    rows = len(met) * block_rows
+    if len(met) and met[-1] == len(cls) - 1:
+        rows -= len(cls) * block_rows - n  # the last block is short
+    return rows / n, cls
+
+
+def _changed_route_census(backend, old_block, new_block, query):
+    """Is the filtered count's rectangle test to run behind the classify?
+    Only where ``backend`` keeps the revisions' pages on the device (else
+    the rows route shrinks the host's or the mesh's work and nothing is
+    asked); there the census (span ``diff.prefilter`` > child
+    ``diff.prefilter.census``: ``blocks``, ``bound_share``) bounds the
+    larger side's keep share, which must reach
+    :data:`CHANGED_ROUTE_MIN_SHARE`. The route is counted under
+    ``diff.prefilter.route{where=rows|changed}``. -> both sides' block
+    classes (:func:`block_census`) where the changed-rows route is taken,
+    else None."""
+    n = max(old_block.count, new_block.count)
+    chosen = None
+    if backend.keeps_pages(n):
+        with tm.span("diff.prefilter", rows=n):
+            with tm.span("diff.prefilter.census") as sp:
+                census = [block_census(b, query) for b in (old_block, new_block)]
+                bound = max(share for share, _ in census)
+                sp.set(
+                    blocks=sum(len(cls) for _, cls in census if cls is not None),
+                    bound_share=bound,
+                )
+        if bound >= CHANGED_ROUTE_MIN_SHARE:
+            chosen = tuple(cls for _, cls in census)
+    tm.incr("diff.prefilter.route", where="rows" if chosen is None else "changed")
+    return chosen
+
+
+def _rows_meet(block, rows, query, block_classes):
+    """bool per row of ``rows``: does its float32 envelope meet ``query``?
+    As the block-pruned scan decides it, bit for bit: a row of a block the
+    census called all-in or all-out takes its block's answer, and only the
+    rows of boundary blocks read their envelopes from the sidecar column
+    (the changed rows are spread over the whole layer: read one by one,
+    they would fault in every page of it)."""
+    from kart_tpu.native import bbox_intersects_f32
+    from kart_tpu.ops.bbox import BLOCK_ALL_IN, BLOCK_BOUNDARY
+
+    if block_classes is None:
+        read = np.arange(len(rows))
+        hits = np.zeros(len(rows), dtype=bool)
+    else:
+        by_block = block_classes[rows // block.env_blocks[2]]
+        read = np.flatnonzero(by_block == BLOCK_BOUNDARY)
+        hits = by_block == BLOCK_ALL_IN
+    hits[read] = bbox_intersects_f32(_take_rows(block.envelopes, rows[read]), query)
+    return hits, len(read)
+
+
+def changed_rows_in_rect(old_block, new_block, classes, changed, query, block_classes):
+    """The prefilter's survivors rule (:func:`spatial_prefilter_blocks`)
+    applied behind the classify of the whole pair, to its changed rows
+    alone: each side's changed rows against the padded rectangle by the
+    scan's own predicate (:func:`_rows_meet`: float32 envelopes against the
+    float64 query, so a borderline feature fails open exactly as there). A
+    DELETE survives where its old envelope meets the rectangle, an INSERT
+    where its new one does, and the k-th UPDATE of the old side with the
+    k-th of the new (both key-sorted) where either does — the changed rows
+    of the keys the rows route keeps, and no other. ``classes`` /
+    ``changed``: the whole blocks' class arrays and :func:`changed_indices`
+    of them; ``block_classes``: both sides' census
+    (:func:`_changed_route_census`). Span ``diff.prefilter`` > child
+    ``diff.prefilter.changed`` (``rows`` tested, ``envelopes_read``,
+    ``survivors``). -> for old and new, (the surviving changed rows, as row
+    numbers of the block, bool per row: is it an UPDATE)."""
+    from kart_tpu.ops.diff_kernel import UPDATE
+
+    with tm.span("diff.prefilter", rows=max(old_block.count, new_block.count)):
+        with tm.span("diff.prefilter.changed") as sp:
+            sides, read = [], 0
+            for block, cls, rows, census in zip(
+                (old_block, new_block), classes, changed, block_classes
+            ):
+                hits, side_read = _rows_meet(block, rows, query, census)
+                sides.append((rows, cls[rows] == UPDATE, hits))
+                read += side_read
+            (_, old_upd, old_hit), (_, new_upd, new_hit) = sides
+            either = old_hit[old_upd] | new_hit[new_upd]
+            old_hit[old_upd] = either
+            new_hit[new_upd] = either
+            survivors = [(rows[hit], upd[hit]) for rows, upd, hit in sides]
+            sp.set(
+                rows=sum(len(rows) for rows in changed),
+                envelopes_read=read,
+                survivors=sum(len(rows) for rows, _ in survivors),
+            )
+    return survivors
+
+
 def _feature_diff_routed(base_ds, target_ds, ds_filter=None, spatial_filter_spec=None):
     """Engine selection for the real CLI path: when both revisions have a
     columnar sidecar (O(1) mmap loads), classification runs as the vectorized
@@ -625,33 +765,30 @@ def _blob_matches(sf, ds, block, row):
     return sf.match_result(feature) is MatchResult.MATCHED
 
 
-def refine_changed_count(spatial_filter_spec, sides, classes, changed):
+def refine_changed_count(spatial_filter_spec, sides):
     """Exact count of the changed features that match the spatial filter,
-    from the classify of the prefilter's survivors. ``sides``: for old and
-    new, (dataset, the whole sidecar block, the survivors' row numbers in
-    it); ``classes`` / ``changed``: the survivors' class arrays and
-    :func:`changed_indices` of them. Only changed rows are looked at: the
-    sidecar envelope decides the ones wholly inside or outside the filter
-    polygon, the blob's geometry the residue. An update counts once, and
-    counts if either of its sides matches (the reference's delta filter,
+    from the changed rows the rectangle kept. ``sides``: for old and new,
+    (dataset, the whole sidecar block, the changed rows to refine as row
+    numbers of it in key order, bool per row: is it an UPDATE). The sidecar
+    envelope decides the rows wholly inside or outside the filter polygon,
+    the blob's geometry the residue. An update counts once, and counts if
+    either of its sides matches (the reference's delta filter,
     kart/base_diff_writer.py:279-341)."""
-    from kart_tpu.ops.diff_kernel import UPDATE
     from kart_tpu.spatial_filter import ENV_CONTAINS, ENV_DISJOINT, ENV_PARTIAL
 
     with tm.span(
-        "diff.refine", candidates=sum(len(idx) for idx in changed)
+        "diff.refine", candidates=sum(len(rows) for _, _, rows, _ in sides)
     ) as sp:
         # one filter for both sides of a delta, the new side's dataset first
         # (as the writers resolve it)
         sf = spatial_filter_spec.resolve_for_dataset(sides[1][0])
         inside = outside = blobs_read = 0
         matched = []
-        for (ds, block, rows), idx in zip(sides, changed):
+        for ds, block, rows, _ in sides:
             if not sf:  # no geometry column, or no way into its CRS: all match
-                inside += len(idx)
-                matched.append(np.ones(len(idx), dtype=bool))
+                inside += len(rows)
+                matched.append(np.ones(len(rows), dtype=bool))
                 continue
-            rows = rows[idx]
             verdict = _envelope_verdicts(sf, spatial_filter_spec, block, rows)
             match = verdict == ENV_CONTAINS
             inside += int(np.count_nonzero(verdict == ENV_CONTAINS))
@@ -668,12 +805,28 @@ def refine_changed_count(spatial_filter_spec, sides, classes, changed):
         # both sides are key-sorted: the k-th update of one is the k-th of
         # the other
         old_match, new_match = matched
-        old_upd, new_upd = (cls[idx] == UPDATE for cls, idx in zip(classes, changed))
+        old_upd, new_upd = (upd for _, _, _, upd in sides)
         return int(
             np.count_nonzero(old_match[~old_upd])
             + np.count_nonzero(new_match[~new_upd])
             + np.count_nonzero(old_match[old_upd] | new_match[new_upd])
         )
+
+
+def _classes_and_changed(backend, old_block, new_block):
+    """``backend``'s class arrays of a pair and :func:`changed_indices` of
+    them, under the ``diff.classify`` span (``counts_only`` false: the
+    classes come home)."""
+    from kart_tpu.ops.diff_kernel import changed_indices
+
+    with tm.span(
+        "diff.classify",
+        rows=max(old_block.count, new_block.count),
+        backend=backend.name,
+        counts_only=False,
+    ):
+        classes = backend.classify(old_block, new_block)[:2]
+        return classes, changed_indices(*classes)
 
 
 def get_dataset_feature_count_fast(
@@ -687,12 +840,26 @@ def get_dataset_feature_count_fast(
     With an active spatial_filter_spec the count requires envelope sidecar
     columns; otherwise returns None so the delta path can apply the
     value-level filter. The filtered count is exact, the number of features
-    `-o json-lines` lists: the envelope prefilter drops the rows outside
-    the filter's padded bounding rectangle before the transfer, the
-    survivors are classified, and the changed ones alone are refined against
-    the filter geometry (:func:`refine_changed_count`: envelope verdicts,
-    blob reads for the residue; NULL and empty geometries match, a blob
-    that is promised matches).
+    `-o json-lines` lists: the changed rows a key of which has an envelope
+    meeting the filter's padded bounding rectangle are refined against the
+    filter geometry (:func:`refine_changed_count`: envelope verdicts, blob
+    reads for the residue; NULL and empty geometries match, a blob that is
+    promised matches). Where the rectangle test runs is chosen from what can
+    be observed (:func:`_changed_route_census`), one answer either way:
+
+    * the *changed-rows route*, where the backend keeps the revisions' pages
+      on the device and the sidecar's block census bounds the keep share at
+      or above :data:`CHANGED_ROUTE_MIN_SHARE` (the constant's measurement
+      is beside it): the whole pair is classified on the pages the device
+      holds — after a process's first command nothing is copied — and the
+      rule is applied to the changed rows alone
+      (:func:`changed_rows_in_rect`). The trade: a process's first filtered
+      command is a page-store miss and copies both whole revisions (28 B a
+      row), not the survivors alone;
+    * the *rows route* everywhere else (the host engine, the mesh, a small
+      rectangle): the envelope prefilter drops the rows outside the
+      rectangle before the classify (:func:`spatial_prefilter_blocks`) and
+      the survivors are classified.
 
     A hash-keyed dataset (msgpack/hash paths) is counted from the classify
     after two guards: no side may hold a key twice or the padding key
@@ -747,30 +914,37 @@ def get_dataset_feature_count_fast(
         return None
 
     from kart_tpu.diff.backend import select_backend
-    from kart_tpu.ops.diff_kernel import changed_indices
-
-    if rect is not None:
-        filtered = spatial_prefilter_blocks(old_block, new_block, rect)
-        if filtered is None:
-            return None  # no envelope columns: delta path applies the filter
-        (old_sub, new_sub), (old_rows, new_rows) = filtered
-        backend = select_backend(max(old_sub.count, new_sub.count))
-        with tm.span(
-            "diff.classify",
-            rows=max(old_sub.count, new_sub.count),
-            backend=backend.name,
-            counts_only=False,
-        ):
-            classes = backend.classify(old_sub, new_sub)[:2]
-            changed = changed_indices(*classes)
-        return refine_changed_count(
-            spatial_filter_spec,
-            ((base_ds, old_block, old_rows), (target_ds, new_block, new_rows)),
-            classes,
-            changed,
-        )
+    from kart_tpu.ops.diff_kernel import UPDATE
 
     backend = select_backend(max(old_block.count, new_block.count))
+    if rect is not None:
+        if old_block.envelopes is None or new_block.envelopes is None:
+            return None  # no envelope columns: delta path applies the filter
+        query = np.asarray(rect, dtype=np.float64)
+        block_classes = _changed_route_census(backend, old_block, new_block, query)
+        if block_classes is not None:
+            # the whole pair, on the pages the device keeps
+            classes, changed = _classes_and_changed(backend, old_block, new_block)
+            (old_rows, old_upd), (new_rows, new_upd) = changed_rows_in_rect(
+                old_block, new_block, classes, changed, query, block_classes
+            )
+        else:
+            (old_sub, new_sub), (old_rows, new_rows) = spatial_prefilter_blocks(
+                old_block, new_block, rect
+            )
+            classes, changed = _classes_and_changed(
+                select_backend(max(old_sub.count, new_sub.count)), old_sub, new_sub
+            )
+            old_rows, new_rows = old_rows[changed[0]], new_rows[changed[1]]
+            old_upd, new_upd = (cls[idx] == UPDATE for cls, idx in zip(classes, changed))
+        return refine_changed_count(
+            spatial_filter_spec,
+            (
+                (base_ds, old_block, old_rows, old_upd),
+                (target_ds, new_block, new_rows, new_upd),
+            ),
+        )
+
     if hash_keyed:
         # the counts and the cross-version guard: on the device beside the
         # classify where the backend can, else the classes come home

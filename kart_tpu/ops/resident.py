@@ -17,8 +17,8 @@ over all rows of both revisions in every call.
 The key is the tree oid, the column, the page number and the page's rows
 (:func:`page_key`), so nothing here can go stale and no ref move has
 anything to drop; the byte budget alone reclaims memory. A block without a
-tree oid (the filtered route's compacted survivors, a test's arrays) is
-never kept: its pages live as long as the call that put them.
+tree oid (the json-lines filtered route's compacted survivors, a test's
+arrays) is never kept: its pages live as long as the call that put them.
 """
 
 import threading
