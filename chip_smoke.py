@@ -499,7 +499,7 @@ def phase_merge(smoke):
             and f"{args.cli_merge_conflicts} conflicts" in dev.output
         )
         rec["checks"]["merge_index_equals_twin"] = dev_index == host_index
-        smoke.mesh_checks(rec, stats, "sharded_merge_calls")
+        smoke.mesh_checks(rec, stats, "sharded_classify_calls", dev_run[1])
 
     with smoke.phase(
         "merge.blocks", rows=args.merge_rows, conflicts=args.merge_conflicts
@@ -523,7 +523,7 @@ def phase_merge(smoke):
             np.array_equal(a, b) for a, b in zip(dev[:3], host[:3])
         )
         rec["checks"]["equals_expected"] = dev[3] == expected
-        smoke.mesh_checks(rec, stats, "sharded_merge_calls")
+        smoke.mesh_checks(rec, stats, "sharded_classify_calls", dev_run[1])
 
 
 def _grid_envelopes(n):

@@ -352,7 +352,6 @@ DEVICE_MODULES = frozenset(
         "kart_tpu/ops/resident.py",
         "kart_tpu/parallel/__init__.py",
         "kart_tpu/parallel/mesh.py",
-        "kart_tpu/parallel/sharded_merge.py",
         "kart_tpu/routing.py",
         "kart_tpu/runtime.py",
         "bench.py",
@@ -381,8 +380,12 @@ DEVICE_SEAMS = {
             # numpy predicates by default, shard_map when the row count
             # clears the sharding floor, host fallback mid-call
             "refine_intersects",
-            # merge_classify: mesh -> streamed -> monolithic -> host
-            # fallback ladder inside the function
+            # classify_span opens the diff's `diff.classify` span (no
+            # device work of its own)
+            "classify_span",
+            # merge_classify: the diff's classify twice through
+            # select_backend's backend (each diff its own host fallback),
+            # then the three-way rule on the host
             "merge_classify",
             # the host overlap predicate the join counts with — the refine
             # stage recomputes it to recover the exact pair set the counts

@@ -325,13 +325,15 @@ class ShardedJaxBackend(DiffBackend):
             )
 
 
-def classify_span(backend, old_block, new_block, counts_only=False):
-    """The ``diff.classify`` span a diff's classify runs under."""
+def classify_span(backend, old_block, new_block, counts_only=False, **attrs):
+    """The ``diff.classify`` span a diff's classify runs under (a merge's
+    two diffs add ``side``)."""
     return tm.span(
         "diff.classify",
         rows=max(old_block.count, new_block.count),
         backend=backend.name,
         counts_only=counts_only,
+        **attrs,
     )
 
 
@@ -365,16 +367,23 @@ def _mesh_or_host(n_rows, allow_device):
     ]
 
 
-# --- 3-way merge classify: mesh -> one device -> host -----------------------
+# --- 3-way merge classify: two diffs through the diff's backend -------------
 
 def merge_classify(ancestor_block, ours_block, theirs_block):
     """FeatureBlock x3 -> (union_keys (U,) int64 np, decision (U,) int8 np,
     presence (U,) int8 np with bits a=1/o=2/t=4, stats dict), the same on
-    every engine. The ``diff.merge_classify`` span names the engine that
-    answered (``backend=`` — the merge twin of the ``diff.classify`` span's
-    attribute) and carries the call's census: ``rows_ancestor``,
-    ``rows_ours``, ``rows_theirs``, ``union``, ``conflicts``,
-    ``take_theirs``."""
+    every engine: the diffs ancestor -> ours and ancestor -> theirs through
+    the backend :func:`select_backend` picks for the largest revision, each
+    under its own ``diff.classify`` span (``side``), then the three-way rule
+    over what they report changed
+    (:func:`kart_tpu.ops.merge_kernel.merge_classify_two_diffs`). A device
+    that fails either diff is that diff's own fallback rung. The
+    ``diff.merge_classify`` span names the backend (``backend=``, as
+    ``diff.classify`` does) and carries the call's census:
+    ``rows_ancestor``, ``rows_ours``, ``rows_theirs``, ``union``,
+    ``conflicts``, ``take_theirs``."""
+    from kart_tpu.ops.merge_kernel import merge_classify_two_diffs
+
     blocks = (ancestor_block, ours_block, theirs_block)
     n_max = max(b.count for b in blocks)
     with tm.span(
@@ -384,68 +393,18 @@ def merge_classify(ancestor_block, ours_block, theirs_block):
         rows_ours=ours_block.count,
         rows_theirs=theirs_block.count,
     ) as span:
-        result, backend = _merge_classify_routed(*blocks, n_max)
-        span.set(backend=backend, union=len(result[0]), **result[3])
+        backend = select_backend(n_max)
+
+        def classify(side, old_block, new_block):
+            with classify_span(backend, old_block, new_block, side=side):
+                if not max(old_block.count, new_block.count):
+                    # the dataset is in neither revision
+                    return np.zeros(0, dtype=np.int8), np.zeros(0, dtype=np.int8)
+                return backend.classify(old_block, new_block)[:2]
+
+        result = merge_classify_two_diffs(*blocks, classify)
+        span.set(backend=backend.name, union=len(result[0]), **result[3])
     return result
-
-
-def _merge_classify_routed(ancestor_block, ours_block, theirs_block, n_max):
-    """-> (merge_classify's result, the name of the backend that produced
-    it): mesh when it exists and pays, one device when profitable, the host
-    engine otherwise and beneath every device rung."""
-    from kart_tpu.ops.diff_kernel import note_device_fallback
-    from kart_tpu.ops.merge_kernel import (
-        _merge_classify_np,
-        decision_stats,
-        merge_classify_two_diffs,
-    )
-
-    if routing.mesh_open(n_max):
-        # >1 device: shard-local 3-way classify over the mesh (block-cyclic
-        # PK partition; only the count vector crosses ICI)
-        from kart_tpu.parallel.sharded_merge import sharded_merge_classify
-
-        try:
-            return (
-                with_pages_let_go(
-                    lambda: sharded_merge_classify(
-                        ancestor_block, ours_block, theirs_block
-                    )
-                ),
-                "sharded_jax",
-            )
-        except Exception as e:
-            note_device_fallback("merge_sharded", e, "single-chip path")
-
-    # same cost model as classify_blocks: small merges never pay backend
-    # init / compile, and XLA-CPU backends route to the host path (where the
-    # native/numpy engines win at every size)
-    if routing.device_open(n_max):
-        try:
-            return (
-                with_pages_let_go(
-                    lambda: merge_classify_two_diffs(
-                        ancestor_block, ours_block, theirs_block
-                    )
-                ),
-                "device_jax",
-            )
-        except Exception as e:
-            # device OOM / runtime failure mid-call: the merge must still
-            # complete (same guarantee classify_blocks gives the diff path)
-            note_device_fallback("merge_device", e, "host path")
-
-    union = np.union1d(
-        np.union1d(
-            ancestor_block.keys[: ancestor_block.count],
-            ours_block.keys[: ours_block.count],
-        ),
-        theirs_block.keys[: theirs_block.count],
-    ).astype(np.int64)
-    decision, presence = _merge_classify_np(
-        ancestor_block, ours_block, theirs_block, union
-    )
-    return (union, decision, presence, decision_stats(decision)), "host_native"
 
 
 # --- sharded bbox prefilter kernel ------------------------------------------

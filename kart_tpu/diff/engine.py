@@ -293,15 +293,10 @@ def get_feature_diff_columnar(base_ds, target_ds, ds_filter=None, *, blocks=None
         # the kernels take for padding: the exact tree-diff path
         return get_feature_diff(base_ds, target_ds, ds_filter)
 
-    from kart_tpu.diff.backend import select_backend
+    from kart_tpu.diff.backend import classify_span, select_backend
 
     backend = select_backend(max(old_block.count, new_block.count))
-    with tm.span(
-        "diff.classify",
-        rows=max(old_block.count, new_block.count),
-        backend=backend.name,
-        counts_only=False,
-    ):
+    with classify_span(backend, old_block, new_block):
         old_class, new_class, _ = backend.classify(old_block, new_block)
         old_idx, new_idx = changed_indices(old_class, new_class)
     if hash_keyed and not hash_guard(old_block, new_block, old_class, new_class):
@@ -817,14 +812,10 @@ def _classes_and_changed(backend, old_block, new_block):
     """``backend``'s class arrays of a pair and :func:`changed_indices` of
     them, under the ``diff.classify`` span (``counts_only`` false: the
     classes come home)."""
+    from kart_tpu.diff.backend import classify_span
     from kart_tpu.ops.diff_kernel import changed_indices
 
-    with tm.span(
-        "diff.classify",
-        rows=max(old_block.count, new_block.count),
-        backend=backend.name,
-        counts_only=False,
-    ):
+    with classify_span(backend, old_block, new_block):
         classes = backend.classify(old_block, new_block)[:2]
         return classes, changed_indices(*classes)
 
@@ -913,7 +904,7 @@ def get_dataset_feature_count_fast(
     if old_block is None or new_block is None:
         return None
 
-    from kart_tpu.diff.backend import select_backend
+    from kart_tpu.diff.backend import classify_span, select_backend
     from kart_tpu.ops.diff_kernel import UPDATE
 
     backend = select_backend(max(old_block.count, new_block.count))
@@ -954,12 +945,7 @@ def get_dataset_feature_count_fast(
         if not guard_passed(verdict):
             return None
     else:
-        with tm.span(
-            "diff.classify",
-            rows=max(old_block.count, new_block.count),
-            backend=backend.name,
-            counts_only=True,
-        ):
+        with classify_span(backend, old_block, new_block, counts_only=True):
             counts = backend.counts(old_block, new_block)
     return counts["inserts"] + counts["updates"] + counts["deletes"]
 
@@ -1010,16 +996,11 @@ def get_feature_diff_rows(base_rs, target_rs, ds_path):
     if old_block is None or new_block is None:
         return None
 
-    from kart_tpu.diff.backend import select_backend
+    from kart_tpu.diff.backend import classify_span, select_backend
     from kart_tpu.ops.diff_kernel import changed_indices
 
     backend = select_backend(max(old_block.count, new_block.count))
-    with tm.span(
-        "diff.classify",
-        rows=max(old_block.count, new_block.count),
-        backend=backend.name,
-        counts_only=False,
-    ):
+    with classify_span(backend, old_block, new_block):
         old_class, new_class, _ = backend.classify(old_block, new_block)
         old_idx, new_idx = changed_indices(old_class, new_class)
     okeys = np.asarray(old_block.keys[old_idx])

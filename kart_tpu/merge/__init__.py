@@ -3,10 +3,11 @@
 The reference delegates tree merging to libgit2 (`repo.merge_trees`,
 `kart/merge.py:99-100`) and inherits per-feature conflicts from the
 one-feature-one-blob layout. Here the same semantics are computed directly:
-feature sets go through the vectorized 3-way kernel
-(`kart_tpu/ops/merge_kernel.py`, routed by `diff/backend.py merge_classify`)
-— one jitted classification of the whole
-PK-space union per dataset — and the small residue (meta items, attachments)
+feature sets go through the vectorized 3-way rule
+(`kart_tpu/ops/merge_kernel.py`, called by `diff/backend.py merge_classify`)
+— two diffs of each dataset, ancestor -> ours and ancestor -> theirs, on the
+engine `kart diff` would take, and the rule over the keys they changed —
+and the small residue (meta items, attachments)
 through an identical host-side rule. Clean changes are written to a merged
 tree immediately; conflicts become a MergeIndex and move the repo to the
 MERGING state, exactly like the reference's state machine

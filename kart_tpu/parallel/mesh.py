@@ -3,8 +3,8 @@
 One logical axis, ``"features"``: the framework's unit of parallelism is the
 PK-space partition (reference analog: the feature-subtree shard key of the
 parallel importer, `kart/fast_import.py:333-337`). Meshes are 1-D because the
-workload is embarrassingly shard-local after block-cyclic partitioning; a
-second axis buys nothing until multi-host DCN topologies (where the axis
+workload is embarrassingly shard-local once the keys are cut into key-range
+record batches; a second axis buys nothing until multi-host DCN topologies (where the axis
 would split into ("host", "device")).
 """
 
@@ -19,7 +19,7 @@ FEATURES_AXIS = "features"
 
 def best_device_count(limit=None):
     """Device count for a new mesh: all visible devices (optionally capped).
-    partition_block pads each shard independently, so any shard count works."""
+    The record batches pad each shard slot alike, so any shard count works."""
     import jax
 
     n = jax.device_count()

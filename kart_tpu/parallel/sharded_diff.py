@@ -3,8 +3,9 @@ synthetic block maker (``chip_smoke.py``, ``bench.py``, the benchmark's
 rehearsals and the driver's ``dryrun_multichip`` import both from here).
 
 The mesh diff itself is :mod:`kart_tpu.diff.device_batch` (key-range record
-batches under ``shard_map``); the mesh merge, with its block-cyclic
-partition, is :mod:`kart_tpu.parallel.sharded_merge`.
+batches under ``shard_map``); a merge on the mesh is that diff twice
+(:func:`kart_tpu.diff.backend.merge_classify`), so it counts two
+``sharded_classify_calls``.
 """
 
 import numpy as np
@@ -14,7 +15,7 @@ from kart_tpu.ops.blocks import PAD_KEY, FeatureBlock, bucket_size
 # observability: how many times the mesh path actually ran this process
 # (dryrun_multichip and tests assert on it — the single-chip path silently
 # taking over would otherwise be invisible)
-STATS = {"sharded_classify_calls": 0, "sharded_merge_calls": 0}
+STATS = {"sharded_classify_calls": 0}
 
 
 def synthetic_block(n, seed=0, change_none=False):

@@ -1,6 +1,6 @@
 """Which engine answers — the host, one device, or the mesh: the one place
 that decides (docs/DEVICE.md §1, §8), asked by ``diff/backend.py``,
-``ops/diff_kernel.py``, ``ops/merge_kernel.py`` and ``ops/bbox.py``.
+``ops/diff_kernel.py`` and ``ops/bbox.py``.
 
 The ladder, cheapest test first: forcing knob → row floor (before any jax
 import, so a small ``kart diff`` stays instant with the accelerator cold or
@@ -14,7 +14,7 @@ The forcing knobs are read here and nowhere else in the program:
 
 * ``KART_DIFF_BACKEND=<engine>`` — :func:`select_engine` answers that
   engine for every row count (an unknown name warns and routes auto);
-  ``host_native`` also closes every device route below it (merge, bbox).
+  ``host_native`` also closes every device route below it (bbox).
 * ``KART_DIFF_DEVICE=1|0`` — the one-device route: ``1`` forces it past the
   floor and the XLA-CPU refusal (tests, experiments), ``0`` closes it.
 * ``KART_DIFF_SHARDED=1|0`` — the same for the mesh route.

@@ -481,20 +481,18 @@ def _merge_triple(seed=3, n=3000):
 @pytest.mark.parametrize(
     "what, sharded, broken",
     [
-        ("merge_device", "0", "kart_tpu.ops.merge_kernel.merge_classify_two_diffs"),
-        (
-            "merge_sharded",
-            "1",
-            "kart_tpu.parallel.sharded_merge.sharded_merge_classify",
-        ),
+        ("device_classify", "0", "kart_tpu.ops.diff_kernel.classify_blocks_streamed"),
+        ("classify", "1", "kart_tpu.diff.device_batch.classify_blocks_batched"),
     ],
 )
 def test_merge_rungs_count_their_fallback(
     what, sharded, broken, monkeypatch, fallback_counter
 ):
-    """merge_classify's device→host rungs (mesh → single chip, single chip
-    → host): same decisions as the host path, the rung taken counted under
-    its own label, and the span names the engine that finally answered."""
+    """A merge whose device classify raises — one chip's
+    (``device_classify``), the mesh's (``classify``) — falls back as its two
+    diffs do: the same decisions as the host engine, the diff's rung
+    counted once a diff, and the span names the backend the ladder picked
+    (the convention of ``diff.classify``)."""
     from kart_tpu import telemetry as tm
     from kart_tpu.diff.backend import merge_classify
 
@@ -502,7 +500,7 @@ def test_merge_rungs_count_their_fallback(
     monkeypatch.setenv("KART_DIFF_DEVICE", "0")
     monkeypatch.setenv("KART_DIFF_SHARDED", "0")
     want = merge_classify(*blocks)
-    monkeypatch.setenv("KART_DIFF_DEVICE", "1" if what == "merge_device" else "0")
+    monkeypatch.setenv("KART_DIFF_DEVICE", "1" if sharded == "0" else "0")
     monkeypatch.setenv("KART_DIFF_SHARDED", sharded)
     monkeypatch.setattr(broken, _boom)
     tm.enable(trace=True)
@@ -512,8 +510,10 @@ def test_merge_rungs_count_their_fallback(
     for g, w in zip(got[:3], want[:3]):
         np.testing.assert_array_equal(g, w)
     assert got[3] == want[3] and got[3]["conflicts"] == 300
-    assert fallback_counter(what) == 1
-    assert [s["args"]["backend"] for s in spans] == ["host_native"]
+    assert fallback_counter(what) == 2
+    assert [s["args"]["backend"] for s in spans] == [
+        "sharded_jax" if sharded == "1" else "device_jax"
+    ]
 
 
 def test_merge_span_names_the_backend_that_answered(monkeypatch):

@@ -75,10 +75,11 @@ class TestMergeKernel:
         for k in list(base)[110:130]:
             del theirs[k]
         a_b, o_b, t_b = _block(base), _block(ours), _block(theirs)
-        union, decision, _, _ = merge_classify(a_b, o_b, t_b)
-        ref_union, ref_decision = merge_classify_reference(a_b, o_b, t_b)
+        union, decision, presence, _ = merge_classify(a_b, o_b, t_b)
+        ref_union, ref_decision, ref_presence = merge_classify_reference(a_b, o_b, t_b)
         assert np.array_equal(union, ref_union)
         assert np.array_equal(decision, ref_decision)
+        assert np.array_equal(presence, ref_presence)
 
 
 @pytest.fixture

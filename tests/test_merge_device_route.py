@@ -1,11 +1,12 @@
-"""The one-device merge classify (ISSUE 40): ``kart diff``'s classify twice
-on resident pages and the three-way rule over the changed keys
-(``ops/merge_kernel.py merge_classify_two_diffs``), forced onto XLA-CPU as
-``tests/test_resident_pages.py`` forces the diff's route, against the
-dict-per-key oracle ``merge_classify_reference`` and the numpy twin; and a
-``kart merge`` of a small repository on every route: the device, the host
-twin, and blocks from a walk of the feature trees as before the merge read
-sidecars."""
+"""The merge classify: ``kart diff``'s classify twice through the diff's
+backend and the three-way rule over the changed keys
+(``ops/merge_kernel.py merge_classify_two_diffs``), on the host engine, on
+one device over resident pages (forced onto XLA-CPU as
+``tests/test_resident_pages.py`` forces the diff's route) and on the
+suite's virtual CPU mesh, against the dict-per-key oracle
+``merge_classify_reference``; and a ``kart merge`` of a small repository on
+every route: the device, the host engine, and blocks from a walk of the
+feature trees as before the merge read sidecars."""
 
 import importlib.util
 import os
@@ -21,9 +22,7 @@ from kart_tpu.ops.merge_kernel import (
     CONFLICT,
     KEEP_OURS,
     TAKE_THEIRS,
-    _merge_classify_np,
     merge_classify_reference,
-    merge_classify_two_diffs,
 )
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,14 +53,12 @@ def _revision(rows, name=None):
 
 
 def _expect(blocks, got):
-    """The device's answer against the oracle and the numpy twin."""
+    """An engine's answer against the oracle."""
     union, decision, presence, stats = got
-    ref_union, ref_decision = merge_classify_reference(*blocks)
+    ref_union, ref_decision, ref_presence = merge_classify_reference(*blocks)
     np.testing.assert_array_equal(union, ref_union)
     np.testing.assert_array_equal(decision, ref_decision)
-    twin_decision, twin_presence = _merge_classify_np(*blocks, ref_union)
-    np.testing.assert_array_equal(decision, twin_decision)
-    np.testing.assert_array_equal(presence, twin_presence)
+    np.testing.assert_array_equal(presence, ref_presence)
     assert decision.dtype == np.int8 and presence.dtype == np.int8
     assert union.dtype == np.int64
     assert stats == {
@@ -169,14 +166,29 @@ SHAPES = {
 
 
 @pytest.mark.parametrize("shape", list(SHAPES))
-def test_the_device_route_equals_the_reference(shape, device, monkeypatch):
+@pytest.mark.parametrize("backend", ["host_native", "device_jax", "sharded_jax"])
+def test_the_device_route_equals_the_reference(backend, shape, device, monkeypatch):
+    """Each engine the diff's ladder can pick answers the merge as the
+    oracle does, its two diffs under ``diff.classify`` spans that name it:
+    the host engine, one device over chunks of resident pages, and the
+    suite's virtual mesh over record batches of 2,048 rows a shard."""
+    from kart_tpu.diff import device_batch
+    from kart_tpu.parallel.sharded_diff import STATS
+
     monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
+    monkeypatch.setattr(device_batch, "DEVICE_BATCH_ROWS", 2_048)
+    monkeypatch.setenv("KART_DIFF_BACKEND", backend)
     blocks = [_revision(v, name) for v, name in zip(SHAPES[shape](), "aot")]
+    mesh_calls = STATS["sharded_classify_calls"]
     got = merge_classify(*blocks)
     _expect(blocks, got)
+    diffs = sum(bool(max(blocks[0].count, b.count)) for b in blocks[1:])
+    assert STATS["sharded_classify_calls"] - mesh_calls == (
+        diffs if backend == "sharded_jax" else 0
+    )
     events = tm.drain_events()
     (merge,) = [e["args"] for e in events if e["name"] == "diff.merge_classify"]
-    assert merge["backend"] == "device_jax"
+    assert merge["backend"] == backend
     assert (merge["rows_ancestor"], merge["rows_ours"], merge["rows_theirs"]) == tuple(
         b.count for b in blocks
     )
@@ -186,7 +198,7 @@ def test_the_device_route_equals_the_reference(shape, device, monkeypatch):
     )
     sides = [e["args"] for e in events if e["name"] == "diff.classify"]
     assert [s["side"] for s in sides] == ["ours", "theirs"]
-    assert all(s["backend"] == "device_jax" for s in sides)
+    assert all(s["backend"] == backend and not s["counts_only"] for s in sides)
     (combine,) = [e["args"] for e in events if e["name"] == "merge.combine"]
     changed = got[1] != KEEP_OURS
     assert combine["both"] >= int(np.sum(got[1] == CONFLICT))
@@ -204,7 +216,7 @@ def test_the_windowed_join_answers_the_merge_as_on_a_tpu(shape, device, monkeypa
     monkeypatch.setattr(diff_kernel, "CLASSIFY_CHUNK_ROWS", _CHUNK)
     monkeypatch.setattr(runtime, "default_backend", lambda: "tpu")
     blocks = [_revision(v, name) for v, name in zip(SHAPES[shape](), "aot")]
-    _expect(blocks, merge_classify_two_diffs(*blocks))
+    _expect(blocks, merge_classify(*blocks))
     overflows = tm.counters_snapshot().get(("diff.device.join_overflows", ()), 0)
     assert (overflows > 0) == (shape == "hole_in_theirs")
     kernels = [e["args"] for e in tm.drain_events() if e["name"] == "diff.device.kernel"]
@@ -621,7 +633,7 @@ DEVICE = {"KART_DIFF_DEVICE": "1", "KART_DIFF_SHARDED": "0"}
 @pytest.mark.parametrize("apply_route", ["whole_tree", "by_path"])
 def test_a_cli_merge_is_the_same_on_every_route(apply_route, merge_layer, tmp_path, monkeypatch):
     """MERGE_INDEX bytes and the merged tree are the same on the device
-    route, on the host twin and with blocks made by walking the feature
+    route, on the host engine and with blocks made by walking the feature
     trees (the parent's way); the answer is the builder's; with sidecars
     there no tree is walked. Both forms of the apply — the merged feature
     tree made whole from the merged columns, the changed paths handed to the

@@ -1,6 +1,6 @@
-"""The mesh paths reached from the CLI and the routers, and the mesh merge
-with its block-cyclic partition: identical to the single-chip and numpy
-paths. (The mesh diff's own tests are tests/test_device_batch.py.)
+"""The mesh paths reached from the CLI and the routers, the merge's two
+diffs among them: identical to the single-chip and host paths. (The mesh
+diff's own tests are tests/test_device_batch.py.)
 
 Runs on whatever devices are live; the multi-device cases skip below 2
 devices (use the virtual CPU mesh per tests/conftest.py).
@@ -13,7 +13,6 @@ import jax
 
 from kart_tpu.ops.blocks import FeatureBlock, pack_oid_hex
 from kart_tpu.parallel.sharded_diff import synthetic_block
-from kart_tpu.parallel.sharded_merge import partition_block
 
 
 def _blocks_with_edits(n=1000, n_ins=7, n_upd=11, n_del=5, seed=42):
@@ -40,24 +39,6 @@ def _blocks_with_edits(n=1000, n_ins=7, n_upd=11, n_del=5, seed=42):
     new_paths = [f"f/{k}" for k in new_keys]
     new = FeatureBlock.from_arrays(new_keys, new_oids, new_paths)
     return old, new, {"inserts": n_ins, "updates": n_upd, "deletes": n_del}
-
-
-def test_partition_block_roundtrip():
-    old, _, _ = _blocks_with_edits()
-    keys, oids, counts, src = partition_block(old, 4)
-    assert counts.sum() == old.count
-    # every shard holds only keys with its own modulus, still sorted
-    for s in range(4):
-        real = keys[s, : counts[s]]
-        assert np.all(real % 4 == s)
-        assert np.all(np.diff(real) > 0)
-        # src maps each slot back to the block row holding the same key
-        rows = src[s, : counts[s]]
-        assert np.array_equal(old.keys[rows], real)
-        assert np.all(src[s, counts[s] :] == -1)
-    # every block row appears exactly once across shards
-    all_rows = src[src >= 0]
-    assert np.array_equal(np.sort(all_rows), np.arange(old.count))
 
 
 def test_mesh_open_env_override(monkeypatch):
@@ -133,45 +114,31 @@ def _merge_blocks(n=3000, seed=9):
     return anc, ours, theirs
 
 
-def test_sharded_merge_matches_single_chip(monkeypatch):
-    """sharded_merge_classify must reproduce merge_classify exactly: same
-    global union order, decisions, presence bits, stats."""
-    from kart_tpu.diff.backend import merge_classify
-    from kart_tpu.parallel.sharded_diff import STATS
-    from kart_tpu.parallel.sharded_merge import sharded_merge_classify
-
-    if jax.device_count() < 2:
-        pytest.skip("needs >= 2 devices")
-    anc, ours, theirs = _merge_blocks()
-    monkeypatch.setenv("KART_DIFF_SHARDED", "0")  # single-chip baseline
-    union_s, dec_s, pres_s, stats_s = merge_classify(anc, ours, theirs)
-    before = STATS["sharded_merge_calls"]
-    union_m, dec_m, pres_m, stats_m = sharded_merge_classify(anc, ours, theirs)
-    assert STATS["sharded_merge_calls"] == before + 1
-    np.testing.assert_array_equal(union_m, union_s)
-    np.testing.assert_array_equal(dec_m, dec_s)
-    np.testing.assert_array_equal(pres_m, pres_s)
-    assert stats_m == stats_s
-    assert stats_m["conflicts"] > 0
-
-
-def test_merge_classify_routes_through_mesh(monkeypatch):
-    """KART_DIFF_SHARDED=1 routes merge_classify itself onto the mesh."""
+@pytest.mark.parametrize(
+    "knobs",
+    [{"KART_DIFF_BACKEND": "sharded_jax"}, {"KART_DIFF_SHARDED": "1"}],
+    ids=["backend_named", "sharded_knob"],
+)
+def test_merge_classify_routes_through_mesh(knobs, monkeypatch):
+    """The mesh backend named, or KART_DIFF_SHARDED=1: merge_classify's two
+    diffs are the mesh's record-batch classify (two calls), and the union,
+    decisions, presence bits and stats are the host engine's."""
     from kart_tpu.diff.backend import merge_classify
     from kart_tpu.parallel.sharded_diff import STATS
 
     if jax.device_count() < 2:
         pytest.skip("needs >= 2 devices")
-    anc, ours, theirs = _merge_blocks(n=1500, seed=4)
+    blocks = _merge_blocks(n=1500, seed=4)
     monkeypatch.setenv("KART_DIFF_SHARDED", "0")
-    expected = merge_classify(anc, ours, theirs)
-    monkeypatch.setenv("KART_DIFF_SHARDED", "1")
-    before = STATS["sharded_merge_calls"]
-    got = merge_classify(anc, ours, theirs)
-    assert STATS["sharded_merge_calls"] == before + 1
+    expected = merge_classify(*blocks)
+    for knob, value in knobs.items():
+        monkeypatch.setenv(knob, value)
+    before = STATS["sharded_classify_calls"]
+    got = merge_classify(*blocks)
+    assert STATS["sharded_classify_calls"] == before + 2
     for a, b in zip(got[:3], expected[:3]):
         np.testing.assert_array_equal(a, b)
-    assert got[3] == expected[3]
+    assert got[3] == expected[3] and got[3]["conflicts"] > 0
 
 
 def test_estimation_routes_through_mesh(monkeypatch):

@@ -62,9 +62,10 @@ def test_merge_classify_fallback_matches_reference(no_jax):
     ours = _block({1: _oid(1), 2: _oid(21), 3: _oid(3), 5: _oid(5)})  # edit 2, del 4, add 5
     theirs = _block({1: _oid(1), 2: _oid(22), 3: _oid(3), 4: _oid(44)})  # edit 2 (conflict), edit 4
     union, decision, presence, stats = merge_classify(anc, ours, theirs)
-    ref_union, ref_decision = merge_classify_reference(anc, ours, theirs)
+    ref_union, ref_decision, ref_presence = merge_classify_reference(anc, ours, theirs)
     np.testing.assert_array_equal(union, ref_union)
     np.testing.assert_array_equal(decision, ref_decision)
+    np.testing.assert_array_equal(presence, ref_presence)
     # 2: both edited differently -> conflict; 4: deleted vs edited -> conflict
     assert stats["conflicts"] == 2
     # presence bits: a=1, o=2, t=4; key 5 is ours-only
@@ -73,9 +74,10 @@ def test_merge_classify_fallback_matches_reference(no_jax):
 
 
 def test_merge_classify_fallback_matches_device_path(no_jax, monkeypatch):
-    """The numpy fallback must agree with the jitted kernel bit-for-bit; run
-    the same inputs through both (jit path via a fresh ready probe). The
-    small-input threshold is lowered so the second call genuinely jits."""
+    """The host engine's merge (no usable jax) must agree with the device
+    route's bit-for-bit; run the same inputs through both (device route via
+    a fresh ready probe). The small-input threshold is lowered so the
+    second call genuinely jits."""
     from kart_tpu import routing
 
     monkeypatch.setattr(routing, "DEVICE_MIN_ROWS", 0)

@@ -328,19 +328,6 @@ def test_hash_guard_compiles_under_its_own_name(one_chip, page, size, updates):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * (cap * 128 * 4 + size)
 
 
-@pytest.mark.parametrize("bucket", [1024, bucket_size(4_000_000)])
-def test_merge_classify_compiles(one_chip, bucket):
-    """The 3-way classify at a small bucket and at the 4M-row merge the
-    chip smoke runs (searchsorted joins: seconds to compile at any size)."""
-    import jax
-
-    from kart_tpu.ops.merge_kernel import _merge_classify_padded_core
-
-    side = _block_shapes(bucket, one_chip)
-    union = (_shape((bucket,), np.int64, one_chip), side[2])
-    jax.jit(_merge_classify_padded_core).lower(*side * 3, *union).compile()
-
-
 @pytest.mark.parametrize("counts_only", [False, True], ids=["classes", "counts"])
 @pytest.mark.parametrize(
     "batch_rows",
@@ -357,21 +344,6 @@ def test_record_batch_classify_compiles(mesh, batch_rows, counts_only):
     fn = make_batched_classify(mesh, counts_only)
     # arg order: old keys, old oids, new keys, new oids, old count, new count
     fn.lower(side[0], side[1], side[0], side[1], side[2], side[2]).compile()
-
-
-def test_sharded_merge_compiles(mesh):
-    """`sharded_merge_classify`'s program at the 4M-row merge: block-cyclic
-    shards of 4M/n rows each."""
-    from kart_tpu.parallel.sharded_merge import make_sharded_merge
-
-    n = int(mesh.devices.size)
-    sharded, _ = _sharded(mesh)
-    bucket = bucket_size(-(-4_000_000 // n), 256)
-    keys, oids, _ = _block_shapes(bucket, sharded, lead=(n,))
-    count = _shape((n,), np.int32, sharded)
-    make_sharded_merge(mesh).lower(
-        *(keys, oids, count) * 3, keys, count
-    ).compile()
 
 
 def test_sharded_bbox_compiles(mesh):
