@@ -14,9 +14,11 @@ cell's command is ``kart diff HEAD...<branch>``):
   next serial ids ``PK_BASE + rows ...`` (a serial pk never fills a hole),
   ``rating = pk / 2`` and the geometry of their pk in a layer grown by them.
 * ``bulk`` — one contiguous run of ``bulk_delete_frac`` of the pks deleted
-  (rows loaded together, so serial ids in one run), its start uniform in
-  the middle 80% of the key range; ``update_frac`` of the rows elsewhere
-  rewritten.
+  (rows loaded together, so serial ids in one run), its start uniform over
+  the starts in the middle 80% of the key range at which the run lies
+  strictly inside one aligned block of ``bulk_block_rows`` rows (so every seed's hole
+  lies within one key-range chunk of the one-chip classify, whose size the
+  configuration states); ``update_frac`` of the rows elsewhere rewritten.
 
 Every count is ``int(rows * frac)``, never drawn, so array shapes repeat
 across seeds. A commit that changes the key set has a feature tree and a
@@ -59,12 +61,32 @@ def churn_edits(rng, params):
     )
 
 
-def bulk_edits(rng, params):
-    """One contiguous run deleted; uniform updates outside it."""
-    n = params["rows"]
+def bulk_starts(params):
+    """Where the bulk run may start: -> (first start, number of starts) of
+    each aligned block of ``bulk_block_rows`` rows that can hold the whole
+    run strictly inside it (a run on a block's edge leaves the block's kept
+    rows one contiguous range, as a run across its edge does), and inside
+    the middle 80% of the rows."""
+    n, block = params["rows"], params["bulk_block_rows"]
     n_del = _count(params, "bulk_delete_frac")
     lo, hi = n // 10, n - n // 10 - n_del  # the run lies in the middle 80%
-    start = int(rng.integers(lo, max(hi, lo) + 1))
+    block_lo = np.arange(0, n, block, dtype=np.int64)
+    first = np.maximum(block_lo + 1, lo)
+    last = np.minimum(block_lo + block - n_del - 1, hi)
+    fits = last >= first
+    return first[fits], (last - first + 1)[fits]
+
+
+def bulk_edits(rng, params):
+    """One contiguous run deleted, strictly inside one aligned block of
+    ``bulk_block_rows`` rows, its start uniform over all such starts;
+    uniform updates outside it."""
+    n = params["rows"]
+    n_del = _count(params, "bulk_delete_frac")
+    first, counts = bulk_starts(params)
+    at = int(rng.integers(int(counts.sum())))
+    block = int(np.searchsorted(np.cumsum(counts), at, side="right"))
+    start = int(first[block] + at - (counts[:block].sum()))
     deleted = np.arange(start, start + n_del, dtype=np.int64)
     # an index into the rows outside the run, stepped over it
     outside = rng.choice(n - n_del, size=_count(params, "update_frac"), replace=False)

@@ -179,7 +179,8 @@ def run(args, work):
         base, work, config["layer"]["params"], args.seed
     )
     clock["layer_s"] = time.perf_counter() - t
-    progress(f"layer: ready ({clock['layer_s']:.1f} s), {info['n_edits']} edits")
+    counted = {k: v for k, v in info.items() if k.startswith("n_") and isinstance(v, int)}
+    progress(f"layer: ready ({clock['layer_s']:.1f} s), {counted}")
 
     # -- the first command: route, fallbacks, reference, twin -----------------
     op = load_module("ops", traffic["op"]).Op(traffic, repo_path, work)

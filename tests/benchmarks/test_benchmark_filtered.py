@@ -32,15 +32,18 @@ SEED = 2147483653  # past 32 signed bits, as the driver's are
 WINDOW = "jit__classify_mergesort_core_window_split(11)"
 SPAN_METRICS = {
     "prefilter.span_s": "diff.prefilter",
-    "prefilter.scan_s": "diff.prefilter.scan",
-    "prefilter.propagate_s": "diff.prefilter.propagate",
-    "prefilter.compact_s": "diff.prefilter.compact",
+    "prefilter.census_s": "diff.prefilter.census",
+    "prefilter.changed_s": "diff.prefilter.changed",
     "refine.span_s": "diff.refine",
 }
+#: the cell's metrics: its spans', the changed-rows route's three shares, the
+#: refine's residue and the kernel's two (the rows route's scan, propagate
+#: and compact have no cell since the census sends the cell's count to the
+#: changed-rows route)
 NEW_METRICS = [
-    *SPAN_METRICS, "prefilter.keep_share", "prefilter.scanned_block_share",
-    "refine.residue_share", "kernel.filtered_classify_s",
-    "kernel.filtered_classify_roofline",
+    *SPAN_METRICS, "prefilter.bound_share", "prefilter.changed_keep_share",
+    "prefilter.envelope_read_share", "refine.residue_share",
+    "kernel.filtered_classify_s", "kernel.filtered_classify_roofline",
 ]
 
 
@@ -115,10 +118,16 @@ def test_the_configuration_states_the_exact_count_and_what_it_cut():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "diff_count_filtered", 1
     )
+    check_manifest(MANIFEST)
+
+
+def check_manifest(manifest):
+    """The cell's metrics list it, by name and by membership: a later PR may
+    list more for it, and list another cell beside it."""
     reads = [
-        m["name"] for m in MANIFEST["per_layer"] if m.get("workloads") == [CELL]
+        m["name"] for m in manifest["per_layer"] if CELL in m.get("workloads", ())
     ]
-    assert set(NEW_METRICS) <= set(reads)  # a later PR may list more for this cell alone
+    assert set(NEW_METRICS) <= set(reads)
 
 
 # -- the builder's edit sets -------------------------------------------------------
@@ -127,34 +136,39 @@ def test_the_configuration_states_the_exact_count_and_what_it_cut():
 def test_edit_rows_are_seeded_counted_and_sorted(rows):
     layer = builder()
     params = config_params(rows)
-    picked = layer.edit_rows(params, SEED)
+    strata = layer.row_strata(params)
+    picked = layer.edit_rows(params, SEED, strata)
     assert len(picked) == max(1, int(rows * params["edit_frac"]))
     assert (np.diff(picked) > 0).all() and 0 <= picked[0] and picked[-1] < rows
     np.testing.assert_array_equal(picked, layer.edit_rows(params, SEED))
-    assert not np.array_equal(picked, layer.edit_rows(params, SEED + 1))
-    # away from the filter's edges the draw is the founding builder's
-    founding = np.sort(
-        np.random.default_rng(SEED).choice(rows, size=len(picked), replace=False)
-    )
-    np.testing.assert_array_equal(picked, founding)
+    assert not np.array_equal(picked, layer.edit_rows(params, SEED + 1, strata))
+    # away from the configuration's size, each stratum gives its expected count
+    counts = layer.expected_counts(strata, len(picked))
+    assert layer.edit_counts(params, strata) == counts
+    drawn = {name: 0 for name in counts}
+    for label in strata[picked]:
+        drawn[layer.stratum_name(label)] += 1
+    assert drawn == counts
 
 
 def test_an_edited_point_near_a_filter_edge_is_drawn_again(monkeypatch):
-    """With a clearance as wide as the layer's spacing some draws fall near
-    an edge: they are replaced, the count and the seed's other rows stay."""
+    """With a clearance as wide as the layer's spacing many rows lie near an
+    edge: none of them is edited, and the count stays."""
     layer = builder()
     params = config_params(40_000, edit_frac=0.05)
-    founding = layer.edit_rows(params, SEED)
     monkeypatch.setattr(layer, "EDGE_CLEARANCE", 0.5)
+    strata = layer.row_strata(params)
     ring = layer.filter_ring(params)
-    x, y = layer.base_layer.origins("POINT", layer.PK_BASE + founding, params["rows"])
+    x, y = layer.base_layer.origins(
+        "POINT", layer.PK_BASE + np.arange(params["rows"]), params["rows"]
+    )
     near = layer.edge_distance(ring, x, y) < 0.5
     assert near.any()
-    picked = layer.edit_rows(params, SEED)
-    assert len(picked) == len(founding) == len(set(picked.tolist()))
-    assert set(founding[~near].tolist()) <= set(picked.tolist())
-    x, y = layer.base_layer.origins("POINT", layer.PK_BASE + picked, params["rows"])
-    assert (layer.edge_distance(ring, x, y) >= 0.5).all()
+    # the rows near an edge, and only they, are never edited
+    np.testing.assert_array_equal(strata == layer.NEVER, near)
+    picked = layer.edit_rows(params, SEED, strata)
+    assert len(picked) == 2000 == len(set(picked.tolist()))
+    assert (layer.edge_distance(ring, x[picked], y[picked]) >= 0.5).all()
 
 
 def test_edge_distance_is_the_distance_to_the_nearest_segment():
@@ -165,6 +179,60 @@ def test_edge_distance_is_the_distance_to_the_nearest_segment():
     np.testing.assert_allclose(
         layer.edge_distance(square, x, y), [5.0, 1.0, 3.0, 5.0, 0.0]
     )
+
+
+def test_a_rectangle_is_on_the_edge_where_a_segment_of_the_ring_meets_it():
+    layer = builder()
+    square = np.array([(0, 0), (10, 0), (10, 10), (0, 10), (0, 0)], dtype=float)
+    # inside, outside, across an edge, a corner inside, touching an edge
+    x0 = np.array([4.0, 12.0, 9.0, -1.0, 10.0])
+    y0 = np.array([4.0, 4.0, 4.0, -1.0, 4.0])
+    hit = layer.crosses_edge(square, x0, x0 + 1, y0, y0 + 1)
+    assert hit.tolist() == [False, False, True, True, True]
+
+
+@pytest.fixture(scope="module")
+def strata_40k():
+    """Every row's stratum of a 40,000-row layer, 5% of it edited."""
+    params = config_params(40_000, edit_frac=0.05)
+    return params, builder().row_strata(params)
+
+
+def test_the_configurations_counts_are_a_uniform_draws_expected_counts(strata_40k):
+    """The counts the configuration states add up to its edits, two on the
+    edge; where the layer is the stated size the builder draws them, and a
+    layer whose expected counts differ is refused (at the configuration's
+    10M rows every run of the cell checks them)."""
+    counts = config()["layer"]["params"]["edit_strata"]["counts"]
+    assert sum(counts.values()) == 100_000
+    assert sum(c for name, c in counts.items() if "on_edge" in name) == 2
+    params, strata = strata_40k
+    layer = builder()
+    want = layer.expected_counts(strata, 2000)
+    stated = dict(params["edit_strata"], rows=40_000, counts=want)
+    assert layer.edit_counts(dict(params, edit_strata=stated), strata) == want
+    (most, _), (other, _) = sorted(want.items(), key=lambda kv: -kv[1])[:2]
+    wrong = dict(want, **{most: want[most] - 1, other: want[other] + 1})
+    with pytest.raises(ValueError):
+        layer.edit_counts(dict(params, edit_strata=dict(stated, counts=wrong)), strata)
+
+
+@pytest.mark.parametrize("seed", [SEED, 2 ** 31 + 7, 12345])
+def test_every_seed_draws_the_configurations_counts(strata_40k, seed):
+    """The same work for every seed: as many edits in the box, in the
+    polygon, in boundary census blocks and on the edge."""
+    params, strata = strata_40k
+    layer = builder()
+    picked = layer.edit_rows(params, seed, strata)
+    assert len(picked) == len(np.unique(picked)) == 2000
+    marks = strata[picked]
+    got = {name: int(np.count_nonzero(marks & bit)) for bit, name in layer.MARKS.items()}
+    counts = layer.expected_counts(strata, 2000)
+    want = {
+        name: sum(c for stratum, c in counts.items() if name in stratum.split(","))
+        for name in layer.MARKS.values()
+    }
+    assert got == want and want["in_box"] > 0 and want["in_polygon"] > 0
 
 
 # -- the reference -----------------------------------------------------------------
@@ -256,52 +324,61 @@ def test_the_program_passes_the_reference_and_the_box_count_fails_it(filtered_re
 
 # -- the metrics -------------------------------------------------------------------
 
-def command(t0, survivors=5_900_000, scanned=272, residue=3, candidates=59_000):
-    """The span events of one traced filtered count that starts at ``t0``."""
+def command(t0, survivors=58_900, read=11_128, bound=0.3379, residue=3):
+    """The span events of one traced filtered count on the changed-rows
+    route that starts at ``t0``: the census, the classify of the whole pair,
+    the rectangle test on its 200,000 changed rows, the refine of the
+    survivors."""
     return [
-        span("cli.command", t0, 0.30),
-        span("diff.prefilter", t0 + 0.04, 0.08, "cli.command", rows=10_000_000),
-        span("diff.prefilter.scan", t0 + 0.04, 0.02, "diff.prefilter",
-             rows=20_000_000, blocks=4884, blocks_scanned=scanned,
-             hits_old=survivors // 2, hits_new=survivors // 2),
-        span("diff.prefilter.propagate", t0 + 0.06, 0.01, "diff.prefilter", probed=0),
-        span("diff.prefilter.compact", t0 + 0.07, 0.05, "diff.prefilter",
-             rows=20_000_000, survivors=survivors, bytes=survivors * 28, runs=50),
-        span("diff.classify", t0 + 0.12, 0.06, "cli.command", rows=survivors // 2,
+        span("cli.command", t0, 0.08),
+        span("diff.prefilter", t0 + 0.010, 0.0006, "cli.command", rows=10_000_000),
+        span("diff.prefilter.census", t0 + 0.010, 0.0005, "diff.prefilter",
+             blocks=4884, bound_share=bound),
+        span("diff.classify", t0 + 0.011, 0.028, "cli.command", rows=10_000_000,
              backend="device_jax", counts_only=False),
-        span("diff.refine", t0 + 0.18, 0.012, "cli.command", candidates=candidates,
-             inside=37_000, outside=candidates - 37_000 - residue, residue=residue,
+        span("diff.prefilter", t0 + 0.040, 0.0045, "cli.command", rows=10_000_000),
+        span("diff.prefilter.changed", t0 + 0.040, 0.004, "diff.prefilter",
+             rows=200_000, envelopes_read=read, survivors=survivors),
+        span("diff.refine", t0 + 0.045, 0.016, "cli.command", candidates=survivors,
+             inside=37_000, outside=survivors - 37_000 - residue, residue=residue,
              blobs_read=residue),
     ]
 
 
 def traced_run():
     return {
-        "ops_events": [command(10.0), command(11.0, survivors=5_900_004, residue=5)],
-        "xla": [module(100.0 + i, 0.0055, name=WINDOW) for i in range(2)],
-        "ops_walls": [0.3, 0.3], "device_kind": "TPU v5 lite",
+        "ops_events": [
+            command(10.0),
+            command(11.0, survivors=58_904, read=11_132, bound=0.3381, residue=5),
+        ],
+        "xla": [module(100.0 + i, 0.0155, name=WINDOW) for i in range(2)],
+        "ops_walls": [0.08, 0.08], "device_kind": "TPU v5 lite",
     }
 
 
 def test_each_new_metric_reads_its_span_or_program():
     ctx = traced_run()
-    durations = {"diff.prefilter": 0.08, "diff.prefilter.scan": 0.02,
-                 "diff.prefilter.propagate": 0.01, "diff.prefilter.compact": 0.05,
-                 "diff.refine": 0.012}
+    durations = {"diff.prefilter": 0.0051, "diff.prefilter.census": 0.0005,
+                 "diff.prefilter.changed": 0.004, "diff.refine": 0.016}
     for name, source in SPAN_METRICS.items():
         assert read_metric(name, ctx) == pytest.approx(durations[source]), name
-    assert read_metric("prefilter.keep_share", ctx) == pytest.approx(
-        100.0 * (5_900_000 + 5_900_004) / 40_000_000
+    assert read_metric("prefilter.bound_share", ctx) == pytest.approx(
+        100.0 * (0.3379 + 0.3381) / 2
     )
-    assert read_metric("prefilter.scanned_block_share", ctx) == pytest.approx(
-        100.0 * 272 / 4884
+    assert read_metric("prefilter.changed_keep_share", ctx) == pytest.approx(
+        100.0 * (58_900 + 58_904) / 400_000
     )
-    assert read_metric("refine.residue_share", ctx) == pytest.approx(100.0 * 8 / 118_000)
-    assert read_metric("kernel.filtered_classify_s", ctx) == pytest.approx(0.0055)
-    # 29 B a row over both sides of the survivors at 819 GB/s, over 0.0055 s
-    least = (2_950_000 + 2_950_002) * 2 * 29 / 819e9
+    assert read_metric("prefilter.envelope_read_share", ctx) == pytest.approx(
+        100.0 * (11_128 + 11_132) / 400_000
+    )
+    assert read_metric("refine.residue_share", ctx) == pytest.approx(
+        100.0 * 8 / (58_900 + 58_904)
+    )
+    assert read_metric("kernel.filtered_classify_s", ctx) == pytest.approx(0.0155)
+    # 29 B a row over both sides of the whole pair at 819 GB/s, over 0.0155 s
+    least = 10_000_000 * 2 * 29 / 819e9
     assert read_metric("kernel.filtered_classify_roofline", ctx) == pytest.approx(
-        100.0 * least / 0.011
+        100.0 * least / 0.0155
     )
     assert read_metric("kernel.filtered_classify_roofline", ctx) < 100.0
 
@@ -328,16 +405,19 @@ def test_each_new_metric_file_names_a_reader_that_is_there_and_says_what_it_read
             spec = json.load(f)
         assert os.path.exists(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
         assert len(spec["what"]) > 40
-    readers = {"span_mean_s", "span_attr_ratio", "xla_module_s", "roofline"}
+    readers = {"span_mean_s", "span_attr_ratio", "span_attr_per_op", "xla_module_s",
+               "roofline"}
     for name in NEW_METRICS:
         with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
             assert json.load(f)["reader"] in readers  # none of them new
 
 
 def test_a_rehearsal_of_the_cell_fails_only_what_a_cpu_must(tmp_path):
-    """One traced rehearsal through run.py, as the driver runs it: every new
-    host-side metric has a value, the reference passes, and the run is
-    `correct: false` for want of a TPU and nothing else."""
+    """One traced rehearsal through the benchmark's command, with the
+    one-device route forced onto the CPU (auto routing takes the host engine
+    here, whose count takes the rows route): every new host-side metric has
+    a value, the reference passes, and the run is `correct: false` for want
+    of a TPU and for the forced route, and nothing else."""
     import subprocess
 
     root = os.path.dirname(BENCH)
@@ -345,23 +425,28 @@ def test_a_rehearsal_of_the_cell_fails_only_what_a_cpu_must(tmp_path):
         [sys.executable, *MANIFEST["command"][1:], "--workload", CELL,
          "--seed", str(SEED), "--seconds", "1", "--trace", "1", "--rows", "20000",
          "--cache-dir", str(tmp_path / "cache")],
-        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path)),
+        cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path),
+                 KART_DIFF_DEVICE="1"),
         capture_output=True, timeout=300,
     )
     assert proc.returncode == 1, proc.stderr.decode()[-2000:]
     result = json.loads(proc.stdout.decode().strip().splitlines()[-1])
     failed = {name for name, ok in result["checks"].items() if not ok}
-    # no TPU here: the host engine answers, which is not the cell's backend
-    # (and the test suite's eight virtual CPU devices are not one chip)
-    assert failed - {"device_count"} == {"not_a_rehearsal", "platform_is_tpu", "backend"}
+    # no TPU here, and a route forced (the test suite's eight virtual CPU
+    # devices are not one chip either)
+    assert failed - {"device_count"} == {"not_a_rehearsal", "platform_is_tpu",
+                                         "auto_routing"}
     assert result["correct"] is False and result["failed"] == 0
     metrics = {name: m["value"] for name, m in result["metrics"].items()}
     host_side = [n for n in NEW_METRICS if not n.startswith("kernel.")]
     assert set(host_side) <= set(metrics)
-    assert 25.0 <= metrics["prefilter.keep_share"] <= 34.0
-    assert 0 < metrics["prefilter.scanned_block_share"] <= 100.0
+    # at 20,000 rows a census block spans whole latitude bands: the bound is
+    # coarse, and at or above 9% it sent the count to the changed-rows route
+    assert 9.0 <= metrics["prefilter.bound_share"] <= 100.0
+    assert 25.0 <= metrics["prefilter.changed_keep_share"] <= 34.0
+    assert 0 < metrics["prefilter.envelope_read_share"] <= 100.0
     assert 0 <= metrics["refine.residue_share"] < 5.0
     assert metrics["prefilter.span_s"] >= (
-        metrics["prefilter.scan_s"] + metrics["prefilter.propagate_s"]
-        + metrics["prefilter.compact_s"]
+        metrics["prefilter.census_s"] + metrics["prefilter.changed_s"]
     ) * 0.999

@@ -66,7 +66,8 @@ def params(rows=3000):
 def test_the_cell_is_one_chip_on_its_own_configuration_with_its_own_traffic():
     (cell,) = [w for w in MANIFEST["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "merge_dry_run", 1)
-    assert [w["name"] for w in MANIFEST["workloads"] if w["config"] == CONFIG] == [CELL]
+    # a later PR may add another cell on this configuration (another traffic)
+    assert CELL in [w["name"] for w in MANIFEST["workloads"] if w["config"] == CONFIG]
     (entry,) = [c for c in MANIFEST["configs"] if c["name"] == CONFIG]
     assert "configs[4]" in entry["source"] and entry["reduced"] == ["sidecar_vertex_column"]
 
